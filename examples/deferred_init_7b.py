@@ -13,9 +13,6 @@ import time
 
 import jax
 
-if os.environ.get("TDX_PLATFORM"):
-    jax.config.update("jax_platforms", os.environ["TDX_PLATFORM"])
-
 import torchdistx_tpu as tdx
 from torchdistx_tpu.models import Llama
 from torchdistx_tpu.parallel import create_mesh, fsdp_shard_rule

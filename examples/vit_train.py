@@ -3,18 +3,13 @@ fine-tuning loop on synthetic data.
 
 Run on a TPU host:          python examples/vit_train.py
 Run on CPU (8 virtual):     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-                            TDX_PLATFORM=cpu python examples/vit_train.py
+                            JAX_PLATFORMS=cpu python examples/vit_train.py
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
-
-if os.environ.get("TDX_PLATFORM"):
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["TDX_PLATFORM"])
 
 import numpy as np
 import optax

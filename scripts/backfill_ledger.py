@@ -1,14 +1,12 @@
 """Backfill ``LEDGER.jsonl`` from every committed bench artifact.
 
-Normalizes the whole committed evidence trail — ``BENCH_r01..r05``,
-``BENCH_r03_local``, ``BENCH_SERVE_<CPU|TPU>.json``,
-``MULTICHIP_r01..r05``, ``CAMPAIGN.json``, ``KERNEL_ACCEPT*.json`` —
-into ``tdx-ledger-v1`` rows, attributed to the commit that landed each
-artifact (``git log -1`` sha + author time, since the old records carry
-no stamp of their own) and ordered by that time, so the perf trajectory
-is populated from PR 1 onward.  Degraded rounds (the r02 crash, the r03
-timeout, the r04/r05 wedged-relay runs) land with ``quality: degraded``
-— recorded, never a baseline.
+Normalizes the whole committed evidence trail — ``BENCH_r*.json``,
+``BENCH_SERVE_<CPU|TPU>.json``, ``MULTICHIP_r01..r05``,
+``KERNEL_ACCEPT*.json`` — into ``tdx-ledger-v1`` rows, attributed to the
+commit that landed each artifact (``git log -1`` sha + author time, since
+the old records carry no stamp of their own) and ordered by that time.
+Degraded rounds (the r03 timeout) land with ``quality: degraded`` —
+recorded, never a baseline.
 
 The live ledger is append-only; this script is the one sanctioned
 rewrite (regenerating history from the artifacts it is derived from),
@@ -37,7 +35,6 @@ ARTIFACT_GLOBS = (
     "BENCH_r*.json",
     "BENCH_SERVE_*.json",
     "MULTICHIP_r*.json",
-    "CAMPAIGN.json",
     "KERNEL_ACCEPT.json",
     "KERNEL_ACCEPT_SMOKE.json",
 )

@@ -7,7 +7,7 @@ biased backward vs the round-3 chunked-recompute backward
 layout-fixpoint warmup as bench.py's train phase.
 
 Usage (TPU):  python scripts/bench_t5_train.py [--chunked-bwd]
-Smoke (CPU):  TDX_BENCH_PLATFORM=cpu TDX_T5_MODEL=tiny TDX_BENCH_SEQ=64 \
+Smoke (CPU):  JAX_PLATFORMS=cpu TDX_T5_MODEL=tiny TDX_BENCH_SEQ=64 \
                   python scripts/bench_t5_train.py
 """
 
@@ -33,10 +33,6 @@ def main() -> None:
 
     import jax
 
-    plat = os.environ.get("TDX_BENCH_PLATFORM")
-    if plat:
-        jax.config.update("jax_platforms", plat)
-
     import jax.numpy as jnp
     import numpy as np
     from jax import lax
@@ -49,9 +45,13 @@ def main() -> None:
     from torchdistx_tpu.optimizers import anyprecision_adamw
     from torchdistx_tpu.ops import flash_attention as fa
     from torchdistx_tpu.utils.benchmarks import (
-        V5E_PEAK_BF16,
+        peak_bf16_flops,
         warm_to_steady_state,
     )
+
+    # raises on a device kind with no peak on record: approx_mfu is
+    # never computed against a default
+    peak = peak_bf16_flops(jax.devices()[0].device_kind)
 
     fa._FORCE_CHUNKED_BWD = args.chunked_bwd
 
@@ -123,7 +123,7 @@ def main() -> None:
         "warm_converged": converged,
         "tokens_per_sec": round(tokens_per_sec, 1),
         "approx_mfu": round(
-            tokens_per_sec * flops_per_token / V5E_PEAK_BF16, 4
+            tokens_per_sec * flops_per_token / peak, 4
         ),
         "final_loss": round(final, 4),
     }))

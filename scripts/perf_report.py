@@ -17,7 +17,7 @@ Usage:
   python scripts/perf_report.py                         # full trend
   python scripts/perf_report.py --metric host_syncs --platform cpu
   python scripts/perf_report.py --source bench_serve --class counter
-  python scripts/perf_report.py --ab BENCH_r01 BENCH_r03_local
+  python scripts/perf_report.py --ab RUN_A RUN_B
 """
 
 from __future__ import annotations

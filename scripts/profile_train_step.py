@@ -42,18 +42,15 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=5)
     args = ap.parse_args()
 
-    p = os.environ.get("TDX_BENCH_PLATFORM")
-    if p:
-        import jax
+    import jax
 
-        jax.config.update("jax_platforms", p)
     import numpy as np
 
     from torchdistx_tpu import obs
     from torchdistx_tpu.utils import profiling
     from torchdistx_tpu.utils.benchmarks import (
-        V5E_PEAK_BF16,
         build_train_workload,
+        peak_bf16_flops,
         warm_to_steady_state,
     )
 
@@ -79,7 +76,8 @@ def main() -> None:
         "cost_analysis"
     ):
         record["cost_analysis"] = profiling.cost_summary(
-            run, carry, peak_flops=V5E_PEAK_BF16
+            run, carry,
+            peak_flops=peak_bf16_flops(jax.devices()[0].device_kind),
         )
     print(json.dumps({"cost_analysis": record["cost_analysis"]}), flush=True)
 

@@ -48,7 +48,7 @@ from torchdistx_tpu.parallel import (
     fsdp_shard_rule,
     optimizer_state_shardings,
 )
-from torchdistx_tpu.parallel.compat import shard_map
+from jax import shard_map
 from torchdistx_tpu.trainer import Trainer
 from torchdistx_tpu.utils.failure import FailureDetector
 

@@ -84,6 +84,57 @@ class TestKernelMatchesReference:
             np.asarray(out), np.asarray(ref), rtol=_ULP, atol=_ULP
         )
 
+    @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+    @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+    def test_head_per_lane_block_layout_matches(self, paged, quantized):
+        """head_dim 128 — the real width — takes the OTHER block layout:
+        one KV head per 128-lane block of the flattened (Hkv * D) tail,
+        picked by the grid (every smaller-D case above spans the whole
+        tail and walks heads in-kernel).  Multi-block rows, GQA, and the
+        int8 one-hot scale-column select, against the jnp path."""
+        from torchdistx_tpu.serve.kv_cache import dequantize_kv, quantize_kv
+
+        rs = np.random.RandomState(128 + 2 * paged + quantized)
+        b, hq, hkv, d, ps, pp = 2, 4, 2, 128, 8, 4
+        q = jnp.asarray(rs.randn(b, 1, hq, d), jnp.float32)
+        pos = jnp.asarray([5, ps * pp - 1], jnp.int32)
+        slab = [
+            jnp.asarray(rs.randn(b, ps * pp, hkv, d), jnp.float32)
+            for _ in range(2)
+        ]
+        scales = {}
+        if quantized:
+            (slab[0], ks), (slab[1], vs) = map(quantize_kv, slab)
+            scales = dict(k_scale=ks, v_scale=vs)
+            dense = [dequantize_kv(slab[0], ks), dequantize_kv(slab[1], vs)]
+        else:
+            dense = slab
+        from torchdistx_tpu.ops.attention import _slot_attend
+
+        ref = _slot_attend(q, dense[0], dense[1], pos, None, None)
+        if paged:
+            # the same rows as pages, in a shuffled pool order
+            order = rs.permutation(b * pp)
+            tables = jnp.asarray(
+                np.argsort(order).reshape(b, pp), jnp.int32
+            )
+            to_pool = lambda c: c.reshape(b * pp, ps, *c.shape[2:])[order]
+            out = paged_decode_attention(
+                q, to_pool(slab[0]), to_pool(slab[1]), tables, pos,
+                interpret=True,
+                **{n: to_pool(x) for n, x in scales.items()},
+            )
+        else:
+            out = decode_attention(
+                q, slab[0], slab[1], pos, block_k=ps, interpret=True,
+                **scales,
+            )
+        # 128-term f32 dots and a 4-block online-softmax merge, outputs
+        # up to ~3: a few ulps at that scale (measured <= 8.4e-7)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), rtol=2e-6, atol=2e-6
+        )
+
     def test_bf16_inputs(self):
         rs = np.random.RandomState(5)
         b, hq, hkv, d, max_seq = 2, 4, 2, 8, 16
@@ -258,9 +309,13 @@ class TestPagedKernel:
             np.asarray(out), np.asarray(ref), rtol=_ULP, atol=_ULP
         )
 
-    def test_tiny_pages_fall_back_to_jnp(self):
-        """Pages below the f32 sublane height can't feed the kernel on
-        real TPUs: use_flash must quietly take the gather path."""
+    def test_tiny_pages_take_the_kernel(self):
+        """Pages below the f32 sublane height feed the kernel too: the
+        page is the K/V block's whole (page_size, D) extent, which
+        Mosaic accepts at any size (tests/test_chip_compile.py compiles
+        16; 1-4 were compiled by hand in PR 24), so there is no tiny-page
+        route to the gather path any more — multi-page rows match the
+        jnp path at the kernel's usual <= 2-ulp bar."""
         rs = np.random.RandomState(7)
         q, k, v, pools, tables, pos = _paged_case(
             rs, 2, 4, 2, 8, 4, 4, [3, 11]
@@ -271,7 +326,9 @@ class TestPagedKernel:
         out, _ = slot_cached_attention(
             q, k, v, pools, pos, use_flash=True, page_tables=tables
         )
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), rtol=_ULP, atol=_ULP
+        )
 
     def test_rejects_bad_shapes(self):
         rs = np.random.RandomState(8)

@@ -323,7 +323,7 @@ class TestRingFlash:
 
     @staticmethod
     def _ring(mesh, causal):
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchdistx_tpu.ops.attention import ring_flash_attention
@@ -381,7 +381,7 @@ class TestRingFlash:
     def test_llama_sp_flash_matches_single_device(self):
         # the model-level path: sp_axis + use_flash routes through
         # ring_flash_attention and must agree with the unsharded model
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchdistx_tpu.nn.module import functional_call
@@ -431,7 +431,7 @@ class TestUlysses:
 
     @staticmethod
     def _ulysses(mesh, causal, use_flash=False):
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchdistx_tpu.ops.attention import ulysses_attention
@@ -498,7 +498,7 @@ class TestUlysses:
             )
 
     def test_indivisible_heads_rejected(self):
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchdistx_tpu.ops.attention import ulysses_attention
@@ -517,7 +517,7 @@ class TestUlysses:
             f(q)
 
     def test_llama_sp_mode_ulysses_matches_single_device(self):
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from torchdistx_tpu.nn.module import functional_call
@@ -582,7 +582,7 @@ class TestRingFlashBias:
 
     @staticmethod
     def _ring(mesh, causal):
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchdistx_tpu.ops.attention import ring_flash_attention
@@ -651,7 +651,7 @@ class TestRingFlashBias:
             )
 
     def test_bad_bias_shape_raises(self):
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchdistx_tpu.ops.attention import ring_flash_attention

@@ -134,7 +134,7 @@ def test_model_hidden_path_matches_logits(family):
 def test_sequence_parallel_shard_map(mesh8):
     # per-shard fused CE + pmean == global CE (equal shard sizes), in
     # value and in grads — the loss SP training composes with
-    from torchdistx_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n, d, v = 512, 64, 256

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from torchdistx_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import torchdistx_tpu as tdx
@@ -208,7 +208,7 @@ class TestT5SequenceParallel:
     @pytest.mark.parametrize("use_flash", [False, True])
     @pytest.mark.slow
     def test_sp_forward_matches_unsharded(self, use_flash):
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchdistx_tpu.models import T5
@@ -246,7 +246,7 @@ class TestT5SequenceParallel:
 
     @pytest.mark.slow
     def test_sp_gradients_match_unsharded(self):
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchdistx_tpu.models import T5
@@ -310,7 +310,7 @@ class TestSequenceParallelFamilies:
 
     @staticmethod
     def _sp_forward(model_sp, params, mesh, *args):
-        from torchdistx_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from torchdistx_tpu.nn import functional_call

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from torchdistx_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import torchdistx_tpu as tdx

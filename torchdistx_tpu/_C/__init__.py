@@ -3,15 +3,18 @@
 The reference exposes its C++ core through a pybind11 extension
 (``torchdistx._C``, reference src/python/torchdistx/_C/module.cc).  pybind11
 is unavailable in this environment, so the native core speaks a flat C ABI
-and this module is the binding layer.  If the shared library is missing
-(fresh checkout), it is compiled on first import with the checked-in
-Makefile — the build is a single translation unit and takes well under a
-second.
+and this module is the binding layer.  The shared library is git-ignored:
+on first import (fresh checkout, ``git archive`` export) it is compiled
+with the checked-in Makefile — a single translation unit, about a second —
+and it is rebuilt whenever ``graph.cc`` no longer hashes to what the
+library was built from.  The hash, not the mtime, decides: a copied tree
+carries a stale library with fresh timestamps.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,12 +25,16 @@ _CSRC = os.path.join(os.path.dirname(_HERE), "csrc")
 # with `make SANITIZE=asan`) — see scripts/run-sanitized-tests.
 _LIB_NAME = os.environ.get("TDX_NATIVE_LIB", "libtdxgraph.so")
 _LIB_PATH = os.path.join(_HERE, _LIB_NAME)
+_STAMP_PATH = _LIB_PATH + ".srchash"  # what the library was built from
 
 _build_lock = threading.Lock()
 
 
 def _build() -> None:
-    cmd = ["make", "-s", "-C", _CSRC]
+    # -B: the source hash already said "rebuild"; make's mtime rule may
+    # disagree.  The Makefile links to a temporary name and renames, so a
+    # concurrent importer never sees a partial library.
+    cmd = ["make", "-s", "-B", "-C", _CSRC]
     for sanitizer in ("asan", "ubsan", "tsan"):
         if _LIB_NAME.endswith(f"-{sanitizer}.so"):
             cmd.append(f"SANITIZE={sanitizer}")
@@ -40,14 +47,38 @@ def _build() -> None:
         )
 
 
+def _source_hash():
+    """sha256 of the native sources, or None without them (an installed
+    wheel ships the built library only)."""
+    h = hashlib.sha256()
+    try:
+        for name in ("graph.cc", "Makefile"):
+            with open(os.path.join(_CSRC, name), "rb") as f:
+                h.update(f.read())
+    except FileNotFoundError:
+        return None
+    return h.hexdigest()
+
+
+def _read_stamp():
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip()
+    except FileNotFoundError:
+        return None
+
+
 def _load() -> ctypes.CDLL:
     with _build_lock:
-        src = os.path.join(_CSRC, "graph.cc")
+        want = _source_hash()
         if not os.path.exists(_LIB_PATH) or (
-            os.path.exists(src)
-            and os.path.getmtime(src) > os.path.getmtime(_LIB_PATH)
+            want is not None and _read_stamp() != want
         ):
             _build()
+            tmp = f"{_STAMP_PATH}.tmp.{os.getpid()}"
+            with open(tmp, "w") as f:
+                f.write(want or "")
+            os.replace(tmp, _STAMP_PATH)
     return ctypes.CDLL(_LIB_PATH)
 
 

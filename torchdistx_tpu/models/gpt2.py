@@ -22,7 +22,6 @@ from ..ops.attention import (
 )
 from ..obs.numerics import tap as _num_tap
 from ..ops.flash_attention import resolve_use_flash
-from ..utils.compat import axis_size
 
 __all__ = ["GPT2Config", "GPT2", "gpt2_configs"]
 
@@ -174,7 +173,7 @@ class GPT2(nn.Module):
             import jax
 
             # s is the LOCAL shard; positions are global (shard offset)
-            n = axis_size(self.cfg.sp_axis)
+            n = jax.lax.axis_size(self.cfg.sp_axis)
             if s * n > self.cfg.n_positions:
                 raise ValueError(
                     f"global sequence length {s * n} exceeds n_positions="

@@ -16,7 +16,6 @@ from .. import nn
 from ..nn import functional as F
 from ..ops.attention import cached_attention
 from ..ops.flash_attention import rel_pos_bucket, resolve_use_flash
-from ..utils.compat import axis_size
 
 __all__ = ["T5Config", "T5", "t5_configs"]
 
@@ -115,7 +114,7 @@ class T5Attention(nn.Module):
         if self.rel_bias is None:
             return None
         axis = self.cfg.sp_axis
-        n = axis_size(axis)
+        n = jax.lax.axis_size(axis)
         return self._bias(
             sq, n * sq, q_offset=jax.lax.axis_index(axis) * sq
         )

@@ -54,7 +54,7 @@ built (docs/observability.md "Cost observatory & capacity planner"):
   serve engine consults as a second admission gate.
 - :mod:`~torchdistx_tpu.obs.watchdog` — dispatch-stall deadline timer
   that dumps the flight recorder naming the in-flight program and its
-  cost card (the wedged-relay black box).
+  cost card (the hung-dispatch black box).
 
 PR 14 adds the *fleet SLO observatory* (docs/observability.md "Fleet
 tracing & SLO observatory"):

@@ -287,9 +287,9 @@ def record_collective(
     n = axis_size
     if n is None:
         try:
-            from ..utils.compat import axis_size as _axis_size
+            from jax import lax
 
-            n = int(_axis_size(axis))
+            n = int(lax.axis_size(axis))
         except Exception:
             n = None
     if n is None or n <= 0:

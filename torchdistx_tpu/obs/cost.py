@@ -8,8 +8,8 @@ bench-level aggregate, and serve admission gated on free pages with no
 idea what a dispatch's temp buffers peak at.  A **CostCard** is that
 record: XLA ``cost_analysis()`` FLOPs/bytes-accessed plus
 ``memory_analysis()`` arg/output/temp/peak bytes for ONE compiled
-program, captured via the ``utils.compat`` shims (the 0.4.37 API
-spellings drift; the peak's source is always named), tagged with the
+program, read through ``utils.compat`` (plain dicts; the peak's
+source is always named), tagged with the
 recompile watcher's scope attribution at capture time.
 
 arXiv:2112.01075 (whose ring cost model ``obs.comm`` implements) is the
@@ -99,6 +99,11 @@ class CostCard:
     peak_source: str = "unavailable"
     scope: Optional[str] = None
     platform: Optional[str] = None
+    #: Mosaic (Pallas TPU) kernels in the compiled program: the
+    #: ``tpu_custom_call`` count of its HLO.  0 on a TPU means the
+    #: program took a jnp path; interpret-mode kernels (CPU) inline into
+    #: plain HLO and also count 0.
+    pallas_calls: Optional[int] = None
     #: the analytic model's FLOP count for one execution of this program
     #: (e.g. 6N + attention-term per token x tokens per dispatch) — the
     #: numerator of ``flop_attribution``
@@ -218,6 +223,9 @@ def compute_cost_card(
         jitted = jax.jit(lambda *a, **kw: fn(*a, **kw))
     with recompile_scope(f"cost_card/{name}"):
         compiled = jitted.lower(*args, **kwargs).compile()
+    card.pallas_calls = compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"'
+    )
     ca = compat.compiled_cost_analysis(compiled)
     if ca:
         card.flops = _num(ca.get("flops"))

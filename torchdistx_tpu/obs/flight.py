@@ -2,7 +2,7 @@
 dumped atomically on failure — the training analog of PyTorch's NCCL
 flight recorder (docs/parity.md).
 
-Every past incident class here (donated-carry recompile, wedged relay,
+Every past incident class here (donated-carry recompile, hung device,
 HBM overcommit, NaN rollback) shared one property: by the time anyone
 looked, the process state that explained it was gone.  The recorder
 keeps the last ``capacity`` structured events (loss, step timings,
@@ -84,8 +84,8 @@ class FlightRecorder:
                 try:
                     self._stream.write(json.dumps(ev) + "\n")
                     # flush per event: the stream exists precisely for
-                    # runs that die without unwinding (kill -9, wedged
-                    # relay) — an unflushed buffer is a lost black box
+                    # runs that die without unwinding (kill -9, a hung
+                    # device) — an unflushed buffer is a lost black box
                     self._stream.flush()
                 except (OSError, ValueError):
                     self._stream = None  # disk gone; keep the ring alive

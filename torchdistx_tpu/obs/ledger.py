@@ -2,7 +2,7 @@
 the repo's evidence discipline.
 
 Every bench emitter in this repo already writes honest, parseable JSON
-records (bench.py, bench_serve.py, the campaign driver, the multichip
+records (bench.py, bench_serve.py, the multichip
 dryrun harvest, the kernel-acceptance sweep, flight dumps) — but until
 now nothing read them back: no normalized history, no cross-run
 comparison, no CI gate.  This module turns every artifact family into
@@ -38,7 +38,7 @@ Class semantics — the whole point of the split:
   of the same platform + fingerprint.
 
 Quality extends the existing evidence-guard honesty rules: ``degraded``
-runs (wedged relay, failed phase, partial sweep) are *recorded* — the
+runs (failed or killed phase, partial sweep) are *recorded* — the
 trajectory never lies by omission — but never become the comparison
 baseline.
 
@@ -65,7 +65,6 @@ _SOURCES = (
     "bench",
     "bench_serve",
     "multichip",
-    "campaign",
     "kernel_accept",
     "flight",
 )
@@ -666,7 +665,7 @@ def ingest_bench_record(record: dict, **kw) -> List[dict]:
         for k in ("optimizer_bytes", "optimizer_bytes_per_device",
                   "zero2_participating_bytes", "zero2_step_wire_bytes"):
             row(k, tz.get(k), "counter", zw, unit="B")
-    # always at least one row, so even an all-null wedged-relay record
+    # always at least one row, so even an all-null failed-run record
     # leaves a (degraded) mark in the trajectory
     row("bench_complete", int(complete), "counter", {"phase": "driver"})
     return rows
@@ -899,81 +898,6 @@ def ingest_flight_dump(path: str, **kw) -> List[dict]:
     ]
 
 
-def ingest_campaign_record(
-    record: dict, step_records: str = "all", **kw
-) -> List[dict]:
-    """``CAMPAIGN.json``: per-step rc/wall rows, plus each step's
-    harvested tail records delegated to the family adapters (bench_serve
-    records to the serve adapter, bench records to the bench adapter;
-    ad-hoc per-script rows — bench_generate, bench_t5_train,
-    bench_flash_attention, bench_fused_ce — have no ledger family and
-    surface only as their step's rc/wall rows).
-
-    ``step_records`` controls the delegation: ``"all"`` (backfill — the
-    committed campaign file is the only channel) or ``"failed"`` (the
-    live campaign's own ledger append: gracefully-exited sub-benches
-    already appended their rows in-process, so only killed/timed-out
-    steps — whose harvest tail is the sole surviving evidence — are
-    delegated, keeping the ledger duplicate-free)."""
-    meta = _meta(record, kw)
-    status = record.get("status")
-    rows: List[dict] = []
-    for step, res in (record.get("steps") or {}).items():
-        if not isinstance(res, dict):
-            continue
-        workload = {"step": step}
-        degraded = (
-            "skipped" in res
-            or res.get("rc") not in (0,)
-            or status in ("wedged", "started", "running")
-        )
-        quality = "degraded" if degraded else "complete"
-        if isinstance(res.get("rc"), int):
-            rows.append(
-                make_row(
-                    source="campaign",
-                    metric="step_rc",
-                    value=res["rc"],
-                    metric_class="counter",
-                    quality=quality,
-                    workload=workload,
-                    **meta,
-                )
-            )
-        if isinstance(res.get("wall_s"), (int, float)):
-            rows.append(
-                make_row(
-                    source="campaign",
-                    metric="step_wall_s",
-                    value=res["wall_s"],
-                    metric_class="timing",
-                    quality=quality,
-                    workload=workload,
-                    unit="s",
-                    **meta,
-                )
-            )
-        recs = [r for r in res.get("records") or [] if isinstance(r, dict)]
-        if recs and (step_records == "all" or res.get("rc") != 0):
-            last = recs[-1]  # the emit-after-every-phase contract: last wins
-            sub_kw = dict(kw, run_id=f"{meta['run_id']}/{step}")
-            sub_kw.setdefault("git_sha", meta.get("git_sha"))
-            sub_kw.setdefault("ts", meta.get("ts"))
-            if last.get("bench") == "serve":
-                sub = ingest_serve_record(last, **sub_kw)
-            elif "metric" in last and "extra" in last:
-                sub = ingest_bench_record(last, **sub_kw)
-            else:
-                sub = []
-            if res.get("rc") != 0:
-                # a killed/timed-out step's record can look clean up to
-                # the kill point — the step verdict overrules it
-                for r in sub:
-                    r["quality"] = "degraded"
-            rows.extend(sub)
-    return rows
-
-
 def _artifact_git_meta(path: str) -> dict:
     """Commit attribution for a COMMITTED artifact: the sha and author
     time of the commit that last touched it — what lets the backfilled
@@ -1037,8 +961,6 @@ def ingest_artifact(path: str, **kw) -> List[dict]:
         return ingest_multichip_record(record, **meta)
     if "tail" in record and "rc" in record:
         return ingest_bench_wrapper(record, **meta)
-    if "steps" in record and "status" in record:
-        return ingest_campaign_record(record, **meta)
     if "cases" in record or str(record.get("metric", "")).startswith(
         "flash_kernel"
     ):
@@ -1071,10 +993,6 @@ def append_record_rows(
             rows = ingest_serve_record(record, **kw)
         elif source == "bench":
             rows = ingest_bench_record(record, **kw)
-        elif source == "campaign":
-            # sub-benches that exited gracefully already appended their
-            # own rows; only killed steps' harvested tails are delegated
-            rows = ingest_campaign_record(record, step_records="failed", **kw)
         else:
             return 0
         return append_rows(path or default_ledger_path(), rows)

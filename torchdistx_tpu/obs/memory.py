@@ -3,8 +3,7 @@
 Two of this repo's past incident classes were silent placement bugs: an
 optimizer state that landed fully replicated because jit does not
 propagate input shardings into ``zeros_like`` outputs (CLAUDE.md /
-``parallel.fsdp.optimizer_state_shardings``), and HBM overcommit that
-wedged the relay for a whole session.  Both are *statically checkable*
+``parallel.fsdp.optimizer_state_shardings``), and HBM overcommit.  Both are *statically checkable*
 after materialization — this module is that check, machine-readable so
 ``bench.py`` and ``dryrun_multichip`` can carry it as evidence.
 

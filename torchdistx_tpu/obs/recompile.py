@@ -9,12 +9,10 @@ instead of an inference from timings.
 
 Mechanism: ``jax.monitoring`` emits a
 ``/jax/core/compile/backend_compile_duration`` duration event per
-backend compile (present on this container's jax 0.4.37; registration
-is wrapped by ``utils.compat.register_compile_listener`` against the
-version drift documented there — when the hook is unavailable,
-``RecompileWatcher.available`` is False and per-function ``_cache_size``
-deltas in ``utils.benchmarks.warm_to_steady_state`` remain the
-fallback).  Attribution is a thread-local scope stack: compiles fired
+backend compile; one module-level listener fans it out to every active
+watcher.  A program served from the persistent compilation cache emits
+no such event, so a warm cache shows as fewer compiles, which is what
+it is.  Attribution is a thread-local scope stack: compiles fired
 while a :func:`recompile_scope` label is active are counted under that
 label, everything else under ``"unattributed"``.
 ``utils.profiling.timed_annotation`` enters a scope named after its
@@ -34,7 +32,7 @@ import contextlib
 import threading
 from typing import Dict, Iterator, List, Optional
 
-from ..utils.compat import register_compile_listener
+from jax import monitoring
 
 __all__ = [
     "RecompileWatcher",
@@ -49,7 +47,7 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _tls = threading.local()
 _lock = threading.Lock()
 _watchers: List["RecompileWatcher"] = []
-_listener_state: Optional[bool] = None  # None = not yet attempted
+_listener_registered = False
 
 
 def _scope_stack() -> list:
@@ -76,7 +74,8 @@ def recompile_scope(label: str) -> Iterator[None]:
         st.pop()
 
 
-def _on_event(key: str, dur: float) -> None:
+def _on_event(key: str, dur: float, **_metadata) -> None:
+    # jax.monitoring passes event metadata (fun_name=...) by keyword
     if key != COMPILE_EVENT:
         return
     label = current_scope() or "unattributed"
@@ -85,13 +84,15 @@ def _on_event(key: str, dur: float) -> None:
             w._record(label, dur)
 
 
-def _ensure_listener() -> bool:
+def _ensure_listener() -> None:
     """Register the module's single dispatcher once (jax.monitoring has
-    no unregister — per-watcher registration would leak listeners)."""
-    global _listener_state
-    if _listener_state is None:
-        _listener_state = register_compile_listener(_on_event)
-    return _listener_state
+    no public unregister — per-watcher registration would leak
+    listeners)."""
+    global _listener_registered
+    with _lock:
+        if not _listener_registered:
+            monitoring.register_event_duration_secs_listener(_on_event)
+            _listener_registered = True
 
 
 _tracked_jits: Dict[str, object] = {}
@@ -164,7 +165,8 @@ class RecompileWatcher:
             self.install()
 
     def install(self) -> "RecompileWatcher":
-        self.available = _ensure_listener()
+        _ensure_listener()
+        self.available = True
         with _lock:
             if self not in _watchers:
                 _watchers.append(self)
@@ -205,9 +207,9 @@ class RecompileWatcher:
 
     def snapshot(self) -> dict:
         """JSON-able record: total compiles + seconds, per-label split.
-        ``available: False`` means the monitoring hook is missing on
-        this jax and every count is structurally zero — consumers must
-        treat that as "unknown", not "no compiles"."""
+        ``available: False`` means the watcher was never installed and
+        every count is structurally zero — consumers must treat that as
+        "unknown", not "no compiles"."""
         with _lock:
             return {
                 "available": self.available,

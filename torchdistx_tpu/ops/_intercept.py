@@ -101,7 +101,7 @@ _METADATA_PASSTHROUGH = {
 
 # jax.random key plumbing: never faked — keys stay real so the
 # counter-based RNG stream (utils/rng.py) keeps deferred/eager init
-# bit-identical.  On this jax (0.4.37) their INTERNALS resolve the
+# bit-identical.  Their INTERNALS resolve the
 # patched public ``jax.numpy`` (jax._src.random does ``import jax.numpy
 # as jnp``), so "not intercepting" them is not enough: a bare
 # ``PRNGKey(0)`` under the mode would have its internal ``jnp.asarray``

@@ -23,7 +23,6 @@ import numpy as np
 from jax import lax
 
 from ..obs.comm import record_collective as _record_comm
-from ..utils.compat import axis_size
 
 __all__ = [
     "multihead_attention",
@@ -373,7 +372,7 @@ def slot_cached_attention(
                     cv, v_new, page_tables, positions, ps
                 )
             new_cache = (ck, cv, cks, cvs) if quantized else (ck, cv)
-            if ps >= 8 and resolve_use_flash(use_flash):
+            if resolve_use_flash(use_flash):
                 from .decode_attention import paged_decode_attention_block
 
                 out = paged_decode_attention_block(
@@ -440,9 +439,7 @@ def slot_cached_attention(
             fvs = flat(cvs).at[rows].set(sv_new[:, 0])
             cks, cvs = fks.reshape(cks.shape), fvs.reshape(cvs.shape)
         new_cache = (ck, cv, cks, cvs) if quantized else (ck, cv)
-        # the paged kernel needs >= sublane-height pages on real TPUs;
-        # tiny pages stay on the gather path
-        if window is None and ps >= 8 and resolve_use_flash(use_flash):
+        if window is None and resolve_use_flash(use_flash):
             from .decode_attention import paged_decode_attention
 
             out = paged_decode_attention(
@@ -577,7 +574,7 @@ def ring_attention(
     parallelism).  The rotating block index selects each hop's column
     slice, so only O(S) bias per device is needed.
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -735,7 +732,7 @@ def _ring_flash_fwd(
 ):
     from .flash_attention import _flash_forward
 
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -808,7 +805,7 @@ def _ring_flash_bwd_rule(
     q, k, v, bias, out, lse = res
     from .flash_attention import _prepare_flash_bwd
 
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -942,7 +939,7 @@ def ring_flash_attention(
     if bias is not None:
         _validate_ring_bias(
             "ring_flash_attention", bias, q.shape[2], q.shape[1],
-            axis_size(axis), k.shape[1],
+            lax.axis_size(axis), k.shape[1],
         )
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
@@ -978,7 +975,7 @@ def ulysses_attention(
     works when ``hkv % n == 0``); prefer the ring for very wide-group
     GQA or head counts that don't divide.
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
     if hq % n != 0 or hkv % n != 0:

@@ -12,10 +12,21 @@ online-softmax accumulator — flash-decode, the single-query sibling of
 Layout and masking:
 
 - The slab is consumed IN ITS NATIVE LAYOUT ``(B, max_len, Hkv, D)`` —
-  no transpose of the multi-hundred-MB cache per decode step.  Grid is
-  ``(B, Hkv, n_k)`` with K/V blocks ``(block_k, D)`` sliced per
-  (slot, kv head); the trailing ``(1, D)``-tiled head slice is the price
-  of the native layout and is irrelevant next to not copying the slab.
+  no transpose of the multi-hundred-MB cache per decode step — but
+  VIEWED with its tail flattened, ``(B, max_len, Hkv * D)`` (a free
+  reshape of a contiguous array).  Mosaic tiles the last two axes of a
+  block as (sublane, lane) and refuses a block that squeezes the
+  second-to-last one, so a head cannot be picked by squeezing the
+  ``Hkv`` axis; in the flattened view a KV head is the 128-aligned lane
+  range ``[h * D, (h + 1) * D)`` and the K/V block is the plain
+  ``(block_k, D)`` tile at lane-block ``h``.  Grid is
+  ``(B, Hkv, n_k)``.  When ``D`` is not a multiple of the 128-lane
+  width (GPT-2's 64, the test models) the block spans the whole
+  ``Hkv * D`` tail instead and the kernel walks the heads with static
+  lane slices (grid ``(B, 1, n_k)``); which of the two is a function of
+  the shapes alone.  int8 scales ``(B, max_len, Hkv, 1)`` are viewed as
+  ``(B, max_len, Hkv)`` and the kernel selects its head's column with
+  an exact one-hot lane reduction.
 - GQA is folded in: the ``n_rep = Hq // Hkv`` query heads of one KV
   group ride as the ROWS of each matmul (padded up to the f32 sublane
   minimum of 8), so no repeated K/V ever materializes — the kernel
@@ -29,7 +40,8 @@ Layout and masking:
 
 ``paged_decode_attention`` is the same kernel over the serve engine's
 PAGED cache (``serve/kv_cache.py``): K/V live as per-layer page pools
-``(num_pages, page_size, Hkv, D)`` and each slot's logical row is the
+``(num_pages, page_size, Hkv, D)`` (viewed ``(num_pages, page_size,
+Hkv * D)`` the same way) and each slot's logical row is the
 chain of pages its scalar-prefetched page-table row names.  The K block
 is the page — the index map does the gather, the kernel body is shared —
 so shared-prefix pages are attended in place, never copied to a
@@ -63,7 +75,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _CompilerParams, _shrink_block
+from .flash_attention import _shrink_block
 
 __all__ = [
     "decode_attention",
@@ -74,48 +86,74 @@ __all__ = [
 
 _NEG_INF = -1e30
 _MIN_ROWS = 8  # f32 sublane minimum: GQA group rows pad up to this
+_LANES = 128  # TPU lane width: a head is its own lane block iff D % 128 == 0
 
 
 def _decode_kernel(
-    pos_ref,  # scalar prefetch: (B,) int32 per-slot visible depth
-    *refs,  # q (rows, D), k/v (block_k, D) [, k/v scales (block_k, 1)],
-    #         o (rows, D), then VMEM scratch acc (rows, D), m/l (rows, 1)
+    *refs,  # scalar prefetch: (B,) int32 per-slot BASE depth [, page table],
+    #         q (g, rows, D), k/v (block_k, g * D) [, k/v scales
+    #         (block_k, Hkv)], o (g, rows, D), then VMEM scratch
+    #         acc (g, rows, D), m/l (g, rows, 1)
+    n_prefetch: int,
     scale: float,
     block_k: int,
     n_k: int,
-    quantized: bool = False,
+    s: int,
+    n_rep: int,
+    g: int,
+    quantized: bool,
 ):
+    """One kernel body for all four families.  ``S`` query tokens per
+    slot ride as EXTRA MATMUL ROWS — row ``r`` is query token
+    ``r // n_rep`` of GQA head ``r % n_rep``, masked to its OWN depth
+    ``pos + r // n_rep`` (the kernel analogue of ``_slot_attend_block``'s
+    shifted mask; ``S == 1`` is the plain decode step and every row masks
+    at ``pos``).  The paged families differ only in their K/V index maps
+    (which block to DMA): the in-block math is position-indexed exactly
+    as in the contiguous layout, so slab and paged cannot diverge.  ``g``
+    KV heads share one K/V block (module docstring); each is an
+    independent attention over its own lane slice."""
+    pos_ref = refs[0]
+    refs = refs[n_prefetch:]
     if quantized:
         q_ref, k_ref, v_ref, ks_ref, vs_ref = refs[:5]
         o_ref, acc_ref, m_ref, l_ref = refs[5:]
     else:
         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
         ks_ref = vs_ref = None
+    d = q_ref.shape[-1]
     b = pl.program_id(0)
+    hb = pl.program_id(1)  # read here: not lowerable inside a pl.when
     kk = pl.program_id(2)
     pos = pos_ref[b]
 
-    def vblock():
-        """This K block's V rows, dequantized in VMEM when int8."""
-        v = v_ref[...].astype(jnp.float32)
-        if vs_ref is not None:
-            v = v * vs_ref[...]
-        return v
+    def kv_block(x_ref, sc_ref, j):
+        """Head ``j``'s (block_k, D) rows of this K/V block in f32.
 
-    def tile(mask_value):
-        """Masked (rows, block_k) f32 logits for this K block.
+        An int8 block dequantizes HERE — elementwise ``int8 -> f32 *
+        scale`` on the block already resident in VMEM, the exact ops the
+        jnp reference's ``dequantize_kv`` applies, so quantized
+        kernel-vs-jnp parity inherits the unquantized bounds.  The
+        head's scale column comes out of the (block_k, Hkv) scale block
+        by a one-hot lane reduction: one real term plus zeros, exact."""
+        x = x_ref[...] if g == 1 else x_ref[:, j * d:(j + 1) * d]
+        x = x.astype(jnp.float32)
+        if sc_ref is not None:
+            sc = sc_ref[...]
+            lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            x = x * jnp.sum(
+                jnp.where(lane == hb * g + j, sc, 0.0),
+                axis=-1, keepdims=True,
+            )
+        return x
 
-        int8 K dequantizes HERE — elementwise ``int8 -> f32 * scale`` on
-        the block already resident in VMEM, the exact ops the jnp
-        reference's ``dequantize_kv`` applies, so quantized kernel-vs-jnp
-        parity inherits the unquantized bounds."""
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        if ks_ref is not None:
-            k = k * ks_ref[...]
+    def tile(j):
+        """Masked (rows, block_k) f32 logits of head ``j`` for this K
+        block."""
         logits = (
             jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q_ref[j].astype(jnp.float32), kv_block(k_ref, ks_ref, j),
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             * scale
@@ -123,22 +161,32 @@ def _decode_kernel(
         cols = kk * block_k + jax.lax.broadcasted_iota(
             jnp.int32, logits.shape, 1
         )
-        return jnp.where(cols <= pos, logits, mask_value)
+        depth = pos
+        if s > 1:
+            # padded rows (row // n_rep >= s) mask like the last real
+            # token; their outputs are sliced off by the wrapper
+            row = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+            depth = pos + jnp.minimum(row // n_rep, s - 1)
+        return jnp.where(cols <= depth, logits, _NEG_INF)
+
+    def pv(p, j):
+        return jax.lax.dot_general(
+            p, kv_block(v_ref, vs_ref, j),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     if n_k == 1:
         # Single-block fast path in the jnp reference's exact op order
         # (mask, rowmax, exp, sum, divide, dot) — bit-identical to
         # slot_cached_attention's softmax in interpret mode.  No scratch
         # state: the whole visible row is here.
-        logits = tile(_NEG_INF)
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        unnorm = jnp.exp(logits - m)
-        probs = unnorm / jnp.sum(unnorm, axis=-1, keepdims=True)
-        o_ref[...] = jax.lax.dot_general(
-            probs, vblock(),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(o_ref.dtype)
+        for j in range(g):
+            logits = tile(j)
+            m = jnp.max(logits, axis=-1, keepdims=True)
+            unnorm = jnp.exp(logits - m)
+            probs = unnorm / jnp.sum(unnorm, axis=-1, keepdims=True)
+            o_ref[j] = pv(probs, j).astype(o_ref.dtype)
         return
 
     @pl.when(kk == 0)
@@ -147,24 +195,24 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # block-level pruning: blocks entirely past the slot's depth are
-    # skipped (their DMA is also clamped away by the index map)
-    @pl.when(kk * block_k <= pos)
+    # block-level pruning on the DEEPEST query row (pos + s - 1): blocks
+    # entirely past it are skipped (their DMA is also clamped away by
+    # the index map)
+    @pl.when(kk * block_k <= pos + (s - 1))
     def _compute():
-        logits = tile(_NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-        p = jnp.exp(logits - m_new)
-        correction = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * correction + jnp.sum(
-            p, axis=-1, keepdims=True
-        )
-        acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
-            p, vblock(),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+        for j in range(g):
+            logits = tile(j)
+            m_prev = m_ref[j]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(logits, axis=-1, keepdims=True)
+            )
+            p = jnp.exp(logits - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            l_ref[j] = l_ref[j] * correction + jnp.sum(
+                p, axis=-1, keepdims=True
+            )
+            acc_ref[j] = acc_ref[j] * correction + pv(p, j)
+            m_ref[j] = m_new
 
     @pl.when(kk == n_k - 1)
     def _emit():
@@ -175,20 +223,96 @@ def _decode_kernel(
         ).astype(o_ref.dtype)
 
 
-def _check_kv_scales(k_scale, v_scale, ck):
-    """Validate the optional int8-dequant scale operands (shared by all
-    four kernel wrappers).  Returns the ``quantized`` flag."""
+def _launch(
+    q, ck, cv, k_scale, v_scale, prefetch, kv_index, *,
+    block_k, n_k, scale, interpret,
+):
+    """Shared wrapper of the four families.  ``ck``/``cv``: the slab
+    (B, max_len, Hkv, D) or the pools (num_pages, page_size, Hkv, D).
+    ``kv_index(bb, kk, *prefetch_refs) -> (lead, row_block)`` names the
+    K/V block grid step ``(bb, ·, kk)`` reads; ``prefetch`` are the
+    scalar-prefetch operands, per-slot base depths first."""
+    b, s, hq, d = q.shape
+    hkv = ck.shape[2]
+    if hq % hkv != 0:
+        raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
-    if k_scale is None:
-        return False
-    want = ck.shape[:3] + (1,)
-    if k_scale.shape != want or v_scale.shape != want:
-        raise ValueError(
-            f"kv scale shapes {k_scale.shape}/{v_scale.shape} != "
-            f"cache rows + trailing 1 {want}"
-        )
-    return True
+    quantized = k_scale is not None
+    if quantized:
+        want = ck.shape[:3] + (1,)
+        if k_scale.shape != want or v_scale.shape != want:
+            raise ValueError(
+                f"kv scale shapes {k_scale.shape}/{v_scale.shape} != "
+                f"cache rows + trailing 1 {want}"
+            )
+    if interpret is None:
+        interpret = jax.devices()[0].platform != "tpu"
+    n_rep = hq // hkv
+    # heads per K/V block: one where a head is a whole number of lane
+    # tiles, else the whole tail (module docstring)
+    g = 1 if d % _LANES == 0 else hkv
+
+    # fold (B, S, Hq, D) into (B, Hkv, rows, D): S tokens x n_rep GQA
+    # heads per KV group, padded up to the f32 sublane minimum
+    real = s * n_rep
+    rows = -(-real // _MIN_ROWS) * _MIN_ROWS
+    qg = q.reshape(b, s, hkv, n_rep, d).transpose(0, 2, 1, 3, 4)
+    qg = qg.reshape(b, hkv, real, d)
+    if rows != real:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - real), (0, 0)))
+
+    def q_index(bb, h, kk, *_):
+        return (bb, h, 0, 0)
+
+    def data_index(bb, h, kk, *pf):
+        return (*kv_index(bb, kk, *pf), h)
+
+    def scale_index(bb, h, kk, *pf):
+        return (*kv_index(bb, kk, *pf), 0)
+
+    flat = lambda c: c.reshape(*c.shape[:2], -1)  # noqa: E731
+    in_specs = [
+        pl.BlockSpec((None, g, rows, d), q_index),
+        pl.BlockSpec((None, block_k, g * d), data_index),
+        pl.BlockSpec((None, block_k, g * d), data_index),
+    ]
+    operands = [qg, flat(ck), flat(cv)]
+    if quantized:
+        in_specs += [pl.BlockSpec((None, block_k, hkv), scale_index)] * 2
+        operands += [flat(k_scale), flat(v_scale)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, hkv // g, n_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, g, rows, d), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((g, rows, d), jnp.float32),
+            pltpu.VMEM((g, rows, 1), jnp.float32),
+            pltpu.VMEM((g, rows, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _decode_kernel, n_prefetch=len(prefetch), scale=(
+                scale if scale is not None else 1.0 / math.sqrt(d)
+            ),
+            block_k=block_k, n_k=n_k, s=s, n_rep=n_rep, g=g,
+            quantized=quantized,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(*prefetch, *operands)
+    return (
+        out[:, :, :real, :]
+        .reshape(b, hkv, s, n_rep, d)
+        .transpose(0, 2, 1, 3, 4)
+        .reshape(b, s, hq, d)
+    )
 
 
 def decode_attention(
@@ -219,201 +343,19 @@ def decode_attention(
 
     **int8 cache** (``kv_dtype="int8"``): pass the f32 per-row per-head
     scales as ``k_scale``/``v_scale`` of shape (B, max_len, Hkv, 1) —
-    they ride the SAME index map as their data (one (block_k, 1) scale
-    block per K/V block, clamped together), and the kernel dequantizes
-    each block in VMEM before Q·K / P·V, which stay f32.  HBM traffic
-    per step is the int8 block plus a 1/D-sized scale column — the
-    halved-bytes contract the cost cards price.
+    they ride the SAME row-block index as their data (one
+    (block_k, Hkv) scale block per K/V block, clamped together), and
+    the kernel dequantizes each block in VMEM before Q·K / P·V, which
+    stay f32.  HBM traffic per step is the int8 block plus a 1/D-sized
+    scale column — the halved-bytes contract the cost cards price.
     """
-    b, s, hq, d = q.shape
-    if s != 1:
-        raise ValueError(f"decode_attention takes one token per slot, got S={s}")
-    max_len, hkv = ck.shape[1], ck.shape[2]
-    if hq % hkv != 0:
-        raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
-    quantized = _check_kv_scales(k_scale, v_scale, ck)
-    n_rep = hq // hkv
-    scale_ = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_k = _shrink_block(block_k, max_len)
-    n_k = max_len // block_k
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-
-    # GQA group rows, padded to a sublane multiple: (B, Hkv, rows, D)
-    rows = -(-n_rep // _MIN_ROWS) * _MIN_ROWS
-    qg = q.reshape(b, hkv, n_rep, d)
-    if rows != n_rep:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - n_rep), (0, 0)))
-    positions = positions.astype(jnp.int32)
-
-    def kv_index(bb, h, kk, pos_ref):
-        # clamp blocks past the slot's depth onto its last visible block:
-        # Pallas skips the DMA when the mapped block index is unchanged,
-        # so pruned grid steps move no bytes
-        return (bb, jnp.minimum(kk, pos_ref[bb] // block_k), h, 0)
-
-    in_specs = [
-        pl.BlockSpec(
-            (None, None, rows, d), lambda bb, h, kk, pos_ref: (bb, h, 0, 0)
-        ),
-        pl.BlockSpec((None, block_k, None, d), kv_index),
-        pl.BlockSpec((None, block_k, None, d), kv_index),
-    ]
-    operands = [qg, ck, cv]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((None, block_k, None, 1), kv_index),
-            pl.BlockSpec((None, block_k, None, 1), kv_index),
-        ]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, n_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (None, None, rows, d), lambda bb, h, kk, pos_ref: (bb, h, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _decode_kernel, scale=scale_, block_k=block_k, n_k=n_k,
-            quantized=quantized,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(positions, *operands)
-    return out[:, :, :n_rep, :].reshape(b, 1, hq, d)
-
-
-def _decode_block_kernel(
-    pos_ref,  # scalar prefetch: (B,) int32 per-slot BASE depth
-    *refs,  # q (rows, D), k/v (block_k, D) [, k/v scales (block_k, 1)],
-    #         o (rows, D), then VMEM scratch
-    scale: float,
-    block_k: int,
-    n_k: int,
-    s: int,
-    n_rep: int,
-    quantized: bool = False,
-):
-    """Speculative-verify sibling of ``_decode_kernel``: S > 1 candidate
-    tokens per slot ride as EXTRA MATMUL ROWS — row ``r`` is query token
-    ``r // n_rep`` of GQA head ``r % n_rep``, masked to its OWN depth
-    ``pos + r // n_rep``.  Same single-block exact-op-order fast path and
-    multi-block online-softmax merge as the one-token kernel; the only
-    new math is the per-row depth offset in the visibility mask (the
-    kernel analogue of ``_slot_attend_block``'s shifted mask).  int8
-    dequant is per K/V block in VMEM, as in ``_decode_kernel``."""
-    if quantized:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref = refs[:5]
-        o_ref, acc_ref, m_ref, l_ref = refs[5:]
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    kk = pl.program_id(2)
-    pos = pos_ref[b]
-
-    def vblock():
-        v = v_ref[...].astype(jnp.float32)
-        if vs_ref is not None:
-            v = v * vs_ref[...]
-        return v
-
-    def tile(mask_value):
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        if ks_ref is not None:
-            k = k * ks_ref[...]
-        logits = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
+    if q.shape[1] != 1:
+        raise ValueError(
+            f"decode_attention takes one token per slot, got S={q.shape[1]}"
         )
-        row = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
-        cols = kk * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, logits.shape, 1
-        )
-        # padded rows (row // n_rep >= s) mask like the last real token;
-        # their outputs are sliced off by the wrapper
-        depth = pos + jnp.minimum(row // n_rep, s - 1)
-        return jnp.where(cols <= depth, logits, mask_value)
-
-    if n_k == 1:
-        logits = tile(_NEG_INF)
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        unnorm = jnp.exp(logits - m)
-        probs = unnorm / jnp.sum(unnorm, axis=-1, keepdims=True)
-        o_ref[...] = jax.lax.dot_general(
-            probs, vblock(),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(o_ref.dtype)
-        return
-
-    @pl.when(kk == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # prune on the DEEPEST query row of the block: pos + s - 1
-    @pl.when(kk * block_k <= pos + (s - 1))
-    def _compute():
-        logits = tile(_NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-        p = jnp.exp(logits - m_new)
-        correction = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * correction + jnp.sum(
-            p, axis=-1, keepdims=True
-        )
-        acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
-            p, vblock(),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
-
-    @pl.when(kk == n_k - 1)
-    def _emit():
-        o_ref[...] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
-
-
-def _block_rows(q: jax.Array, hkv: int):
-    """Fold (B, S, Hq, D) into the block kernels' (B, Hkv, rows, D) row
-    layout — S tokens x n_rep GQA heads per KV group, padded up to the
-    f32 sublane minimum — and return the layout metadata."""
-    b, s, hq, d = q.shape
-    n_rep = hq // hkv
-    real = s * n_rep
-    rows = -(-real // _MIN_ROWS) * _MIN_ROWS
-    qg = q.reshape(b, s, hkv, n_rep, d).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(b, hkv, real, d)
-    if rows != real:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - real), (0, 0)))
-    return qg, rows, real, n_rep
-
-
-def _block_unfold(out: jax.Array, b, s, hq, d, hkv, n_rep, real):
-    return (
-        out[:, :, :real, :]
-        .reshape(b, hkv, s, n_rep, d)
-        .transpose(0, 2, 1, 3, 4)
-        .reshape(b, s, hq, d)
+    return decode_attention_block(
+        q, ck, cv, positions, scale=scale, block_k=block_k,
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale,
     )
 
 
@@ -440,184 +382,23 @@ def decode_attention_block(
     to the sublane minimum), so the verify costs ONE kernel launch with
     a slightly taller matmul instead of S launches — the whole point of
     speculation.  The DMA clamp and block pruning use the block's
-    deepest row ``positions[b] + S - 1``.  The one-token kernel
-    (:func:`decode_attention`) is untouched; its S == 1 exactness
-    contract is pinned separately.  ``k_scale``/``v_scale``: int8-cache
-    dequant scales, exactly as in :func:`decode_attention`.
+    deepest row ``positions[b] + S - 1`` (blocks past it re-map onto the
+    last visible one: Pallas skips the DMA when the mapped block index
+    is unchanged, so pruned grid steps move no bytes).
+    ``k_scale``/``v_scale``: int8-cache dequant scales, exactly as in
+    :func:`decode_attention`.
     """
-    b, s, hq, d = q.shape
-    max_len, hkv = ck.shape[1], ck.shape[2]
-    if hq % hkv != 0:
-        raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
-    quantized = _check_kv_scales(k_scale, v_scale, ck)
-    scale_ = scale if scale is not None else 1.0 / math.sqrt(d)
+    s, max_len = q.shape[1], ck.shape[1]
     block_k = _shrink_block(block_k, max_len)
-    n_k = max_len // block_k
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
 
-    qg, rows, real, n_rep = _block_rows(q, hkv)
-    positions = positions.astype(jnp.int32)
-
-    def kv_index(bb, h, kk, pos_ref):
+    def kv_index(bb, kk, pos_ref):
         last = jnp.minimum(pos_ref[bb] + (s - 1), max_len - 1) // block_k
-        return (bb, jnp.minimum(kk, last), h, 0)
+        return (bb, jnp.minimum(kk, last))
 
-    in_specs = [
-        pl.BlockSpec(
-            (None, None, rows, d), lambda bb, h, kk, pos_ref: (bb, h, 0, 0)
-        ),
-        pl.BlockSpec((None, block_k, None, d), kv_index),
-        pl.BlockSpec((None, block_k, None, d), kv_index),
-    ]
-    operands = [qg, ck, cv]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((None, block_k, None, 1), kv_index),
-            pl.BlockSpec((None, block_k, None, 1), kv_index),
-        ]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, n_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (None, None, rows, d), lambda bb, h, kk, pos_ref: (bb, h, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _decode_block_kernel,
-            scale=scale_, block_k=block_k, n_k=n_k, s=s, n_rep=n_rep,
-            quantized=quantized,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+    return _launch(
+        q, ck, cv, k_scale, v_scale, [positions.astype(jnp.int32)],
+        kv_index, block_k=block_k, n_k=max_len // block_k, scale=scale,
         interpret=interpret,
-    )(positions, *operands)
-    return _block_unfold(out, b, s, hq, d, hkv, n_rep, real)
-
-
-def _paged_decode_block_kernel(
-    pos_ref, pt_ref, *refs, scale, block_k, n_k, s, n_rep, quantized=False
-):
-    """Paged twin of ``_decode_block_kernel`` — as with the one-token
-    pair, the page table lives entirely in the K/V index maps and the
-    in-block math is shared."""
-    del pt_ref
-    _decode_block_kernel(
-        pos_ref, *refs, scale=scale, block_k=block_k, n_k=n_k, s=s,
-        n_rep=n_rep, quantized=quantized,
-    )
-
-
-def paged_decode_attention_block(
-    q: jax.Array,
-    ck: jax.Array,
-    cv: jax.Array,
-    page_tables: jax.Array,
-    positions: jax.Array,
-    *,
-    scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Paged multi-token decode attention: :func:`decode_attention_block`
-    over the page pools, gathered page-by-page through the
-    scalar-prefetched table exactly like :func:`paged_decode_attention`
-    (block == page; pruning and the DMA clamp run in TABLE space on the
-    block's deepest row ``positions[b] + S - 1``).  ``k_scale``/
-    ``v_scale``: int8-cache dequant scales of shape (num_pages,
-    page_size, Hkv, 1), gathered through the same table."""
-    b, s, hq, d = q.shape
-    ps, hkv = ck.shape[1], ck.shape[2]
-    if hq % hkv != 0:
-        raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
-    if page_tables.shape[0] != b:
-        raise ValueError(
-            f"page_tables rows {page_tables.shape[0]} != batch {b}"
-        )
-    quantized = _check_kv_scales(k_scale, v_scale, ck)
-    pp = page_tables.shape[1]
-    scale_ = scale if scale is not None else 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-
-    qg, rows, real, n_rep = _block_rows(q, hkv)
-    positions = positions.astype(jnp.int32)
-    pt_flat = page_tables.astype(jnp.int32).reshape(-1)
-
-    def kv_index(bb, h, kk, pos_ref, pt_ref):
-        last = jnp.minimum(pos_ref[bb] + (s - 1), pp * ps - 1) // ps
-        page = pt_ref[bb * pp + jnp.minimum(kk, last)]
-        return (page, 0, h, 0)
-
-    in_specs = [
-        pl.BlockSpec(
-            (None, None, rows, d),
-            lambda bb, h, kk, pos_ref, pt_ref: (bb, h, 0, 0),
-        ),
-        pl.BlockSpec((None, ps, None, d), kv_index),
-        pl.BlockSpec((None, ps, None, d), kv_index),
-    ]
-    operands = [qg, ck, cv]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((None, ps, None, 1), kv_index),
-            pl.BlockSpec((None, ps, None, 1), kv_index),
-        ]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, pp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (None, None, rows, d),
-            lambda bb, h, kk, pos_ref, pt_ref: (bb, h, 0, 0),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_decode_block_kernel,
-            scale=scale_, block_k=ps, n_k=pp, s=s, n_rep=n_rep,
-            quantized=quantized,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(positions, pt_flat, *operands)
-    return _block_unfold(out, b, s, hq, d, hkv, n_rep, real)
-
-
-def _paged_decode_kernel(
-    pos_ref, pt_ref, *refs, scale, block_k, n_k, quantized=False
-):
-    """The paged grid's kernel body IS the slot kernel's: the page table
-    is consumed entirely by the K/V index maps (which block to DMA); the
-    in-block math — masking against ``pos``, online softmax, GQA rows —
-    is position-indexed exactly as in the contiguous layout, so the two
-    kernels cannot diverge."""
-    del pt_ref
-    _decode_kernel(
-        pos_ref, *refs, scale=scale, block_k=block_k, n_k=n_k,
-        quantized=quantized,
     )
 
 
@@ -658,79 +439,53 @@ def paged_decode_attention(
     int8-cache dequant scales of shape (num_pages, page_size, Hkv, 1),
     gathered through the same table as their pages.
     """
-    b, s, hq, d = q.shape
-    if s != 1:
+    if q.shape[1] != 1:
         raise ValueError(
-            f"paged_decode_attention takes one token per slot, got S={s}"
+            f"paged_decode_attention takes one token per slot, "
+            f"got S={q.shape[1]}"
         )
-    ps, hkv = ck.shape[1], ck.shape[2]
-    if hq % hkv != 0:
-        raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
-    if page_tables.shape[0] != b:
-        raise ValueError(
-            f"page_tables rows {page_tables.shape[0]} != batch {b}"
-        )
-    quantized = _check_kv_scales(k_scale, v_scale, ck)
-    pp = page_tables.shape[1]
-    n_rep = hq // hkv
-    scale_ = scale if scale is not None else 1.0 / math.sqrt(d)
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-
-    rows = -(-n_rep // _MIN_ROWS) * _MIN_ROWS
-    qg = q.reshape(b, hkv, n_rep, d)
-    if rows != n_rep:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - n_rep), (0, 0)))
-    positions = positions.astype(jnp.int32)
-    # flattened for SMEM scalar prefetch: entry b*pp + kk
-    pt_flat = page_tables.astype(jnp.int32).reshape(-1)
-
-    def kv_index(bb, h, kk, pos_ref, pt_ref):
-        # table-space clamp: blocks past the slot's depth re-read its
-        # last visible page — an unchanged mapped block, so Pallas skips
-        # the DMA (the paged twin of the slot kernel's row clamp)
-        page = pt_ref[bb * pp + jnp.minimum(kk, pos_ref[bb] // ps)]
-        return (page, 0, h, 0)
-
-    in_specs = [
-        pl.BlockSpec(
-            (None, None, rows, d),
-            lambda bb, h, kk, pos_ref, pt_ref: (bb, h, 0, 0),
-        ),
-        pl.BlockSpec((None, ps, None, d), kv_index),
-        pl.BlockSpec((None, ps, None, d), kv_index),
-    ]
-    operands = [qg, ck, cv]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((None, ps, None, 1), kv_index),
-            pl.BlockSpec((None, ps, None, 1), kv_index),
-        ]
-        operands += [k_scale, v_scale]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, pp),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (None, None, rows, d),
-            lambda bb, h, kk, pos_ref, pt_ref: (bb, h, 0, 0),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-        ],
+    return paged_decode_attention_block(
+        q, ck, cv, page_tables, positions, scale=scale,
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale,
     )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel, scale=scale_, block_k=ps, n_k=pp,
-            quantized=quantized,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(positions, pt_flat, *operands)
-    return out[:, :, :n_rep, :].reshape(b, 1, hq, d)
+
+
+def paged_decode_attention_block(
+    q: jax.Array,
+    ck: jax.Array,
+    cv: jax.Array,
+    page_tables: jax.Array,
+    positions: jax.Array,
+    *,
+    scale: Optional[float] = None,
+    interpret: Optional[bool] = None,
+    k_scale: Optional[jax.Array] = None,
+    v_scale: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Paged multi-token decode attention: :func:`decode_attention_block`
+    over the page pools, gathered page-by-page through the
+    scalar-prefetched table exactly like :func:`paged_decode_attention`
+    (block == page; pruning and the DMA clamp run in TABLE space on the
+    block's deepest row ``positions[b] + S - 1``).  ``k_scale``/
+    ``v_scale``: int8-cache dequant scales of shape (num_pages,
+    page_size, Hkv, 1), gathered through the same table."""
+    s, ps = q.shape[1], ck.shape[1]
+    if page_tables.shape[0] != q.shape[0]:
+        raise ValueError(
+            f"page_tables rows {page_tables.shape[0]} != batch {q.shape[0]}"
+        )
+    pp = page_tables.shape[1]
+
+    def kv_index(bb, kk, pos_ref, pt_ref):
+        last = jnp.minimum(pos_ref[bb] + (s - 1), pp * ps - 1) // ps
+        return (pt_ref[bb * pp + jnp.minimum(kk, last)], 0)
+
+    return _launch(
+        q, ck, cv, k_scale, v_scale,
+        # the table is flattened for SMEM scalar prefetch: entry b*pp + kk
+        [
+            positions.astype(jnp.int32),
+            page_tables.astype(jnp.int32).reshape(-1),
+        ],
+        kv_index, block_k=ps, n_k=pp, scale=scale, interpret=interpret,
+    )

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs.comm import record_collective as _record
-from .compat import axis_size as _axis_size
 
 __all__ = [
     "all_reduce",
@@ -135,7 +134,7 @@ def exchange(
 
 def shift(tree: Any, axis: str, offset: int = 1) -> Any:
     """Ring shift by ``offset`` (the ring-collective building block)."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     _record("shift", axis, tree, axis_size=n, senders=n)
     perm = [(i, (i + offset) % n) for i in range(n)]
     return jax.tree_util.tree_map(lambda x: lax.ppermute(x, axis, perm), tree)
@@ -147,7 +146,7 @@ def all_gather(tree: Any, axis: str, tiled_axis: int = 0) -> Any:
     if current_comm_profile() is not None:
         # payload is the GATHERED size (audit convention, obs/comm.py);
         # the operand here is the local shard
-        n = _axis_size(axis)
+        n = lax.axis_size(axis)
         _record(
             "all_gather", axis,
             payload_bytes=tree_bytes(tree) * n, axis_size=n,
@@ -223,4 +222,4 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str) -> int:
-    return _axis_size(axis)
+    return lax.axis_size(axis)

@@ -36,7 +36,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..obs.comm import record_collective as _record_comm, tree_bytes as _leaf_bytes
-from .compat import shard_map
+from jax import shard_map
 
 from .comm_hooks import DefaultState, Hook, HookContext, allreduce_hook
 

@@ -61,7 +61,7 @@ import numpy as np
 from jax import lax
 
 from ..obs.comm import record_collective as _record_comm, tree_bytes as _tree_bytes
-from .compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [

@@ -140,8 +140,9 @@ class Trainer:
         # so after one step it holds the per-step analytic comm plan
         self.comm_profile = CommProfile()
         # MFU: tokens/sec * flops/token / peak; only reported when the
-        # caller supplies the model's flops_per_token (and optionally the
-        # chip peak — default v5e bf16)
+        # caller supplies the model's flops_per_token and a peak is known
+        # (given here, or on record for the running device kind in
+        # utils.benchmarks.PEAK_BF16_FLOPS)
         self.flops_per_token = flops_per_token
         self.peak_flops = peak_flops
         # goodput accounting (productive vs compile/checkpoint/rollback
@@ -526,14 +527,18 @@ class Trainer:
         sps = self.metrics["steps_per_sec"]
         peak = self.peak_flops
         if peak is None:
-            from .utils.benchmarks import V5E_PEAK_BF16 as peak
+            # the running chip's peak, or no utilization at all: a device
+            # kind without a peak on record (the CPU mesh) reports none
+            from .utils.benchmarks import PEAK_BF16_FLOPS
+
+            peak = PEAK_BF16_FLOPS.get(jax.devices()[0].device_kind)
         if sps and self.tokens_per_batch:
             tps = sps * self.tokens_per_batch
             self.metrics["tokens_per_sec"] = tps
-            if self.flops_per_token:
+            if self.flops_per_token and peak:
                 self.metrics["mfu"] = tps * self.flops_per_token / peak
         card = self.cost_card
-        if card is not None and card.flops and sps:
+        if card is not None and card.flops and sps and peak:
             # the XLA-counted sibling of `mfu`: per-window measured
             # throughput against what the compiler actually built, not
             # the paper formula — and their ratio as the cost-model

@@ -1,141 +1,46 @@
-"""jax API drift shims.
+"""Private or shape-drifting jax introspection, read in one place.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to the ``jax``
-top level, its replication-check kwarg was renamed ``check_rep`` ->
-``check_vma``, and ``lax.axis_size`` grew out of ``core.axis_frame`` in
-the same window.  Every call site in this repo (library, tests, examples,
-driver) writes the NEW spelling and imports the wrapper from here (or via
-the ``parallel.compat`` re-export), so the whole codebase tracks one jax
-version boundary in one place.
-
-The observability layer adds two more drift-prone surfaces tracked
-here: the private jit ``_cache_size`` introspection
-(:func:`jit_cache_size`) and the ``jax.monitoring`` compile-event hook
-(:func:`register_compile_listener`) behind ``obs.recompile``.  The
-persistent decode loop adds the host-callback pair
-(:func:`get_io_callback` / :func:`get_debug_callback`) — availability
-probes returning None on drifted jax, with the engine falling back to
-its pure ring-drain path when both are absent.  The cost observatory
-(``obs.cost``) adds the compiled-executable introspection pair
-(:func:`compiled_cost_analysis` / :func:`compiled_memory_analysis`):
-``Compiled.cost_analysis()`` has already flipped between returning a
-list-of-dicts and a bare dict across jax versions, and
-``memory_analysis()`` returns a ``CompiledMemoryStats`` whose
-attribute set drifts (this container's 0.4.37 has
-``argument/output/temp/alias_size_in_bytes`` but NO peak field —
-newer jaxlibs add ``peak_memory_in_bytes``), so both are normalized
-to plain dicts here and the peak's SOURCE is always named.
-
-Lives under ``utils`` so leaf consumers (``ops.attention``, the model
-forwards) can use ``axis_size`` without importing the parallel package —
-``parallel/__init__`` eagerly pulls in fsdp/pp/tp/optax, which is both
-heavyweight for kernel-only imports and a circular-import trap.
+Three surfaces of the installed jax (0.9.0) that are not public API or
+whose return shape is awkward, each with exactly one reader here:
+the private jit ``_cache_size`` (:func:`jit_cache_size`) and the
+compiled-executable pair behind ``obs.cost``
+(:func:`compiled_cost_analysis` / :func:`compiled_memory_analysis`,
+normalized to plain dicts with the peak's SOURCE always named).
+Everything else — ``jax.shard_map``, ``lax.axis_size``,
+``jax.experimental.io_callback``, ``jax.monitoring`` — is called
+directly at its use site.
 """
 
 from __future__ import annotations
 
-from jax import lax
-
-try:
-    from jax import shard_map as _shard_map
-
-    _LEGACY_KW = False
-except ImportError:  # pre-rename jax: experimental namespace, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _LEGACY_KW = True
-
 __all__ = [
-    "shard_map",
-    "axis_size",
     "jit_cache_size",
-    "register_compile_listener",
-    "get_io_callback",
-    "get_debug_callback",
     "compiled_cost_analysis",
     "compiled_memory_analysis",
 ]
 
 
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` with the modern ``check_vma=`` kwarg accepted on
-    older jax (mapped onto ``check_rep=``)."""
-    if _LEGACY_KW and "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(f, **kwargs)
-
-
-def axis_size(axis) -> int:
-    """``lax.axis_size`` (static size of a named mapped axis), with the
-    pre-0.4.3x fallback where ``core.axis_frame(name)`` returns it."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    from jax import core
-
-    return core.axis_frame(axis)
-
-
 def jit_cache_size(fn):
-    """Compiled-executable count behind a jitted callable, or None.
+    """Compiled-executable count behind a jitted callable, or None for a
+    callable that is not a jit wrapper.
 
-    ``_cache_size`` is a private jax API that has already moved once;
-    every consumer (``ServeEngine.num_compiled_programs``,
-    ``utils.benchmarks.warm_to_steady_state``, the recompile watcher's
-    fallback path) reads it through here so the next rename is a
-    one-line fix.  None means "unknown", never "zero" — callers must
-    fall back to another steadiness signal, not assume no compiles."""
+    ``_cache_size`` is a private jax API; every consumer
+    (``ServeEngine.num_compiled_programs``,
+    ``utils.benchmarks.warm_to_steady_state``, the jit-cache metrics
+    collector) reads it through here.  None means "unknown", never
+    "zero" — callers must fall back to another steadiness signal, not
+    assume no compiles."""
     cache_size = getattr(fn, "_cache_size", None)
     if cache_size is None:
         return None
-    try:
-        return int(cache_size())
-    except Exception:
-        return None
-
-
-def get_io_callback():
-    """``jax.experimental.io_callback`` or None when this jax lacks it.
-
-    ``io_callback`` has lived in ``jax.experimental`` since 0.4.x but is
-    still export-drift-prone (this container pins 0.4.37; newer jax may
-    promote or rename it).  The persistent decode loop
-    (``serve.engine``) uses it only for the OPTIONAL token-streaming
-    tail — None means "stream unavailable", and every consumer must
-    fall back to the pure ring-drain path, never error."""
-    try:
-        from jax.experimental import io_callback
-    except ImportError:
-        return None
-    return io_callback
-
-
-def get_debug_callback():
-    """``jax.debug.callback`` or None.  The streaming tail's second
-    choice (debug effects are the most control-flow-tolerant callback
-    lowering); same None-means-fall-back-to-drain contract as
-    :func:`get_io_callback`."""
-    try:
-        from jax import debug
-    except ImportError:
-        return None
-    return getattr(debug, "callback", None)
+    return int(cache_size())
 
 
 def compiled_cost_analysis(compiled):
     """XLA cost analysis of a ``Compiled`` executable as one plain dict
-    (``{"flops": ..., "bytes accessed": ...}``), or None when this jax
-    offers no cost analysis.  Normalizes the cross-version return drift:
-    0.4.x returns a one-element list of dicts (one per partition), newer
-    jax a bare dict, and some backends None."""
-    fn = getattr(compiled, "cost_analysis", None)
-    if fn is None:
-        return None
-    try:
-        ca = fn()
-    except Exception:
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
+    (``{"flops": ..., "bytes accessed": ...}``), or None where the
+    backend offers none."""
+    ca = compiled.cost_analysis()
     return dict(ca) if isinstance(ca, dict) else None
 
 
@@ -154,23 +59,17 @@ def compiled_memory_analysis(compiled):
     """Buffer-assignment sizes of a ``Compiled`` executable as a plain
     dict (``arg_bytes``/``out_bytes``/``temp_bytes``/``alias_bytes``/
     ``generated_code_bytes`` + ``peak_bytes`` with its source NAMED), or
-    None when this jax has no ``memory_analysis``.
+    None where the backend reports none.
 
     ``peak_source`` says where ``peak_bytes`` came from: ``"xla_peak"``
-    (a jaxlib exposing ``peak_memory_in_bytes``) or
-    ``"arg+out+temp"`` (this container's 0.4.37, which reports the
-    components but no peak — the sum is the executable's worst-case
-    live footprint with no overlap credit, an upper bound).  Callers
-    that fall further back (e.g. to ``obs.memory.hbm_watermark``) must
-    keep naming the source — a peak whose provenance is unknown is how
-    HBM-overcommit postmortems go wrong."""
-    fn = getattr(compiled, "memory_analysis", None)
-    if fn is None:
-        return None
-    try:
-        ma = fn()
-    except Exception:
-        return None
+    (the backend filled ``peak_memory_in_bytes``) or ``"arg+out+temp"``
+    (it left the peak at zero, as the CPU backend does — the sum is the
+    executable's worst-case live footprint with no overlap credit, an
+    upper bound).  Callers that fall further back (e.g. to
+    ``obs.memory.hbm_watermark``) must keep naming the source — a peak
+    whose provenance is unknown is how HBM-overcommit postmortems go
+    wrong."""
+    ma = compiled.memory_analysis()
     if ma is None:
         return None
     out = {}
@@ -192,22 +91,3 @@ def compiled_memory_analysis(compiled):
         )
         out["peak_source"] = "arg+out+temp"
     return out
-
-
-def register_compile_listener(cb) -> bool:
-    """Register ``cb(event_key, duration_s)`` for ``jax.monitoring``
-    duration events (the ``/jax/core/compile/backend_compile_duration``
-    stream the recompile watcher counts).  Returns False when this jax
-    has no monitoring surface (the watcher then reports
-    ``available: False`` rather than silently counting nothing).
-    Registration is permanent — jax.monitoring has no unregister — so
-    callers register ONE dispatcher and fan out themselves."""
-    try:
-        from jax import monitoring
-    except ImportError:
-        return False
-    reg = getattr(monitoring, "register_event_duration_secs_listener", None)
-    if reg is None:
-        return False
-    reg(cb)
-    return True
