@@ -344,8 +344,6 @@ def _phase_summary(rec: dict) -> dict:
         "wall_tokens_per_sec": derived.get("wall_tokens_per_sec"),
         "syncs_per_token": derived.get("syncs_per_token"),
         "host_syncs": counters.get("host_syncs"),
-        "decode_token_s_p50": (hists.get("decode_token_s") or {}).get("p50"),
-        "decode_token_s_p95": (hists.get("decode_token_s") or {}).get("p95"),
         "masked_slot_steps": counters.get("masked_slot_steps"),
         # compiles inside the measured window (recompile watcher):
         # anything nonzero means the phase's timings include XLA

@@ -23,6 +23,7 @@ device cannot be read back without one, and warns on every later run.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,35 @@ def _compile(fn, one_chip, *shapes):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
     return text
+
+
+def _kernel_names(text):
+    """The names the executable gives its Mosaic custom calls, without
+    the instruction number: what a device trace shows each kernel as."""
+    names = []
+    for line in text.splitlines():
+        head, sep, rest = line.strip().partition(" = ")
+        if sep and 'custom_call_target="tpu_custom_call"' in rest:
+            names.append(re.sub(r"[.\d]+$", "", head.split()[-1].lstrip("%")))
+    return names
+
+
+# The kernels' names are fixed by the program (``name=`` on each
+# ``pl.pallas_call``) and the benchmark's trace readers find kernels by
+# three rules on them, stated here and not imported, and checked on the
+# names read from each executable:
+#   1. flash forward: the name contains "flash_forward";
+#   2. flash backward (dK/dV, dQ): contains neither "flash_forward" nor
+#      "decode";
+#   3. decode attention: does not contain "flash_forward".
+FLASH_FORWARD = "tdx_flash_forward"
+FLASH_BACKWARD = ["tdx_flash_backward_dkv", "tdx_flash_backward_dq"]
+DECODE_NAMES = {
+    "decode_attention": "tdx_decode_attention",
+    "decode_attention_block": "tdx_decode_attention",
+    "paged_decode_attention": "tdx_paged_decode_attention",
+    "paged_decode_attention_block": "tdx_paged_decode_attention",
+}
 
 
 B, D, SLOTS_L = 8, 128, 2048  # decode batch (slots), head_dim, slab rows
@@ -121,28 +151,45 @@ DECODE_CASES = [
 )
 def test_decode_attention_compiles(one_chip, family, quantized, hq, hkv):
     fn, shapes = _decode_case(family, quantized, hq, hkv)
-    _compile(fn, one_chip, *shapes)
+    text = _compile(fn, one_chip, *shapes)
+    names = _kernel_names(text)
+    assert names == [DECODE_NAMES[family]]
+    assert "flash_forward" not in names[0] and "decode" in names[0]  # rule 3
 
 
 @pytest.mark.parametrize(
-    "b,s,hq,hkv,window",
+    "b,s,hq,hkv,window,remat",
     [
-        (2, 2048, 32, 32, None),  # llama2_7b MHA
-        (1, 4096, 32, 8, None),  # mistral_7b / llama3_8b GQA
-        (1, 4096, 32, 8, 1024),  # sliding window
+        (2, 2048, 32, 32, None, False),  # llama2_7b MHA
+        (1, 4096, 32, 8, None, False),  # mistral_7b / llama3_8b GQA
+        (1, 4096, 32, 8, 1024, False),  # sliding window
+        (4, 2048, 16, 16, None, True),  # dscoder-1.3b under full remat
     ],
-    ids=["mha", "gqa", "gqa-window"],
+    ids=["mha", "gqa", "gqa-window", "mha-remat"],
 )
-def test_flash_attention_fwd_bwd_compiles(one_chip, b, s, hq, hkv, window):
-    def loss(q, k, v):
-        out = flash_attention(
+def test_flash_attention_fwd_bwd_compiles(
+    one_chip, b, s, hq, hkv, window, remat
+):
+    def attend(q, k, v):
+        return flash_attention(
             q, k, v, causal=True, window=window, interpret=False
         )
+
+    def loss(q, k, v):
+        out = (jax.checkpoint(attend) if remat else attend)(q, k, v)
         return jnp.sum(out.astype(jnp.float32))
 
     q = ((b, s, hq, D), jnp.bfloat16)
     kv = ((b, s, hkv, D), jnp.bfloat16)
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
+    # the same three names whatever wraps the call: under remat the
+    # backward pair used to take the name "checkpoint", and the forward
+    # kernel there is the recomputed one, which keeps the forward's name
+    names = _kernel_names(text)
+    assert sorted(names) == sorted([FLASH_FORWARD] + FLASH_BACKWARD)
+    forward = [n for n in names if "flash_forward" in n]
+    assert forward == [FLASH_FORWARD]  # rule 1
+    assert not [n for n in names if n not in forward and "decode" in n]  # 2
 
 
 @pytest.mark.parametrize(
@@ -154,7 +201,11 @@ def test_fused_ce_fwd_bwd_compiles(one_chip, n, d, v):
     def loss(x, w, labels):
         return fused_linear_cross_entropy(x, w, labels, interpret=False)
 
-    _compile(
+    text = _compile(
         jax.grad(loss, argnums=(0, 1)), one_chip,
         ((n, d), jnp.bfloat16), ((v, d), jnp.bfloat16), ((n,), jnp.int32),
     )
+    assert sorted(_kernel_names(text)) == [
+        "tdx_fused_ce_backward_dw", "tdx_fused_ce_backward_dx",
+        "tdx_fused_ce_forward",
+    ]

@@ -106,15 +106,15 @@ class TestProfilingHelpers:
     def test_trace_and_memory_stats(self, tmp_path):
         import os
 
+        from torchdistx_tpu.obs import get_tracer
         from torchdistx_tpu.utils import (
-            annotate,
             device_memory_stats,
             format_memory_stats,
             trace,
         )
 
         with trace(str(tmp_path)):
-            with annotate("probe"):
+            with get_tracer().span("probe"):
                 jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
         files = sum(len(f) for _, _, f in os.walk(tmp_path))
         assert files > 0

@@ -22,6 +22,7 @@
 import functools
 import json
 import os
+import re
 import time
 import urllib.request
 
@@ -611,3 +612,201 @@ class TestReplaySpans:
         names = [e["name"] for e in tracer.events()]
         assert "materialize_module" in names
         assert any(n.startswith("replay/") for n in names)
+
+
+SERVE_LEAVES = [
+    "serve/schedule", "serve/decode_args", "serve/decode", "serve/harvest",
+]
+
+
+def _profiled_host_spans(tmp_path, body):
+    """Run ``body()`` under a real ``jax.profiler`` trace and read the
+    host plane back: ``[(name, start_ns, end_ns)]`` of every span under
+    the program's prefixes, by start."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    found = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert found, "the profiler wrote no trace"
+    data = jax.profiler.ProfileData.from_file(str(found[-1]))
+    spans = [
+        (e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+        for plane in data.planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name.startswith(("serve/", "trainer/"))
+    ]
+    return sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+class TestOneSpanPrimitive:
+    """ISSUE 27: every span is a profiler annotation, once, whether or
+    not the tracer records; the phases of a serve step and of a train
+    step land in a profile under exactly their names."""
+
+    @pytest.fixture
+    def entered(self, monkeypatch):
+        """Names of the profiler annotations entered, in order."""
+        names = []
+
+        class Annotation:
+            def __init__(self, name, **kwargs):
+                self.name = name
+
+            def __enter__(self):
+                names.append(self.name)
+
+            def __exit__(self, *exc):
+                return None
+
+        monkeypatch.setattr(obs.trace, "TraceAnnotation", Annotation)
+        monkeypatch.setattr(obs.trace, "StepTraceAnnotation", Annotation)
+        return names
+
+    def test_disabled_tracer_still_annotates_once(self, entered):
+        tracer = obs.get_tracer()
+        assert not tracer.enabled
+        with tracer.span("obs/plain", cat="x", detail=3):
+            pass
+        with tracer.span("obs/step", step_num=7):
+            pass
+        with profiling.timed_annotation("obs/timed") as t:
+            pass
+        assert entered == ["obs/plain", "obs/step", "obs/timed"]
+        assert t["seconds"] >= 0.0
+        assert tracer.events() == []
+
+    def test_enabled_tracer_annotates_once_and_records(self, entered, tracer):
+        with profiling.timed_annotation("obs/timed"):
+            pass
+        with tracer.span("obs/step", cat="trainer", step_num=7):
+            pass
+        assert entered == ["obs/timed", "obs/step"]
+        events = {e["name"]: e for e in tracer.events()}
+        assert set(events) == {"obs/timed", "obs/step"}
+        assert events["obs/step"]["args"] == {"step_num": 7}
+
+    def test_failing_sink_still_exits_the_annotation(self, monkeypatch, tracer):
+        exited = []
+
+        class Annotation:
+            def __init__(self, name, **kwargs):
+                self.name = name
+
+            def __enter__(self):
+                return None
+
+            def __exit__(self, *exc):
+                exited.append(self.name)
+
+        def full_disk(event):
+            raise OSError("no space left on device")
+
+        monkeypatch.setattr(obs.trace, "TraceAnnotation", Annotation)
+        monkeypatch.setattr(tracer, "_add", full_disk)
+        with pytest.raises(OSError):
+            with tracer.span("obs/sink_fails"):
+                pass
+        assert exited == ["obs/sink_fails"]
+
+    def test_serve_step_phases_in_a_real_profile(self, tmp_path):
+        engine = ServeEngine(_llama(), num_slots=2, max_len=32)
+        prompts = _prompts(11, [5, 3, 4, 6])
+        engine.run([{"prompt": p, "max_new_tokens": 3} for p in prompts[:2]])
+        for p in prompts[2:]:
+            engine.submit(p, max_new_tokens=8)
+
+        def three_steps():
+            for _ in range(3):
+                assert engine.step() > 0
+
+        spans = _profiled_host_spans(tmp_path, three_steps)
+        names = [n for n, _, _ in spans]
+        # exactly these names: no ``#k=v#`` tail, no per-step label
+        assert set(names) == set(SERVE_LEAVES) | {"serve/prefill"}
+        starts = [i for i, n in enumerate(names) if n == "serve/schedule"]
+        assert len(starts) == 3
+        for k, i in enumerate(starts):
+            step = spans[i : starts[k + 1] if k + 1 < len(starts) else None]
+            schedule = step[0]
+            leaves = [s for s in step if s[0] != "serve/prefill"]
+            # flat leaves in order, harvest last: nothing of the step
+            # is left after it (nothing timed)
+            assert [n for n, _, _ in leaves] == SERVE_LEAVES
+            prefills = [s for s in step if s[0] == "serve/prefill"]
+            assert len(prefills) == (2 if k == 0 else 0)
+            for _, t0, t1 in prefills:  # children of the schedule phase
+                assert schedule[1] <= t0 and t1 <= schedule[2]
+            for a, b in zip(leaves, leaves[1:]):  # no overlap
+                assert a[2] <= b[1]
+
+    def test_trainer_fit_phases_in_a_real_profile(self, tmp_path):
+        from torchdistx_tpu.trainer import Trainer
+
+        step = jax.jit(lambda p, o, b: (p, o, jnp.sum(b)))
+        t = Trainer(step, params={}, opt_state={}, log_every=1,
+                    log_fn=lambda m: None)
+        batches = [jnp.ones((4,)) * i for i in range(3)]
+        t.fit(batches[:1], num_steps=1)  # the compiling step, outside
+        spans = _profiled_host_spans(
+            tmp_path, lambda: t.fit(iter(batches[1:]), num_steps=3)
+        )
+        names = [n for n, _, _ in spans]
+        assert names == [
+            "trainer/next_batch", "trainer/step", "trainer/sync",
+            "trainer/next_batch", "trainer/step", "trainer/sync",
+        ]
+        for a, b in zip(spans, spans[1:]):
+            assert a[2] <= b[1]
+
+    def test_phase_histograms_in_to_json_and_reset(self):
+        engine = ServeEngine(_llama(), num_slots=2, max_len=32)
+        engine.run([{"prompt": p, "max_new_tokens": 3}
+                    for p in _prompts(5, [4, 6, 3])])
+        phases = ("schedule_s", "decode_args_s", "harvest_s")
+        hists = engine.metrics.to_json()["histograms"]
+        dispatches = engine.metrics.counters["decode_dispatches"]
+        assert dispatches > 0
+        for name in phases:
+            assert hists[name]["count"] > 0, name
+        assert hists["decode_args_s"]["count"] == dispatches
+        assert hists["harvest_s"]["count"] == dispatches
+        assert hists["decode_s"]["count"] == dispatches
+        engine.reset_metrics()
+        hists = engine.metrics.to_json()["histograms"]
+        for name in phases:
+            assert hists[name]["count"] == 0, name
+
+    def test_named_scopes_reach_the_lowered_train_step(self):
+        from torchdistx_tpu.nn import functional as F
+        from torchdistx_tpu.nn.module import functional_call
+        from torchdistx_tpu.optimizers import anyprecision_adamw
+        from torchdistx_tpu.parallel import ShardedTrainStep, create_mesh
+
+        model = _llama()
+        params = dict(model.named_parameters())
+
+        def loss_fn(p, batch):
+            tokens, labels = batch
+            return F.cross_entropy(
+                functional_call(model, p, (tokens,)), labels
+            )
+
+        mesh = create_mesh({"fsdp": 1}, devices=jax.devices()[:1])
+        step = ShardedTrainStep(
+            loss_fn, anyprecision_adamw(1e-4), mesh, shard_axis="fsdp"
+        )
+        opt_state = step.init_optimizer(params)
+        tokens = jnp.zeros((2, 16), jnp.int32)
+        text = jax.jit(lambda p, o, b: step(p, o, b)).lower(
+            params, opt_state, (tokens, tokens)
+        ).as_text(debug_info=True)
+        # metadata only: the scopes are path components of op names
+        for scope in ("attention", "mlp", "vocab_projection", "loss",
+                      "optimizer"):
+            assert re.search(rf'[/"(]{scope}[/")]', text), scope

@@ -313,23 +313,34 @@ class LlamaBlock(nn.Module):
         # inherits the whole attention/cache scaffolding
         self.mlp = mlp if mlp is not None else LlamaMLP(cfg)
 
+    # each half runs under a ``jax.named_scope`` (metadata only): the
+    # compiled operations carry ``attention`` / ``mlp`` in their op_name,
+    # so a profile's fusions can be put down to a half of the block
+
+    def _mlp_half(self, x):
+        with jax.named_scope("mlp"):
+            return x + self.mlp(self.mlp_norm(x))
+
     def forward(self, x, rope):
-        x = x + self.attn(self.attn_norm(x), rope)
-        return x + self.mlp(self.mlp_norm(x))
+        with jax.named_scope("attention"):
+            x = x + self.attn(self.attn_norm(x), rope)
+        return self._mlp_half(x)
 
     def forward_cached(self, x, rope, cache, cache_pos):
-        a, cache = self.attn.forward_cached(
-            self.attn_norm(x), rope, cache, cache_pos
-        )
-        x = x + a
-        return x + self.mlp(self.mlp_norm(x)), cache
+        with jax.named_scope("attention"):
+            a, cache = self.attn.forward_cached(
+                self.attn_norm(x), rope, cache, cache_pos
+            )
+            x = x + a
+        return self._mlp_half(x), cache
 
     def forward_decode(self, x, rope, cache, positions, page_tables=None):
-        a, cache = self.attn.forward_decode(
-            self.attn_norm(x), rope, cache, positions, page_tables
-        )
-        x = x + a
-        return x + self.mlp(self.mlp_norm(x)), cache
+        with jax.named_scope("attention"):
+            a, cache = self.attn.forward_decode(
+                self.attn_norm(x), rope, cache, positions, page_tables
+            )
+            x = x + a
+        return self._mlp_half(x), cache
 
 
 class Llama(nn.Module):
@@ -381,7 +392,8 @@ class Llama(nn.Module):
         x = self.norm(x)
         if return_hidden:
             return x
-        return _num_tap("logits", self.lm_head(x))
+        with jax.named_scope("vocab_projection"):
+            return _num_tap("logits", self.lm_head(x))
 
     # -- incremental decoding (KV cache) ----------------------------------
 
@@ -410,7 +422,8 @@ class Llama(nn.Module):
             x, c = blk.forward_cached(x, rope, c, cache_pos)
             new_cache.append(c)
         x = self.norm(x)
-        return self.lm_head(x), new_cache
+        with jax.named_scope("vocab_projection"):
+            return self.lm_head(x), new_cache
 
     def forward_decode(self, tokens, cache, positions, page_tables=None):
         """One decode step for a batch of independent serving slots:
@@ -428,7 +441,8 @@ class Llama(nn.Module):
             x, c = blk.forward_decode(x, rope, c, positions, page_tables)
             new_cache.append(c)
         x = self.norm(x)
-        return self.lm_head(x), new_cache
+        with jax.named_scope("vocab_projection"):
+            return self.lm_head(x), new_cache
 
 
 def pp_stage(cfg: LlamaConfig, n_blocks: int = 1):

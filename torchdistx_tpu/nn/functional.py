@@ -147,6 +147,7 @@ def avg_pool2d(x, window, stride=None, padding=0):
 
 def cross_entropy(logits, labels, axis=-1):
     """Mean token cross-entropy; logits (..., vocab), integer labels."""
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=axis)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=axis)[..., 0]
-    return jnp.mean(nll)
+    with jax.named_scope("loss"):  # metadata only: names the fusions
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=axis)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=axis)[..., 0]
+        return jnp.mean(nll)

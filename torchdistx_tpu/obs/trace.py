@@ -1,31 +1,33 @@
-"""Host-side span tracer with Chrome-trace (Perfetto) export + JSONL sink.
+"""The one span primitive, with Chrome-trace (Perfetto) export + JSONL sink.
 
-The host half of the observability story: ``jax.profiler`` traces show
-the XLA timeline, but every perf regression so far (the donated-carry
-recompile, dispatch-dominated decode) lived in HOST control flow — the
-engine's dispatch loop, the scheduler, the replay executor.  This tracer
-records those host spans with ``time.monotonic`` timestamps (the same
-clock the serving ``Request`` lifecycle uses, so per-request spans and
-``ServeMetrics`` histograms derive from identical numbers) and exports a
-valid catapult ``traceEvents`` JSON that Perfetto / ``chrome://tracing``
-opens directly — *alongside*, never replacing, a ``jax.profiler`` trace.
+:meth:`Tracer.span` is the single way the package marks a host region.
+Every span enters a ``jax.profiler.TraceAnnotation`` of its name — a
+TraceMe, which costs a flag test while no profile is being taken and
+lands on the profiler's own clock, beside the device's operations, the
+moment one is (``jax.profiler.start_trace``, the benchmark's ``--trace
+1``).  That is where the chip's idle gaps get the name of the host phase
+that caused them.
 
-Zero-dependency and near-zero-cost when disabled: the module-level
-tracer starts disabled, ``span()`` on a disabled tracer is a no-op
-context manager, and nothing here ever touches the device.  Enable with
-:func:`enable_tracing` (optionally with a JSONL structured-event sink
-for post-hoc analysis — one JSON object per line, written as events
-complete) or the ``TDX_TRACE_DIR`` environment variable.
+When the tracer is *enabled* (:func:`enable_tracing` or the
+``TDX_TRACE_DIR`` environment variable) a span also records its own
+event with ``time.monotonic`` timestamps — the clock the serving
+``Request`` lifecycle uses, so per-request spans and ``ServeMetrics``
+histograms derive from identical numbers — and :meth:`Tracer.export`
+writes a valid catapult ``traceEvents`` JSON that Perfetto /
+``chrome://tracing`` opens directly, optionally streamed as JSONL (one
+JSON object per line, written as events complete) for post-hoc analysis.
+Disabled, nothing is recorded and nothing here ever touches the device.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 __all__ = [
     "Tracer",
@@ -81,29 +83,26 @@ class Tracer:
                 # ms-scale, so a per-line flush is noise
                 self._jsonl.flush()
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "host", **args: Any) -> Iterator[None]:
-        """Record a complete ("X") event around the body.  No-op (and
-        allocation-free on the hot path) when the tracer is disabled."""
-        if not self.enabled:
-            yield
-            return
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            t1 = time.monotonic()
-            self._add(
-                {
-                    "ph": "X",
-                    "name": name,
-                    "cat": cat,
-                    "ts": t0,
-                    "dur": t1 - t0,
-                    "tid": threading.get_ident() & 0x7FFFFFFF,
-                    **({"args": args} if args else {}),
-                }
-            )
+    def span(
+        self,
+        name: str,
+        cat: str = "host",
+        *,
+        step_num: Optional[int] = None,
+        **args: Any,
+    ) -> "_Span":
+        """A context manager around one host region.  It always enters a
+        profiler annotation called ``name`` (``args`` ride along as the
+        event's stats, not in its name), and records a complete ("X")
+        event of its own only while the tracer is enabled.  With
+        ``step_num`` the annotation is a ``StepTraceAnnotation``: the
+        profiler's tools then group device work by step."""
+        if step_num is None:
+            annotation = TraceAnnotation(name, **args)
+        else:
+            args["step_num"] = step_num
+            annotation = StepTraceAnnotation(name, **args)
+        return _Span(self, name, cat, args, annotation)
 
     def instant(self, name: str, cat: str = "host", **args: Any) -> None:
         if not self.enabled:
@@ -189,6 +188,43 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return path
+
+
+class _Span:
+    """What :meth:`Tracer.span` returns (a class, not a generator: a
+    decode step enters several of these)."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_annotation", "_t0")
+
+    def __init__(self, tracer, name, cat, args, annotation):
+        self._tracer, self._name, self._cat = tracer, name, cat
+        self._args, self._annotation = args, annotation
+        self._t0 = None
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+        if self._tracer.enabled:
+            self._t0 = time.monotonic()
+
+    def __exit__(self, *exc) -> None:
+        t0 = self._t0
+        try:
+            if t0 is not None:
+                self._tracer._add(
+                    {
+                        "ph": "X",
+                        "name": self._name,
+                        "cat": self._cat,
+                        "ts": t0,
+                        "dur": time.monotonic() - t0,
+                        "tid": threading.get_ident() & 0x7FFFFFFF,
+                        **({"args": self._args} if self._args else {}),
+                    }
+                )
+        finally:
+            # a sink that fails (full disk, closed file) must not leave
+            # the profiler's nesting on this thread one level too deep
+            self._annotation.__exit__(*exc)
 
 
 _TRACER = Tracer(enabled=False)

@@ -223,15 +223,19 @@ def _decode_kernel(
         ).astype(o_ref.dtype)
 
 
+# the extra scope keeps a transformation's wrapping (``vmap(...)``) off
+# the kernel's own name (ops/flash_attention.py has the long form)
+@jax.named_scope("decode_attention")
 def _launch(
     q, ck, cv, k_scale, v_scale, prefetch, kv_index, *,
-    block_k, n_k, scale, interpret,
+    block_k, n_k, scale, interpret, name,
 ):
     """Shared wrapper of the four families.  ``ck``/``cv``: the slab
     (B, max_len, Hkv, D) or the pools (num_pages, page_size, Hkv, D).
     ``kv_index(bb, kk, *prefetch_refs) -> (lead, row_block)`` names the
     K/V block grid step ``(bb, ·, kk)`` reads; ``prefetch`` are the
-    scalar-prefetch operands, per-slot base depths first."""
+    scalar-prefetch operands, per-slot base depths first.  ``name`` is
+    the kernel's fixed name in the compiled program and in a profile."""
     b, s, hq, d = q.shape
     hkv = ck.shape[2]
     if hq % hkv != 0:
@@ -302,6 +306,7 @@ def _launch(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
+        name=name,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -398,7 +403,7 @@ def decode_attention_block(
     return _launch(
         q, ck, cv, k_scale, v_scale, [positions.astype(jnp.int32)],
         kv_index, block_k=block_k, n_k=max_len // block_k, scale=scale,
-        interpret=interpret,
+        interpret=interpret, name="tdx_decode_attention",
     )
 
 
@@ -488,4 +493,5 @@ def paged_decode_attention_block(
             page_tables.astype(jnp.int32).reshape(-1),
         ],
         kv_index, block_k=ps, n_k=pp, scale=scale, interpret=interpret,
+        name="tdx_paged_decode_attention",
     )

@@ -546,6 +546,12 @@ def _bwd_dtable_kernel(
         dt_ref[0, :] = dt_acc[0, :].astype(dt_ref.dtype)
 
 
+# A kernel's ``name=`` becomes its instruction's name in the compiled
+# program (and so in a device trace) only if no transformation wraps it:
+# jvp / transpose rewrite the FIRST scope inside them
+# (``transpose(jvp(name))``).  So every call site below sits inside one
+# more ``jax.named_scope``, which takes the wrapping instead.
+@jax.named_scope("flash_backward")
 def _flash_dtable(
     qh, doh, oh, lse_b, kh, vh, table, *,
     b, hq, hkv, causal, scale, block_q, block_k, interpret, bucket_cfg,
@@ -593,6 +599,7 @@ def _flash_dtable(
         out_specs=table_spec,
         out_shape=jax.ShapeDtypeStruct((hq, buckets), table.dtype),
         scratch_shapes=[pltpu.VMEM((1, buckets), jnp.float32)],
+        name="tdx_flash_backward_dtable",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "arbitrary", "arbitrary", "arbitrary"
@@ -693,6 +700,7 @@ def _prepare_flash_bwd(q, g, out, lse):
     return qh, doh, oh, lse_b
 
 
+@jax.named_scope("flash_backward")
 def _flash_backward_core(
     qh, doh, oh, lse_b, kh, vh, *,
     b, hq, hkv, causal, scale, block_q, block_k, interpret,
@@ -767,6 +775,7 @@ def _flash_backward_core(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="tdx_flash_backward_dkv",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -821,6 +830,7 @@ def _flash_backward_core(
         ),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), dq_dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="tdx_flash_backward_dq",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -829,6 +839,7 @@ def _flash_backward_core(
     return dq, dk_part, dv_part
 
 
+@jax.named_scope("flash_backward")
 def _flash_dbias(
     qh, doh, oh, lse_b, kh, vh, bias, *,
     b, hq, hkv, causal, scale, block_q, block_k, interpret,
@@ -875,6 +886,7 @@ def _flash_dbias(
         out_specs=bias_spec,
         out_shape=jax.ShapeDtypeStruct((hq, sq, skv), bias.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32)],
+        name="tdx_flash_backward_dbias",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary"
@@ -1276,6 +1288,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        name="tdx_flash_forward",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),

@@ -536,10 +536,13 @@ class ShardedTrainStep:
                 )
                 digs["loss"] = array_digest(loss)
                 digs.update(tree_group_digest(grads, "grads/"))
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = jax.tree_util.tree_map(
-                lambda p, u: (p + u).astype(p.dtype), params, updates
-            )
+            with jax.named_scope("optimizer"):  # metadata only
+                updates, opt_state = optimizer.update(
+                    grads, opt_state, params
+                )
+                params = jax.tree_util.tree_map(
+                    lambda p, u: (p + u).astype(p.dtype), params, updates
+                )
             if num_on:
                 return params, opt_state, loss, digs
             return params, opt_state, loss

@@ -832,9 +832,30 @@ class ServeEngine:
         running-request deadlines are checked once per chunk (a deadline
         can overshoot by at most one chunk's wall time).  Returns the
         number of unfinished requests (queued + running)."""
+        with self._phase("schedule"):
+            self._schedule("step")
+            if not self.scheduler.running:
+                self._observe_gauges()  # nothing to decode: the tick ends
+                return self.scheduler.queue_depth
+        # ends in ``serve/harvest``, which observes the gauges
+        self._decode_step()
+        return self.scheduler.queue_depth + len(self.scheduler.running)
+
+    def _phase(self, name: str):
+        """One phase of a tick: the span ``serve/<name>`` (in any
+        profile, and on the host tracer when enabled) with its host
+        seconds recorded into the ``<name>_s`` histogram."""
+        return timed_annotation(
+            f"serve/{name}", getattr(self.metrics, f"{name}_s").record
+        )
+
+    def _schedule(self, tick_kind: str) -> None:
+        """The ``serve/schedule`` phase of a tick: expire deadlines, then
+        admit into free slots, one prefill each (its dispatch and
+        first-token sync are the child span ``serve/prefill``)."""
         if self._bb_on and self._bb_driver and not self._bb_in_drain:
             self.recorder.tick += 1
-            self.recorder.record("step", tick=self.recorder.tick)
+            self.recorder.record(tick_kind, tick=self.recorder.tick)
         now = time.monotonic()
         for req in self.scheduler.expire_queued(now):
             self._count_finish(req)
@@ -848,14 +869,15 @@ class ServeEngine:
         )
         for req, slot in self.scheduler.admit(now, gate=gate):
             self._prefill_request(req, slot)
-        if self.scheduler.running:
-            self._decode_step()
+
+    def _observe_gauges(self) -> None:
+        """Queue depth, slot occupancy and pages in use, sampled where a
+        decode dispatch's harvest ends (or the tick, if it ran none)."""
         self.metrics.observe_gauges(
             self.scheduler.queue_depth, self.cache.active_count
         )
         if self.paged:
             self.metrics.observe_pages(self.pool.in_use)
-        return self.scheduler.queue_depth + len(self.scheduler.running)
 
     def step_prefill(self) -> int:
         """The disaggregated prefill role's scheduler tick (docs/
@@ -873,27 +895,9 @@ class ServeEngine:
                 "persistent loop defers first-token fetches to a decode "
                 "drain a prefill-role engine never runs"
             )
-        if self._bb_on and self._bb_driver and not self._bb_in_drain:
-            self.recorder.tick += 1
-            self.recorder.record("step_prefill", tick=self.recorder.tick)
-        now = time.monotonic()
-        for req in self.scheduler.expire_queued(now):
-            self._count_finish(req)
-        for req in list(self.scheduler.running):
-            if req.expired(now):
-                self._finish(req, "deadline", now)
-        gate = (
-            self._gate
-            if (self._draining or self.paged or self.hbm_budget is not None)
-            else None
-        )
-        for req, slot in self.scheduler.admit(now, gate=gate):
-            self._prefill_request(req, slot)
-        self.metrics.observe_gauges(
-            self.scheduler.queue_depth, self.cache.active_count
-        )
-        if self.paged:
-            self.metrics.observe_pages(self.pool.in_use)
+        with self._phase("schedule"):
+            self._schedule("step_prefill")
+            self._observe_gauges()
         return self.scheduler.queue_depth + len(self.scheduler.running)
 
     def run(
@@ -2204,9 +2208,7 @@ class ServeEngine:
             jnp.asarray([req.seed], jnp.int32),
         )
         self._ensure_card(name, program, args)
-        with timed_annotation(
-            "serve/prefill", self.metrics.prefill_s.record
-        ), self._watch(name):
+        with self._phase("prefill"), self._watch(name):
             out = program(*args)
             kv, tok = out[0], out[1]
             # rebind BEFORE the host sync: the dispatch donated the old
@@ -2282,9 +2284,7 @@ class ServeEngine:
                     jnp.asarray([req.seed], jnp.int32),
                 )
             self._ensure_card(name, program, args)
-            with timed_annotation(
-                "serve/prefill", self.metrics.prefill_s.record
-            ), self._watch(name):
+            with self._phase("prefill"), self._watch(name):
                 out = program(*args)
                 kv, tok = out[0], out[1]
                 self.cache.kv = kv  # before any sync: slab was donated
@@ -2334,9 +2334,7 @@ class ServeEngine:
             "warm" if pfx > 0 else "cold", bucket
         )
         self._ensure_card(name, program, tuple(args))
-        with timed_annotation(
-            "serve/prefill", self.metrics.prefill_s.record
-        ), self._watch(name):
+        with self._phase("prefill"), self._watch(name):
             out = program(*args)
             kv, tok = out[0], out[1]
             self.cache.kv = kv  # before the sync: the pools were donated
@@ -2419,9 +2417,7 @@ class ServeEngine:
                 "warm" if warm else "cold", bucket
             )
             self._ensure_card(name, program, tuple(args))
-            with timed_annotation(
-                "serve/prefill", self.metrics.prefill_s.record
-            ), self._watch(name):
+            with self._phase("prefill"), self._watch(name):
                 out = program(*args)
                 kv, tok = out[0], out[1]
                 self.cache.kv = kv  # before any sync: pools were donated
@@ -2451,76 +2447,79 @@ class ServeEngine:
             return self._persistent_step(skip)
         if self.speculate:
             return self._spec_decode_step(skip)
-        running = self.scheduler.running
-        k_steps = self.decode_chunk
-        program = self._decode_program()
-        args = [
-            self.params,
-            self.cache.kv,
-            jnp.asarray(self._last_tok),
-            jnp.asarray(self.cache.positions()),
-            jnp.asarray(self._temps),
-            jnp.asarray(self._seeds),
-            jnp.asarray(self._ntok),
-            jnp.asarray(self._budget),
-            jnp.asarray(~self.cache.active),  # retired slots: finished
-        ]
-        if self.paged:
-            # tiny int32 dynamic input; rewritten host-side at every
-            # admit/retire, scan-invariant within the chunk
-            args.append(jnp.asarray(self.cache.page_tables))
-        name = f"serve/decode/k{k_steps}"
-        self._ensure_card(name, program, tuple(args))
-        with timed_annotation(
-            "serve/decode", self.metrics.decode_s.record
-        ) as timing, self._watch(name):
+        with self._phase("decode_args"):
+            running = self.scheduler.running
+            k_steps = self.decode_chunk
+            program = self._decode_program()
+            args = [
+                self.params,
+                self.cache.kv,
+                jnp.asarray(self._last_tok),
+                jnp.asarray(self.cache.positions()),
+                jnp.asarray(self._temps),
+                jnp.asarray(self._seeds),
+                jnp.asarray(self._ntok),
+                jnp.asarray(self._budget),
+                jnp.asarray(~self.cache.active),  # retired slots: finished
+            ]
+            if self.paged:
+                # tiny int32 dynamic input; rewritten host-side at every
+                # admit/retire, scan-invariant within the chunk
+                args.append(jnp.asarray(self.cache.page_tables))
+            name = f"serve/decode/k{k_steps}"
+            self._ensure_card(name, program, tuple(args))
+        with self._phase("decode"), self._watch(name):
             out = program(*args)
             kv, block = out[0], out[1]
             self.cache.kv = kv  # before the sync: old slab was donated
             if self.numerics:
                 self._pending_digests.append(out[-1])
             block = np.asarray(block)  # ONE host sync per K slot-steps
-        self.metrics.count("host_syncs")
-        self._harvest_numerics()
-        self.metrics.count("decode_dispatches")
-        self.metrics.count("decode_steps", k_steps)
-        self._record_tp_collectives(self.num_slots, k_steps)
-        now = time.monotonic()
-        emitted = 0
-        for req in running:
-            if req is skip or not self.cache.active[req.slot]:
-                # not yet cache-admitted: the mid-chunked-prefill request
-                # itself (parked, device-frozen) or a same-batch admit an
-                # interleaved dispatch ran ahead of — their tokens start
-                # at their own prefill, not here
-                continue
-            slot = req.slot
-            took = 0
-            for j in range(k_steps):
-                tok = int(block[j, slot])
-                self._ntok[slot] += 1
-                self.cache.advance_slot(slot)
-                self._last_tok[slot] = tok
-                req.generated.append(tok)
-                emitted += 1
-                took = j + 1
-                if self._check_finished(req, tok, now):
-                    # the device froze this slot for the rest of the
-                    # chunk; those slot-steps bought nothing
-                    self.metrics.count("masked_slot_steps", k_steps - 1 - j)
-                    break
-            ev = ("decode_chunk", now, {"tokens": took})
-            if req.events and req.events[-1][0] == "finish":
-                # _check_finished logged the finish inside the loop; keep
-                # the lifecycle log in causal order (chunk, then finish)
-                req.events.insert(-1, ev)
-            else:
-                req.events.append(ev)
-        self.metrics.count("tokens_generated", emitted)
-        self.metrics.count("tokens_decoded", emitted)
-        if emitted:
-            self.metrics.decode_token_s.record(timing["seconds"] / emitted)
-        self._record_drain()
+        with self._phase("harvest"):
+            # drop this dispatch's device handles here, inside the phase:
+            # left to the frame's teardown they are freed after it, in
+            # nobody's span (seven small buffers and the token block)
+            del args, out
+            self.metrics.count("host_syncs")
+            self._harvest_numerics()
+            self.metrics.count("decode_dispatches")
+            self.metrics.count("decode_steps", k_steps)
+            self._record_tp_collectives(self.num_slots, k_steps)
+            now = time.monotonic()
+            emitted = 0
+            for req in running:
+                if req is skip or not self.cache.active[req.slot]:
+                    # not yet cache-admitted: the mid-chunked-prefill request
+                    # itself (parked, device-frozen) or a same-batch admit an
+                    # interleaved dispatch ran ahead of — their tokens start
+                    # at their own prefill, not here
+                    continue
+                slot = req.slot
+                took = 0
+                for j in range(k_steps):
+                    tok = int(block[j, slot])
+                    self._ntok[slot] += 1
+                    self.cache.advance_slot(slot)
+                    self._last_tok[slot] = tok
+                    req.generated.append(tok)
+                    emitted += 1
+                    took = j + 1
+                    if self._check_finished(req, tok, now):
+                        # the device froze this slot for the rest of the
+                        # chunk; those slot-steps bought nothing
+                        self.metrics.count("masked_slot_steps", k_steps - 1 - j)
+                        break
+                ev = ("decode_chunk", now, {"tokens": took})
+                if req.events and req.events[-1][0] == "finish":
+                    # _check_finished logged the finish inside the loop; keep
+                    # the lifecycle log in causal order (chunk, then finish)
+                    req.events.insert(-1, ev)
+                else:
+                    req.events.append(ev)
+            self.metrics.count("tokens_generated", emitted)
+            self.metrics.count("tokens_decoded", emitted)
+            self._record_drain()
+            self._observe_gauges()
 
     def _persistent_step(self, skip: Optional[Request] = None) -> None:
         """One persistent-loop dispatch: the while_loop runs on-device
@@ -2534,44 +2533,43 @@ class ServeEngine:
         ring cut off (budget-bound exit) simply stays running and
         continues from its frozen carry at the next dispatch — spanning
         drains is the persistent analog of spanning chunks."""
-        running = self.scheduler.running
-        program = self._persistent_program()
-        toks = jnp.asarray(self._last_tok)
-        for slot, dev_tok in self._pending_first.items():
-            # freshly prefilled slots: their first token exists only on
-            # device; splice it into the loop's last-token row without a
-            # fetch (a tiny host-staged update, no sync).  The index is
-            # ARRAY-typed on purpose: a python-int index is a static
-            # value baked into the scatter executable, so each distinct
-            # slot would compile its own op — a per-slot recompile the
-            # recompile watcher flags in the bench's measured window
-            toks = toks.at[jnp.asarray(slot, jnp.int32)].set(dev_tok)
-        args = [
-            self.params,
-            self.cache.kv,
-            toks,
-            jnp.asarray(self.cache.positions()),
-            jnp.asarray(self._temps),
-            jnp.asarray(self._seeds),
-            jnp.asarray(self._ntok),
-            jnp.asarray(self._budget),
-            # the active mask carries the cache-full rule: positions()
-            # is clamped to max_len - 1, so the room check must come
-            # from the UNCLAMPED host positions or it could never fire
-            # (_make_persistent_decode docstring)
-            jnp.asarray(self.cache.active & (self.cache.pos < self.max_len)),
-        ]
-        if self.paged:
-            # scan-invariant within the loop: pages are only ever freed
-            # or reallocated host-side at drain boundaries, so no frozen
-            # in-loop write can land on a page this table doesn't own
-            args.append(jnp.asarray(self.cache.page_tables))
-        self._stream_events.clear()
-        name = f"serve/decode/persistent/r{self.ring_capacity}"
-        self._ensure_card(name, program, tuple(args))
-        with timed_annotation(
-            "serve/decode", self.metrics.decode_s.record
-        ) as timing, self._watch(name):
+        with self._phase("decode_args"):
+            running = self.scheduler.running
+            program = self._persistent_program()
+            toks = jnp.asarray(self._last_tok)
+            for slot, dev_tok in self._pending_first.items():
+                # freshly prefilled slots: their first token exists only on
+                # device; splice it into the loop's last-token row without a
+                # fetch (a tiny host-staged update, no sync).  The index is
+                # ARRAY-typed on purpose: a python-int index is a static
+                # value baked into the scatter executable, so each distinct
+                # slot would compile its own op — a per-slot recompile the
+                # recompile watcher flags in the bench's measured window
+                toks = toks.at[jnp.asarray(slot, jnp.int32)].set(dev_tok)
+            args = [
+                self.params,
+                self.cache.kv,
+                toks,
+                jnp.asarray(self.cache.positions()),
+                jnp.asarray(self._temps),
+                jnp.asarray(self._seeds),
+                jnp.asarray(self._ntok),
+                jnp.asarray(self._budget),
+                # the active mask carries the cache-full rule: positions()
+                # is clamped to max_len - 1, so the room check must come
+                # from the UNCLAMPED host positions or it could never fire
+                # (_make_persistent_decode docstring)
+                jnp.asarray(self.cache.active & (self.cache.pos < self.max_len)),
+            ]
+            if self.paged:
+                # scan-invariant within the loop: pages are only ever freed
+                # or reallocated host-side at drain boundaries, so no frozen
+                # in-loop write can land on a page this table doesn't own
+                args.append(jnp.asarray(self.cache.page_tables))
+            self._stream_events.clear()
+            name = f"serve/decode/persistent/r{self.ring_capacity}"
+            self._ensure_card(name, program, tuple(args))
+        with self._phase("decode"), self._watch(name):
             out = program(*args)
             kv, ring, valid, iters = out[0], out[1], out[2], out[3]
             self.cache.kv = kv  # before the sync: old slab was donated
@@ -2582,73 +2580,75 @@ class ServeEngine:
             block, vmask, n_it, firsts = jax.device_get(
                 (ring, valid, iters, dict(self._pending_first))
             )
-        n_it = int(n_it)
-        self._pending_first.clear()
-        self.metrics.count("host_syncs")  # the drain IS the sync
-        self._harvest_numerics()
-        self.metrics.count("ring_drains")
-        self.metrics.count("decode_dispatches")
-        self.metrics.count("decode_steps", n_it)
-        self.metrics.count("loop_iterations", n_it)
-        self._record_tp_collectives(self.num_slots, n_it)
-        self.metrics.observe_ring(n_it)
-        now = time.monotonic()
-        # streamed tail (opt-in): the iteration-0 callback timestamp is
-        # when the wave's first tokens actually existed host-side —
-        # tighter than the drain time for first-token latency
-        first_ts = now
-        if self._stream_events:
-            first_ts = min(now, self._stream_events[0][0])
-        emitted = 0
-        any_cut = False
-        for req in running:
-            if req is skip:
-                # mid-chunked-prefill request: parked, device-frozen
-                continue
-            slot = req.slot
-            taken = 0
-            finished = False
-            if slot in firsts:
-                tok = int(firsts[slot])
-                self._record_first(req, tok, first_ts)
-                if self._check_finished(req, tok, first_ts):
-                    # the device's fin0 froze this slot before iteration
-                    # 0 (EOS first token / one-token budget): it idled
-                    # the whole loop
-                    finished = True
-            if not finished:
-                for j in range(n_it):
-                    if not vmask[j, slot]:
-                        break  # frozen from here on: rows are rewrites
-                    tok = int(block[j, slot])
-                    self._ntok[slot] += 1
-                    self.cache.advance_slot(slot)
-                    self._last_tok[slot] = tok
-                    req.generated.append(tok)
-                    emitted += 1
-                    taken = j + 1
-                    if self._check_finished(req, tok, now):
+        with self._phase("harvest"):
+            # (device handles: see _decode_step)
+            del args, toks, out, ring, valid, iters
+            n_it = int(n_it)
+            self._pending_first.clear()
+            self.metrics.count("host_syncs")  # the drain IS the sync
+            self._harvest_numerics()
+            self.metrics.count("ring_drains")
+            self.metrics.count("decode_dispatches")
+            self.metrics.count("decode_steps", n_it)
+            self.metrics.count("loop_iterations", n_it)
+            self._record_tp_collectives(self.num_slots, n_it)
+            self.metrics.observe_ring(n_it)
+            now = time.monotonic()
+            # streamed tail (opt-in): the iteration-0 callback timestamp is
+            # when the wave's first tokens actually existed host-side —
+            # tighter than the drain time for first-token latency
+            first_ts = now
+            if self._stream_events:
+                first_ts = min(now, self._stream_events[0][0])
+            emitted = 0
+            any_cut = False
+            for req in running:
+                if req is skip:
+                    # mid-chunked-prefill request: parked, device-frozen
+                    continue
+                slot = req.slot
+                taken = 0
+                finished = False
+                if slot in firsts:
+                    tok = int(firsts[slot])
+                    self._record_first(req, tok, first_ts)
+                    if self._check_finished(req, tok, first_ts):
+                        # the device's fin0 froze this slot before iteration
+                        # 0 (EOS first token / one-token budget): it idled
+                        # the whole loop
                         finished = True
-                        break
-            if finished:
-                # iterations the loop kept running past this slot's
-                # finish — the persistent analog of mid-chunk waste
-                self.metrics.count("masked_slot_steps", n_it - taken)
-            else:
-                any_cut = True  # ring filled before this request's end
-            ev = ("decode_chunk", now, {"tokens": taken})
-            if req.events and req.events[-1][0] == "finish":
-                # keep the lifecycle log causal (chunk, then finish)
-                req.events.insert(-1, ev)
-            else:
-                req.events.append(ev)
-        if any_cut:
-            self.metrics.count("ring_full_drains")
-        self.metrics.count("tokens_generated", emitted)
-        self.metrics.count("tokens_decoded", emitted)
-        if emitted:
-            self.metrics.decode_token_s.record(timing["seconds"] / emitted)
-        self._record_drain()
+                if not finished:
+                    for j in range(n_it):
+                        if not vmask[j, slot]:
+                            break  # frozen from here on: rows are rewrites
+                        tok = int(block[j, slot])
+                        self._ntok[slot] += 1
+                        self.cache.advance_slot(slot)
+                        self._last_tok[slot] = tok
+                        req.generated.append(tok)
+                        emitted += 1
+                        taken = j + 1
+                        if self._check_finished(req, tok, now):
+                            finished = True
+                            break
+                if finished:
+                    # iterations the loop kept running past this slot's
+                    # finish — the persistent analog of mid-chunk waste
+                    self.metrics.count("masked_slot_steps", n_it - taken)
+                else:
+                    any_cut = True  # ring filled before this request's end
+                ev = ("decode_chunk", now, {"tokens": taken})
+                if req.events and req.events[-1][0] == "finish":
+                    # keep the lifecycle log causal (chunk, then finish)
+                    req.events.insert(-1, ev)
+                else:
+                    req.events.append(ev)
+            if any_cut:
+                self.metrics.count("ring_full_drains")
+            self.metrics.count("tokens_generated", emitted)
+            self.metrics.count("tokens_decoded", emitted)
+            self._record_drain()
+            self._observe_gauges()
 
     def _consume_spec_block(
         self, req: Request, ys_row, c: int, now: float
@@ -2697,28 +2697,27 @@ class ServeEngine:
         device's emitted count (0 exactly where the old valid/finished
         mask was False), so host bookkeeping and device carries agree
         iteration for iteration, token for token."""
-        running = self.scheduler.running
-        k_steps = self.decode_chunk
-        program = self._spec_decode_program()
-        args = [
-            self.params,
-            self.cache.kv,
-            jnp.asarray(self._last_tok),
-            jnp.asarray(self.cache.positions()),
-            jnp.asarray(self._hist),
-            jnp.asarray(self._temps),
-            jnp.asarray(self._seeds),
-            jnp.asarray(self._ntok),
-            jnp.asarray(self._budget),
-            jnp.asarray(~self.cache.active),  # retired slots: finished
-        ]
-        if self.paged:
-            args.append(jnp.asarray(self.cache.page_tables))
-        name = f"serve/decode/spec{self.speculate}/k{k_steps}"
-        self._ensure_card(name, program, tuple(args))
-        with timed_annotation(
-            "serve/decode", self.metrics.decode_s.record
-        ) as timing, self._watch(name):
+        with self._phase("decode_args"):
+            running = self.scheduler.running
+            k_steps = self.decode_chunk
+            program = self._spec_decode_program()
+            args = [
+                self.params,
+                self.cache.kv,
+                jnp.asarray(self._last_tok),
+                jnp.asarray(self.cache.positions()),
+                jnp.asarray(self._hist),
+                jnp.asarray(self._temps),
+                jnp.asarray(self._seeds),
+                jnp.asarray(self._ntok),
+                jnp.asarray(self._budget),
+                jnp.asarray(~self.cache.active),  # retired slots: finished
+            ]
+            if self.paged:
+                args.append(jnp.asarray(self.cache.page_tables))
+            name = f"serve/decode/spec{self.speculate}/k{k_steps}"
+            self._ensure_card(name, program, tuple(args))
+        with self._phase("decode"), self._watch(name):
             out = program(*args)
             kv, ys, cs = out[0], out[1], out[2]
             self.cache.kv = kv  # before the sync: old slab was donated
@@ -2726,47 +2725,49 @@ class ServeEngine:
                 self._pending_digests.append(out[-1])
             # ONE host sync for the blocks and the counts together
             ys, cs = jax.device_get((ys, cs))
-        self.metrics.count("host_syncs")
-        self._harvest_numerics()
-        self.metrics.count("decode_dispatches")
-        self.metrics.count("decode_steps", k_steps)
-        self._record_tp_collectives(
-            self.num_slots * (self.speculate + 1), k_steps
-        )
-        now = time.monotonic()
-        emitted = 0
-        for req in running:
-            if req is skip or not self.cache.active[req.slot]:
-                # not yet cache-admitted (mid-chunked-prefill / a
-                # same-batch admit an interleaved dispatch ran ahead of)
-                continue
-            slot = req.slot
-            took = 0
-            for j in range(k_steps):
-                c = int(cs[j, slot])
-                if c == 0:
-                    break  # frozen from here on
-                n, finished = self._consume_spec_block(
-                    req, ys[j, slot], c, now
-                )
-                emitted += n
-                took = j + 1
-                if finished:
-                    # the device froze this slot for the rest of the
-                    # chunk; those iterations bought nothing
-                    self.metrics.count("masked_slot_steps", k_steps - 1 - j)
-                    break
-            ev = ("decode_chunk", now, {"tokens": took})
-            if req.events and req.events[-1][0] == "finish":
-                # keep the lifecycle log causal (chunk, then finish)
-                req.events.insert(-1, ev)
-            else:
-                req.events.append(ev)
-        self.metrics.count("tokens_generated", emitted)
-        self.metrics.count("tokens_decoded", emitted)
-        if emitted:
-            self.metrics.decode_token_s.record(timing["seconds"] / emitted)
-        self._record_drain()
+        with self._phase("harvest"):
+            # (device handles: see _decode_step)
+            del args, out
+            self.metrics.count("host_syncs")
+            self._harvest_numerics()
+            self.metrics.count("decode_dispatches")
+            self.metrics.count("decode_steps", k_steps)
+            self._record_tp_collectives(
+                self.num_slots * (self.speculate + 1), k_steps
+            )
+            now = time.monotonic()
+            emitted = 0
+            for req in running:
+                if req is skip or not self.cache.active[req.slot]:
+                    # not yet cache-admitted (mid-chunked-prefill / a
+                    # same-batch admit an interleaved dispatch ran ahead of)
+                    continue
+                slot = req.slot
+                took = 0
+                for j in range(k_steps):
+                    c = int(cs[j, slot])
+                    if c == 0:
+                        break  # frozen from here on
+                    n, finished = self._consume_spec_block(
+                        req, ys[j, slot], c, now
+                    )
+                    emitted += n
+                    took = j + 1
+                    if finished:
+                        # the device froze this slot for the rest of the
+                        # chunk; those iterations bought nothing
+                        self.metrics.count("masked_slot_steps", k_steps - 1 - j)
+                        break
+                ev = ("decode_chunk", now, {"tokens": took})
+                if req.events and req.events[-1][0] == "finish":
+                    # keep the lifecycle log causal (chunk, then finish)
+                    req.events.insert(-1, ev)
+                else:
+                    req.events.append(ev)
+            self.metrics.count("tokens_generated", emitted)
+            self.metrics.count("tokens_decoded", emitted)
+            self._record_drain()
+            self._observe_gauges()
 
     def _spec_persistent_step(self, skip: Optional[Request] = None) -> None:
         """The speculative sibling of ``_persistent_step``: one
@@ -2776,39 +2777,38 @@ class ServeEngine:
         exactly where it was True), so ``host_syncs == ring_drains``
         exactly as before — speculation multiplies tokens per sync, it
         never adds one."""
-        running = self.scheduler.running
-        program = self._spec_persistent_program()
-        toks = jnp.asarray(self._last_tok)
-        for slot, dev_tok in self._pending_first.items():
-            # freshly prefilled slots: splice the on-device first token
-            # into the loop's last-token row without a fetch (ARRAY-
-            # typed index: a python int would bake a per-slot scatter
-            # executable — see _persistent_step)
-            toks = toks.at[jnp.asarray(slot, jnp.int32)].set(dev_tok)
-        args = [
-            self.params,
-            self.cache.kv,
-            toks,
-            jnp.asarray(self.cache.positions()),
-            jnp.asarray(self._hist),
-            jnp.asarray(self._temps),
-            jnp.asarray(self._seeds),
-            jnp.asarray(self._ntok),
-            jnp.asarray(self._budget),
-            # room check from the UNCLAMPED host positions, exactly as
-            # in _persistent_step
-            jnp.asarray(self.cache.active & (self.cache.pos < self.max_len)),
-        ]
-        if self.paged:
-            args.append(jnp.asarray(self.cache.page_tables))
-        name = (
-            f"serve/decode/persistent/spec{self.speculate}"
-            f"/r{self.ring_capacity}"
-        )
-        self._ensure_card(name, program, tuple(args))
-        with timed_annotation(
-            "serve/decode", self.metrics.decode_s.record
-        ) as timing, self._watch(name):
+        with self._phase("decode_args"):
+            running = self.scheduler.running
+            program = self._spec_persistent_program()
+            toks = jnp.asarray(self._last_tok)
+            for slot, dev_tok in self._pending_first.items():
+                # freshly prefilled slots: splice the on-device first token
+                # into the loop's last-token row without a fetch (ARRAY-
+                # typed index: a python int would bake a per-slot scatter
+                # executable — see _persistent_step)
+                toks = toks.at[jnp.asarray(slot, jnp.int32)].set(dev_tok)
+            args = [
+                self.params,
+                self.cache.kv,
+                toks,
+                jnp.asarray(self.cache.positions()),
+                jnp.asarray(self._hist),
+                jnp.asarray(self._temps),
+                jnp.asarray(self._seeds),
+                jnp.asarray(self._ntok),
+                jnp.asarray(self._budget),
+                # room check from the UNCLAMPED host positions, exactly as
+                # in _persistent_step
+                jnp.asarray(self.cache.active & (self.cache.pos < self.max_len)),
+            ]
+            if self.paged:
+                args.append(jnp.asarray(self.cache.page_tables))
+            name = (
+                f"serve/decode/persistent/spec{self.speculate}"
+                f"/r{self.ring_capacity}"
+            )
+            self._ensure_card(name, program, tuple(args))
+        with self._phase("decode"), self._watch(name):
             out = program(*args)
             kv, ring, cnts, iters = out[0], out[1], out[2], out[3]
             self.cache.kv = kv  # before the sync: old slab was donated
@@ -2819,65 +2819,67 @@ class ServeEngine:
             block, cmat, n_it, firsts = jax.device_get(
                 (ring, cnts, iters, dict(self._pending_first))
             )
-        n_it = int(n_it)
-        self._pending_first.clear()
-        self.metrics.count("host_syncs")  # the drain IS the sync
-        self._harvest_numerics()
-        self.metrics.count("ring_drains")
-        self.metrics.count("decode_dispatches")
-        self.metrics.count("decode_steps", n_it)
-        self.metrics.count("loop_iterations", n_it)
-        self._record_tp_collectives(
-            self.num_slots * (self.speculate + 1), n_it
-        )
-        self.metrics.observe_ring(n_it)
-        now = time.monotonic()
-        emitted = 0
-        any_cut = False
-        for req in running:
-            if req is skip:
-                # mid-chunked-prefill request: parked, device-frozen
-                continue
-            slot = req.slot
-            taken = 0
-            finished = False
-            if slot in firsts:
-                tok = int(firsts[slot])
-                self._record_first(req, tok, now)
-                if self._check_finished(req, tok, now):
-                    # fin0 froze this slot before iteration 0
-                    finished = True
-            if not finished:
-                for j in range(n_it):
-                    c = int(cmat[j, slot])
-                    if c == 0:
-                        break  # frozen from here on: rows are rewrites
-                    n, finished = self._consume_spec_block(
-                        req, block[j, slot], c, now
-                    )
-                    emitted += n
-                    taken = j + 1
-                    if finished:
-                        break
-            if finished:
-                # iterations the loop kept running past this slot's
-                # finish — the persistent analog of mid-chunk waste
-                self.metrics.count("masked_slot_steps", n_it - taken)
-            else:
-                any_cut = True  # ring filled before this request's end
-            ev = ("decode_chunk", now, {"tokens": taken})
-            if req.events and req.events[-1][0] == "finish":
-                # keep the lifecycle log causal (chunk, then finish)
-                req.events.insert(-1, ev)
-            else:
-                req.events.append(ev)
-        if any_cut:
-            self.metrics.count("ring_full_drains")
-        self.metrics.count("tokens_generated", emitted)
-        self.metrics.count("tokens_decoded", emitted)
-        if emitted:
-            self.metrics.decode_token_s.record(timing["seconds"] / emitted)
-        self._record_drain()
+        with self._phase("harvest"):
+            # (device handles: see _decode_step)
+            del args, toks, out, ring, cnts, iters
+            n_it = int(n_it)
+            self._pending_first.clear()
+            self.metrics.count("host_syncs")  # the drain IS the sync
+            self._harvest_numerics()
+            self.metrics.count("ring_drains")
+            self.metrics.count("decode_dispatches")
+            self.metrics.count("decode_steps", n_it)
+            self.metrics.count("loop_iterations", n_it)
+            self._record_tp_collectives(
+                self.num_slots * (self.speculate + 1), n_it
+            )
+            self.metrics.observe_ring(n_it)
+            now = time.monotonic()
+            emitted = 0
+            any_cut = False
+            for req in running:
+                if req is skip:
+                    # mid-chunked-prefill request: parked, device-frozen
+                    continue
+                slot = req.slot
+                taken = 0
+                finished = False
+                if slot in firsts:
+                    tok = int(firsts[slot])
+                    self._record_first(req, tok, now)
+                    if self._check_finished(req, tok, now):
+                        # fin0 froze this slot before iteration 0
+                        finished = True
+                if not finished:
+                    for j in range(n_it):
+                        c = int(cmat[j, slot])
+                        if c == 0:
+                            break  # frozen from here on: rows are rewrites
+                        n, finished = self._consume_spec_block(
+                            req, block[j, slot], c, now
+                        )
+                        emitted += n
+                        taken = j + 1
+                        if finished:
+                            break
+                if finished:
+                    # iterations the loop kept running past this slot's
+                    # finish — the persistent analog of mid-chunk waste
+                    self.metrics.count("masked_slot_steps", n_it - taken)
+                else:
+                    any_cut = True  # ring filled before this request's end
+                ev = ("decode_chunk", now, {"tokens": taken})
+                if req.events and req.events[-1][0] == "finish":
+                    # keep the lifecycle log causal (chunk, then finish)
+                    req.events.insert(-1, ev)
+                else:
+                    req.events.append(ev)
+            if any_cut:
+                self.metrics.count("ring_full_drains")
+            self.metrics.count("tokens_generated", emitted)
+            self.metrics.count("tokens_decoded", emitted)
+            self._record_drain()
+            self._observe_gauges()
 
     def _check_finished(self, req: Request, tok: int, now: float) -> bool:
         if self.eos_token is not None and tok == self.eos_token:

@@ -167,9 +167,12 @@ class ServeMetrics:
     lifecycle timestamps so the aggregate and ``RequestResult.tpot_s``
     provably agree), ``slot_occupancy`` (active / total slots, sampled
     per decode dispatch), ``prefill_s`` / ``decode_s`` (per-dispatch
-    wall times, fetch included), and ``decode_token_s`` (decode dispatch
-    wall time / tokens it emitted — the per-token latency a consumer
-    actually experiences, amortized over the chunk).
+    wall times, fetch included), and the host phases of a tick around
+    those dispatches — ``schedule_s`` (expiry + admissions, prefills
+    included), ``decode_args_s`` (host arrays and their transfers before
+    the decode dispatch) and ``harvest_s`` (the token walk and the
+    gauges after its sync): the spans ``serve/schedule``, ``serve/decode_args`` and
+    ``serve/harvest`` of a profile, for an operator without one.
 
     Prometheus: :meth:`collector` re-registers this whole set through an
     ``obs.metrics.MetricsRegistry`` (counters -> ``*_total``, gauges
@@ -186,7 +189,9 @@ class ServeMetrics:
         "slot_occupancy",
         "prefill_s",
         "decode_s",
-        "decode_token_s",
+        "schedule_s",
+        "decode_args_s",
+        "harvest_s",
     )
 
     def __init__(
@@ -286,7 +291,9 @@ class ServeMetrics:
         self.slot_occupancy = Histogram()
         self.prefill_s = Histogram()
         self.decode_s = Histogram()
-        self.decode_token_s = Histogram()
+        self.schedule_s = Histogram()
+        self.decode_args_s = Histogram()
+        self.harvest_s = Histogram()
 
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] += n

@@ -571,7 +571,10 @@ class Trainer:
             if num_steps is not None and self.global_step >= num_steps:
                 break
             try:
-                batch = next(it)
+                # where an input-bound run waits (or a feed that holds the
+                # host a fixed number of steps ahead of the device)
+                with get_tracer().span("trainer/next_batch", cat="trainer"):
+                    batch = next(it)
             except StopIteration:
                 break
             if self._bb_on:
@@ -585,15 +588,16 @@ class Trainer:
                     rng_counter=self._rng_counter(),
                     batch=self._last_batch_digest,
                 )
-            # a host tracer span per step (obs.trace — no-op unless
-            # tracing is enabled); the dispatch is async, so the span
-            # measures host-side submit time, not device step time —
-            # device time shows at the log boundaries' block_until_ready
+            # a span per step (obs.trace: a step annotation in any
+            # profile, a host event when the tracer is enabled); the
+            # dispatch is async, so the span measures host-side submit
+            # time, not device step time — device time shows under
+            # trainer/sync at the log boundaries' block_until_ready
             # the comm audit only sees Python-level collectives at TRACE
             # time, so this is free after the first (compiling) call and
             # self.comm_profile ends up holding the per-step comm plan
             with get_tracer().span(
-                "trainer/step", cat="trainer", step=self.global_step
+                "trainer/step", cat="trainer", step_num=self.global_step
             ), comm_audit(self.comm_profile), self._watch("trainer/step"):
                 self.params, self.opt_state, loss = self.step(
                     self.params, self.opt_state, batch
@@ -607,7 +611,9 @@ class Trainer:
             if warmup_pending:
                 # exclude the first step's jit compile from throughput
                 # windows: wait for it, then restart the clock
-                with self._watch("trainer/warmup_sync"):
+                with get_tracer().span(
+                    "trainer/sync", cat="trainer"
+                ), self._watch("trainer/warmup_sync"):
                     jax.block_until_ready(loss)
                 # the cost observatory's card (one extra compile) rides
                 # the same warmup boundary, booked as compile overhead
@@ -627,7 +633,9 @@ class Trainer:
             # window_steps == 0 right after the warmup reset (log_every=1):
             # skip that boundary instead of logging 0.0 steps/sec
             if self.global_step % self.log_every == 0 and window_steps > 0:
-                with self._watch("trainer/step_sync"):
+                with get_tracer().span(
+                    "trainer/sync", cat="trainer"
+                ), self._watch("trainer/step_sync"):
                     jax.block_until_ready(loss)
                 dt = time.time() - t_window
                 last_loss = float(loss)

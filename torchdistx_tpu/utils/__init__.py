@@ -1,5 +1,4 @@
 from .profiling import (
-    annotate,
     device_memory_stats,
     format_memory_stats,
     trace,
@@ -11,7 +10,6 @@ __all__ = [
     "next_rng_key",
     "rng_scope",
     "trace",
-    "annotate",
     "device_memory_stats",
     "format_memory_stats",
 ]

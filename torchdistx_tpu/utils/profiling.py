@@ -4,13 +4,11 @@ The reference has no tracing/metrics at all (SURVEY §5.1, §5.5); on TPU the
 canonical tools are XLA profiler traces (viewable in TensorBoard/XProf) and
 PJRT device memory counters.  These helpers wrap them with zero deps.
 
-:func:`timed_annotation` is the unification point with the host-side
-telemetry layer (:mod:`~torchdistx_tpu.obs`): one region lands on the
-XLA timeline (``jax.profiler`` annotation), on the host Perfetto trace
-(``obs.trace`` span), in a metrics histogram (the ``sink``), and as a
-recompile-attribution scope (``obs.recompile``) — so the serve engine's
-``serve/prefill`` / ``serve/decode`` dispatch regions mean the same
-thing in every view.
+:func:`timed_annotation` is the span primitive of
+:mod:`~torchdistx_tpu.obs.trace` (profiler annotation + host tracer
+event) with a metrics histogram (the ``sink``) and a
+recompile-attribution scope (``obs.recompile``) added — so the serve
+engine's ``serve/*`` phases mean the same thing in every view.
 """
 
 from __future__ import annotations
@@ -26,7 +24,6 @@ from ..obs.trace import get_tracer
 
 __all__ = [
     "trace",
-    "annotate",
     "timed_annotation",
     "device_memory_stats",
     "format_memory_stats",
@@ -44,30 +41,24 @@ def trace(log_dir: str) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named region that shows up on the profiler timeline."""
-    return jax.profiler.TraceAnnotation(name)
-
-
 @contextlib.contextmanager
 def timed_annotation(name: str, sink: Optional[Any] = None) -> Iterator[dict]:
-    """:func:`annotate` plus wall-clock timing: the region lands on the
-    XLA timeline AND its host-side duration is captured.  Yields a dict
-    that gains ``{"seconds": ...}`` on exit; ``sink(seconds)`` is called
-    if given (e.g. a ``serve.metrics.Histogram.record``).  The serving
-    engine wraps its prefill/decode dispatches with this so a profiler
-    trace and the metrics snapshot describe the same regions.
+    """The span primitive (``obs.trace.Tracer.span``) plus wall-clock
+    timing.  Yields a dict that gains ``{"seconds": ...}`` on exit;
+    ``sink(seconds)`` is called if given (e.g. a
+    ``serve.metrics.Histogram.record``).  The serving engine wraps every
+    phase of a step with this, so a profiler trace and the metrics
+    snapshot describe the same regions.
 
-    The region is also a host tracer span (``obs.trace``, no-op unless
-    tracing is enabled) and a recompile-attribution scope
-    (``obs.recompile``): an XLA compile fired inside it is counted under
-    ``name`` by any installed ``RecompileWatcher``.
+    The span enters the profiler annotation (once) and, with the tracer
+    enabled, records the host event.  The region is also a
+    recompile-attribution scope (``obs.recompile``): an XLA compile
+    fired inside it is counted under ``name`` by any installed
+    ``RecompileWatcher``.
     """
     out: dict = {}
     t0 = time.perf_counter()
-    with annotate(name), recompile_scope(name), get_tracer().span(
-        name, cat="dispatch"
-    ):
+    with get_tracer().span(name, cat="dispatch"), recompile_scope(name):
         yield out
     out["seconds"] = time.perf_counter() - t0
     if sink is not None:
