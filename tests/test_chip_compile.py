@@ -13,6 +13,14 @@ tests' and ``chip_smoke.py``'s.
 Widths: head_dim 128; Hkv 32 (llama2_7b) and 8 (mistral_7b, llama3_8b);
 slab 2048-4096; page 16; vocab 32000 and GPT-2's 50257.
 
+The compiled program is also where a LAYOUT shows: the serve cache is
+stored as the decode kernel's operand (``serve/kv_cache.py``), and
+``test_decode_step_leaves_the_cache_in_place`` pins that a decode
+step's write + attend holds no instruction that relayouts or copies an
+array of the cache's size (on the chip a ``(…, Hkv, D)`` array and its
+``(…, Hkv * D)`` "view" are tiled differently, and the reshape between
+them was 29 % of a Mistral-7B decode step).
+
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU's library, pytest-xdist workers
 all import this file, and only the worker that runs it may touch libtpu.
@@ -22,6 +30,7 @@ cache is off around these compiles: an entry written for a described
 device cannot be read back without one, and warns on every later run.
 """
 
+import math
 import os
 import re
 
@@ -31,6 +40,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from torchdistx_tpu.ops import decode_attention as da
+from torchdistx_tpu.ops.attention import slot_cached_attention
 from torchdistx_tpu.ops.flash_attention import flash_attention
 from torchdistx_tpu.ops.fused_ce import fused_linear_cross_entropy
 
@@ -55,14 +65,16 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, donate=()):
     """Compile ``fn`` for the described chip from (shape, dtype) pairs;
     returns the executable's HLO text."""
     args = [
         jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
         for shape, dtype in shapes
     ]
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    text = (
+        jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
+    )
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
     return text
 
@@ -106,16 +118,16 @@ def _decode_case(family, quantized, hq, hkv):
     kv = jnp.int8 if quantized else jnp.bfloat16
     paged = family.startswith("paged")
     rows = (PAGES, PS) if paged else (B, SLOTS_L)
-    shapes = [
+    shapes = [  # the caches as the engine stores them: head tail merged
         ((B, s, hq, D), jnp.bfloat16),
-        ((*rows, hkv, D), kv),
-        ((*rows, hkv, D), kv),
+        ((*rows, hkv * D), kv),
+        ((*rows, hkv * D), kv),
     ]
     if paged:
         shapes.append(((B, SLOTS_L // PS), jnp.int32))
     shapes.append(((B,), jnp.int32))
     if quantized:
-        shapes += [((*rows, hkv, 1), jnp.float32)] * 2
+        shapes += [((*rows, hkv), jnp.float32)] * 2
     kernel = getattr(da, family)
 
     def fn(q, ck, cv, *rest):
@@ -155,6 +167,116 @@ def test_decode_attention_compiles(one_chip, family, quantized, hq, hkv):
     names = _kernel_names(text)
     assert names == [DECODE_NAMES[family]]
     assert "flash_forward" not in names[0] and "decode" in names[0]  # rule 3
+
+
+# One layer's decode write + attend at Mistral-7B widths (the serve
+# cell's: 16 slots of 2048 rows, 32 / 8 heads of 128), through
+# ``slot_cached_attention`` with the cache donated, as the engine's decode
+# program runs it 24 times a step.
+M_B, M_L, M_HQ, M_HKV = 16, 2048, 32, 8
+# what may have a result of the cache's size: the cache coming in and
+# going out, and the in-place write of the new rows
+IN_PLACE = {"dynamic-update-slice", "scatter"}
+PLUMBING = {"parameter", "tuple", "get-tuple-element", "bitcast", "while"}
+_INSTRUCTION = re.compile(
+    r"\s*(?:ROOT )?%?(?P<name>[\w.\-]+) = (?P<type>\(.*?\)|\S+) "
+    r"(?P<op>[\w\-]+)\("
+)
+_ARRAY = re.compile(r"\w+\[([\d,]*)\](\{[^}]*\})?")
+
+
+def _cache_sized(text, n):
+    """``(computation, name, opcode, result type, line)`` of every
+    instruction of the module, fused computations' bodies included,
+    with an array of ``n`` elements in its result."""
+    found, computation = [], None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            head = line.split()
+            computation = head[1 if head[0] == "ENTRY" else 0].lstrip("%")
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        sizes = [
+            math.prod(int(x) for x in dims.split(",") if x)
+            for dims, _ in _ARRAY.findall(m["type"])
+        ]
+        if n in sizes:
+            found.append((computation, m["name"], m["op"], m["type"], line))
+    return found
+
+
+def _same_tiling(type_):
+    """True when every array of a (copy-start's) result type has one
+    shape and one tiling: a move between memory spaces (``S(n)``), which
+    the compiler's memory-space assignment may schedule for a kernel's
+    operand, and not a relayout."""
+    seen = {
+        (dims, re.sub(r"S\(\d+\)", "", layout))
+        for dims, layout in _ARRAY.findall(type_)
+        if dims.count(",")  # not the u32[] context word
+    }
+    return len(seen) == 1
+
+
+@pytest.mark.parametrize(
+    "paged,quantized",
+    [(False, False), (False, True), (True, False)],
+    ids=["slab-bf16", "slab-int8", "paged16-bf16"],
+)
+def test_decode_step_leaves_the_cache_in_place(
+    one_chip, monkeypatch, paged, quantized
+):
+    """The cache reaches the kernel as it is stored: besides parameters
+    and tuple plumbing, only the in-place row write (and the fusion
+    around it) has a result of the cache's size — no ``reshape``,
+    ``copy``, ``transpose`` or other fusion.  On the ``(…, Hkv, D)``
+    storage this failed with two ``reshape`` instructions a layer."""
+    (chip,) = one_chip.device_set
+    # ``interpret=None`` and ``use_flash`` ask jax.devices()[0].platform
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+    lead = (M_B * M_L // PS + 1, PS) if paged else (M_B, M_L)
+    kv = jnp.int8 if quantized else jnp.bfloat16
+    row = ((M_B, 1, M_HKV, D), jnp.bfloat16)
+    cache = [((*lead, M_HKV * D), kv)] * 2
+    if quantized:
+        cache += [((*lead, M_HKV), jnp.float32)] * 2
+    ints = [((M_B,), jnp.int32)]
+    if paged:
+        ints.append(((M_B, M_L // PS), jnp.int32))
+
+    def fn(q, k_new, v_new, positions, *rest):
+        *tables, cache = rest
+        return slot_cached_attention(
+            q, k_new, v_new, cache, positions, use_flash=True,
+            page_tables=tables[0] if tables else None,
+        )
+
+    shapes = [((M_B, 1, M_HQ, D), jnp.bfloat16), row, row, *ints]
+    n = len(shapes)
+    text = _compile(
+        lambda *a: fn(*a[:n], tuple(a[n:])), one_chip, *shapes, *cache,
+        donate=tuple(range(n, n + len(cache))),
+    )
+    assert _kernel_names(text) == [
+        "tdx_paged_decode_attention" if paged else "tdx_decode_attention"
+    ]
+    found = _cache_sized(text, math.prod(lead) * M_HKV * D)
+    updated = {c for c, _, op, _, _ in found if op in IN_PLACE}
+    assert updated, "the write of the new rows is not in the program"
+    offenders = []
+    for _, name, op, type_, line in found:
+        if op in IN_PLACE or op in PLUMBING:
+            continue
+        if op == "fusion":  # only the fusion around an in-place write
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            if called and called[1] in updated:
+                continue
+        if op in ("copy-start", "copy-done") and _same_tiling(type_):
+            continue
+        offenders.append(f"{name} = {type_} {op}")
+    assert not offenders, offenders
 
 
 @pytest.mark.parametrize(

@@ -23,17 +23,20 @@ from torchdistx_tpu.ops.decode_attention import (
     decode_attention,
     paged_decode_attention,
 )
+from torchdistx_tpu.serve.kv_cache import merge_heads, split_heads
 
 _ULP = 3e-7  # ~2 f32 ulps at unit scale
 
 
 def _case(rs, b, hq, hkv, d, max_seq, positions, dtype=jnp.float32):
+    """New rows as the model makes them, (B, 1, H, D); the cache as the
+    engine stores it, (B, max_seq, Hkv * D)."""
     q = jnp.asarray(rs.randn(b, 1, hq, d), dtype)
     k = jnp.asarray(rs.randn(b, 1, hkv, d), dtype)
     v = jnp.asarray(rs.randn(b, 1, hkv, d), dtype)
     cache = (
-        jnp.asarray(rs.randn(b, max_seq, hkv, d), dtype),
-        jnp.asarray(rs.randn(b, max_seq, hkv, d), dtype),
+        merge_heads(jnp.asarray(rs.randn(b, max_seq, hkv, d), dtype)),
+        merge_heads(jnp.asarray(rs.randn(b, max_seq, hkv, d), dtype)),
     )
     return q, k, v, cache, jnp.asarray(positions, jnp.int32)
 
@@ -88,7 +91,7 @@ class TestKernelMatchesReference:
     @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
     def test_head_per_lane_block_layout_matches(self, paged, quantized):
         """head_dim 128 — the real width — takes the OTHER block layout:
-        one KV head per 128-lane block of the flattened (Hkv * D) tail,
+        one KV head per 128-lane block of the stored (Hkv * D) tail,
         picked by the grid (every smaller-D case above spans the whole
         tail and walks heads in-kernel).  Multi-block rows, GQA, and the
         int8 one-hot scale-column select, against the jnp path."""
@@ -112,6 +115,9 @@ class TestKernelMatchesReference:
         from torchdistx_tpu.ops.attention import _slot_attend
 
         ref = _slot_attend(q, dense[0], dense[1], pos, None, None)
+        # the kernels take the stored layout: head tails merged
+        slab = [merge_heads(c) for c in slab]
+        scales = {n: merge_heads(x) for n, x in scales.items()}
         if paged:
             # the same rows as pages, in a shuffled pool order
             order = rs.permutation(b * pp)
@@ -198,14 +204,14 @@ class TestRouting:
     def test_rejects_multi_token(self):
         rs = np.random.RandomState(4)
         q = jnp.asarray(rs.randn(2, 2, 4, 8), jnp.float32)
-        ck = jnp.asarray(rs.randn(2, 16, 2, 8), jnp.float32)
+        ck = jnp.asarray(rs.randn(2, 16, 2 * 8), jnp.float32)
         with pytest.raises(ValueError, match="one token per slot"):
             decode_attention(q, ck, ck, jnp.zeros((2,), jnp.int32))
 
     def test_rejects_indivisible_heads(self):
         rs = np.random.RandomState(4)
         q = jnp.asarray(rs.randn(2, 1, 3, 8), jnp.float32)
-        ck = jnp.asarray(rs.randn(2, 16, 2, 8), jnp.float32)
+        ck = jnp.asarray(rs.randn(2, 16, 2 * 8), jnp.float32)
         with pytest.raises(ValueError, match="not a multiple"):
             decode_attention(q, ck, ck, jnp.zeros((2,), jnp.int32))
 
@@ -217,9 +223,9 @@ def _paged_case(rs, b, hq, hkv, d, pp, ps, positions, dtype=jnp.float32):
     q = jnp.asarray(rs.randn(b, 1, hq, d), dtype)
     k = jnp.asarray(rs.randn(b, 1, hkv, d), dtype)
     v = jnp.asarray(rs.randn(b, 1, hkv, d), dtype)
-    pools = (
-        jnp.asarray(rs.randn(num_pages, ps, hkv, d), dtype),
-        jnp.asarray(rs.randn(num_pages, ps, hkv, d), dtype),
+    pools = (  # as the engine stores them: (num_pages, ps, Hkv * D)
+        merge_heads(jnp.asarray(rs.randn(num_pages, ps, hkv, d), dtype)),
+        merge_heads(jnp.asarray(rs.randn(num_pages, ps, hkv, d), dtype)),
     )
     tables = 1 + rs.permutation(b * pp).reshape(b, pp).astype(np.int32)
     return (
@@ -272,7 +278,7 @@ class TestPagedKernel:
             rs, b, hq, hkv, d, pp, ps, [3, 17, 30]
         )
         slab = tuple(
-            jnp.stack([p.reshape(-1, hkv, d)[
+            jnp.stack([p.reshape(-1, hkv * d)[
                 (np.asarray(tables[row])[:, None] * ps
                  + np.arange(ps)[None, :]).reshape(-1)
             ] for row in range(b)])
@@ -289,7 +295,8 @@ class TestPagedKernel:
         for row, p in enumerate([3, 17, 30]):
             page = int(tables[row, p // ps])
             np.testing.assert_array_equal(
-                np.asarray(gk[page, p % ps]), np.asarray(k[row, 0])
+                np.asarray(gk[page, p % ps]),
+                np.asarray(k[row, 0]).reshape(-1),
             )
 
     def test_routing_through_slot_cached_attention(self):
@@ -333,7 +340,7 @@ class TestPagedKernel:
     def test_rejects_bad_shapes(self):
         rs = np.random.RandomState(8)
         q = jnp.asarray(rs.randn(2, 2, 4, 8), jnp.float32)
-        pool = jnp.asarray(rs.randn(5, 16, 2, 8), jnp.float32)
+        pool = jnp.asarray(rs.randn(5, 16, 2 * 8), jnp.float32)
         pt = jnp.zeros((2, 2), jnp.int32)
         with pytest.raises(ValueError, match="one token per slot"):
             paged_decode_attention(q, pool, pool, pt, jnp.zeros(2, jnp.int32))
@@ -385,7 +392,9 @@ class TestWindowedDecodeBoundaries:
         out, (ck, cv) = slot_cached_attention(
             q, k, v, cache, pos, window=window, use_flash=False
         )
-        ref = self._dense_reference(q, ck, cv, positions, window)
+        ref = self._dense_reference(
+            q, split_heads(ck, hkv), split_heads(cv, hkv), positions, window
+        )
         np.testing.assert_allclose(
             np.asarray(out), ref, rtol=1e-6, atol=1e-6
         )
@@ -399,7 +408,7 @@ class TestWindowedDecodeBoundaries:
             rs, b, hq, hkv, d, pp, ps, positions
         )
         slab = tuple(
-            jnp.stack([p.reshape(-1, hkv, d)[
+            jnp.stack([p.reshape(-1, hkv * d)[
                 (np.asarray(tables[row])[:, None] * ps
                  + np.arange(ps)[None, :]).reshape(-1)
             ] for row in range(b)])

@@ -38,6 +38,7 @@ from torchdistx_tpu.serve.kv_cache import (
     canonicalize_kv_dtype,
     dequantize_cache,
     dequantize_kv,
+    merge_heads,
     quantize_cache,
     quantize_kv,
 )
@@ -155,6 +156,12 @@ class TestQuantizedKernelParity:
         pos = jnp.asarray(rs.randint(0, max_seq, (b,)), jnp.int32)
         return q, (qk, qv, sk, sv), pos
 
+    @staticmethod
+    def _stored(*arrays):
+        """Model layout (…, Hkv, D) / scales (…, Hkv, 1) → the engine's
+        stored layout, head tail merged."""
+        return tuple(merge_heads(a) for a in arrays)
+
     def test_slab_kernel_matches_dequantized_jnp(self):
         from torchdistx_tpu.ops.attention import slot_cached_attention
         from torchdistx_tpu.ops.decode_attention import decode_attention
@@ -168,11 +175,14 @@ class TestQuantizedKernelParity:
             q,
             jnp.take_along_axis(dk, idx, axis=1),
             jnp.take_along_axis(dv, idx, axis=1),
-            (dk, dv),
+            self._stored(dk, dv),
             pos,
             use_flash=False,
         )
-        np.testing.assert_array_equal(np.asarray(rk), np.asarray(dk))
+        np.testing.assert_array_equal(
+            np.asarray(rk), np.asarray(merge_heads(dk))
+        )
+        qk, qv, sk, sv = self._stored(qk, qv, sk, sv)
         out = decode_attention(
             q, qk, qv, pos, k_scale=sk, v_scale=sv, interpret=True
         )
@@ -208,10 +218,11 @@ class TestQuantizedKernelParity:
             q,
             jnp.take_along_axis(slab_k, idx, axis=1),
             jnp.take_along_axis(slab_v, idx, axis=1),
-            (slab_k, slab_v),
+            self._stored(slab_k, slab_v),
             pos,
             use_flash=False,
         )
+        qk, qv, sk, sv = self._stored(qk, qv, sk, sv)
         out = paged_decode_attention(
             q, qk, qv, tables, pos, k_scale=sk, v_scale=sv, interpret=True
         )
@@ -222,14 +233,17 @@ class TestQuantizedKernelParity:
     def test_scales_must_come_together_and_shaped(self):
         from torchdistx_tpu.ops.decode_attention import decode_attention
 
-        q, (qk, qv, sk, sv), pos = self._quant_case(13)
-        with pytest.raises(ValueError):
+        q, quant, pos = self._quant_case(13)
+        qk, qv, sk, sv = self._stored(*quant)
+        with pytest.raises(ValueError, match="together"):
             decode_attention(q, qk, qv, pos, k_scale=sk, interpret=True)
-        with pytest.raises(ValueError):
+        with pytest.raises(ValueError, match="scale shapes"):
             decode_attention(
-                q, qk, qv, pos, k_scale=sk[..., 0], v_scale=sv[..., 0],
+                q, qk, qv, pos, k_scale=sk[..., :1], v_scale=sv[..., :1],
                 interpret=True,
             )
+        with pytest.raises(ValueError, match="stored layout"):
+            decode_attention(q, *quant[:2], pos, interpret=True)
 
 
 class TestQuantizedEngine:

@@ -36,6 +36,7 @@ import torchdistx_tpu as tdx
 from torchdistx_tpu.generation import generate
 from torchdistx_tpu.models import GPT2, Llama
 from torchdistx_tpu.serve import Request, Scheduler, ServeEngine, SlotKVCache
+from torchdistx_tpu.serve.kv_cache import merge_heads, split_heads
 from torchdistx_tpu.serve.metrics import Histogram, ServeMetrics
 
 
@@ -75,9 +76,13 @@ class TestSlotDecodeParity:
             jnp.asarray(rs.randn(b, max_seq, hkv, d), jnp.float32),
         )
         positions = np.array([2, 9, 5], np.int32)
-        out, (ck, cv) = slot_cached_attention(
-            q, k, v, cache, jnp.asarray(positions)
+        # the slot primitive takes and returns the engine's stored
+        # layout (head tail merged); the scalar one the model's
+        out, stored = slot_cached_attention(
+            q, k, v, tuple(merge_heads(c) for c in cache),
+            jnp.asarray(positions),
         )
+        ck, cv = (split_heads(c, hkv) for c in stored)
         for row, p in enumerate(positions):
             r = slice(row, row + 1)
             ref, (rk, rv) = cached_attention(
@@ -110,7 +115,9 @@ class TestSlotDecodeParity:
                 for i in range(len(seeded[0]))
             ]
             logits, _ = model.forward_decode(
-                toks, big, jnp.asarray(positions)
+                toks,
+                [tuple(merge_heads(c) for c in pair) for pair in big],
+                jnp.asarray(positions),
             )
             for row, p in enumerate(positions):
                 r = slice(row, row + 1)
@@ -827,6 +834,140 @@ class TestKVCacheUnit:
         assert cache.positions()[0] == 3
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested jaxprs (jit, scan, while, the
+    vmapped write's loop) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+class TestStoredLayout:
+    """The engine stores K/V as the decode kernel's operand — head tail
+    merged, ``(lead, rows, Hkv * D)`` — so the compiled decode program
+    hands the stored array to the kernel as it is.  On the chip a
+    ``(…, Hkv, D)`` array and its ``(…, Hkv * D)`` "view" are tiled
+    differently and the reshape between them copies the array;
+    tests/test_chip_compile.py pins the compiled program, this pins the
+    storage, the values and the traced program."""
+
+    N_NEW = 9  # the prefill's token + 8 decode steps
+    MAX_LEN = 48  # no weight of the tiny model has a cache array's size
+
+    def _engine(self, paged, quantized, use_flash=None):
+        tdx.manual_seed(0)
+        model = Llama.from_name(
+            "tiny", n_kv_heads=2, max_seq_len=64, use_flash=use_flash
+        )
+        opts = dict(page_size=8) if paged else {}
+        if quantized:
+            opts["kv_dtype"] = "int8"
+        return model, ServeEngine(
+            model, num_slots=2, max_len=self.MAX_LEN,
+            prefill_buckets=(16,), **opts
+        )
+
+    @pytest.mark.parametrize("quantized", [False, True], ids=["plain", "int8"])
+    @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+    def test_storage_stream_and_decode_program(self, paged, quantized):
+        model, engine = self._engine(paged, quantized)
+        cfg = model.cfg
+        hkv, d = cfg.n_kv_heads, cfg.head_dim
+        lead = (
+            (engine.num_pages, engine.page_size)
+            if paged
+            else (2, self.MAX_LEN)
+        )
+        # -- storage: rank 3, tail Hkv * D (scales: Hkv)
+        assert engine.cache.kv_heads == hkv
+        assert len(engine.cache.kv) == cfg.n_layers
+        for entry in engine.cache.kv:
+            assert len(entry) == (4 if quantized else 2)
+            for a in entry[:2]:
+                assert a.shape == (*lead, hkv * d)
+            for a in entry[2:]:
+                assert a.shape == (*lead, hkv) and a.dtype == jnp.float32
+        # -- values: write_slot (paged: the suffix scatter) + 8 decode
+        # steps.  The model-dtype cache is bit-identical to generate();
+        # int8 to the other geometry's int8 stream, and its first token
+        # (sampled from the unquantized prefill) to generate()'s
+        (prompt,) = _prompts(7, (11,))
+        (res,) = engine.run(
+            [{"prompt": prompt, "max_new_tokens": self.N_NEW}]
+        )
+        ref = np.asarray(
+            generate(model, jnp.asarray(prompt[None]), self.N_NEW)
+        )[0, len(prompt):]
+        if quantized:
+            _, other = self._engine(not paged, True)
+            (twin,) = other.run(
+                [{"prompt": prompt, "max_new_tokens": self.N_NEW}]
+            )
+            np.testing.assert_array_equal(res.tokens, twin.tokens)
+            assert res.tokens[0] == ref[0]
+        else:
+            np.testing.assert_array_equal(res.tokens, ref)
+        # -- the traced decode program on the KERNEL path: the cache
+        # reaches pallas_call without a reshape, transpose or copy
+        kmodel, kengine = self._engine(paged, quantized, use_flash=True)
+        extra = (
+            (jnp.asarray(kengine.cache.page_tables),) if paged else ()
+        )
+        jaxpr = jax.make_jaxpr(
+            lambda kv: kmodel.forward_decode(
+                jnp.zeros((2, 1), jnp.int32), kv,
+                jnp.asarray([3, 5], jnp.int32), *extra,
+            )
+        )(kengine.cache.kv)
+        n = int(np.prod(lead)) * hkv * d
+        names = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+        assert names.count("pallas_call") == cfg.n_layers
+        relayouts = [
+            f"{e.primitive.name}{tuple(v.aval.shape for v in e.invars)}"
+            for e in _eqns(jaxpr.jaxpr)
+            if e.primitive.name in ("reshape", "transpose", "copy")
+            and any(
+                int(np.prod(v.aval.shape)) == n
+                for v in e.invars if hasattr(v.aval, "shape")
+            )
+        ]
+        assert not relayouts, relayouts
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    def test_cache_is_made_stored_never_as_a_second_copy(self, kv_dtype):
+        """``init_cache``'s model-layout arrays exist only inside the
+        one jitted program that makes the stored ones: built eagerly,
+        both would be alive at once — on the chip that was 3.2 GB too
+        many next to 11 GB of Mistral-7B weights (PR 28's first call)."""
+        model, concrete = _llama(), []
+        make = model.init_cache
+
+        def spy(*args, **kwargs):
+            out = make(*args, **kwargs)
+            concrete.append(not isinstance(out[0][0], jax.core.Tracer))
+            return out
+
+        model.init_cache = spy
+        cache = SlotKVCache(model, num_slots=2, max_len=16, kv_dtype=kv_dtype)
+        assert concrete and not any(concrete)
+        assert all(a.committed for entry in cache.kv for a in entry)
+
+    def test_merge_and_split_are_inverse_views(self):
+        rs = np.random.RandomState(0)
+        x = jnp.asarray(rs.randn(3, 5, 2, 8), jnp.float32)
+        assert merge_heads(x).shape == (3, 5, 16)
+        np.testing.assert_array_equal(
+            np.asarray(split_heads(merge_heads(x), 2)), np.asarray(x)
+        )
+        scale = x[..., :1]  # (…, Hkv, 1), as quantize_kv makes scales
+        assert merge_heads(scale).shape == (3, 5, 2)
+        assert split_heads(merge_heads(scale), 2).shape == (3, 5, 2, 1)
+
+
 class TestShardedParams:
     def test_fsdp_sharded_params_serve_and_match_generate(self, mesh8):
         # the advertised params= override with mesh-committed (FSDP)
@@ -1057,6 +1198,30 @@ class TestTPServing:
             engine.memory_plan()["components"]["kv_cache"]
             == engine.cache.nbytes // 2
         )
+
+    def test_plan_rule_in_the_models_layout_maps_onto_the_stored_array(self):
+        """A plan may state its ``kv_cache`` rule against the model's
+        (lead, rows, Hkv, D): the head entry lands on the merged axis of
+        the stored (lead, rows, Hkv * D) array; a rule that splits
+        head_dim has no axis to split and is refused by name."""
+        from jax.sharding import PartitionSpec as P
+
+        from torchdistx_tpu.parallel.plan import ShardingPlan
+        from torchdistx_tpu.serve.engine import _cache_sharding
+
+        mesh = _tp_mesh(2)
+
+        def placed(spec):
+            plan = ShardingPlan(mesh, rules=((r"^kv_cache$", spec),))
+            return _cache_sharding({}, mesh=mesh, kv_heads=2, plan=plan).spec
+
+        assert placed(P(None, None, "tp", None)) == P(None, None, "tp")
+        assert placed(P(None, None, "tp")) == P(None, None, "tp")
+        assert _cache_sharding({}, mesh=mesh, kv_heads=2).spec == P(
+            None, None, "tp"
+        )
+        with pytest.raises(ValueError, match="shards head_dim"):
+            placed(P(None, None, None, "tp"))
 
     def test_tp2_paged_persistent_matches_single_device(self):
         model = _llama_tp()

@@ -39,6 +39,7 @@ from torchdistx_tpu.generation import (
 )
 from torchdistx_tpu.models import GPT2, Llama
 from torchdistx_tpu.serve import ServeEngine
+from torchdistx_tpu.serve.kv_cache import merge_heads
 
 _ULP = 3e-7  # ~2 f32 ulps at unit scale (test_decode_attention.py)
 
@@ -232,11 +233,12 @@ class TestMultiTokenScatter:
         from torchdistx_tpu.serve.kv_cache import scatter_slot_tokens
 
         rs = np.random.RandomState(0)
-        cache = jnp.zeros((2, 8, 2, 4), jnp.float32)
+        cache = jnp.zeros((2, 8, 2 * 4), jnp.float32)  # stored: (B, L, H*D)
         x = jnp.asarray(rs.randn(2, 4, 2, 4), jnp.float32)
         out = np.asarray(
             scatter_slot_tokens(cache, x, jnp.asarray([6, 1], jnp.int32))
         )
+        x = x.reshape(2, 4, 8)  # the rows as they land: head tail merged
         # slot 0 at pos 6: rows 6, 7 written; rows 8, 9 DROPPED — not
         # clamped onto row 7, not wrapped into slot 1's row 0/1
         np.testing.assert_array_equal(out[0, 6], np.asarray(x)[0, 0])
@@ -251,7 +253,7 @@ class TestMultiTokenScatter:
 
         rs = np.random.RandomState(1)
         ps, npages = 4, 6
-        pool = jnp.zeros((npages, ps, 2, 4), jnp.float32)
+        pool = jnp.zeros((npages, ps, 2 * 4), jnp.float32)  # stored layout
         x = jnp.asarray(rs.randn(2, 3, 2, 4), jnp.float32)
         # slot 0: pages [2, 5], logical span 8 rows; slot 1: pages [4, 1]
         tables = jnp.asarray([[2, 5], [4, 1]], jnp.int32)
@@ -260,7 +262,7 @@ class TestMultiTokenScatter:
                 pool, x, tables, jnp.asarray([3, 6], jnp.int32), ps
             )
         )
-        xx = np.asarray(x)
+        xx = np.asarray(x).reshape(2, 3, 8)  # head tail merged on the rows
         # slot 0 offsets 3,4,5 -> page 2 row 3, page 5 rows 0,1
         np.testing.assert_array_equal(out[2, 3], xx[0, 0])
         np.testing.assert_array_equal(out[5, 0], xx[0, 1])
@@ -329,7 +331,8 @@ class TestVerifyBlockAttention:
         ref = _slot_attend_block(q, ck, cv, pos, 1.0 / np.sqrt(d))
         for block_k in (16, 512):  # multi-block online softmax AND 1-block
             out = decode_attention_block(
-                q, ck, cv, pos, block_k=block_k, interpret=True
+                q, merge_heads(ck), merge_heads(cv), pos, block_k=block_k,
+                interpret=True,
             )
             np.testing.assert_allclose(out, ref, rtol=_ULP, atol=_ULP)
 
@@ -342,7 +345,9 @@ class TestVerifyBlockAttention:
         rs = np.random.RandomState(5)
         q, ck, cv, pos = self._case(rs, 2, 3, 4, 2, 8, 16, [0, 13])
         ref = _slot_attend_block(q, ck, cv, pos, 1.0 / np.sqrt(8))
-        out = decode_attention_block(q, ck, cv, pos, interpret=True)
+        out = decode_attention_block(
+            q, merge_heads(ck), merge_heads(cv), pos, interpret=True
+        )
         np.testing.assert_allclose(out, ref, rtol=_ULP, atol=_ULP)
 
     @pytest.mark.parametrize("s", [2, 4])
@@ -368,7 +373,8 @@ class TestVerifyBlockAttention:
             q, gather(pool_k), gather(pool_v), pos, 1.0 / np.sqrt(d)
         )
         out = paged_decode_attention_block(
-            q, pool_k, pool_v, tables, pos, interpret=True
+            q, merge_heads(pool_k), merge_heads(pool_v), tables, pos,
+            interpret=True,
         )
         np.testing.assert_allclose(out, ref, rtol=_ULP, atol=_ULP)
 

@@ -228,10 +228,11 @@ class GPT2(nn.Module):
         """One decode step for a batch of independent serving slots:
         ``tokens`` (B, S), ``positions`` (B,) int32 per-row cache depths
         (token ``(b, i)`` sits at depth ``positions[b] + i``; ``S > 1``
-        is the speculative verify block).  With ``page_tables`` the
-        cache pytree is the per-layer page pools (``serve/kv_cache.py``).
-        Returns (logits, new_cache); same cache pytree as it was
-        given."""
+        is the speculative verify block).  ``cache`` is the serve
+        engine's, in its stored layout (``serve/kv_cache.py``: head tail
+        merged, (B, max_len, H * D)); with ``page_tables`` it is the
+        per-layer page pools.  Returns (logits, new_cache); same cache
+        pytree as it was given."""
         s = tokens.shape[1]
         if s == 1:
             x = self.tok_emb(tokens) + self.pos_emb(positions)[:, None]

@@ -429,9 +429,12 @@ class Llama(nn.Module):
         """One decode step for a batch of independent serving slots:
         ``tokens`` (B, 1), ``positions`` (B,) int32 — row ``b``'s token
         is written at its own cache depth ``positions[b]``
-        (``ops.attention.slot_cached_attention``).  With ``page_tables``
-        the cache pytree is the per-layer page pools and row ``b``'s
-        depth indexes its page chain.  Returns (logits, new_cache); same
+        (``ops.attention.slot_cached_attention``).  ``cache`` is the
+        serve engine's, in its STORED layout (``serve/kv_cache.py``: per
+        layer ``(k, v)`` of shape (B, max_len, Hkv * D), not
+        ``init_cache``'s (B, S, Hkv, D)).  With ``page_tables`` the
+        cache pytree is the per-layer page pools and row ``b``'s depth
+        indexes its page chain.  Returns (logits, new_cache); same
         cache-ins/cache-outs pytree as it was given."""
         cfg = self.cfg
         x = self.tok_emb(tokens)

@@ -188,8 +188,9 @@ def _slot_attend(
     window: Optional[int],
 ) -> jax.Array:
     """The jnp per-slot attend shared by the contiguous and paged decode
-    paths: ``ck``/``cv`` are (B, max_seq, Hkv, D) — the slab itself or a
-    page-table gather of it — and row ``b`` attends rows
+    paths: ``ck``/``cv`` are (B, max_seq, Hkv, D) — a 4-D view of the
+    stored slab or of a page-table gather of the pools — and row ``b``
+    attends rows
     ``j <= positions[b]`` (within the trailing ``window`` when set).  One
     definition so the two layouts can never diverge bitwise: a gathered
     view holds the same visible values as the slab, and the masked tail
@@ -269,10 +270,17 @@ def slot_cached_attention(
     the speculative verify block (``ServeEngine(speculate=K)`` passes
     ``S = K + 1`` candidates), where row ``i`` writes at
     ``positions[b] + i`` and attends ``j <= positions[b] + i``.
-    ``cache`` is ``(k, v)`` of shape (B, max_seq, Hkv, D);
-    ``positions`` is (B,) int32.  Row-for-row this is exactly the
-    ``s == 1`` path of :func:`cached_attention` (same write, same
-    visibility rule, f32 softmax), so a slot's decode stream is
+    ``cache`` is ``(k, v)`` in the serve engine's STORED layout
+    (``serve/kv_cache.py``): shape (B, max_seq, Hkv * D), the head tail
+    merged — the decode kernel's operand as it is, so the compiled
+    program never relayouts the cache.  The new rows are flattened
+    ``(B, S, Hkv, D) -> (B, S, Hkv * D)`` before the write; the jnp
+    attends take a 4-D view of what they read (free on CPU, a copy on
+    the chip, where those paths already materialize ``_repeat_kv``
+    copies of the whole cache).  ``positions`` is (B,) int32.
+    Row-for-row this is exactly the
+    ``s == 1`` path of :func:`cached_attention` (the same row written,
+    same visibility rule, f32 softmax), so a slot's decode stream is
     bit-identical to single-request decode at the same position.
     GQA-aware; ``window`` applies the same end-aligned sliding band as
     the scalar path.  Returns (out, (ck, cv)).
@@ -283,14 +291,15 @@ def slot_cached_attention(
     (``ops.decode_attention``): per-slot length-masked blocks streamed
     off the slab, no ``_repeat_kv`` copy, no (B, H, max_seq) logits
     band — the hot op of the serve engine's fused decode loop.  The
-    write itself (vmap'd ``dynamic_update_slice``) is identical on both
+    write itself (one scatter of the slots' rows per cache array,
+    ``serve/kv_cache.scatter_slot_tokens``) is identical on both
     paths, and the kernel's single-K-block configuration is
     bit-identical to the jnp path in interpret mode
     (``ops/decode_attention.py`` docstring); windowed decode stays jnp.
 
     **Paged cache**: with ``page_tables`` (B, pages_per_slot) int32 set,
     ``cache`` is instead the per-layer page pools of shape
-    ``(num_pages, page_size, Hkv, D)`` and row ``b``'s logical cache is
+    ``(num_pages, page_size, Hkv * D)`` and row ``b``'s logical cache is
     the concatenation of the pages ``page_tables[b]`` names.  The new
     K/V are scattered to ``page_tables[b, positions[b] // page_size]``
     at offset ``positions[b] % page_size``; the attend either runs the
@@ -303,7 +312,8 @@ def slot_cached_attention(
 
     **Quantized cache** (``ServeEngine(kv_dtype="int8")``): ``cache`` is
     the 4-tuple ``(k, v, k_scale, v_scale)`` — int8 data plus f32
-    per-row per-head scales (``serve/kv_cache.py``).  New K/V quantize
+    per-row per-head scales of shape (B, max_seq, Hkv)
+    (``serve/kv_cache.py``).  New K/V quantize
     on write (data and scale rows ride the same scatter indices), the
     pallas kernels dequantize blocks as they stream through VMEM
     (``k_scale=``/``v_scale=`` operands), and the jnp paths attend the
@@ -314,175 +324,77 @@ def slot_cached_attention(
     b, s, hq, d = q.shape
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
-    quantized = len(cache) == 4
-    if quantized:
-        from ..serve.kv_cache import _tap_quant, dequantize_kv, quantize_kv
-
-        ck, cv, cks, cvs = cache
-        qk_new, sk_new = quantize_kv(k_new)
-        qv_new, sv_new = quantize_kv(v_new)
-        _tap_quant(k_new, qk_new, sk_new)
-        _tap_quant(v_new, qv_new, sv_new)
-    else:
-        ck, cv = cache
-        cks = cvs = None
+    if s != 1 and window is not None:
+        raise ValueError(
+            f"multi-token slot decode does not support window "
+            f"(got S={s}, window={window})"
+        )
+    from ..serve.kv_cache import (
+        heads_view,
+        paged_scatter_tokens,
+        paged_view,
+        scatter_slot_tokens,
+        stored_rows,
+    )
     from .flash_attention import resolve_use_flash
 
-    if s != 1:
-        # Speculative verify block (ServeEngine(speculate=K)): S = K + 1
-        # candidate tokens per slot, query row i masked to its OWN depth
-        # positions[b] + i.  Every op on this path is query-row
-        # independent, so row i's output is bit-identical to the S == 1
-        # call at position positions[b] + i with the same cache prefix —
-        # the property the engine's greedy spec-vs-nonspec bit-identity
-        # contract rests on.  Writes go through the multi-token scatters
-        # (serve/kv_cache.py): rows past max_len are dropped, never
-        # clamped or wrapped.
-        if window is not None:
-            raise ValueError(
-                f"multi-token slot decode does not support window "
-                f"(got S={s}, window={window})"
-            )
-        from ..serve.kv_cache import (
-            paged_scatter_tokens,
-            scatter_slot_tokens,
-        )
+    paged = page_tables is not None
+    # the new rows in the cache's own form — one array per cache array
+    # (K, V and, quantized, their scale rows), head tail merged, cache
+    # dtype.  The CACHE is never reshaped on its way to the kernel.
+    rows = stored_rows(cache, k_new, v_new)
 
-        if page_tables is not None:
-            ps = ck.shape[1]
-            pp = page_tables.shape[1]
-            if quantized:
-                ck = paged_scatter_tokens(
-                    ck, qk_new, page_tables, positions, ps
-                )
-                cv = paged_scatter_tokens(
-                    cv, qv_new, page_tables, positions, ps
-                )
-                cks = paged_scatter_tokens(
-                    cks, sk_new, page_tables, positions, ps
-                )
-                cvs = paged_scatter_tokens(
-                    cvs, sv_new, page_tables, positions, ps
-                )
-            else:
-                ck = paged_scatter_tokens(
-                    ck, k_new, page_tables, positions, ps
-                )
-                cv = paged_scatter_tokens(
-                    cv, v_new, page_tables, positions, ps
-                )
-            new_cache = (ck, cv, cks, cvs) if quantized else (ck, cv)
-            if resolve_use_flash(use_flash):
-                from .decode_attention import paged_decode_attention_block
-
-                out = paged_decode_attention_block(
-                    q, ck, cv, page_tables, positions, scale=scale,
-                    k_scale=cks, v_scale=cvs,
-                )
-                return out, new_cache
-            flat = lambda c: c.reshape(-1, *c.shape[2:])  # noqa: E731
-            view_rows = (
-                page_tables[:, :, None] * ps + jnp.arange(ps)[None, None, :]
-            ).reshape(b, pp * ps)
-            vk, vv = flat(ck)[view_rows], flat(cv)[view_rows]
-            if quantized:
-                vk = dequantize_kv(vk, flat(cks)[view_rows])
-                vv = dequantize_kv(vv, flat(cvs)[view_rows])
-            out = _slot_attend_block(q, vk, vv, positions, scale)
-            return out, new_cache
-        if quantized:
-            ck = scatter_slot_tokens(ck, qk_new, positions)
-            cv = scatter_slot_tokens(cv, qv_new, positions)
-            cks = scatter_slot_tokens(cks, sk_new, positions)
-            cvs = scatter_slot_tokens(cvs, sv_new, positions)
-        else:
-            ck = scatter_slot_tokens(ck, k_new, positions)
-            cv = scatter_slot_tokens(cv, v_new, positions)
-        new_cache = (ck, cv, cks, cvs) if quantized else (ck, cv)
-        if resolve_use_flash(use_flash):
-            from .decode_attention import decode_attention_block
-
-            out = decode_attention_block(
-                q, ck, cv, positions, scale=scale, k_scale=cks, v_scale=cvs
-            )
-            return out, new_cache
-        if quantized:
-            out = _slot_attend_block(
-                q, dequantize_kv(ck, cks), dequantize_kv(cv, cvs),
-                positions, scale,
-            )
-        else:
-            out = _slot_attend_block(q, ck, cv, positions, scale)
-        return out, new_cache
-    if page_tables is not None:
-        ps = ck.shape[1]
-        pp = page_tables.shape[1]
-        flat = lambda c: c.reshape(-1, *c.shape[2:])  # noqa: E731
-        # the write: one pool row per slot.  A slot's current tail page
-        # is exclusively owned (sharing is full-prefix-pages only), so
-        # the scatter indices of ACTIVE slots never collide; retired
-        # slots' tables all name the scratch page, whose bits are never
-        # visible to any query.
-        rows = (
-            page_tables[jnp.arange(b), positions // ps] * ps
-            + positions % ps
+    # -- the write: S rows per slot, each array one scatter of (slot |
+    # page, row) indexed rows (serve/kv_cache.py).  S == 1 is the decode
+    # step (paged: a slot's current tail page is exclusively owned —
+    # sharing is full-prefix-pages only — so the scatter indices of
+    # ACTIVE slots never collide; retired slots' tables all name the
+    # scratch page, whose bits are never visible to any query); S > 1
+    # the speculative verify block (ServeEngine(speculate=K)), S = K + 1
+    # candidate rows per slot.  Rows past max_len are dropped, never
+    # clamped or wrapped; the engine's positions are always in range.
+    if paged:
+        ps = cache[0].shape[1]
+        cache = tuple(
+            paged_scatter_tokens(c, x, page_tables, positions, ps)
+            for c, x in zip(cache, rows)
         )
-        fk = flat(ck).at[rows].set(
-            (qk_new if quantized else k_new)[:, 0].astype(ck.dtype)
-        )
-        fv = flat(cv).at[rows].set(
-            (qv_new if quantized else v_new)[:, 0].astype(cv.dtype)
-        )
-        ck, cv = fk.reshape(ck.shape), fv.reshape(cv.shape)
-        if quantized:
-            fks = flat(cks).at[rows].set(sk_new[:, 0])
-            fvs = flat(cvs).at[rows].set(sv_new[:, 0])
-            cks, cvs = fks.reshape(cks.shape), fvs.reshape(cvs.shape)
-        new_cache = (ck, cv, cks, cvs) if quantized else (ck, cv)
-        if window is None and resolve_use_flash(use_flash):
-            from .decode_attention import paged_decode_attention
-
-            out = paged_decode_attention(
-                q, ck, cv, page_tables, positions, scale=scale,
-                k_scale=cks, v_scale=cvs,
-            )
-            return out, new_cache
-        view_rows = (
-            page_tables[:, :, None] * ps + jnp.arange(ps)[None, None, :]
-        ).reshape(b, pp * ps)
-        vk, vv = fk[view_rows], fv[view_rows]
-        if quantized:
-            vk = dequantize_kv(vk, fks[view_rows])
-            vv = dequantize_kv(vv, fvs[view_rows])
-        out = _slot_attend(q, vk, vv, positions, scale, window)
-        return out, new_cache
-    write = lambda c, x, p: lax.dynamic_update_slice(  # noqa: E731
-        c, x.astype(c.dtype), (p, 0, 0)
-    )
-    if quantized:
-        ck = jax.vmap(write)(ck, qk_new, positions)
-        cv = jax.vmap(write)(cv, qv_new, positions)
-        cks = jax.vmap(write)(cks, sk_new, positions)
-        cvs = jax.vmap(write)(cvs, sv_new, positions)
     else:
-        ck = jax.vmap(write)(ck, k_new, positions)
-        cv = jax.vmap(write)(cv, v_new, positions)
-    new_cache = (ck, cv, cks, cvs) if quantized else (ck, cv)
+        cache = tuple(
+            scatter_slot_tokens(c, x, positions) for c, x in zip(cache, rows)
+        )
+
+    # -- the attend.  Every op on either path is query-row independent,
+    # so row i of an S > 1 block is bit-identical to the S == 1 call at
+    # position positions[b] + i with the same cache prefix — the
+    # property the engine's greedy spec-vs-nonspec bit-identity contract
+    # rests on.
     if window is None and resolve_use_flash(use_flash):
-        from .decode_attention import decode_attention
+        from . import decode_attention as da
 
-        out = decode_attention(
-            q, ck, cv, positions, scale=scale, k_scale=cks, v_scale=cvs
-        )
-        return out, new_cache
-    if quantized:
-        out = _slot_attend(
-            q, dequantize_kv(ck, cks), dequantize_kv(cv, cvs),
-            positions, scale, window,
-        )
+        scales = dict(zip(("k_scale", "v_scale"), cache[2:]))
+        if paged:
+            out = da.paged_decode_attention_block(
+                q, cache[0], cache[1], page_tables, positions,
+                scale=scale, **scales,
+            )
+        else:
+            out = da.decode_attention_block(
+                q, cache[0], cache[1], positions, scale=scale, **scales
+            )
+        return out, cache
+    # jnp: a 4-D view of what is read — the slab, or each slot's logical
+    # row gathered through its page table
+    hkv = k_new.shape[2]
+    if paged:
+        vk, vv = paged_view([cache], page_tables, hkv)[0]
     else:
-        out = _slot_attend(q, ck, cv, positions, scale, window)
-    return out, new_cache
+        vk, vv = heads_view(cache, hkv)
+    if s == 1:
+        out = _slot_attend(q, vk, vv, positions, scale, window)
+    else:
+        out = _slot_attend_block(q, vk, vv, positions, scale)
+    return out, cache
 
 
 def multihead_attention(

@@ -5,26 +5,34 @@ the hot inner op of ``ServeEngine``'s fused decode loop.  The jnp
 reference (``ops.attention.slot_cached_attention``) materializes the full
 ``(B, H, 1, max_len)`` f32 logits band and a ``_repeat_kv`` copy of the
 whole slab every step; this kernel streams per-slot length-masked K/V
-blocks straight off the ``(num_slots, max_len, Hkv, D)`` slab with an
+blocks straight off the ``(num_slots, max_len, Hkv * D)`` slab with an
 online-softmax accumulator — flash-decode, the single-query sibling of
 ``ops/flash_attention.py``.
 
 Layout and masking:
 
-- The slab is consumed IN ITS NATIVE LAYOUT ``(B, max_len, Hkv, D)`` —
-  no transpose of the multi-hundred-MB cache per decode step — but
-  VIEWED with its tail flattened, ``(B, max_len, Hkv * D)`` (a free
-  reshape of a contiguous array).  Mosaic tiles the last two axes of a
-  block as (sublane, lane) and refuses a block that squeezes the
-  second-to-last one, so a head cannot be picked by squeezing the
-  ``Hkv`` axis; in the flattened view a KV head is the 128-aligned lane
-  range ``[h * D, (h + 1) * D)`` and the K/V block is the plain
-  ``(block_k, D)`` tile at lane-block ``h``.  Grid is
-  ``(B, Hkv, n_k)``.  When ``D`` is not a multiple of the 128-lane
-  width (GPT-2's 64, the test models) the block spans the whole
-  ``Hkv * D`` tail instead and the kernel walks the heads with static
-  lane slices (grid ``(B, 1, n_k)``); which of the two is a function of
-  the shapes alone.  int8 scales ``(B, max_len, Hkv, 1)`` are viewed as
+- The slab is consumed AS THE ENGINE STORES IT, ``(B, max_len,
+  Hkv * D)`` with the head tail merged (``serve/kv_cache.py``) — no
+  transpose and no reshape of the multi-hundred-MB cache per decode
+  step.  Mosaic tiles the last two axes of a block as (sublane, lane)
+  and refuses a block that squeezes the second-to-last one, so a head
+  cannot be picked by squeezing an ``Hkv`` axis; with the tail merged a
+  KV head is the 128-aligned lane range ``[h * D, (h + 1) * D)`` and
+  the K/V block is the plain ``(block_k, D)`` tile at lane-block ``h``.
+  The cache has to be STORED that way, because on the chip the merge is
+  not a view: XLA tiles the last two axes of an array too, so a bf16
+  ``(B, L, Hkv, D)`` array is laid out ``{3,2,1,0:T(8,128)(2,1)}`` with
+  one tile holding the ``Hkv`` heads of ONE row, and ``(B, L, Hkv * D)``
+  is ``{2,1,0:T(8,128)(2,1)}`` with one tile holding eight ROWS of one
+  head's lanes — the ``reshape`` between the two reads and writes the
+  whole array (67 MB a slab at Mistral-7B widths, for K and for V, in
+  every layer of every decode step: 29 % of the device's busy time when
+  this wrapper still did it, PERF.md §6 PR 28).
+  Grid is ``(B, Hkv, n_k)``.  When ``D`` is not a multiple of the
+  128-lane width (GPT-2's 64, the test models) the block spans the
+  whole ``Hkv * D`` tail instead and the kernel walks the heads with
+  static lane slices (grid ``(B, 1, n_k)``); which of the two is a
+  function of the shapes alone.  int8 scales are stored
   ``(B, max_len, Hkv)`` and the kernel selects its head's column with
   an exact one-hot lane reduction.
 - GQA is folded in: the ``n_rep = Hq // Hkv`` query heads of one KV
@@ -40,8 +48,7 @@ Layout and masking:
 
 ``paged_decode_attention`` is the same kernel over the serve engine's
 PAGED cache (``serve/kv_cache.py``): K/V live as per-layer page pools
-``(num_pages, page_size, Hkv, D)`` (viewed ``(num_pages, page_size,
-Hkv * D)`` the same way) and each slot's logical row is the
+``(num_pages, page_size, Hkv * D)`` and each slot's logical row is the
 chain of pages its scalar-prefetched page-table row names.  The K block
 is the page — the index map does the gather, the kernel body is shared —
 so shared-prefix pages are attended in place, never copied to a
@@ -231,24 +238,30 @@ def _launch(
     block_k, n_k, scale, interpret, name,
 ):
     """Shared wrapper of the four families.  ``ck``/``cv``: the slab
-    (B, max_len, Hkv, D) or the pools (num_pages, page_size, Hkv, D).
+    (B, max_len, Hkv * D) or the pools (num_pages, page_size, Hkv * D),
+    handed to the kernel as they are; ``Hkv`` is ``ck.shape[-1] // D``.
     ``kv_index(bb, kk, *prefetch_refs) -> (lead, row_block)`` names the
     K/V block grid step ``(bb, ·, kk)`` reads; ``prefetch`` are the
     scalar-prefetch operands, per-slot base depths first.  ``name`` is
     the kernel's fixed name in the compiled program and in a profile."""
     b, s, hq, d = q.shape
-    hkv = ck.shape[2]
+    if ck.ndim != 3 or ck.shape[-1] % d != 0 or cv.shape != ck.shape:
+        raise ValueError(
+            f"K/V cache shapes {ck.shape}/{cv.shape} are not the stored "
+            f"layout (lead, rows, Hkv * {d})"
+        )
+    hkv = ck.shape[-1] // d
     if hq % hkv != 0:
         raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
     quantized = k_scale is not None
     if quantized:
-        want = ck.shape[:3] + (1,)
+        want = ck.shape[:2] + (hkv,)
         if k_scale.shape != want or v_scale.shape != want:
             raise ValueError(
                 f"kv scale shapes {k_scale.shape}/{v_scale.shape} != "
-                f"cache rows + trailing 1 {want}"
+                f"cache rows + kv heads {want}"
             )
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
@@ -275,16 +288,15 @@ def _launch(
     def scale_index(bb, h, kk, *pf):
         return (*kv_index(bb, kk, *pf), 0)
 
-    flat = lambda c: c.reshape(*c.shape[:2], -1)  # noqa: E731
     in_specs = [
         pl.BlockSpec((None, g, rows, d), q_index),
         pl.BlockSpec((None, block_k, g * d), data_index),
         pl.BlockSpec((None, block_k, g * d), data_index),
     ]
-    operands = [qg, flat(ck), flat(cv)]
+    operands = [qg, ck, cv]
     if quantized:
         in_specs += [pl.BlockSpec((None, block_k, hkv), scale_index)] * 2
-        operands += [flat(k_scale), flat(v_scale)]
+        operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b, hkv // g, n_k),
@@ -336,7 +348,7 @@ def decode_attention(
 
     ``q``: (B, 1, Hq, D) — each slot's next-token query, positional
     encoding already applied.  ``ck``/``cv``: the engine slab
-    (B, max_len, Hkv, D) with the new K/V already written at each slot's
+    (B, max_len, Hkv * D) with the new K/V already written at each slot's
     row (``slot_cached_attention`` performs the write; this kernel only
     attends).  ``positions``: (B,) int32 — slot ``b`` attends cache rows
     ``j <= positions[b]``.  Returns (B, 1, Hq, D) in ``q.dtype``.
@@ -347,7 +359,7 @@ def decode_attention(
     defaults to True off-TPU, per the repo kernel convention.
 
     **int8 cache** (``kv_dtype="int8"``): pass the f32 per-row per-head
-    scales as ``k_scale``/``v_scale`` of shape (B, max_len, Hkv, 1) —
+    scales as ``k_scale``/``v_scale`` of shape (B, max_len, Hkv) —
     they ride the SAME row-block index as their data (one
     (block_k, Hkv) scale block per K/V block, clamped together), and
     the kernel dequantizes each block in VMEM before Q·K / P·V, which
@@ -423,7 +435,7 @@ def paged_decode_attention(
     engine's prefix-sharing sibling of :func:`decode_attention`.
 
     ``q``: (B, 1, Hq, D).  ``ck``/``cv``: the per-layer page pools,
-    shape (num_pages, page_size, Hkv, D), the new K/V already scattered
+    shape (num_pages, page_size, Hkv * D), the new K/V already scattered
     at each slot's current row (``slot_cached_attention`` performs the
     write).  ``page_tables``: (B, pages_per_slot) int32 — slot ``b``'s
     logical cache is the concatenation of the pages ``page_tables[b]``
@@ -441,7 +453,7 @@ def paged_decode_attention(
     bit-exact-softmax fast path the slot kernel pins; multi-page rows
     take the online-softmax merge at the same <= 2-ulp association bar
     (tests/test_decode_attention.py).  ``k_scale``/``v_scale``:
-    int8-cache dequant scales of shape (num_pages, page_size, Hkv, 1),
+    int8-cache dequant scales of shape (num_pages, page_size, Hkv),
     gathered through the same table as their pages.
     """
     if q.shape[1] != 1:
@@ -473,7 +485,7 @@ def paged_decode_attention_block(
     (block == page; pruning and the DMA clamp run in TABLE space on the
     block's deepest row ``positions[b] + S - 1``).  ``k_scale``/
     ``v_scale``: int8-cache dequant scales of shape (num_pages,
-    page_size, Hkv, 1), gathered through the same table."""
+    page_size, Hkv), gathered through the same table."""
     s, ps = q.shape[1], ck.shape[1]
     if page_tables.shape[0] != q.shape[0]:
         raise ValueError(

@@ -79,8 +79,9 @@ def llama_tp_plan(
     sub-layer, which XLA inserts.  Embedding and head shard over vocab.
     With ``fsdp_axis``, the other matrix dim is additionally FSDP-sharded
     (2D TP x FSDP).  The plan also carries the serve KV pool's layout as
-    the ``kv_cache`` pseudo-path rule (pages sharded over heads on
-    ``tp_axis`` — dim 2 of the (slots, pages, heads, head_dim) pool).
+    the ``kv_cache`` pseudo-path rule (sharded over heads on ``tp_axis``
+    — dim 2 of the stored (slots | pages, rows, heads * head_dim) pool,
+    which splits into contiguous ``heads / tp`` groups).
     """
     f = fsdp_axis  # may be None -> replicated on that dim
     rules = (
@@ -90,7 +91,7 @@ def llama_tp_plan(
         (r"\.w_down\.weight$", P(f, tp_axis)),
         (r"tok_emb\.weight$", P(tp_axis, f)),
         (r"lm_head\.weight$", P(tp_axis, f)),
-        (r"^kv_cache$", P(None, None, tp_axis, None)),
+        (r"^kv_cache$", P(None, None, tp_axis)),
     )
     return ShardingPlan(mesh, rules=rules, **plan_kwargs)
 

@@ -61,7 +61,7 @@ top-k/top-p filters; the two jitted programs live in the model's
 model.
 
 **Paged mode** (``page_size=N``): the device cache becomes per-layer
-page pools ``(num_pages, page_size, Hkv, D)`` with host page tables
+page pools ``(num_pages, page_size, Hkv * D)`` with host page tables
 (``serve/kv_cache.py``) and a refcounted radix prefix index
 (``serve/prefix_cache.py``).  Admission additionally gates on free
 pages (a request claims only its page-aligned ``prompt +
@@ -118,7 +118,7 @@ from .kv_cache import (
     PagedKVCache,
     SlotKVCache,
     canonicalize_kv_dtype,
-    dequantize_kv,
+    heads_view,
     paged_scatter_rows,
     paged_view,
     write_slot,
@@ -160,16 +160,20 @@ def _cache_sharding(
     ``kv_heads % tp`` divisibility assertion still gates below either way.
 
     With a ``mesh`` the policy is the **head-axis sharding**: every cache
-    array is ``(num_slots | num_pages, rows, Hkv, D)``, and
-    ``NamedSharding(mesh, P(None, None, tp_axis, None))`` co-locates each
-    device's ``Hkv / tp`` head group with the Megatron column shards
-    (``wq``/``wk``/``wv``) that produce it — attention then partitions
-    along heads under GSPMD with no cache collective at all, and each
-    device holds ``1/tp`` of the KV footprint (which is what
+    array is stored ``(num_slots | num_pages, rows, Hkv * D)`` (scales:
+    ``Hkv``; ``serve/kv_cache.py``), and ``NamedSharding(mesh, P(None,
+    None, tp_axis))`` splits the merged tail into contiguous ``Hkv / tp``
+    head groups, co-locating each device's group with the Megatron
+    column shards (``wq``/``wk``/``wv``) that produce it — attention then
+    partitions along heads under GSPMD with no cache collective at all,
+    and each device holds ``1/tp`` of the KV footprint (which is what
     ``memory_plan()`` admits against).  ``kv_heads % tp`` is asserted
     here with a named error: an uneven split would make GSPMD pad or
     replicate the head axis, silently devouring the HBM the sharding
-    exists to save.
+    exists to save.  A plan's ``kv_cache`` rule may also be written
+    against the model's ``(lead, rows, Hkv, D)`` layout: its head entry
+    is mapped onto the merged axis (``head_dim`` cannot be split
+    separately — the stored array has no such axis).
 
     REPLICATED is the *fallback*, not the policy: with no mesh but
     sharded params (e.g. FSDP-materialized weights passed via
@@ -198,9 +202,17 @@ def _cache_sharding(
             )
         spec = None
         if plan is not None:
-            spec = plan.maybe_spec_for("kv_cache", (0, 0, kv_heads, 0))
+            spec = plan.maybe_spec_for("kv_cache", (0, 0, kv_heads))
         if spec is None:
-            spec = PartitionSpec(None, None, tp_axis, None)
+            spec = PartitionSpec(None, None, tp_axis)
+        elif len(spec) > 3:
+            if any(p is not None for p in spec[3:]):
+                raise ValueError(
+                    f"the plan's kv_cache rule {spec} shards head_dim: "
+                    "the KV cache is stored (lead, rows, Hkv * D) and "
+                    "splits over heads only"
+                )
+            spec = PartitionSpec(*spec[:3])
         return NamedSharding(mesh, spec)
     for leaf in jax.tree_util.tree_leaves(params):
         sh = getattr(leaf, "sharding", None)
@@ -325,9 +337,9 @@ class ServeEngine:
         (``parallel.tp.shard_params`` applies its rule projection — a
         no-op for leaves already carrying the target sharding), the
         KV slab/pools are sharded by the plan's ``kv_cache`` rule
-        (:func:`_cache_sharding`, default ``P(None, None, tp_axis,
-        None)``, with ``n_kv_heads % tp`` asserted), page tables stay
-        host-side,
+        (:func:`_cache_sharding`, default ``P(None, None, tp_axis)`` on
+        the stored ``(lead, rows, Hkv * D)`` arrays, with
+        ``n_kv_heads % tp`` asserted), page tables stay host-side,
         and every compiled program becomes one SPMD program with
         explicit ``out_shardings`` on its donated KV carry and sampled
         outputs (jit does not propagate input shardings into fresh
@@ -1665,27 +1677,23 @@ class ServeEngine:
         (it is the request's first token, sampler step 0 — identical to
         the unchunked program's); intermediate chunks discard it."""
         model, sampler, max_len = self.model, self._sampler, self.max_len
-        num_on = self.numerics
+        num_on, kv_heads = self.numerics, self.cache.kv_heads
 
         def build(params, kv, tokens, cache_pos, true_len, slot, temp, seed):
             def body():
                 def row(c):
                     return jax.lax.dynamic_slice(
-                        c, (slot, 0, 0, 0), (1, max_len) + c.shape[2:]
+                        c, (slot, 0, 0), (1, max_len, c.shape[2])
                     )
 
-                # quantized caches: slice data + scale rows, hand the
-                # model a dequantized pair view; write_slot requantizes
-                # on the way back (bit-stable for untouched rows —
-                # power-of-two scales, serve/kv_cache.py)
+                # the model gets the slot's row with its head axis back
+                # (a view of the one row, not of the cache); quantized
+                # caches slice data + scale rows and hand over a
+                # dequantized pair; write_slot requantizes on the way
+                # back (bit-stable for untouched rows — power-of-two
+                # scales, serve/kv_cache.py)
                 view = [
-                    (
-                        (dequantize_kv(row(e[0]), row(e[2])),
-                         dequantize_kv(row(e[1]), row(e[3])))
-                        if len(e) == 4
-                        else (row(e[0]), row(e[1]))
-                    )
-                    for e in kv
+                    heads_view([row(a) for a in e], kv_heads) for e in kv
                 ]
                 logits, view = functional_call(
                     model, params, (tokens, view, cache_pos),
@@ -1724,12 +1732,12 @@ class ServeEngine:
         the scratch page, where nothing ever reads them.
         """
         model, sampler, ps = self.model, self._sampler, self.page_size
-        num_on = self.numerics
+        num_on, kv_heads = self.numerics, self.cache.kv_heads
 
         def build_warm(params, kv, pt_row, tokens, pfx_len, true_len,
                        temp, seed):
             def body():
-                view = paged_view(kv, pt_row, ps)
+                view = paged_view(kv, pt_row, kv_heads)
                 logits, view = functional_call(
                     model, params, (tokens, view, pfx_len),
                     method="forward_cached",
@@ -1747,7 +1755,7 @@ class ServeEngine:
 
         def build_cold(params, kv, pt_row, tokens, true_len, temp, seed):
             def body():
-                view = paged_view(kv, pt_row, ps)
+                view = paged_view(kv, pt_row, kv_heads)
                 logits, view = functional_call(
                     model, params, (tokens, view, 0),
                     method="forward_cached",

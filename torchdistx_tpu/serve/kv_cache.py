@@ -3,17 +3,30 @@
 Two device layouts behind one host-bookkeeping contract:
 
 - :class:`SlotKVCache` — per layer ``(k, v)`` arrays of shape
-  ``(num_slots, max_len, heads, head_dim)`` (the model's own
-  ``init_cache(num_slots, max_len)`` layout).  HBM cost is
+  ``(num_slots, max_len, heads * head_dim)``.  HBM cost is
   ``num_slots x max_len`` regardless of actual request lengths.
 - :class:`PagedKVCache` — per layer ``(k, v)`` **page pools** of shape
-  ``(num_pages, page_size, heads, head_dim)`` (``init_cache(num_pages,
-  page_size)``), plus host-side per-slot page tables padded to
-  ``max_len / page_size`` entries.  A slot's logical cache is the
-  concatenation of the pages its table row names; requests claim only
-  the pages their ``prompt + max_new_tokens`` footprint needs, and
-  page-aligned shared prefixes are handed over by **table rewrite**
-  (two tables naming the same page), never by copying KV.
+  ``(num_pages, page_size, heads * head_dim)``, plus host-side per-slot
+  page tables padded to ``max_len / page_size`` entries.  A slot's
+  logical cache is the concatenation of the pages its table row names;
+  requests claim only the pages their ``prompt + max_new_tokens``
+  footprint needs, and page-aligned shared prefixes are handed over by
+  **table rewrite** (two tables naming the same page), never by copying
+  KV.
+
+**The stored layout is the decode kernel's operand layout**: the head
+tail is merged, ``(lead, rows, Hkv * D)`` (int8 scales ``(lead, rows,
+Hkv)``), because that is the array ``ops/decode_attention.py`` hands to
+Mosaic, and on the chip a ``(…, Hkv, D)`` array and its ``(…, Hkv * D)``
+"view" are tiled differently: the reshape between them copies the whole
+array (that file's docstring has the two tilings).  The merge happens
+once, at construction (:func:`merge_heads` on what
+``model.init_cache(lead, rows)`` returns); models keep their
+``(B, S, Hkv, D)`` ``init_cache`` / ``forward_cached`` contract for
+``generate()`` and for the prefill's one-request slab.  Every write
+flattens the NEW ROWS, never the cache; a reader that needs heads (the
+jnp attends, :func:`paged_view`, chunked prefill's warm program) takes
+a 4-D view of what it read (:func:`split_heads`).
 
 In both, admitting/retiring a request changes only tiny dynamic inputs
 (positions, a table row, a host bit) — never a device shape — so the
@@ -81,6 +94,10 @@ from .prefix_cache import SCRATCH_PAGE
 __all__ = [
     "SlotKVCache",
     "PagedKVCache",
+    "merge_heads",
+    "split_heads",
+    "heads_view",
+    "stored_rows",
     "write_slot",
     "paged_view",
     "paged_scatter_rows",
@@ -97,9 +114,10 @@ __all__ = [
 #
 # ``kv_dtype="int8"`` stores each layer as a 4-tuple ``(k, v, k_scale,
 # v_scale)`` instead of the ``(k, v)`` pair: int8 data plus f32
-# per-token-row per-head scales of shape ``(lead, rows, Hkv, 1)``.  The
+# per-token-row per-head scales, ``(…, Hkv, 1)`` as :func:`quantize_kv`
+# makes them and ``(lead, rows, Hkv)`` as the engine stores them.  The
 # scales are DEVICE arrays riding through the same scatter/gather sites
-# as the data (they share its leading dims, so every flat-row index
+# as the data (they share its leading dims, so every (lead, row) index
 # computed for a K/V write addresses the matching scale row) — host-side
 # scales could not ride through the donated jitted programs.
 #
@@ -176,7 +194,8 @@ def _tap_quant(orig: jax.Array, q: jax.Array, scale: jax.Array) -> None:
 
 
 def quantize_cache(kv: Any) -> Any:
-    """Pairs → per-layer ``(k, v, k_scale, v_scale)`` 4-tuples."""
+    """Pairs → per-layer ``(k, v, k_scale, v_scale)`` 4-tuples (the
+    model's ``(…, Hkv, D)`` layout on both sides)."""
     out: List[tuple] = []
     for k, v in kv:
         qk, sk = quantize_kv(k)
@@ -197,118 +216,128 @@ def dequantize_cache(kv: Any) -> Any:
     return out
 
 
+# -- the stored layout ------------------------------------------------------
+
+
+def merge_heads(x: jax.Array) -> jax.Array:
+    """Model layout → stored layout: K/V ``(…, Hkv, D)`` →
+    ``(…, Hkv * D)``, scales ``(…, Hkv, 1)`` → ``(…, Hkv)``.  Applied
+    to the whole cache once, at construction, and to NEW ROWS at every
+    write."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def split_heads(x: jax.Array, kv_heads: int) -> jax.Array:
+    """Stored layout → model layout: the inverse of :func:`merge_heads`
+    for a reader that needs the head axis (K/V ``(…, Hkv * D)`` →
+    ``(…, Hkv, D)``, scales ``(…, Hkv)`` → ``(…, Hkv, 1)``).  Only ever
+    applied to what was READ for a jnp path, never on the kernel path:
+    on the chip it is a copy of its operand."""
+    return x.reshape(*x.shape[:-1], kv_heads, -1)
+
+
+def heads_view(entry: Any, kv_heads: int) -> tuple:
+    """Stored ``(k, v)`` / ``(k, v, k_scale, v_scale)`` arrays (or any
+    rows gathered from them) → the model-facing ``(k, v)`` pair with
+    the head axis back, dequantized where there are scales."""
+    k, v = (split_heads(a, kv_heads) for a in entry[:2])
+    if len(entry) == 4:
+        ks, vs = (split_heads(a, kv_heads) for a in entry[2:])
+        return dequantize_kv(k, ks), dequantize_kv(v, vs)
+    return k, v
+
+
+def stored_rows(entry: Any, k: jax.Array, v: jax.Array) -> tuple:
+    """Freshly computed K/V rows ``(…, Hkv, D)`` → one array per array
+    of the stored ``entry``, each in that array's dtype with the head
+    tail merged: ``(k, v)``, or quantized on the way ``(k, v, k_scale,
+    v_scale)`` — so a write site is one ``zip`` over the entry whatever
+    the cache's dtype."""
+    if len(entry) == 4:
+        qk, sk = quantize_kv(k)
+        qv, sv = quantize_kv(v)
+        _tap_quant(k, qk, sk)
+        _tap_quant(v, qv, sv)
+        rows = (qk, qv, sk, sv)
+    else:
+        rows = (k, v)
+    return tuple(merge_heads(x).astype(c.dtype) for x, c in zip(rows, entry))
+
+
 def write_slot(kv: Any, slab: Any, slot) -> Any:
     """Write one request's prefilled cache slab into slot row ``slot``.
 
     ``kv``: the engine cache — list per layer of ``(k, v)`` with shape
-    (num_slots, max_len, H, D), or quantized 4-tuples ``(k, v, k_scale,
-    v_scale)`` (the slab pairs quantize on write).  ``slab``:
-    ``init_cache(1, bucket)`` output run through the model's prefill —
-    list per layer of ``(k, v)`` with shape (1, bucket, H, D).  ``slot``
-    may be traced (it is, inside the jitted prefill program); the write
-    is a pure ``dynamic_update_slice`` per layer — no recompile across
-    slots.
+    (num_slots, max_len, Hkv * D), or quantized 4-tuples ``(k, v,
+    k_scale, v_scale)`` with scales (num_slots, max_len, Hkv) (the slab
+    pairs quantize on write).  ``slab``: ``init_cache(1, bucket)``
+    output run through the model's prefill — list per layer of
+    ``(k, v)`` with shape (1, bucket, Hkv, D), the model's layout; its
+    head tail is merged here, on the slab (2 MB an array at bucket
+    1024), not on the cache.  ``slot`` may be traced (it is, inside the
+    jitted prefill program); the write is a pure
+    ``dynamic_update_slice`` per layer — no recompile across slots.
     """
-    out: List[tuple] = []
-    for entry, (sk, sv) in zip(kv, slab):
-        if len(entry) == 4:
-            ck, cv, cks, cvs = entry
-            qk, ssk = quantize_kv(sk)
-            qv, ssv = quantize_kv(sv)
-            _tap_quant(sk, qk, ssk)
-            _tap_quant(sv, qv, ssv)
-            out.append(
-                (
-                    lax.dynamic_update_slice(ck, qk, (slot, 0, 0, 0)),
-                    lax.dynamic_update_slice(cv, qv, (slot, 0, 0, 0)),
-                    lax.dynamic_update_slice(cks, ssk, (slot, 0, 0, 0)),
-                    lax.dynamic_update_slice(cvs, ssv, (slot, 0, 0, 0)),
-                )
-            )
-            continue
-        ck, cv = entry
-        out.append(
-            (
-                lax.dynamic_update_slice(
-                    ck, sk.astype(ck.dtype), (slot, 0, 0, 0)
-                ),
-                lax.dynamic_update_slice(
-                    cv, sv.astype(cv.dtype), (slot, 0, 0, 0)
-                ),
-            )
+    return [
+        tuple(
+            lax.dynamic_update_slice(c, x, (slot, 0, 0))
+            for c, x in zip(entry, stored_rows(entry, sk, sv))
         )
-    return out
+        for entry, (sk, sv) in zip(kv, slab)
+    ]
 
 
-def paged_view(kv: Any, table_row: jax.Array, page_size: int) -> Any:
-    """Gather one slot's logical cache from the page pools.
+def paged_view(kv: Any, tables: jax.Array, kv_heads: int) -> Any:
+    """Gather slots' logical caches from the page pools.
 
     ``kv``: list per layer of ``(k, v)`` pools, shape (num_pages,
-    page_size, H, D).  ``table_row``: (pages_per_slot,) int32 page ids
-    (unassigned entries name the scratch page — their rows are garbage
-    but sit beyond the visibility mask).  Returns the model-facing view:
-    list per layer of ``(k, v)`` with shape (1, max_len, H, D), where
-    ``max_len = pages_per_slot * page_size``.  A pure gather — the pools
-    are read, never copied page-to-page.  Quantized 4-tuple pools
-    dequantize in the gather: the view is always model-dtype pairs.
+    page_size, Hkv * D).  ``tables``: one slot's (pages_per_slot,) int32
+    page ids, or (B, pages_per_slot) for B slots (unassigned entries
+    name the scratch page — their rows are garbage but sit beyond the
+    visibility mask).  Returns the model-facing view: list per layer of
+    ``(k, v)`` with shape (B, max_len, Hkv, D) — B = 1 for one table
+    row — where ``max_len = pages_per_slot * page_size`` and
+    ``Hkv = kv_heads``.  A pure gather — the pools are read, never
+    copied page-to-page.  Quantized 4-tuple pools dequantize in the
+    gather: the view is always model-dtype pairs.
     """
-    rows = (
-        table_row[:, None] * page_size + jnp.arange(page_size)[None, :]
-    ).reshape(-1)
-    out: List[tuple] = []
-    for entry in kv:
-        k, v = entry[0], entry[1]
-        fk = k.reshape(-1, *k.shape[2:])[rows]
-        fv = v.reshape(-1, *v.shape[2:])[rows]
-        if len(entry) == 4:
-            ks, vs = entry[2], entry[3]
-            fk = dequantize_kv(fk, ks.reshape(-1, *ks.shape[2:])[rows])
-            fv = dequantize_kv(fv, vs.reshape(-1, *vs.shape[2:])[rows])
-        out.append((fk[None], fv[None]))
-    return out
+    tables = jnp.atleast_2d(tables)
+    return [
+        heads_view(
+            [
+                a[tables].reshape(tables.shape[0], -1, a.shape[-1])
+                for a in entry
+            ],
+            kv_heads,
+        )
+        for entry in kv
+    ]
 
 
 def paged_scatter_rows(
     kv: Any, view: Any, table_row: jax.Array, page_size: int, start, length: int
 ) -> Any:
     """Write ``length`` freshly computed rows of an updated slot view
-    (starting at traced row ``start``) back into the page pools through
-    the slot's table row.  Only the suffix span moves — shared prefix
-    pages are never rewritten.  ``length`` is static (the prefill
-    bucket); rows landing past the slot's allocated pages route to the
-    scratch page (bucket padding) and are never visible.  Quantized
-    4-tuple pools quantize the suffix on write (the scale rows scatter
-    through the same flat-row indices as the data)."""
+    (``(1, max_len, Hkv, D)`` pairs, starting at traced row ``start``)
+    back into the page pools through the slot's table row.  Only the
+    suffix span moves — shared prefix pages are never rewritten.
+    ``length`` is static (the prefill bucket); rows landing past the
+    slot's allocated pages route to the scratch page (bucket padding)
+    and are never visible.  Quantized 4-tuple pools quantize the suffix
+    on write (the scale rows scatter through the same (page, row)
+    indices as the data)."""
     offs = start + jnp.arange(length)
-    rows = table_row[offs // page_size] * page_size + offs % page_size
+    pages, rows = table_row[offs // page_size], offs % page_size
     out: List[tuple] = []
     for entry, (wk, wv) in zip(kv, view):
-        k, v = entry[0], entry[1]
         seg_k = lax.dynamic_slice_in_dim(wk[0], start, length, axis=0)
         seg_v = lax.dynamic_slice_in_dim(wv[0], start, length, axis=0)
-        if len(entry) == 4:
-            ks, vs = entry[2], entry[3]
-            seg_qk, seg_ks = quantize_kv(seg_k)
-            seg_qv, seg_vs = quantize_kv(seg_v)
-            _tap_quant(seg_k, seg_qk, seg_ks)
-            _tap_quant(seg_v, seg_qv, seg_vs)
-            seg_k, seg_v = seg_qk, seg_qv
-            fks = ks.reshape(-1, *ks.shape[2:]).at[rows].set(seg_ks)
-            fvs = vs.reshape(-1, *vs.shape[2:]).at[rows].set(seg_vs)
-            fk = k.reshape(-1, *k.shape[2:]).at[rows].set(seg_k)
-            fv = v.reshape(-1, *v.shape[2:]).at[rows].set(seg_v)
-            out.append(
-                (
-                    fk.reshape(k.shape),
-                    fv.reshape(v.shape),
-                    fks.reshape(ks.shape),
-                    fvs.reshape(vs.shape),
-                )
+        out.append(
+            tuple(
+                c.at[pages, rows].set(x)
+                for c, x in zip(entry, stored_rows(entry, seg_k, seg_v))
             )
-            continue
-        fk = k.reshape(-1, *k.shape[2:]).at[rows].set(seg_k.astype(k.dtype))
-        fv = v.reshape(-1, *v.shape[2:]).at[rows].set(seg_v.astype(v.dtype))
-        out.append((fk.reshape(k.shape), fv.reshape(v.shape)))
+        )
     return out
 
 
@@ -316,34 +345,34 @@ def scatter_slot_tokens(
     cache: jax.Array, x_new: jax.Array, positions: jax.Array
 ) -> jax.Array:
     """Write ``S`` consecutive freshly computed rows per slot into the
-    contiguous slab at each slot's own depth: the multi-token decode
-    write (``ServeEngine(speculate=K)`` verifies ``S = K + 1`` candidate
-    positions per iteration).
+    contiguous slab at each slot's own depth: the decode step's write
+    (``S == 1``) and the multi-token one (``ServeEngine(speculate=K)``
+    verifies ``S = K + 1`` candidate positions per iteration).
 
-    ``cache``: (num_slots, max_len, H, D).  ``x_new``: (B, S, H, D).
+    ``cache``: (num_slots, max_len, T) with ``T = Hkv * D`` (scales:
+    ``Hkv``).  ``x_new``: (B, S, T), or the rows with their head axis
+    still split, (B, S, Hkv, D): the tail is merged here, on the rows.
     ``positions``: (B,) int32 — slot ``b``'s rows land at
-    ``positions[b] + [0..S)``.  Rows past ``max_len`` are DROPPED via an
-    out-of-bounds flat index + ``mode="drop"`` — NOT clamped
-    (``dynamic_update_slice`` clamping would corrupt row ``max_len - 1``)
-    and NOT left to wrap (a flat ``b * max_len + row`` index past the
-    slot would alias into slot ``b + 1``'s row 0).  At ``S == 1`` and
-    in-range positions this is elementwise-identical to the vmapped
-    ``dynamic_update_slice`` write in ``slot_cached_attention``.
+    ``positions[b] + [0..S)``.  Rows past ``max_len`` are DROPPED — the
+    scatter indexes (slot, row) pairs, a row index past the slab is out
+    of bounds and ``mode="drop"`` discards it: NOT clamped (a clamped
+    write would corrupt row ``max_len - 1``) and with nowhere to wrap
+    to (a flat ``b * max_len + row`` index past the slot would alias
+    into slot ``b + 1``'s row 0).
+
+    ONE scatter, not a ``vmap`` of ``dynamic_update_slice``: XLA's TPU
+    compiler runs this as one fusion an array, and expands the vmapped
+    form into a loop over the slots of five small operations each —
+    0.62 s of a traced 4 s at 16 slots x 48 arrays a step, where a row
+    of the stored layout is a sixteenth of a tile (PERF.md §6, PR 28).
     """
-    b, max_len = cache.shape[0], cache.shape[1]
-    s = x_new.shape[1]
+    b, s = x_new.shape[0], x_new.shape[1]
     rows = positions[:, None] + jnp.arange(s)[None, :]
-    flat_rows = jnp.where(
-        rows < max_len,
-        jnp.arange(b)[:, None] * max_len + rows,
-        b * max_len,  # out of bounds on purpose: dropped
-    )
-    flat = cache.reshape(b * max_len, *cache.shape[2:])
-    flat = flat.at[flat_rows.reshape(-1)].set(
-        x_new.astype(cache.dtype).reshape(b * s, *x_new.shape[2:]),
+    slots = jnp.broadcast_to(jnp.arange(b)[:, None], rows.shape)
+    return cache.at[slots, rows].set(
+        x_new.astype(cache.dtype).reshape(b, s, cache.shape[-1]),
         mode="drop",
     )
-    return flat.reshape(cache.shape)
 
 
 def paged_scatter_tokens(
@@ -357,12 +386,13 @@ def paged_scatter_tokens(
     ``S`` per-slot rows through the slot's page table into the page
     pool.
 
-    ``pool``: (num_pages, page_size, H, D).  ``x_new``: (B, S, H, D).
+    ``pool``: (num_pages, page_size, T).  ``x_new``: (B, S, T) or
+    (B, S, Hkv, D), as in :func:`scatter_slot_tokens`.
     ``page_tables``: (B, pages_per_slot) int32.  ``positions``: (B,).
-    Logical rows past ``max_len`` are dropped (OOB + ``mode="drop"``);
-    rows inside ``max_len`` but past the slot's allocated chain follow
-    the table to the scratch page, exactly like the frozen single-token
-    writes (module docstring).
+    Logical rows past ``max_len`` are dropped (page index out of bounds
+    + ``mode="drop"``); rows inside ``max_len`` but past the slot's
+    allocated chain follow the table to the scratch page, exactly like
+    the frozen single-token writes (module docstring).
     """
     npages = pool.shape[0]
     b, s = x_new.shape[0], x_new.shape[1]
@@ -371,17 +401,13 @@ def paged_scatter_tokens(
     page = jnp.take_along_axis(
         page_tables, jnp.clip(offs // page_size, 0, pp - 1), axis=1
     )
-    rows = jnp.where(
-        offs < pp * page_size,
-        page * page_size + offs % page_size,
-        npages * page_size,  # out of bounds on purpose: dropped
+    page = jnp.where(
+        offs < pp * page_size, page, npages  # out of bounds on purpose
     )
-    flat = pool.reshape(npages * page_size, *pool.shape[2:])
-    flat = flat.at[rows.reshape(-1)].set(
-        x_new.astype(pool.dtype).reshape(b * s, *x_new.shape[2:]),
+    return pool.at[page, offs % page_size].set(
+        x_new.astype(pool.dtype).reshape(b, s, pool.shape[-1]),
         mode="drop",
     )
-    return flat.reshape(pool.shape)
 
 
 class _HostBookkeeping:
@@ -470,17 +496,72 @@ class _HostBookkeeping:
             for a in entry[2:]
         )
 
-    def _apply_kv_dtype(self, base: Any, kv_dtype: Any) -> Any:
-        """Canonicalize + record ``kv_dtype`` and transform the freshly
-        initialized model-dtype pairs into the stored representation."""
-        self.kv_dtype = canonicalize_kv_dtype(kv_dtype)
-        self.quantized = self.kv_dtype == "int8"
-        if self.quantized:
-            return quantize_cache(base)
-        if self.kv_dtype is not None:
-            dt = _KV_DTYPES[self.kv_dtype]
-            return [(k.astype(dt), v.astype(dt)) for k, v in base]
-        return base
+    def _init_device(
+        self, model: Any, lead: int, rows: int, kv_dtype: Any, placement: Any
+    ) -> None:
+        """Build ``self.kv``: ``model.init_cache(lead, rows)`` — the
+        model's layout and dtype, ``(lead, rows, Hkv, D)`` pairs —
+        brought into the stored representation (the cache's dtype, every
+        array's head tail merged: module docstring) and COMMITTED to
+        ``placement``.  Also records ``kv_dtype`` / ``quantized`` /
+        ``kv_heads`` (the readers that need the head axis back ask for
+        the last).
+
+        One jitted program makes the stored arrays directly: built
+        eagerly, the model-layout cache and its merged (or quantized)
+        copy would both be alive for a moment — twice the cache, which a
+        7B model's weights leave no room for on one chip.  The program
+        lives in the model's jit store (``generation._cached_jit``),
+        keyed by geometry, dtype and placement, so a second engine on
+        the same model (a warmed scale-up, a fleet replica) compiles
+        nothing.
+
+        Why committed: the engine's programs return committed arrays,
+        and an uncommitted first-call cache would flip the jit signature
+        (committed-ness is part of it) on the second call — one silent
+        recompile per program, the exact class the two-program
+        discipline exists to prevent.  The placement must agree with the
+        params' devices (mixed committed device sets are a jit error),
+        so the engine derives it from the params (replicated over their
+        mesh when they are sharded).  Under ServeEngine(mesh=) it is a
+        NamedSharding that shards the merged Hkv * D axis over tp —
+        contiguous Hkv/tp head groups, so each device commits only its
+        head slice; the f32 scale arrays of a quantized cache share the
+        data's leading dims with an Hkv tail, so the same NamedSharding
+        commits them alongside their head slice.  Everything host-side
+        (lengths, active, page tables) is per-slot metadata and never
+        sharded."""
+        from jax.sharding import Sharding, SingleDeviceSharding
+
+        from ..generation import _cached_jit
+
+        self.kv_dtype = kv_dtype = canonicalize_kv_dtype(kv_dtype)
+        self.quantized = quantized = kv_dtype == "int8"
+
+        def stored():  # closes over the model only, never over ``self``
+            base = model.init_cache(lead, rows)
+            if quantized:
+                base = quantize_cache(base)
+            elif kv_dtype is not None:
+                dt = _KV_DTYPES[kv_dtype]
+                base = [(k.astype(dt), v.astype(dt)) for k, v in base]
+            return [tuple(merge_heads(a) for a in entry) for entry in base]
+
+        self.kv_heads = int(
+            jax.eval_shape(lambda: model.init_cache(lead, rows))[0][0].shape[2]
+        )
+        if placement is None:
+            placement = jax.devices()[0]
+        sharding = (
+            placement
+            if isinstance(placement, Sharding)
+            else SingleDeviceSharding(placement)
+        )
+        make = _cached_jit(
+            model, "_kv_init_jit_cache", (lead, rows, kv_dtype, sharding),
+            stored, out_shardings=sharding,
+        )
+        self.kv = jax.device_put(make(), placement)
 
 
 class SlotKVCache(_HostBookkeeping):
@@ -495,26 +576,8 @@ class SlotKVCache(_HostBookkeeping):
         kv_dtype: Optional[str] = None,
     ):
         self._init_host(num_slots, max_len)
-        # COMMIT the fresh cache to its placement: the engine's programs
-        # return committed arrays, and an uncommitted first-call cache
-        # would flip the jit signature (committed-ness is part of it) on
-        # the second call — one silent recompile per program, the exact
-        # class the two-program discipline exists to prevent.  The
-        # placement must agree with the params' devices (mixed committed
-        # device sets are a jit error), so the engine derives it from the
-        # params (replicated over their mesh when they are sharded).
-        # Under ServeEngine(mesh=) the placement is a NamedSharding that
-        # shards the Hkv axis over tp — each device commits only its
-        # Hkv/tp head slice; everything host-side here (lengths, active,
-        # page tables) is per-slot metadata and never sharded.  The f32
-        # scale arrays of a quantized cache share the data's leading
-        # dims with a trailing 1, so the same NamedSharding prefix
-        # commits them alongside their head slice.
-        self.kv = jax.device_put(
-            self._apply_kv_dtype(
-                model.init_cache(self.num_slots, self.max_len), kv_dtype
-            ),
-            placement if placement is not None else jax.devices()[0],
+        self._init_device(
+            model, self.num_slots, self.max_len, kv_dtype, placement
         )
 
 
@@ -522,7 +585,7 @@ class PagedKVCache(_HostBookkeeping):
     """Host bookkeeping around the page-pool device cache.
 
     The device arrays are per-layer ``(k, v)`` pools of shape
-    ``(num_pages, page_size, Hkv, D)``; ``page_tables`` maps each slot's
+    ``(num_pages, page_size, Hkv * D)``; ``page_tables`` maps each slot's
     logical rows onto pages (``pages_per_slot = max_len / page_size``
     int32 entries per slot, unassigned entries naming the scratch page).
     The table rides into the compiled programs as a tiny dynamic int32
@@ -556,12 +619,8 @@ class PagedKVCache(_HostBookkeeping):
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
         self.pages_per_slot = self.max_len // self.page_size
-        # same commit-at-construction rationale as SlotKVCache
-        self.kv = jax.device_put(
-            self._apply_kv_dtype(
-                model.init_cache(self.num_pages, self.page_size), kv_dtype
-            ),
-            placement if placement is not None else jax.devices()[0],
+        self._init_device(
+            model, self.num_pages, self.page_size, kv_dtype, placement
         )
         self.page_tables = np.full(
             (self.num_slots, self.pages_per_slot), SCRATCH_PAGE, np.int32
