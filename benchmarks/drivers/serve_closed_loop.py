@@ -14,7 +14,7 @@ import time
 
 import numpy as np
 
-from harness import counts, reference, traffic
+from harness import reference, traffic
 
 
 class Client:
@@ -29,7 +29,10 @@ class Driver:
         self.ctx = ctx
         self.mix = ctx.cell.traffic
         self.cfg = ctx.cell.config
-        self.arch = reference.Arch.from_config(self.cfg)
+        # everything that depends on the architecture comes from the
+        # configuration's family, never from a module named here
+        self.family = ctx.family("reference.ServeReference", "counts.serve_flops")
+        self.arch = self.family.reference.Arch.from_config(self.cfg)
         self.stream = traffic.RequestStream(self.mix, self.arch.vocab_size,
                                             ctx.seed)
         self.finished = []      # (prompt, served tokens) of the window
@@ -38,6 +41,7 @@ class Driver:
         self.attempted = self.failed = 0
         self.measuring = False
         self.prompt_lens, self.decode_rows = [], []
+        self.step_ends = []     # the window's steps, each at its end
 
     # -- the loop -------------------------------------------------------------
 
@@ -97,6 +101,9 @@ class Driver:
         while not until(now):
             self.engine.step()
             now = time.monotonic()
+            if self.measuring:
+                self.step_ends.append(
+                    (now, self.engine.metrics.counters.get("prefill_calls", 0)))
             self.ctx.tick(now)
             self._observe(now, resubmit_until is None or now < resubmit_until)
         return now
@@ -110,11 +117,9 @@ class Driver:
 
             import torchdistx_tpu as tdx
             from torchdistx_tpu.serve import ServeEngine
-
-            family = ctx.family()
         with ctx.span("materialize"):
             tdx.manual_seed(reference.seed31(ctx.seed))
-            model = tdx.deferred_init(family.constructor(self.cfg))
+            model = tdx.deferred_init(self.family.constructor(self.cfg))
             tdx.materialize_module(model)
             jax.block_until_ready([p for _, p in model.named_parameters()])
         opts = dict(self.mix["engine"])
@@ -172,8 +177,14 @@ class Driver:
             "serve.prefill_s_p50": m.prefill_s.quantile(0.5),
             "serve.decode_dispatches": cnt.get("decode_dispatches", 0),
             "serve.prefill_calls": cnt.get("prefill_calls", 0),
-            "serve.flops": counts.serve_flops(self.cfg, self.prompt_lens,
-                                              self.decode_rows),
+            # the host's part of a step, by the host's clock (the trace's
+            # two clocks disagree by 1-2 ms): None where nothing was recorded
+            "serve.decode_args_s_p50": m.decode_args_s.quantile(0.5),
+            "serve.harvest_s_p50": m.harvest_s.quantile(0.5),
+            "serve.schedule_s_total": m.schedule_s.total,
+            "serve.prefill_s_total": m.prefill_s.total,
+            "serve.flops": self.family.counts.serve_flops(
+                self.cfg, self.prompt_lens, self.decode_rows),
             "serve.prompt_lens": list(map(int, self.prompt_lens)),
             "serve.decode_rows_sum": int(sum(self.decode_rows)),
             "serve.requests_finished": len(self.finished),
@@ -181,6 +192,7 @@ class Driver:
         })
         return {
             "attempted": self.attempted, "failed": self.failed,
+            "look": self._look(t0, m),
             "end_to_end": {
                 "serve_tokens_per_s": self.tokens / self.window_s,
                 "ttft_p50_ms": 1e3 * _pct(self.ttft, 50),
@@ -188,11 +200,50 @@ class Driver:
             },
         }
 
+    def _look(self, t0, m, slices=8):
+        """Where a run's time went, for whoever has to say why one run read
+        apart from the rest: the cycle (end of one ``step()`` to the end of
+        the next) of the steps that admitted nothing, by eighth of the
+        window, the longest cycles, and the engine's own split of a step.
+        Shown in the result line (``window_look``), never compared."""
+        if len(self.step_ends) < slices:
+            return None
+        ends = np.asarray([t0] + [t for t, _ in self.step_ends], np.float64)
+        cycles = 1e3 * np.diff(ends)
+        at = ends[1:] - t0
+        plain = np.diff([0] + [n for _, n in self.step_ends]) == 0
+        eighth = np.minimum((at * slices / at[-1]).astype(int), slices - 1)
+
+        def p50_ms(values):
+            return round(float(np.median(values)), 3) if len(values) else None
+
+        def hist_ms(h):
+            q = h.quantile(0.5)
+            return None if q is None else round(1e3 * q, 4)
+
+        return {
+            "steps": int(cycles.size), "decode_only_steps": int(plain.sum()),
+            "decode_only_cycle_ms_p50_by_eighth": [
+                p50_ms(cycles[plain & (eighth == i)]) for i in range(slices)],
+            "decode_only_cycle_ms_p50": p50_ms(cycles[plain]),
+            "longest_cycles_at_s_ms": [
+                [round(float(at[i]), 2), round(float(cycles[i]), 1)]
+                for i in np.argsort(cycles)[::-1][:5]],
+            "decode_ms_p50": hist_ms(m.decode_s),
+            "prefill_ms_p50": hist_ms(m.prefill_s),
+            "decode_args_ms_p50": hist_ms(m.decode_args_s),
+            "harvest_ms_p50": hist_ms(m.harvest_s),
+            "ttft_ms_p25_p50_p75": [round(1e3 * _pct(self.ttft, q), 3)
+                                    for q in (25, 50, 75)],
+            "requests_submitted": len(self.ttft),
+        }
+
     def after_window(self):
         """Readings that need the live program, taken once the window has
         closed and the memory peak has been read."""
         self.weights_differ = reference.weights_differ(
-            self.arch, self.ctx.seed, dict(self.model.named_parameters()))
+            self.arch, self.family.reference.leaf_plan(self.arch),
+            self.ctx.seed, dict(self.model.named_parameters()))
 
     def free(self):
         self.engine = self.model = self.clients = None
@@ -234,7 +285,7 @@ class Driver:
             verdict.add("requests_finished", float("inf"), 0, "none finished")
             return
         seqs, lens = picked
-        ref = reference.ServeReference(self.arch, self.ctx.seed, "f32")
+        ref = self.family.reference.ServeReference(self.arch, self.ctx.seed, "f32")
         gaps, _ = reference.served_gaps(ref, seqs, lens)
         served = sum(gaps["tokens"])
         note = f"{len(lens)} requests, {served} served tokens"

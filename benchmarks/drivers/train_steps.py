@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from harness import check, counts, reference, traffic
+from harness import check, reference, traffic
 
 
 class StepProbe:
@@ -48,7 +48,13 @@ class Driver:
         self.ctx = ctx
         self.mix = ctx.cell.traffic
         self.cfg = ctx.cell.config
-        self.arch = reference.Arch.from_config(self.cfg)
+        # everything that depends on the architecture comes from the
+        # configuration's family, never from a module named here
+        self.family = ctx.family("reference.TrainReference",
+                                 "reference.sample_leaves",
+                                 "counts.train_flops_per_token")
+        self.arch = self.family.reference.Arch.from_config(self.cfg)
+        self.plan = self.family.reference.leaf_plan(self.arch)
         self.batch = int(self.mix["batch"])
         self.seq = int(self.mix["seq"])
         self.first_steps = 3
@@ -86,13 +92,11 @@ class Driver:
             from torchdistx_tpu.parallel import (ShardedTrainStep, create_mesh,
                                                  fsdp_shard_rule)
             from torchdistx_tpu.trainer import Trainer
-
-            family = ctx.family()
         mesh = create_mesh({"fsdp": ctx.cell.chips},
                            devices=jax.devices()[: ctx.cell.chips])
         with ctx.span("materialize"):
             tdx.manual_seed(reference.seed31(ctx.seed))
-            model = tdx.deferred_init(family.constructor(self.cfg))
+            model = tdx.deferred_init(self.family.constructor(self.cfg))
             tdx.materialize_module(model, sharding_rule=fsdp_shard_rule(mesh))
             params = dict(model.named_parameters())
             jax.block_until_ready(list(params.values()))
@@ -119,7 +123,7 @@ class Driver:
         tr = self.trainer
         with ctx.span("weights_check"):
             self.weights_differ = reference.weights_differ(
-                self.arch, ctx.seed, tr.params)
+                self.arch, self.plan, ctx.seed, tr.params)
         # the first steps, through the window's own call and feed
         self.prog_loss, self.prog_grad, self.prog_change = [], {}, {}
         for k in range(1, self.first_steps + 1):
@@ -134,11 +138,11 @@ class Driver:
                     self.prog_grad = reference.tree_norms(m)
                     self.prog_grad_sample = {
                         n: np.asarray(m[n], np.float32) / (1.0 - self.adamw.b1)
-                        for n in reference.sample_leaves(self.arch)}
+                        for n in self.family.reference.sample_leaves(self.arch)}
                     del m
         with ctx.span("first_steps_readings"):
             self.prog_change = reference.change_norm_against_seed(
-                self.arch, ctx.seed, tr.params)
+                self.arch, self.plan, ctx.seed, tr.params)
             self.prog_loss = [float(x) for x in self.prog_loss]
             self.prog_grad = {n: float(v) / (1.0 - self.adamw.b1)
                               for n, v in self.prog_grad.items()}
@@ -176,7 +180,8 @@ class Driver:
             "train.tokens": self.tokens,
             "train.window_s": self.window_s,
             "train.step_s_p50": float(statistics.median(gaps)) if len(gaps) else None,
-            "train.flops_per_token": counts.train_flops_per_token(self.cfg, self.seq),
+            "train.flops_per_token": self.family.counts.train_flops_per_token(
+                self.cfg, self.seq),
             "train.batch": self.batch, "train.seq": self.seq,
         })
         return {
@@ -194,9 +199,9 @@ class Driver:
         """The reference over the first steps: losses, first gradient
         norms, change norms.  (Also what the control and the planted
         faults are read with.)"""
-        ref = reference.TrainReference(self.arch, self.ctx.seed, self.adamw,
-                                       precision=precision, rows=rows)
-        ref.keep = reference.sample_leaves(self.arch)
+        ref = self.family.reference.TrainReference(
+            self.arch, self.ctx.seed, self.adamw, precision=precision, rows=rows)
+        ref.keep = self.family.reference.sample_leaves(self.arch)
         losses, grad = [], {}
         for k in range(self.first_steps):
             tokens, labels = traffic.train_batch(

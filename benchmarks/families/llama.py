@@ -1,8 +1,21 @@
-"""Configuration file -> the program's model, for the Llama family
-(``torchdistx_tpu.models.Llama``).  The only place that turns the
-published key names into the program's."""
+"""The Llama family (``torchdistx_tpu.models.Llama``: dense MHA/GQA
+decoders), as the harness's protocol asks of every family:
+
+``constructor(config)``  configuration file -> the program's model; the
+                         only place that turns the published key names
+                         into the program's
+``reference``            the family's plain reference
+                         (``llama_reference.py``): ``Arch``, ``leaf_plan``,
+                         ``ServeReference``, ``TrainReference``,
+                         ``sample_leaves``, ``PRECISIONS``
+``counts``               the family's model FLOPs (``llama_counts.py``):
+                         ``train_flops_per_token``, ``serve_flops``
+"""
 
 from __future__ import annotations
+
+from families import llama_counts as counts  # noqa: F401
+from families import llama_reference as reference  # noqa: F401
 
 
 def constructor(config: dict):

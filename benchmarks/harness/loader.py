@@ -5,6 +5,8 @@ and metrics; each resolves to files of its own under ``benchmarks/``:
     configs/<config>.json     the sizes as run, source, reduced, assumed
     traffic/<traffic>.json    the driver kind and its parameters
     drivers/<kind>.py         the code that drives one kind of traffic
+    families/<family>.py      what depends on the architecture: the program's
+                              constructor, the plain reference, the counts
     metrics/<metric>.json     unit, layer, moves, source, reader
     metrics/<module>.py       the reader functions
 
@@ -21,6 +23,10 @@ import os
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO = os.path.dirname(ROOT)
 REHEARSAL = os.path.join(ROOT, "rehearsal")
+FAMILIES = os.path.join(ROOT, "families")
+#: what every family brings, whatever drives it (a driver names the rest)
+FAMILY_PROTOCOL = ("constructor", "reference.Arch", "reference.leaf_plan",
+                   "reference.PRECISIONS", "counts")
 
 
 class BenchmarkError(Exception):
@@ -139,6 +145,24 @@ def load_cell(name: str, rehearsal: bool = False) -> Cell:
                 traffic_name=cell["traffic"], traffic=traffic,
                 limits=cell.get("limits", {}), end_to_end=e2e,
                 per_layer=per_layer, rehearsal=rehearsal)
+
+
+def load_family(name: str, needs=()):
+    """The family ``name``: ``families/<name>.py`` with ``constructor``,
+    ``reference`` and ``counts``.  ``needs`` are the dotted names the
+    caller will use beside the protocol's (``"counts.serve_flops"``).
+    What is missing is an error that names it: no architecture is ever
+    stood in for by another."""
+    family = load_module(os.path.join(FAMILIES, name + ".py"), "model family")
+    for dotted in FAMILY_PROTOCOL + tuple(needs):
+        obj = family
+        for part in dotted.split("."):
+            if not hasattr(obj, part):
+                raise BenchmarkError(
+                    f"model family {name!r} (families/{name}.py) lacks "
+                    f"{dotted!r}")
+            obj = getattr(obj, part)
+    return family
 
 
 def load_driver(kind: str):

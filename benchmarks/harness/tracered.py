@@ -49,7 +49,7 @@ def compact(text: str) -> tuple[str, str]:
 
 
 def base_name(name: str) -> str:
-    """``jvp_jit__flash_forward__.38`` -> ``jvp_jit__flash_forward__``."""
+    """``tdx_flash_forward.38`` -> ``tdx_flash_forward``."""
     return re.sub(r"[.\d]+$", "", name)
 
 

@@ -8,6 +8,12 @@ from __future__ import annotations
 from harness import peaks
 
 
+def _ms(ctx, counter):
+    """A counter kept in seconds, in milliseconds; None where it is not."""
+    s = ctx.counters.get(counter)
+    return None if s is None else 1e3 * s
+
+
 def materialize_s(ctx):
     return ctx.spans.get("materialize")
 
@@ -17,8 +23,7 @@ def compile_s(ctx):
 
 
 def train_step_ms_p50(ctx):
-    s = ctx.counters.get("train.step_s_p50")
-    return None if s is None else 1e3 * s
+    return _ms(ctx, "train.step_s_p50")
 
 
 def train_step_mfu_pct(ctx):
@@ -37,13 +42,37 @@ def serve_host_syncs_per_token(ctx):
 
 
 def serve_decode_step_ms_p50(ctx):
-    s = ctx.counters.get("serve.decode_s_p50")
-    return None if s is None else 1e3 * s
+    return _ms(ctx, "serve.decode_s_p50")
 
 
 def serve_prefill_ms_p50(ctx):
-    s = ctx.counters.get("serve.prefill_s_p50")
-    return None if s is None else 1e3 * s
+    return _ms(ctx, "serve.prefill_s_p50")
+
+
+def serve_decode_args_ms_p50(ctx):
+    """Median of ``serve/decode_args`` (the engine's ``decode_args_s``):
+    the host arrays and their transfers before a decode dispatch."""
+    return _ms(ctx, "serve.decode_args_s_p50")
+
+
+def serve_harvest_ms_p50(ctx):
+    """Median of ``serve/harvest`` (``harvest_s``): from the end of the
+    token block's sync to ``step()``'s return."""
+    return _ms(ctx, "serve.harvest_s_p50")
+
+
+def serve_schedule_ms_per_step(ctx):
+    """The host's part of ``serve/schedule`` over the window, a decode
+    step: ``serve/prefill`` is its child, so the prefills' dispatch-to-sync
+    time is taken out (``schedule_s.total - prefill_s.total``); what stays
+    is expiry, admission, a prefill's arguments and first-token
+    bookkeeping.  Over the decode dispatches, since one step in several
+    admits."""
+    total, steps = (ctx.counters.get("serve.schedule_s_total"),
+                    ctx.counters.get("serve.decode_dispatches"))
+    if not total or not steps:
+        return None
+    return 1e3 * (total - ctx.counters.get("serve.prefill_s_total", 0.0)) / steps
 
 
 def serve_step_mfu_pct(ctx):

@@ -1,41 +1,42 @@
 """Readers of the per-layer metrics that come from the device trace.
 
-A kernel is found by the name its Pallas call carries in the trace (the
-kernel function's, since the program gives none of its own): see
-``KERNELS``.  A reader that finds no such operation returns None, never
-0.  What a call needs is counted from the shapes by ``harness.counts``:
-the causal half for flash attention, the visible rows for decode."""
+A kernel is found by the name its Pallas call carries (``name=`` on every
+``pl.pallas_call`` of the program since PR 27; the trace shows it as the
+operation's name, numbered: ``tdx_flash_forward.3``), matched exactly:
+see ``KERNELS``.  A Mosaic kernel of another name -- fused
+cross-entropy's, a later latent-decode or grouped-expert kernel -- enters
+none of these metrics; it brings a reader and a metric of its own.  A
+reader that finds no such operation returns None, never 0.  What a call
+needs is counted from the shapes by ``harness.counts``: the causal half
+for flash attention, the visible rows for decode."""
 
 from __future__ import annotations
 
 from harness import counts, peaks, tracered
 
-#: kernel -> how its operations are told in the trace.  The program
-#: names no Pallas call, so an operation is a Mosaic kernel by its
-#: ``tpu_custom_call`` target (tag ``pallas``) and the rest is the best
-#: match the trace gives: the jaxpr name stack's last word.
-def _is_flash_fwd(name, tag):
-    return tag.startswith("pallas") and "flash_forward" in name
+#: metric's kernel -> the program's names of the Pallas calls it is made of
+KERNELS = {
+    "flash_fwd": ("tdx_flash_forward",),
+    # the backward pass is two kernels: dK/dV, then dQ
+    "flash_bwd": ("tdx_flash_backward_dkv", "tdx_flash_backward_dq"),
+    # slab and paged caches have a kernel each; a program holds one of them
+    "decode_attn": ("tdx_decode_attention", "tdx_paged_decode_attention"),
+}
 
 
-def _is_flash_bwd(name, tag):
-    # the step's only other Mosaic kernels: dK/dV and dQ, which the trace
-    # names after the ``checkpoint`` (remat) they are transposed under
-    return tag.startswith("pallas") and "flash_forward" not in name and "decode" not in name
-
-
-def _is_decode_attn(name, tag):
-    return tag.startswith("pallas") and "flash_forward" not in name
-
-
-KERNELS = {"flash_fwd": _is_flash_fwd, "flash_bwd": _is_flash_bwd,
-           "decode_attn": _is_decode_attn}
+def is_kernel(kernel: str):
+    """``match(name, tag)`` for ``tracered.kernel_seconds``: a Mosaic
+    kernel (tag ``pallas``) whose name, less the number the compiler
+    appends, is one of ``KERNELS[kernel]``."""
+    names = KERNELS[kernel]
+    return lambda name, tag: (tag.startswith("pallas")
+                              and tracered.base_name(name) in names)
 
 
 def _seconds(ctx, kernel):
     if ctx.reduction is None:
         return 0.0, 0
-    return tracered.kernel_seconds(ctx.reduction["ops"], KERNELS[kernel])
+    return tracered.kernel_seconds(ctx.reduction["ops"], is_kernel(kernel))
 
 
 def device_idle_pct(ctx):

@@ -56,8 +56,7 @@ def main(argv):
     devices = list(topo.devices)[: cell.chips]
     mesh = Mesh(np.array(devices), ("fsdp",))
     whole = NamedSharding(mesh, P())
-    family = loader.load_module(
-        os.path.join(HERE, "families", cell.config["family"] + ".py"), "model family")
+    family = loader.load_family(cell.config["family"])
     model = tdx.deferred_init(family.constructor(cell.config))
     real_devices = jax.devices
     described = lambda *a, **k: list(topo.devices)  # noqa: E731

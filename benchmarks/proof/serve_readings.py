@@ -56,8 +56,11 @@ def main(argv=None):
             driver.free()
             t1 = time.time()
             seqs, lens = driver.sample()
-            ref = reference.ServeReference(driver.arch, seed, "f32")
-            control = (reference.ServeReference(driver.arch, seed, args.control)
+            family_ref = driver.family.reference  # the architecture's own
+            if args.control not in family_ref.PRECISIONS:
+                raise SystemExit(f"the family has no control {args.control!r}")
+            ref = family_ref.ServeReference(driver.arch, seed, "f32")
+            control = (family_ref.ServeReference(driver.arch, seed, args.control)
                        if i < args.controls else None)
             gaps, control_gaps = reference.served_gaps(ref, seqs, lens, control)
             row = {"seed": seed, "program_s": round(t1 - t0, 2),

@@ -23,14 +23,16 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def one_run(cell, seed, seconds, trace):
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+def one_run(cell, seed, seconds, trace, tree=REPO):
+    """The benchmark's own command, as ``tree``'s BENCHMARK.json gives it,
+    run from ``tree``."""
+    with open(os.path.join(tree, "BENCHMARK.json")) as f:
         command = json.load(f)["command"]
     t0 = time.time()
     proc = subprocess.run(
         command + ["--workload", cell, "--seed", str(seed), "--seconds",
                    str(seconds), "--trace", str(trace)],
-        cwd=REPO, capture_output=True, text=True)
+        cwd=tree, capture_output=True, text=True)
     lines = [line for line in proc.stdout.splitlines() if line.strip()]
     row = {"cell": cell, "seed": seed, "trace": trace, "rc": proc.returncode,
            "wall_s": round(time.time() - t0, 2)}

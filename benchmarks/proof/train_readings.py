@@ -90,9 +90,10 @@ def main(argv=None):
                    "weights_differ": driver.weights_differ,
                    "setup_split_s": {k: round(v, 2) for k, v in ctx.spans.items()},
                    "setup_s": round(t1 - t0, 2), "reference_s": round(t2 - t1, 2),
-                   "ref_grad_norms": {k: ref[1][k] for k in
-                                      ("tok_emb.weight", "lm_head.weight",
-                                       "blocks.0.attn.wq.weight", "norm.weight")},
+                   # the plan's first and last leaf and the sampled ones
+                   "ref_grad_norms": {k: ref[1][k] for k in dict.fromkeys(
+                       (driver.plan[0][0], driver.plan[-1][0],
+                        *driver.family.reference.sample_leaves(driver.arch)))},
                    **numbers(driver, *prog, ref)}
             print(json.dumps(row), flush=True)
             f.write(json.dumps(row) + "\n")

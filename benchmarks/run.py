@@ -63,10 +63,11 @@ class Context:
         finally:
             self.spans[name] = self.spans.get(name, 0.0) + time.monotonic() - t0
 
-    def family(self):
-        return loader.load_module(
-            os.path.join(HERE, "families", self.cell.config["family"] + ".py"),
-            "model family")
+    def family(self, *needs: str):
+        """The configuration's family: everything that depends on the
+        architecture (``constructor``, ``reference``, ``counts``);
+        ``needs`` as in ``loader.load_family``.  A driver asks once."""
+        return loader.load_family(self.cell.config["family"], needs)
 
     def log(self, record: dict) -> None:
         print(json.dumps(record), file=sys.stderr, flush=True)
@@ -221,6 +222,8 @@ def main(argv=None) -> int:
                             if isinstance(v, int) and not isinstance(v, bool)}
     else:
         result["setup_split_s"] = {k: round(v, 3) for k, v in ctx.spans.items()}
+        if out.get("look"):   # where the window's time went: shown, never compared
+            result["window_look"] = out["look"]
     result["read_not_compared"] = {n: v for n, v, _ in verdict.read}
     result["compared"] = verdict.as_dict()
     sys.stdout.flush()

@@ -15,8 +15,6 @@ that size (``proof/*_readings.py --rehearsal``); the benchmark's cells
 carry limits read on the chip at their size.
 """
 
-import json
-
 import numpy as np
 import pytest
 
@@ -24,22 +22,14 @@ import run
 from harness import check, loader
 
 
-def drive(capsys, workload, seed=5):
-    rc = run.main(["--rehearsal", "--workload", workload, "--seed", str(seed),
-                   "--seconds", "1", "--trace", "0"])
-    assert rc == 0
-    lines = [line for line in capsys.readouterr().out.splitlines() if line.strip()]
-    return json.loads(lines[-1])
-
-
-def test_sound_train_run_is_correct(capsys):
-    result = drive(capsys, "tiny.train")
+def test_sound_train_run_is_correct(drive):
+    result = drive("tiny.train")
     assert result["correct"] is True, result["compared"]
     assert list(result)[-1] == "compared"
     assert result["metrics"] == {} and result["device"]["platform"] == "cpu"
 
 
-def test_state_left_unchanged_is_not_correct(capsys, monkeypatch):
+def test_state_left_unchanged_is_not_correct(drive, monkeypatch):
     from torchdistx_tpu.parallel.fsdp import ShardedTrainStep
 
     real = ShardedTrainStep.__call__
@@ -53,14 +43,14 @@ def test_state_left_unchanged_is_not_correct(capsys, monkeypatch):
         return params, opt_state, loss  # the state as it came
 
     monkeypatch.setattr(ShardedTrainStep, "__call__", broken)
-    result = drive(capsys, "tiny.train")
+    result = drive("tiny.train")
     assert result["correct"] is False
     c = result["compared"]
     assert c["change_norm_gap"]["value"] == pytest.approx(1.0, abs=1e-6)
     assert c["grad_norm_gap"]["value"] > c["grad_norm_gap"]["limit"]
 
 
-def test_half_of_the_batch_left_out_is_not_correct(capsys, monkeypatch):
+def test_half_of_the_batch_left_out_is_not_correct(drive, monkeypatch):
     from torchdistx_tpu.parallel.fsdp import ShardedTrainStep
 
     real = ShardedTrainStep.__call__
@@ -70,19 +60,19 @@ def test_half_of_the_batch_left_out_is_not_correct(capsys, monkeypatch):
         return real(self, params, opt_state, half)
 
     monkeypatch.setattr(ShardedTrainStep, "__call__", broken)
-    result = drive(capsys, "tiny.train")
+    result = drive("tiny.train")
     assert result["correct"] is False
     c = result["compared"]
     assert c["grad_norm_gap"]["value"] > 10 * c["grad_norm_gap"]["limit"]
 
 
-def test_sound_serve_run_is_correct(capsys):
-    result = drive(capsys, "tiny.batch4")
+def test_sound_serve_run_is_correct(drive):
+    result = drive("tiny.batch4")
     assert result["correct"] is True, result["compared"]
     assert result["counts"]["serve.requests_finished"] > 0
 
 
-def test_altered_token_is_not_correct(capsys, monkeypatch):
+def test_altered_token_is_not_correct(drive, monkeypatch):
     from torchdistx_tpu.serve.engine import ServeEngine
 
     real = ServeEngine._record_first
@@ -91,7 +81,7 @@ def test_altered_token_is_not_correct(capsys, monkeypatch):
         return real(self, req, (int(tok) + 1) % 512, now)
 
     monkeypatch.setattr(ServeEngine, "_record_first", broken)
-    result = drive(capsys, "tiny.batch4")
+    result = drive("tiny.batch4")
     assert result["correct"] is False
     c = result["compared"]["logit_gap"]
     assert c["value"] > c["limit"]
@@ -134,8 +124,9 @@ def test_serve_control_is_not_correct(seed):
     drv.after_window()
     drv.free()
     seqs, lens = drv.sample()
-    ref = reference.ServeReference(drv.arch, seed, "f32")
-    control = reference.ServeReference(drv.arch, seed, "bf16")
+    family_ref = drv.family.reference
+    ref = family_ref.ServeReference(drv.arch, seed, "f32")
+    control = family_ref.ServeReference(drv.arch, seed, "bf16")
     served, ctl = reference.served_gaps(ref, seqs, lens, control)
     lim = drv.ctx.cell.limits
     assert drv.weights_differ == 0

@@ -1,6 +1,7 @@
-"""The FLOP and byte functions against numbers worked by hand."""
+"""The FLOP and byte functions against numbers worked by hand: the
+kernels' from ``harness.counts``, the model's through its family."""
 
-from harness import counts, peaks
+from harness import counts, loader, peaks
 
 MISTRAL = {"hidden_size": 4096, "intermediate_size": 14336,
            "num_attention_heads": 32, "num_key_value_heads": 8,
@@ -31,12 +32,13 @@ def test_decode_attention_one_shape():
     assert bound == "memory" and abs(need - 39321600 / 819e9) < 1e-12
 
 
-def test_model_counts():
+def test_model_counts_of_the_llama_family():
+    llama = loader.load_family("llama").counts
     # per layer: 4096*4096*2 + 2*4096*1024 + 3*4096*14336 = 218,103,808
-    assert counts.matmul_params(MISTRAL) == 24 * 218103808 + 32768 * 4096
-    assert counts.total_params(MISTRAL) == (
+    assert llama.matmul_params(MISTRAL) == 24 * 218103808 + 32768 * 4096
+    assert llama.total_params(MISTRAL) == (
         24 * 218103808 + 2 * 32768 * 4096 + 49 * 4096)
     # one prompt of 3 tokens, one decoded token over 4 rows
-    n = counts.matmul_params(MISTRAL)
-    assert counts.serve_flops(MISTRAL, [3], [4]) == 2.0 * n * 4 + 4.0 * 4096 * 24 * (6 + 4)
-    assert counts.train_flops_per_token(MISTRAL, 2048) == 6.0 * n + 6.0 * 24 * 2048 * 4096
+    n = llama.matmul_params(MISTRAL)
+    assert llama.serve_flops(MISTRAL, [3], [4]) == 2.0 * n * 4 + 4.0 * 4096 * 24 * (6 + 4)
+    assert llama.train_flops_per_token(MISTRAL, 2048) == 6.0 * n + 6.0 * 24 * 2048 * 4096
