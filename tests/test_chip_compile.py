@@ -11,7 +11,9 @@ program holds the Mosaic call), not results — those are the interpret
 tests' and ``chip_smoke.py``'s.
 
 Widths: head_dim 128; Hkv 32 (llama2_7b) and 8 (mistral_7b, llama3_8b);
-slab 2048-4096; page 16; vocab 32000 and GPT-2's 50257.
+slab 2048-4096; page 16; vocab 32000 and GPT-2's 50257; the DeepSeek-V3
+family at kanana-2-30b-a3b's: a 576-lane latent row (stored on 640) in 32
+slots of 8192, 32 heads at qk 192 / v 128, 128 experts of 2048 x 768.
 
 The compiled program is also where a LAYOUT shows: the serve cache is
 stored as the decode kernel's operand (``serve/kv_cache.py``), and
@@ -220,6 +222,27 @@ def _same_tiling(type_):
     return len(seen) == 1
 
 
+def _relayouts_of_the_cache(text, n):
+    """Instructions with a result of the cache's size (``n`` elements)
+    that are neither plumbing, nor the in-place write of the new rows
+    (and the fusion around it), nor a move between memory spaces."""
+    found = _cache_sized(text, n)
+    updated = {c for c, _, op, _, _ in found if op in IN_PLACE}
+    assert updated, "the write of the new rows is not in the program"
+    offenders = []
+    for _, name, op, type_, line in found:
+        if op in IN_PLACE or op in PLUMBING:
+            continue
+        if op == "fusion":  # only the fusion around an in-place write
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            if called and called[1] in updated:
+                continue
+        if op in ("copy-start", "copy-done") and _same_tiling(type_):
+            continue
+        offenders.append(f"{name} = {type_} {op}")
+    return offenders
+
+
 @pytest.mark.parametrize(
     "paged,quantized",
     [(False, False), (False, True), (True, False)],
@@ -262,21 +285,83 @@ def test_decode_step_leaves_the_cache_in_place(
     assert _kernel_names(text) == [
         "tdx_paged_decode_attention" if paged else "tdx_decode_attention"
     ]
-    found = _cache_sized(text, math.prod(lead) * M_HKV * D)
-    updated = {c for c, _, op, _, _ in found if op in IN_PLACE}
-    assert updated, "the write of the new rows is not in the program"
-    offenders = []
-    for _, name, op, type_, line in found:
-        if op in IN_PLACE or op in PLUMBING:
-            continue
-        if op == "fusion":  # only the fusion around an in-place write
-            called = re.search(r"calls=%?([\w.\-]+)", line)
-            if called and called[1] in updated:
-                continue
-        if op in ("copy-start", "copy-done") and _same_tiling(type_):
-            continue
-        offenders.append(f"{name} = {type_} {op}")
+    offenders = _relayouts_of_the_cache(text, math.prod(lead) * M_HKV * D)
     assert not offenders, offenders
+
+
+# The DeepSeek-V3 family at kanana-2-30b-a3b's widths (the serve cell's:
+# 32 slots of 8192 rows; 32 heads; a latent row of 512 + 64 lanes stored
+# on 640; 128 experts of 2048 x 768, top 6).
+K_B, K_L, K_H, K_W, K_R = 32, 8192, 32, 640, 512
+K_E, K_D, K_F, K_TOP = 128, 2048, 768, 6
+
+
+def test_latent_decode_step_leaves_the_cache_in_place(one_chip, monkeypatch):
+    """One layer's latent decode write + attend, the cache donated, as
+    the engine's decode program runs it: ``tdx_latent_decode_attention``
+    is handed the slab as it is stored, so each visible row is the
+    operand of that one call, and nothing of the cache's size is copied
+    or relayouted.  (Stored 576 lanes wide the slab failed this: 576 is
+    no multiple of 128, the compiler stored it rows-minor and relayouted
+    1.3 GB a layer and step.)"""
+    from torchdistx_tpu.ops.attention import latent_slot_cached_attention
+
+    (chip,) = one_chip.device_set
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+
+    def fn(q, row, positions, latent):
+        return latent_slot_cached_attention(
+            q, row, (latent,), positions, value_width=K_R,
+            scale=192**-0.5, use_flash=True,
+        )
+
+    text = _compile(
+        fn, one_chip, ((K_B, K_H, K_W), jnp.bfloat16),
+        ((K_B, 1, K_W), jnp.bfloat16), ((K_B,), jnp.int32),
+        ((K_B, K_L, K_W), jnp.bfloat16), donate=(3,),
+    )
+    assert _kernel_names(text) == ["tdx_latent_decode_attention"]
+    offenders = _relayouts_of_the_cache(text, K_B * K_L * K_W)
+    assert not offenders, offenders
+
+
+@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode32", "prefill4096"])
+def test_grouped_matmul_compiles(one_chip, tokens):
+    """The experts' SwiGLU as two grouped matmuls (gate and up fused,
+    then down) over a decode step's 192 rows (16-row tiles) and a
+    prefill's 24576 (256-row tiles)."""
+    from torchdistx_tpu.ops.grouped_matmul import (
+        grouped_matmul, plan_groups, row_tile,
+    )
+
+    def experts(x, ids, w_gate, w_up, w_down):
+        plan = plan_groups(ids, K_E, row_tile(ids.shape[0], x.dtype))
+        kw = dict(use_kernel=True, interpret=False)
+        h = grouped_matmul(
+            x[plan.src // K_TOP], w_gate, plan, rhs_up=w_up, block_n=384, **kw
+        )
+        return grouped_matmul(h, w_down, plan, block_n=512, **kw)[plan.dest]
+
+    text = _compile(
+        experts, one_chip, ((tokens, K_D), jnp.bfloat16),
+        ((tokens * K_TOP,), jnp.int32), ((K_E, K_D, K_F), jnp.bfloat16),
+        ((K_E, K_D, K_F), jnp.bfloat16), ((K_E, K_F, K_D), jnp.bfloat16),
+    )
+    assert _kernel_names(text) == ["tdx_grouped_matmul"] * 2
+
+
+def test_flash_forward_at_unequal_widths_compiles(one_chip):
+    """Multi-head latent attention's prefill: scores on 192 lanes,
+    128-wide values, one ``tdx_flash_forward`` (forward only)."""
+    text = _compile(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, scale=192**-0.5, interpret=False
+        ),
+        one_chip, ((1, 4096, K_H, 192), jnp.bfloat16),
+        ((1, 4096, K_H, 192), jnp.bfloat16),
+        ((1, 4096, K_H, 128), jnp.bfloat16),
+    )
+    assert _kernel_names(text) == [FLASH_FORWARD]
 
 
 @pytest.mark.parametrize(

@@ -156,3 +156,19 @@ def test_remat_matches_no_remat():
         )
     )(p)
     assert float(jnp.abs(g["blocks.0.mlp.w_gate"]).sum()) > 0
+
+
+def test_grouped_dispatch_matches_dense():
+    """``moe_dispatch="grouped"`` (no token dropped, work ~ tokens x
+    top_k: the path the DeepSeek-V3 family serves on) is the dense
+    compute's result: same weights, same routing, float32 sums in
+    another order."""
+    tdx.manual_seed(13)
+    m_dense = Mixtral.from_name("tiny")
+    tdx.manual_seed(13)
+    m_grouped = Mixtral.from_name("tiny", moe_dispatch="grouped")
+    tok = _tokens(seed=3)
+    np.testing.assert_allclose(
+        np.asarray(m_dense(tok)), np.asarray(m_grouped(tok)),
+        rtol=2e-5, atol=2e-5,
+    )

@@ -191,3 +191,208 @@ class TestCapacityDispatch:
         y = m(x)
         assert y.shape == x.shape
         assert bool(jnp.all(jnp.isfinite(np.asarray(y))))
+
+
+def _pair(dispatch_kw, seed=9, dim=16, ffn=32, e=8, k=3, **router):
+    """The same weights under dense compute and under ``dispatch_kw``."""
+    tdx.manual_seed(seed)
+    dense = MoE(dim, ffn, e, k, **router)
+    other = MoE(dim, ffn, e, k, **router, **dispatch_kw)
+    other.load_state_dict(dict(dense.named_parameters()))
+    return dense, other
+
+
+DSV3_ROUTER = dict(
+    scoring="sigmoid", selection_bias=True, routed_scale=2.448,
+    shared_ffn_dim=24,
+)
+
+
+class TestGroupedDispatch:
+    """``dispatch_mode="grouped"``: every (token, expert) row computed,
+    none dropped, whatever the routing; an expert without a row never
+    read."""
+
+    @pytest.mark.parametrize("router", [{}, DSV3_ROUTER], ids=["softmax", "dsv3"])
+    @pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "pallas"])
+    def test_matches_dense(self, router, use_kernel):
+        dense, grouped = _pair(
+            dict(dispatch_mode="grouped", use_kernel=use_kernel), **router
+        )
+        x = jnp.asarray(np.random.RandomState(0).randn(3, 7, 16), jnp.float32)
+        np.testing.assert_allclose(
+            np.asarray(grouped(x)), np.asarray(dense(x)), rtol=2e-5, atol=2e-5
+        )
+
+    @pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "pallas"])
+    def test_uneven_routing_drops_nothing(self, use_kernel):
+        """A selection bias that sends EVERY token to expert 2 and none
+        to expert 5: the long group is computed whole (a capacity of the
+        mean load would have dropped most of it) and expert 5's weights,
+        poisoned with NaN, are never read."""
+        dense, grouped = _pair(
+            dict(dispatch_mode="grouped", use_kernel=use_kernel), **DSV3_ROUTER
+        )
+        bias = jnp.zeros((8,)).at[2].set(10.0).at[5].set(-10.0)
+        for m in (dense, grouped):
+            m.e_score_correction_bias = tdx.nn.Parameter(bias)
+        x = jnp.asarray(np.random.RandomState(1).randn(40, 16), jnp.float32)
+        _, top_i = grouped._choose(grouped._route(x))
+        top_i = np.asarray(top_i)
+        assert (top_i == 2).any(-1).all() and not (top_i == 5).any()
+        want = np.asarray(dense(x))
+        for name in ("w_gate", "w_up", "w_down"):
+            w = getattr(grouped, name)
+            setattr(grouped, name, tdx.nn.Parameter(w.at[5].set(jnp.nan)))
+        got = np.asarray(grouped(x))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+    def test_counts_rows_and_groups(self):
+        from torchdistx_tpu.nn.moe import moe_count_tape, tape_totals
+
+        _, grouped = _pair(dict(dispatch_mode="grouped"), **DSV3_ROUTER)
+        x = jnp.asarray(np.random.RandomState(2).randn(5, 16), jnp.float32)
+        with moe_count_tape() as tape:
+            grouped(x)
+            grouped(x[:2])
+        rows, groups = (int(v) for v in tape_totals(tape))
+        assert rows == 5 * 3 + 2 * 3  # tokens x top_k: no more, no fewer
+        _, top_i = grouped._choose(grouped._route(x))
+        touched = len(np.unique(np.asarray(top_i)))
+        touched2 = len(np.unique(np.asarray(top_i)[:2]))
+        assert groups == touched + touched2
+        grouped(x)  # no tape open: nothing recorded, nothing raised
+
+    def test_jits_and_takes_gradients(self):
+        _, grouped = _pair(dict(dispatch_mode="grouped"), **DSV3_ROUTER)
+        params = dict(grouped.named_parameters())
+        x = jnp.asarray(np.random.RandomState(3).randn(6, 16), jnp.float32)
+        y = jax.jit(lambda p, x: functional_call(grouped, p, (x,)))(params, x)
+        np.testing.assert_allclose(
+            np.asarray(y), np.asarray(grouped(x)), rtol=1e-6, atol=1e-6
+        )
+        g = jax.grad(
+            lambda p: jnp.mean(functional_call(grouped, p, (x,)) ** 2)
+        )(params)
+        for name in ("w_gate", "w_down", "router.weight", "shared.w_up.weight"):
+            assert float(jnp.abs(g[name]).sum()) > 0.0, name
+
+    def test_grouped_takes_no_capacity(self):
+        with pytest.raises(ValueError, match="grouped.*capacity_factor"):
+            MoE(8, 16, 4, 2, dispatch_mode="grouped", capacity_factor=1.0)
+
+
+class TestGroupedMatmul:
+    """``ops/grouped_matmul.py``: the layout and the kernel."""
+
+    def _plan(self, ids, n_groups=6, tm=8):
+        from torchdistx_tpu.ops.grouped_matmul import plan_groups
+
+        return plan_groups(jnp.asarray(ids, jnp.int32), n_groups, tm)
+
+    def test_plan_layout(self):
+        ids = [3, 0, 3, 3, 5, 0, 3, 3, 3, 3, 3, 3]  # group 3: 9 rows, 2 tiles
+        plan = self._plan(ids)
+        assert int(plan.groups) == 3 and int(plan.n_tiles[0]) == 4
+        assert plan.tile_group.shape == (2 + 6,)  # ceil(12 / 8) + groups
+        np.testing.assert_array_equal(plan.tile_group[:4], [0, 3, 3, 5])
+        np.testing.assert_array_equal(plan.tile_group[4:], [5] * 4)  # dead
+        # every pair sits in a row of a tile of its own group
+        dest = np.asarray(plan.dest)
+        np.testing.assert_array_equal(
+            np.asarray(plan.tile_group)[dest // 8], ids
+        )
+        assert len(set(dest)) == len(ids)
+        np.testing.assert_array_equal(np.asarray(plan.src)[dest], range(12))
+
+    @pytest.mark.parametrize("swiglu", [False, True])
+    def test_kernel_matches_jnp_path_and_rows(self, swiglu):
+        from torchdistx_tpu.ops.grouped_matmul import grouped_matmul
+
+        rs = np.random.RandomState(4)
+        ids = rs.randint(0, 6, 50)
+        ids[ids == 4] = 1  # group 4 has no row
+        plan = self._plan(ids)
+        rows = jnp.asarray(rs.randn(50, 16), jnp.float32)
+        w = jnp.asarray(rs.randn(6, 16, 256), jnp.float32)
+        up = jnp.asarray(rs.randn(6, 16, 256), jnp.float32) if swiglu else None
+        lhs = rows[plan.src]
+        kw = dict(rhs_up=up, block_n=128)
+        got = grouped_matmul(lhs, w.at[4].set(jnp.nan), plan, use_kernel=True, **kw)
+        ref = grouped_matmul(lhs, w.at[4].set(jnp.nan), plan, use_kernel=False, **kw)
+        want = np.einsum("rk,rkn->rn", rows, np.asarray(w)[ids])
+        if swiglu:
+            want = np.asarray(jax.nn.silu(want)) * np.einsum(
+                "rk,rkn->rn", rows, np.asarray(up)[ids]
+            )
+        for out in (got, ref):
+            np.testing.assert_allclose(
+                np.asarray(out)[plan.dest], want, rtol=1e-5, atol=1e-4
+            )
+
+    def test_rejects_a_layout_that_does_not_fit(self):
+        from torchdistx_tpu.ops.grouped_matmul import grouped_matmul
+
+        plan = self._plan([0, 1, 2])
+        with pytest.raises(ValueError, match="do not fit the plan"):
+            grouped_matmul(jnp.zeros((8, 4)), jnp.zeros((6, 4, 4)), plan)
+        lhs = jnp.zeros((plan.tile_group.shape[0] * 8, 4))
+        with pytest.raises(ValueError, match="gate .* and up .* differ"):
+            grouped_matmul(
+                lhs, jnp.zeros((6, 4, 4)), plan, rhs_up=jnp.zeros((6, 4, 8))
+            )
+
+
+class TestRouter:
+    def test_selection_bias_changes_the_choice_not_the_weights(self):
+        tdx.manual_seed(21)
+        m = MoE(16, 32, 8, 2, **DSV3_ROUTER)
+        m.e_score_correction_bias = tdx.nn.Parameter(jnp.zeros((8,)))
+        x = jnp.asarray(np.random.RandomState(5).randn(12, 16), jnp.float32)
+        scores = m._route(x)
+        p0, i0 = m._choose(scores)
+        np.testing.assert_allclose(np.asarray(p0.sum(-1)), 2.448, rtol=1e-6)
+        # a bias large enough lifts expert 7 into every token's choice
+        m.e_score_correction_bias = tdx.nn.Parameter(
+            jnp.zeros((8,)).at[7].set(5.0)
+        )
+        p1, i1 = m._choose(scores)
+        assert (np.asarray(i1) == 7).any(-1).all()
+        assert not (np.asarray(i0) == 7).any(-1).all()
+        # the weights are the chosen experts' OWN scores, renormalised:
+        # the bias is nowhere in them
+        own = np.take_along_axis(np.asarray(scores), np.asarray(i1), -1)
+        np.testing.assert_allclose(
+            np.asarray(p1), own / own.sum(-1, keepdims=True) * 2.448, rtol=1e-6
+        )
+
+    def test_sigmoid_scores_are_float32(self):
+        tdx.manual_seed(22)
+        m = MoE(16, 32, 8, 2, dtype=jnp.bfloat16, scoring="sigmoid")
+        x = jnp.asarray(np.random.RandomState(6).randn(4, 16), jnp.bfloat16)
+        scores = m._route(x)
+        assert scores.dtype == jnp.float32
+        want = jax.nn.sigmoid(
+            np.asarray(x, np.float32) @ np.asarray(m.router.weight, np.float32).T
+        )
+        np.testing.assert_allclose(np.asarray(scores), want, rtol=1e-5, atol=1e-6)
+
+    def test_shared_expert_is_added_for_every_token(self):
+        tdx.manual_seed(23)
+        m = MoE(16, 32, 4, 2, shared_ffn_dim=24)
+        x = jnp.asarray(np.random.RandomState(7).randn(5, 16), jnp.float32)
+        params = dict(m.named_parameters())
+        no_shared = dict(
+            params, **{"shared.w_down.weight": jnp.zeros((16, 24))}
+        )
+        routed = functional_call(m, no_shared, (x,))
+        np.testing.assert_allclose(
+            np.asarray(m(x)), np.asarray(routed + m.shared(x)),
+            rtol=1e-6, atol=1e-6,
+        )
+        assert float(jnp.abs(m.shared(x)).max()) > 1e-3
+
+    def test_bad_scoring_rejected(self):
+        with pytest.raises(ValueError, match="scoring"):
+            MoE(8, 16, 4, 2, scoring="tanh")

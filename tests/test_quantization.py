@@ -210,6 +210,23 @@ class TestQuantizedMoE:
         rel = np.abs(np.asarray(ya - yb)) / np.abs(np.asarray(ya)).max()
         assert np.quantile(rel, 0.99) < 0.05
 
+    @pytest.mark.parametrize(
+        "kwargs,name",
+        [
+            (dict(dispatch_mode="grouped"), "dispatch_mode='grouped'"),
+            (dict(scoring="sigmoid"), "scoring='sigmoid'"),
+            (dict(selection_bias=True), "a selection bias"),
+            (dict(shared_ffn_dim=16), "a shared expert"),
+        ],
+    )
+    def test_unsupported_routers_are_refused_by_name(self, kwargs, name):
+        from torchdistx_tpu.nn.moe import MoE
+        from torchdistx_tpu.nn import QuantizedMoE
+
+        with pytest.raises(ValueError) as err:
+            QuantizedMoE.from_moe(MoE(16, 32, 4, 2, **kwargs))
+        assert name in str(err.value)
+
     def test_filter_excluded_moe_keeps_router(self):
         # a filtered-out MoE must not be PARTIALLY quantized (its router
         # previously got swapped even when the filter rejected the layer)

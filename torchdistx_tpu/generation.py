@@ -177,6 +177,7 @@ def _make_fused_decode(
     max_len: int,
     decode_chunk: int,
     numerics: bool = False,
+    moe_counts: bool = False,
 ):
     """Build the serve engine's fused K-step decode program body: a
     ``lax.scan`` of ``decode_chunk`` single-token ``forward_decode`` +
@@ -219,6 +220,12 @@ def _make_fused_decode(
     ``{site: digest}`` dict rides the carry, returned as one extra
     trailing output — same dispatch, same sync, one more (tiny) fetched
     leaf.  ``numerics=False`` traces the exact pre-observatory program.
+
+    With ``moe_counts=True`` (a model whose expert layers record under
+    ``nn.moe.moe_count_tape``; without ``numerics``) the K steps' rows
+    and groups are summed on the device and returned as one trailing
+    int32 ``[rows, groups]`` output, which the engine accumulates
+    without fetching.
     """
 
     step = _make_decode_body(
@@ -228,6 +235,18 @@ def _make_fused_decode(
     def run(params, kv, toks, positions, temps, seeds, steps, budgets,
             finished, *extra):
         init = (kv, toks, positions, steps, finished)
+        if moe_counts and not numerics:
+            from .nn.moe import moe_count_tape, tape_totals
+
+            def body(carry, _):
+                with moe_count_tape() as tape:
+                    carry = step(params, temps, seeds, budgets, extra, carry)
+                return carry, (carry[1], tape_totals(tape))
+
+            (kv, _, _, _, _), (toks_block, counts) = jax.lax.scan(
+                body, init, None, length=decode_chunk
+            )
+            return kv, toks_block, jnp.sum(counts, axis=0)
         if not numerics:
             def body(carry, _):
                 carry = step(params, temps, seeds, budgets, extra, carry)

@@ -1,3 +1,4 @@
+from .deepseek_v3 import DeepseekV3, DeepseekV3Config, deepseek_v3_configs
 from .gpt2 import GPT2, GPT2Config, gpt2_configs
 from .llama import Llama, LlamaConfig, llama_configs
 from .mixtral import Mixtral, MixtralConfig, mixtral_configs
@@ -6,6 +7,9 @@ from .t5 import T5, T5Config, t5_configs
 from .vit import ViT, ViTConfig, vit_configs
 
 __all__ = [
+    "DeepseekV3",
+    "DeepseekV3Config",
+    "deepseek_v3_configs",
     "Llama",
     "LlamaConfig",
     "llama_configs",

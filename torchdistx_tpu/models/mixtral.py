@@ -36,8 +36,9 @@ class MixtralConfig(LlamaConfig):
     # None = dense compute (every expert, masked combine — exact);
     # a float enables GShard capacity dispatch (see nn.moe)
     capacity_factor: Optional[float] = None
-    # "einsum" (GSPMD-partitionable) or "gather" (no bookkeeping MACs —
-    # the single-chip fast path); see nn.moe's module docstring
+    # "einsum" (GSPMD-partitionable), "gather" (no bookkeeping MACs —
+    # the single-chip fast path) or "grouped" (no capacity: no token
+    # dropped, work ~ tokens x top_k); see nn.moe's module docstring
     moe_dispatch: str = "einsum"
 
 

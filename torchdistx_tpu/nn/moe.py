@@ -36,11 +36,33 @@ Capacity dispatch itself has two implementations (``dispatch_mode``):
 
 With ``capacity_factor >= E / top_k`` no token can be dropped and all
 modes agree (tested).
+
+A third compute path drops no token at any routing and does work
+proportional to ``tokens x top_k``: ``dispatch_mode="grouped"`` (no
+``capacity_factor``) sorts the (token, expert) rows by expert and runs
+the SwiGLU as grouped matmuls over the experts that have rows
+(``ops/grouped_matmul.py``, the Pallas kernel ``tdx_grouped_matmul``).
+An expert no token chose is never read; one every token chose simply
+has a long group.  It is the path for serving an expert model on one
+chip, where dense compute is ``E / top_k`` times the FLOPs and a
+capacity that cannot drop is dense again.
+
+The router is configurable for the DeepSeek-V3 family: ``scoring``
+(``"softmax"`` | ``"sigmoid"``, the latter in float32), a learned
+``selection_bias`` that enters the choice of experts and not their
+weights, ``routed_scale`` on the renormalised weights, and a shared
+expert (``shared_ffn_dim``) that every token takes.
+
+Counters: under :func:`moe_count_tape` every grouped call records the
+rows it computed and the groups it touched (a traced scalar); the serve
+programs sum them on the device (``serve/engine.py``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 from typing import Callable, Optional
 
 import jax
@@ -50,7 +72,50 @@ from . import init
 from .module import Module, Parameter
 from .layers import Linear
 
-__all__ = ["MoE", "moe_shard_rule"]
+__all__ = ["MoE", "moe_shard_rule", "moe_count_tape", "tape_totals"]
+
+
+class _Tape(threading.local):
+    current = None
+
+
+_tape = _Tape()
+
+
+@contextlib.contextmanager
+def moe_count_tape():
+    """While open (at trace time), every grouped expert call appends
+    ``(rows, groups)``: the (token, expert) rows it computed (static) and
+    the experts that had at least one (a traced int32 scalar)."""
+    tape: list = []
+    prev, _tape.current = _tape.current, tape
+    try:
+        yield tape
+    finally:
+        _tape.current = prev
+
+
+def tape_totals(tape) -> jax.Array:
+    """int32 ``[rows, groups]`` summed over the tape's calls."""
+    rows = sum(r for r, _ in tape)
+    groups = sum((g for _, g in tape), jnp.zeros((), jnp.int32))
+    return jnp.stack([jnp.asarray(rows, jnp.int32), groups])
+
+
+class _SharedFFN(Module):
+    """The SwiGLU every token takes beside its routed experts."""
+
+    def __init__(self, dim, ffn_dim, dtype, weight_init):
+        super().__init__()
+        lin = lambda i, o: Linear(  # noqa: E731
+            i, o, bias=False, dtype=dtype, weight_init=weight_init
+        )
+        self.w_gate = lin(dim, ffn_dim)
+        self.w_up = lin(dim, ffn_dim)
+        self.w_down = lin(ffn_dim, dim)
+
+    def forward(self, x):
+        return self.w_down(jax.nn.silu(self.w_gate(x)) * self.w_up(x))
 
 
 class MoE(Module):
@@ -68,14 +133,25 @@ class MoE(Module):
         dtype=jnp.float32,
         capacity_factor: Optional[float] = None,
         dispatch_mode: str = "einsum",
+        scoring: str = "softmax",
+        selection_bias: bool = False,
+        routed_scale: float = 1.0,
+        shared_ffn_dim: Optional[int] = None,
+        weight_init: Optional[Callable] = None,
+        use_kernel: Optional[bool] = None,
     ) -> None:
+        """``weight_init``: optional ``fn(shape, dtype)`` for every leaf
+        (router, selection bias, expert stacks, shared expert), in
+        construction order; default: the uniform fan-in bounds.
+        ``use_kernel``: the grouped path's Pallas kernel (None = on a
+        TPU), else its jnp form."""
         super().__init__()
         if not 1 <= top_k <= n_experts:
             raise ValueError(f"top_k={top_k} out of range for {n_experts} experts")
-        if dispatch_mode not in ("einsum", "gather"):
+        if dispatch_mode not in ("einsum", "gather", "grouped"):
             raise ValueError(
-                f"dispatch_mode {dispatch_mode!r} (expected 'einsum' or "
-                "'gather')"
+                f"dispatch_mode {dispatch_mode!r} (expected 'einsum', "
+                "'gather' or 'grouped')"
             )
         if dispatch_mode == "gather" and capacity_factor is None:
             raise ValueError(
@@ -83,47 +159,136 @@ class MoE(Module):
                 "compute (capacity_factor=None) has no dispatch step for "
                 "the gather path to replace"
             )
+        if dispatch_mode == "grouped" and capacity_factor is not None:
+            raise ValueError(
+                "dispatch_mode='grouped' drops no token and takes no "
+                "capacity_factor"
+            )
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"scoring {scoring!r} (expected 'softmax' or 'sigmoid')"
+            )
         self.dim = dim
         self.ffn_dim = ffn_dim
         self.n_experts = n_experts
         self.top_k = top_k
         self.capacity_factor = capacity_factor
         self.dispatch_mode = dispatch_mode
-        self.router = Linear(dim, n_experts, bias=False, dtype=dtype)
+        self.scoring = scoring
+        self.routed_scale = float(routed_scale)
+        self.use_kernel = use_kernel
+        self.router = Linear(
+            dim, n_experts, bias=False, dtype=dtype, weight_init=weight_init
+        )
         bound = math.sqrt(1.0 / dim)
-        self.w_gate = Parameter(
-            init.uniform((n_experts, dim, ffn_dim), -bound, bound, dtype=dtype)
-        )
-        self.w_up = Parameter(
-            init.uniform((n_experts, dim, ffn_dim), -bound, bound, dtype=dtype)
-        )
         down_bound = math.sqrt(1.0 / ffn_dim)
-        self.w_down = Parameter(
-            init.uniform(
-                (n_experts, ffn_dim, dim), -down_bound, down_bound, dtype=dtype
+        if weight_init is None:
+            up_init = lambda s, d: init.uniform(s, -bound, bound, dtype=d)  # noqa: E731
+            down_init = lambda s, d: init.uniform(  # noqa: E731
+                s, -down_bound, down_bound, dtype=d
             )
+        else:
+            up_init = down_init = weight_init
+        if selection_bias:
+            # DeepSeek-V3's e_score_correction_bias: added to the scores
+            # for the CHOICE of experts only
+            self.e_score_correction_bias = Parameter(
+                (weight_init or init.zeros)((n_experts,), dtype)
+            )
+        else:
+            self.register_parameter("e_score_correction_bias", None)
+        self.w_gate = Parameter(up_init((n_experts, dim, ffn_dim), dtype))
+        self.w_up = Parameter(up_init((n_experts, dim, ffn_dim), dtype))
+        self.w_down = Parameter(down_init((n_experts, ffn_dim, dim), dtype))
+        self.shared = (
+            _SharedFFN(dim, shared_ffn_dim, dtype, weight_init)
+            if shared_ffn_dim
+            else None
         )
 
     def _route(self, x):
+        """Every expert's score, float32: softmax over the router's
+        logits, or (DeepSeek-V3) their sigmoid with the logits
+        accumulated in float32."""
+        if self.scoring == "sigmoid":
+            logits = jnp.einsum(
+                "...d,ed->...e", x, self.router.weight,
+                preferred_element_type=jnp.float32,
+            )
+            return jax.nn.sigmoid(logits)
         logits = self.router(x).astype(jnp.float32)  # (..., E)
         return jax.nn.softmax(logits, axis=-1)
+
+    def _choose(self, probs):
+        """The ``top_k`` experts of every token and their weights: the
+        choice is by score plus the selection bias (where there is one),
+        the weight is the chosen experts' OWN scores renormalised to sum
+        to one, times ``routed_scale``."""
+        bias = self.e_score_correction_bias
+        if bias is None:
+            top_p, top_i = jax.lax.top_k(probs, self.top_k)
+        else:
+            _, top_i = jax.lax.top_k(
+                probs + bias.astype(jnp.float32), self.top_k
+            )
+            top_p = jnp.take_along_axis(probs, top_i, axis=-1)
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        if self.routed_scale != 1.0:
+            top_p = top_p * self.routed_scale
+        return top_p, top_i
 
     def forward(self, x, return_aux: bool = False):
         """Apply the layer; with ``return_aux=True`` also return the
         load-balancing auxiliary loss computed from the SAME routing pass
         (no second router forward)."""
-        probs = self._route(x)
-        if self.capacity_factor is not None:
+        with jax.named_scope("moe/route"):
+            probs = self._route(x)
+        if self.dispatch_mode == "grouped":
+            y = self._grouped_forward(x, probs)
+        elif self.capacity_factor is not None:
             y = self._capacity_forward(x, probs)
         else:
             y = self._dense_forward(x, probs)
+        if self.shared is not None:
+            with jax.named_scope("moe/shared"):
+                y = y + self.shared(x)
         if return_aux:
             return y, self._balance_loss(probs)
         return y
 
+    def _grouped_forward(self, x, probs):
+        """No token dropped, work proportional to ``tokens x top_k``: the
+        (token, expert) rows sorted by expert, the SwiGLU as grouped
+        matmuls over the experts that have rows, each token's ``top_k``
+        results gathered back and summed under their weights."""
+        from ..ops.grouped_matmul import grouped_matmul, plan_groups, row_tile
+
+        k = self.top_k
+        lead, d = x.shape[:-1], x.shape[-1]
+        xf = x.reshape(-1, d)
+        n = xf.shape[0]
+        with jax.named_scope("moe/route"):
+            top_p, top_i = self._choose(probs.reshape(n, self.n_experts))
+            plan = plan_groups(
+                top_i.reshape(-1).astype(jnp.int32), self.n_experts,
+                row_tile(n * k, x.dtype),
+            )
+        if _tape.current is not None:
+            _tape.current.append((n * k, plan.groups))
+        with jax.named_scope("moe/experts"):
+            h = grouped_matmul(
+                xf[plan.src // k], self.w_gate, plan, rhs_up=self.w_up,
+                block_n=384, use_kernel=self.use_kernel,
+            )
+            y = grouped_matmul(
+                h, self.w_down, plan, block_n=512, use_kernel=self.use_kernel
+            )
+            y = y[plan.dest].reshape(n, k, d).astype(jnp.float32)
+            y = jnp.einsum("nk,nkd->nd", top_p, y).astype(x.dtype)
+        return y.reshape(*lead, d)
+
     def _dense_forward(self, x, probs):
-        top_p, top_i = jax.lax.top_k(probs, self.top_k)
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p, top_i = self._choose(probs)
         # combine weights as a dense (..., E) mask — partition-friendly
         onehot = jax.nn.one_hot(top_i, self.n_experts, dtype=probs.dtype)
         combine = jnp.einsum("...k,...ke->...e", top_p, onehot)
@@ -146,8 +311,7 @@ class MoE(Module):
         Priority runs top-1 slots before top-2 across all tokens, then by
         token order — the standard GShard discipline."""
         e, k = self.n_experts, self.top_k
-        top_p, top_i = jax.lax.top_k(pf, k)
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p, top_i = self._choose(pf)
         slots = []
         counts = jnp.zeros((e,), jnp.int32)
         for j in range(k):  # static, small

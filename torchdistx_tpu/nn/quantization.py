@@ -110,11 +110,27 @@ class QuantizedMoE(MoE):
 
     @classmethod
     def from_moe(cls, m: MoE) -> "QuantizedMoE":
+        unsupported = {
+            "dispatch_mode='grouped'": m.dispatch_mode == "grouped",
+            "scoring='sigmoid'": m.scoring != "softmax",
+            "a selection bias": m.e_score_correction_bias is not None,
+            "a shared expert": m.shared is not None,
+        }
+        bad = [name for name, has in unsupported.items() if has]
+        if bad:
+            raise ValueError(
+                f"QuantizedMoE does not support {', '.join(bad)}: only the "
+                "softmax router over the dense and capacity paths has int8 "
+                "expert einsums"
+            )
         q = cls.__new__(cls)
         Module.__init__(q)
         for attr in ("dim", "ffn_dim", "n_experts", "top_k",
-                     "capacity_factor", "dispatch_mode"):
+                     "capacity_factor", "dispatch_mode", "scoring",
+                     "routed_scale", "use_kernel"):
             object.__setattr__(q, attr, getattr(m, attr))
+        q.register_parameter("e_score_correction_bias", None)
+        q.shared = None
         q.router = QuantizedLinear.from_linear(m.router)
         wg, sg = _quantize_stacked(m.w_gate, out_axis=2)  # (E, D, F)
         wu, su = _quantize_stacked(m.w_up, out_axis=2)

@@ -32,6 +32,7 @@ __all__ = [
     "ulysses_attention",
     "cached_attention",
     "slot_cached_attention",
+    "latent_slot_cached_attention",
 ]
 
 
@@ -395,6 +396,47 @@ def slot_cached_attention(
     else:
         out = _slot_attend_block(q, vk, vv, positions, scale)
     return out, cache
+
+
+def latent_slot_cached_attention(
+    q: jax.Array,
+    row_new: jax.Array,
+    cache: tuple,
+    positions: jax.Array,
+    *,
+    value_width: int,
+    scale: float,
+    use_flash: Optional[bool] = None,
+):
+    """The latent-cache (MLA) sibling of :func:`slot_cached_attention`:
+    one decode token per serving slot in the ABSORBED form.
+
+    ``q``: (B, H, W) absorbed queries ``[q_nope W_uk ; q_rope]``, ``W =
+    latent + rope width``.  ``row_new``: (B, 1, W), each slot's new cache
+    row ``[c ; k_r]`` (norm and rope applied).  ``cache``: the engine's
+    latent entry, a 1-tuple ``(latent,)`` of shape (slots, max_len, W) —
+    the kernel's operand as it is stored (``serve/kv_cache.py``).  The
+    row is written at ``positions[b]`` by the slab's one scatter
+    (``scatter_slot_tokens``), then slot ``b`` attends rows ``j <=
+    positions[b]``: the Pallas kernel ``tdx_latent_decode_attention``
+    when ``use_flash`` resolves on (auto = TPU), else the jnp path of
+    the same math (``ops.latent_decode_attention.latent_attend``).  Every
+    visible row is read once, for the score and for the value (its first
+    ``value_width`` lanes).  Returns ``(o~ (B, H, value_width),
+    (latent,))``; ``o~`` still goes through ``W_uv``."""
+    from ..serve.kv_cache import scatter_slot_tokens
+    from . import latent_decode_attention as lda
+    from .flash_attention import resolve_use_flash
+
+    (latent,) = cache
+    latent = scatter_slot_tokens(latent, row_new, positions)
+    attend = (
+        lda.latent_decode_attention
+        if resolve_use_flash(use_flash)
+        else lda.latent_attend
+    )
+    out = attend(q, latent, positions, value_width=value_width, scale=scale)
+    return out, (latent,)
 
 
 def multihead_attention(

@@ -922,6 +922,11 @@ def _flash_fwd_rule(
     q, k, v, bias, causal, scale, block_q, block_k, interpret, bucket_cfg,
     window,
 ):
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            f"flash attention's backward takes one head width; got qk "
+            f"{q.shape[-1]} and v {v.shape[-1]} (forward only)"
+        )
     # pallas backward path (biased or not): save the output + per-row lse
     # instead of recomputing the softmax state chunk by chunk — the saved
     # lse includes the bias, so the backward's p = exp(logits + bias - lse)
@@ -1150,6 +1155,11 @@ def _flash_forward(
 ):
     """(B, Sq, Hq, D) x (B, Skv, Hkv, D)^2 -> (B, Sq, Hq, D).
 
+    The values may be narrower or wider than the keys (``v``:
+    (B, Skv, Hkv, Dv), the output then (B, Sq, Hq, Dv)): multi-head
+    latent attention scores on 192 lanes and sums 128-wide values.
+    Forward only; the backward kernels assume one width.
+
     With ``bucket_cfg = (buckets, max_dist, bidirectional)`` the ``bias``
     operand is the per-head bucket TABLE of shape (Hq, buckets) instead
     of a materialized (Hq, Sq, Skv) bias: each kernel tile computes its
@@ -1179,6 +1189,7 @@ def _flash_forward(
         raise ValueError("return_residuals and return_lse are exclusive")
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
+    dv = v.shape[-1]
     if hq % hkv != 0:
         raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
     if causal and sq > skv:
@@ -1197,7 +1208,7 @@ def _flash_forward(
 
     qh = jnp.transpose(q, (0, 2, 1, 3)).reshape(b * hq, sq, d)
     kh = jnp.transpose(k, (0, 2, 1, 3)).reshape(b * hkv, skv, d)
-    vh = jnp.transpose(v, (0, 2, 1, 3)).reshape(b * hkv, skv, d)
+    vh = jnp.transpose(v, (0, 2, 1, 3)).reshape(b * hkv, skv, dv)
 
     def kv_index(c, i, kk):
         # combined q index c = batch * hq + h  ->  batch * hkv + h // n_rep
@@ -1206,7 +1217,7 @@ def _flash_forward(
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda c, i, kk: (c, i, 0)),
         pl.BlockSpec((1, block_k, d), kv_index),
-        pl.BlockSpec((1, block_k, d), kv_index),
+        pl.BlockSpec((1, block_k, dv), kv_index),
     ]
     operands = [qh, kh, vh]
     if bias is not None:
@@ -1242,10 +1253,10 @@ def _flash_forward(
             )
         operands.append(bias)
 
-    out_specs = [pl.BlockSpec((1, block_q, d), lambda c, i, kk: (c, i, 0))]
+    out_specs = [pl.BlockSpec((1, block_q, dv), lambda c, i, kk: (c, i, 0))]
     out_shape = [
         jax.ShapeDtypeStruct(
-            (b * hq, sq, d),
+            (b * hq, sq, dv),
             jnp.float32 if return_residuals else q.dtype,
         )
     ]
@@ -1284,7 +1295,7 @@ def _flash_forward(
         out_specs=out_specs if multi_out else out_specs[0],
         out_shape=out_shape if multi_out else out_shape[0],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
@@ -1295,13 +1306,13 @@ def _flash_forward(
         interpret=interpret,
     )(*operands)
     if not multi_out:
-        return jnp.transpose(outs.reshape(b, hq, sq, d), (0, 2, 1, 3))
+        return jnp.transpose(outs.reshape(b, hq, sq, dv), (0, 2, 1, 3))
     if return_lse:
         out, lse = outs
-        out = jnp.transpose(out.reshape(b, hq, sq, d), (0, 2, 1, 3))
+        out = jnp.transpose(out.reshape(b, hq, sq, dv), (0, 2, 1, 3))
         return out, lse[..., 0].reshape(b, hq, sq)
     out, m, l = outs
-    out = jnp.transpose(out.reshape(b, hq, sq, d), (0, 2, 1, 3))
+    out = jnp.transpose(out.reshape(b, hq, sq, dv), (0, 2, 1, 3))
     return (
         out,
         m[..., 0].reshape(b, hq, sq),

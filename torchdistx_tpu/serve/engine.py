@@ -112,6 +112,7 @@ from ..generation import (
     _make_slot_sampler,
 )
 from ..nn.module import functional_call
+from ..nn.moe import moe_count_tape, tape_totals
 from ..utils.compat import jit_cache_size
 from ..utils.profiling import timed_annotation
 from .kv_cache import (
@@ -446,6 +447,29 @@ class ServeEngine:
                 f"length {limit}"
             )
         self.model = model
+        # a latent-cache model (multi-head latent attention: one latent
+        # row a token and layer, models/deepseek_v3.py) is served from
+        # the slab with the default programs only; everything else is
+        # refused here, by name, not half-built
+        self.latent = bool(getattr(model, "latent_cache", False))
+        if self.latent:
+            refused = {
+                "page_size (a paged cache, and with it the prefix cache)":
+                    page_size is not None or num_pages is not None,
+                "kv_dtype='int8'":
+                    canonicalize_kv_dtype(kv_dtype) == "int8",
+                "speculate": bool(speculate),
+                "decode_mode='persistent'": decode_mode == "persistent",
+                "chunked_prefill": chunked_prefill is not None,
+                "mesh (tensor parallelism)": mesh is not None,
+            }
+            bad = [name for name, asked in refused.items() if asked]
+            if bad:
+                raise ValueError(
+                    f"{', '.join(bad)}: not supported over a latent cache "
+                    f"({type(model).__name__}): it is served from the "
+                    "slab cache with the chunked decode program only"
+                )
         self.params = (
             params if params is not None else dict(model.named_parameters())
         )
@@ -658,6 +682,14 @@ class ServeEngine:
             numerics_enabled() if numerics is None else bool(numerics)
         )
         self.numerics_book = NumericsBook()
+        # an expert model's rows and groups (nn.moe.moe_count_tape): one
+        # more output of the slab prefill and chunked decode programs,
+        # accumulated on the device by ServeMetrics.add_device_counts
+        self._moe_counts = (
+            bool(getattr(model, "moe_counters", False))
+            and not self.numerics
+            and mesh is None
+        )
         self._pending_digests: list = []
         self._kv_quant_alarmed = False
         # the dtype actually stored (model default resolved), for the
@@ -680,6 +712,7 @@ class ServeEngine:
             kv_bytes_per_token=self.cache.nbytes // _kv_rows,
             kv_quant_err_max=0.0 if self.kv_quantized else None,
             kv_quant_err_rms=0.0 if self.kv_quantized else None,
+            kv_row_bytes=self.cache.kv_row_bytes,
         )
         self._sampler = _make_slot_sampler(jnp.int32, top_k, top_p)
         # persistent mode: prefill defers its first-token fetch — the
@@ -1511,6 +1544,7 @@ class ServeEngine:
             kv_bytes_per_token=self.cache.nbytes // _kv_rows,
             kv_quant_err_max=self.metrics.kv_quant_err_max,
             kv_quant_err_rms=self.metrics.kv_quant_err_rms,
+            kv_row_bytes=self.cache.kv_row_bytes,
         )
         return self.metrics
 
@@ -1629,21 +1663,31 @@ class ServeEngine:
 
     def _prefill_program(self, bucket: int):
         model, sampler = self.model, self._sampler
-        num_on = self.numerics
+        num_on, moe_counts = self.numerics, self._moe_counts
+        # a model that can apply its head to one position is asked for
+        # the sampled one only: no (bucket, vocab) array in the program
+        one_row = bool(getattr(model, "prefill_logits_at", False))
 
         def build(params, kv, tokens, true_len, slot, temp, seed):
             def body():
                 slab = model.init_cache(1, bucket)
                 logits, slab = functional_call(
                     model, params, (tokens, slab, 0),
+                    {"logits_at": true_len - 1} if one_row else None,
                     method="forward_cached",
                 )
-                last = tap("logits", jax.lax.dynamic_slice_in_dim(
-                    logits, true_len - 1, 1, axis=1
-                )[:, 0, :])
+                if not one_row:
+                    logits = jax.lax.dynamic_slice_in_dim(
+                        logits, true_len - 1, 1, axis=1
+                    )
+                last = tap("logits", logits[:, 0, :])
                 tok = sampler(last, temp, seed, jnp.zeros((1,), jnp.int32))
                 return write_slot(kv, slab, slot), tok[0]
 
+            if moe_counts:
+                with moe_count_tape() as tape:
+                    out = body()
+                return (*out, tape_totals(tape))
             return _taped(num_on, body)
 
         # the kv slab is donated: self.cache.kv is rebound to the output
@@ -1797,6 +1841,7 @@ class ServeEngine:
             max_len=self.max_len,
             decode_chunk=self.decode_chunk,
             numerics=self.numerics,
+            moe_counts=self._moe_counts,
         )
         return _cached_jit(
             self.model,
@@ -2225,6 +2270,8 @@ class ServeEngine:
             self.cache.kv = kv
             if self.numerics:
                 self._pending_digests.append(out[-1])
+            if self._moe_counts:
+                self.metrics.add_device_counts("prefill", out[2])
             if not self._persistent:  # persistent defers to the drain
                 tok = int(np.asarray(tok))  # host sync: first token exists
         self.metrics.count("tokens_prefilled", bucket)
@@ -2482,6 +2529,8 @@ class ServeEngine:
             self.cache.kv = kv  # before the sync: old slab was donated
             if self.numerics:
                 self._pending_digests.append(out[-1])
+            if self._moe_counts:
+                self.metrics.add_device_counts("decode", out[2])
             block = np.asarray(block)  # ONE host sync per K slot-steps
         with self._phase("harvest"):
             # drop this dispatch's device handles here, inside the phase:

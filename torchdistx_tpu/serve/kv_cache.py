@@ -28,6 +28,19 @@ flattens the NEW ROWS, never the cache; a reader that needs heads (the
 jnp attends, :func:`paged_view`, chunked prefill's warm program) takes
 a 4-D view of what it read (:func:`split_heads`).
 
+**A latent entry** (multi-head latent attention, ``models/deepseek_v3``):
+a layer's entry is ONE array ``(latent,)`` of shape ``(num_slots,
+max_len, W)`` — a token's compressed key/value and its one shared rope
+key side by side (``kv_lora_rank + qk_rope_head_dim`` = 576 lanes,
+which the model zero-pads to whole 128-lane tiles, ``W`` = 640), where
+the pair layout would hold ``heads x (qk + v)`` (10240 for the same
+model).  It has no head axis to merge: ``model.init_cache`` already
+returns it in the form ``tdx_latent_decode_attention`` reads, prefill
+writes its slab with the same ``dynamic_update_slice`` and a decode step
+its row with the same scatter as a pair's arrays.  Only the slab layout
+holds one (the engine refuses paging, int8 and the warm / chunked
+programs over it, by name).
+
 In both, admitting/retiring a request changes only tiny dynamic inputs
 (positions, a table row, a host bit) — never a device shape — so the
 compiled prefill/decode programs survive any admit/retire sequence: the
@@ -98,6 +111,7 @@ __all__ = [
     "split_heads",
     "heads_view",
     "stored_rows",
+    "is_latent",
     "write_slot",
     "paged_view",
     "paged_scatter_rows",
@@ -264,6 +278,12 @@ def stored_rows(entry: Any, k: jax.Array, v: jax.Array) -> tuple:
     return tuple(merge_heads(x).astype(c.dtype) for x, c in zip(rows, entry))
 
 
+def is_latent(entry: Any) -> bool:
+    """A layer's entry that is one latent array ``(latent,)`` (module
+    docstring), not a ``(k, v)`` pair or its quantized 4-tuple."""
+    return len(entry) == 1
+
+
 def write_slot(kv: Any, slab: Any, slot) -> Any:
     """Write one request's prefilled cache slab into slot row ``slot``.
 
@@ -277,13 +297,17 @@ def write_slot(kv: Any, slab: Any, slot) -> Any:
     1024), not on the cache.  ``slot`` may be traced (it is, inside the
     jitted prefill program); the write is a pure
     ``dynamic_update_slice`` per layer — no recompile across slots.
+    A latent entry's slab ``(latent (1, bucket, W),)`` is already in the
+    stored form.
     """
     return [
         tuple(
-            lax.dynamic_update_slice(c, x, (slot, 0, 0))
-            for c, x in zip(entry, stored_rows(entry, sk, sv))
+            lax.dynamic_update_slice(c, x.astype(c.dtype), (slot, 0, 0))
+            for c, x in zip(
+                entry, s if is_latent(entry) else stored_rows(entry, *s)
+            )
         )
-        for entry, (sk, sv) in zip(kv, slab)
+        for entry, s in zip(kv, slab)
     ]
 
 
@@ -488,6 +512,13 @@ class _HostBookkeeping:
         )
 
     @property
+    def kv_row_bytes(self) -> int:
+        """Bytes one token takes in one layer's data arrays: ``2 x Hkv x
+        D x itemsize`` for a pair, ``W x itemsize`` for a latent entry
+        (1280 for DeepSeek-V3's 576 bf16 lanes stored on 640)."""
+        return sum(a.shape[-1] * a.dtype.itemsize for a in self.kv[0][:2])
+
+    @property
     def kv_scale_nbytes(self) -> int:
         """Bytes of the f32 scale arrays (0 for unquantized caches)."""
         return sum(
@@ -537,9 +568,19 @@ class _HostBookkeeping:
 
         self.kv_dtype = kv_dtype = canonicalize_kv_dtype(kv_dtype)
         self.quantized = quantized = kv_dtype == "int8"
+        shapes = jax.eval_shape(lambda: model.init_cache(lead, rows))
+        self.latent = latent = is_latent(shapes[0])
+        if latent and quantized:
+            raise ValueError(
+                "kv_dtype='int8' is not supported over a latent cache: "
+                "the per-head scales have no head to belong to"
+            )
 
         def stored():  # closes over the model only, never over ``self``
             base = model.init_cache(lead, rows)
+            if latent:  # already the stored form: no head tail to merge
+                dt = base[0][0].dtype if kv_dtype is None else _KV_DTYPES[kv_dtype]
+                return [(c.astype(dt),) for (c,) in base]
             if quantized:
                 base = quantize_cache(base)
             elif kv_dtype is not None:
@@ -547,9 +588,8 @@ class _HostBookkeeping:
                 base = [(k.astype(dt), v.astype(dt)) for k, v in base]
             return [tuple(merge_heads(a) for a in entry) for entry in base]
 
-        self.kv_heads = int(
-            jax.eval_shape(lambda: model.init_cache(lead, rows))[0][0].shape[2]
-        )
+        # a latent row is shared by every head: it has no head axis
+        self.kv_heads = None if latent else int(shapes[0][0].shape[2])
         if placement is None:
             placement = jax.devices()[0]
         sharding = (

@@ -16,9 +16,22 @@ its last stdout line.
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
-__all__ = ["Histogram", "ServeMetrics"]
+import numpy as np
+
+__all__ = ["Histogram", "ServeMetrics", "latest_metrics"]
+
+#: the ServeMetrics this process made last.  It holds host numbers and a
+#: few device scalars, never a cache or a weight, so a reader that
+#: outlives the engine (a benchmark's per-layer metric, read after the
+#: program is freed) finds the window's counters here
+_LATEST: Optional["ServeMetrics"] = None
+
+
+def latest_metrics() -> Optional["ServeMetrics"]:
+    """The most recently constructed :class:`ServeMetrics`, or None."""
+    return _LATEST
 
 
 class Histogram:
@@ -204,8 +217,22 @@ class ServeMetrics:
         kv_bytes_per_token: Optional[int] = None,
         kv_quant_err_max: Optional[float] = None,
         kv_quant_err_rms: Optional[float] = None,
+        kv_row_bytes: Optional[int] = None,
     ):
+        global _LATEST
+        _LATEST = self
         self.num_slots = int(num_slots)
+        # bytes one token takes in one layer's cache data: 2 x Hkv x D x
+        # itemsize for a (k, v) pair, W x itemsize for a latent row
+        self.kv_row_bytes = (
+            kv_row_bytes if kv_row_bytes is None else int(kv_row_bytes)
+        )
+        # counters the serve programs accumulate ON THE DEVICE (an expert
+        # model's rows and groups): name -> a device int32 scalar, folded
+        # into ``counters`` only when this object is read (``to_json`` /
+        # ``snapshot`` / ``sync_device_counters``), never inside step()
+        self._device_counters: Dict[str, Any] = {}
+        self._device_adds = 0
         self.num_pages = num_pages if num_pages is None else int(num_pages)
         self.ring_capacity = (
             ring_capacity if ring_capacity is None else int(ring_capacity)
@@ -298,6 +325,34 @@ class ServeMetrics:
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] += n
 
+    def add_device_counts(self, phase: str, counts: Any) -> None:
+        """Accumulate one dispatch's expert-layer counts, a device int32
+        ``[rows, groups]`` (``nn.moe.tape_totals``), under ``phase``
+        (``"prefill"`` | ``"decode"``).  An add on the device: no
+        transfer, no sync (but for one fold every 4096 adds)."""
+        key = f"moe_{phase}"
+        prev = self._device_counters.get(key)
+        self._device_counters[key] = counts if prev is None else prev + counts
+        # int32 on the device: a long prefill adds ~3e5 rows, so fold
+        # into the host's counters (one 8-byte fetch) long before 2**31
+        self._device_adds += 1
+        if self._device_adds >= 4096:
+            self.sync_device_counters()
+
+    def sync_device_counters(self) -> None:
+        """Fetch what the device accumulated into ``counters``
+        (``moe_routed_rows_<phase>``: (token, expert) rows computed;
+        ``moe_groups_<phase>``: experts with at least one row, summed
+        over layers and calls) and start the accumulators again."""
+        pending, self._device_counters = self._device_counters, {}
+        self._device_adds = 0
+        for key, value in pending.items():
+            rows, groups = (int(v) for v in np.asarray(value))
+            phase = key[len("moe_"):]
+            for name, n in (("moe_routed_rows", rows), ("moe_groups", groups)):
+                for full in (name, f"{name}_{phase}"):
+                    self.counters[full] = self.counters.get(full, 0) + n
+
     def observe_gauges(self, queue_depth: int, active_slots: int) -> None:
         self.queue_depth = queue_depth
         self.active_slots = active_slots
@@ -334,6 +389,7 @@ class ServeMetrics:
         (``count/mean/p50/p95/max``), and the derived rates.
         ``scripts/bench_serve.py`` embeds this whole object per phase
         instead of hand-picking fields."""
+        self.sync_device_counters()
         gauges: dict = {
             "queue_depth": self.queue_depth,
             "active_slots": self.active_slots,
@@ -359,6 +415,8 @@ class ServeMetrics:
             gauges["kv_cache_bytes"] = self.kv_cache_bytes
         if self.kv_bytes_per_token is not None:
             gauges["kv_bytes_per_token"] = self.kv_bytes_per_token
+        if self.kv_row_bytes is not None:
+            gauges["kv_row_bytes"] = self.kv_row_bytes
         if self.kv_quant_err_max is not None:
             gauges["kv_quant_err_max"] = self.kv_quant_err_max
         if self.kv_quant_err_rms is not None:
