@@ -289,6 +289,53 @@ def test_decode_step_leaves_the_cache_in_place(
     assert not offenders, offenders
 
 
+def test_serve_decode_program_compiles_from_the_packed_state(
+    one_chip, monkeypatch
+):
+    """The engine's whole decode program at Mistral-7B's widths (two
+    layers of the cell's 24; 16 slots of 2048), lowered from the
+    signature the engine dispatches since PR 31 — ``(params, kv, state)``
+    with the per-slot state ONE ``(7, num_slots)`` int32 argument
+    (``generation.pack_slot_state``) — and compiled for the described
+    chip: the unpacking costs the program no kernel (one decode-attention
+    call a layer, as before) and nothing of the cache's size is copied.
+    ``benchmarks/proof/describe_compile.py`` spells the seven-argument
+    list of before; this is the described compile of the new one."""
+    import torchdistx_tpu as tdx
+    from torchdistx_tpu.generation import SLOT_STATE_ROWS
+    from torchdistx_tpu.models import Llama
+    from torchdistx_tpu.serve import ServeEngine
+
+    (chip,) = one_chip.device_set
+    layers = 2
+    model = tdx.deferred_init(
+        lambda: Llama.from_name(
+            "mistral_7b", n_layers=layers, vocab_size=32768,
+            max_seq_len=M_L, rope_theta=1e6, sliding_window=None,
+            dtype=jnp.bfloat16,
+        )
+    )
+    engine = ServeEngine(  # its (small) cache lives on the CPU
+        model, num_slots=M_B, max_len=M_L, prefill_buckets=(128,),
+        cost_cards=False,
+    )
+
+    def shape(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = {n: shape(p) for n, p in model.named_parameters()}
+    kv = jax.tree_util.tree_map(shape, engine.cache.kv)
+    state = jax.ShapeDtypeStruct(
+        (SLOT_STATE_ROWS, M_B), jnp.int32, sharding=one_chip
+    )
+    # ``interpret=None`` and ``use_flash`` ask jax.devices()[0].platform
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+    text = engine._decode_program().lower(params, kv, state).compile().as_text()
+    assert _kernel_names(text) == ["tdx_decode_attention"] * layers
+    offenders = _relayouts_of_the_cache(text, M_B * M_L * M_HKV * D)
+    assert not offenders, offenders
+
+
 # The DeepSeek-V3 family at kanana-2-30b-a3b's widths (the serve cell's:
 # 32 slots of 8192 rows; 32 heads; a latent row of 512 + 64 lanes stored
 # on 640; 128 experts of 2048 x 768, top 6).

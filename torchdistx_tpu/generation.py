@@ -14,6 +14,8 @@ from __future__ import annotations
 
 from typing import Any, Callable, Optional
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
@@ -126,6 +128,51 @@ def _make_slot_sampler(
     return sample
 
 
+#: rows of the packed per-slot state every serve decode program takes
+#: (``pack_slot_state`` / ``_unpack_slot_state``)
+SLOT_STATE_ROWS = 7
+
+
+def pack_slot_state(
+    toks, positions, temps, seeds, steps, budgets, mask
+) -> np.ndarray:
+    """The serve decode programs' per-slot state as ONE host array:
+    ``(SLOT_STATE_ROWS, num_slots)`` int32 — last tokens, write
+    positions, temperatures (their float32 BITS), sampler seeds, tokens
+    sampled so far, budgets, and the program's mask (finished for the
+    fused scan, active for the persistent loop) as 0/1.
+
+    One array is one host-to-device transfer inside the dispatch call.
+    Seven arrays were seven: 0.11 ms each on the chip's host through the
+    call's own argument path and 0.28 ms each through ``jnp.asarray``,
+    with the device idle, in every decode step (PERF.md, PR 31).  The
+    result is fresh — nothing of the caller's is aliased — and
+    ``_unpack_slot_state`` gives the seven back bit for bit on the
+    device."""
+    toks = np.asarray(toks)
+    state = np.empty((SLOT_STATE_ROWS, toks.shape[0]), np.int32)
+    state[0] = toks
+    state[1] = positions
+    state[2] = np.asarray(temps, np.float32).view(np.int32)
+    state[3] = seeds
+    state[4] = steps
+    state[5] = budgets
+    state[6] = mask
+    return state
+
+
+def _unpack_slot_state(state):
+    """``pack_slot_state``'s inverse inside a traced program: ``(toks,
+    positions, temps, seeds, steps, budgets, mask)`` with the dtypes the
+    decode bodies take (int32; float32 temperatures by a bit cast, so
+    negative, subnormal and NaN values survive; a bool mask)."""
+    toks, positions, temps, seeds, steps, budgets, mask = (
+        state[i] for i in range(SLOT_STATE_ROWS)
+    )
+    temps = jax.lax.bitcast_convert_type(temps, jnp.float32)
+    return toks, positions, temps, seeds, steps, budgets, mask != 0
+
+
 def _make_decode_body(
     model: Any,
     sampler,
@@ -207,10 +254,11 @@ def _make_fused_decode(
     separate one-step dispatches do to a retired slot's row, which is
     what makes fused-vs-sequential cache states comparable.
 
-    Returns ``run(params, kv, toks, positions, temps, seeds, steps,
-    budgets, finished, *extra) -> (kv, (K, B) token block)``.  ``extra``
-    is empty for the contiguous slot cache; the PAGED engine passes its
-    device page tables there — scan-invariant (a request's full
+    Returns ``run(params, kv, state, *extra) -> (kv, (K, B) token
+    block)``.  ``state`` is ``pack_slot_state(toks, positions, temps,
+    seeds, steps, budgets, finished)``: one argument, one transfer.
+    ``extra`` is empty for the contiguous slot cache; the PAGED engine
+    passes its page tables there — scan-invariant (a request's full
     page-aligned footprint is allocated at admission, so no chunk ever
     needs a page the table doesn't already name) and forwarded to
     ``forward_decode`` each step.
@@ -232,8 +280,10 @@ def _make_fused_decode(
         model, sampler, eos_token=eos_token, max_len=max_len
     )
 
-    def run(params, kv, toks, positions, temps, seeds, steps, budgets,
-            finished, *extra):
+    def run(params, kv, state, *extra):
+        toks, positions, temps, seeds, steps, budgets, finished = (
+            _unpack_slot_state(state)
+        )
         init = (kv, toks, positions, steps, finished)
         if moe_counts and not numerics:
             from .nn.moe import moe_count_tape, tape_totals
@@ -319,8 +369,10 @@ def _make_persistent_decode(
     first-token latency (``utils.compat``); the ring drain stays the
     authoritative token path whether or not the stream fires.
 
-    Returns ``run(params, kv, toks, positions, temps, seeds, steps,
-    budgets, active, *extra) -> (kv, ring, valid, iterations)``, plus a
+    Returns ``run(params, kv, state, *extra) -> (kv, ring, valid,
+    iterations)`` (``state``: ``pack_slot_state`` with ``active`` as its
+    mask; the engine splices deferred first tokens into its row 0 on the
+    device), plus a
     trailing merged ``{site: digest}`` dict when ``numerics=True`` (the
     accumulator rides the loop carry — the drain stays the one sync).
     """
@@ -329,8 +381,10 @@ def _make_persistent_decode(
         model, sampler, eos_token=eos_token, max_len=max_len
     )
 
-    def run(params, kv, toks, positions, temps, seeds, steps, budgets,
-            active, *extra):
+    def run(params, kv, state, *extra):
+        toks, positions, temps, seeds, steps, budgets, active = (
+            _unpack_slot_state(state)
+        )
         fin0 = (~active) | (steps >= budgets)
         if eos_token is not None:
             fin0 = fin0 | (toks == eos_token)
@@ -547,9 +601,9 @@ def _make_fused_spec_decode(
     the host walk can consume a VARIABLE number of tokens per iteration
     per slot while the device shapes stay static.
 
-    Returns ``run(params, kv, toks, positions, hist, temps, seeds,
-    steps, budgets, finished, *extra) -> (kv, (chunk, B, K+1) token
-    blocks, (chunk, B) counts)``.
+    Returns ``run(params, kv, state, hist, *extra) -> (kv, (chunk, B,
+    K+1) token blocks, (chunk, B) counts)`` (``state``:
+    ``pack_slot_state``, as for ``_make_fused_decode``).
     """
 
     step = _make_spec_decode_body(
@@ -561,8 +615,10 @@ def _make_fused_spec_decode(
         ngram=ngram,
     )
 
-    def run(params, kv, toks, positions, hist, temps, seeds, steps,
-            budgets, finished, *extra):
+    def run(params, kv, state, hist, *extra):
+        toks, positions, temps, seeds, steps, budgets, finished = (
+            _unpack_slot_state(state)
+        )
         init = (kv, toks, positions, steps, finished, hist)
         if not numerics:
             def body(carry, _):
@@ -615,8 +671,8 @@ def _make_persistent_spec_decode(
     exactly as before — speculation multiplies tokens per sync, it never
     adds a sync.
 
-    Returns ``run(params, kv, toks, positions, hist, temps, seeds,
-    steps, budgets, active, *extra) -> (kv, ring, cnts, iterations)``.
+    Returns ``run(params, kv, state, hist, *extra) -> (kv, ring, cnts,
+    iterations)`` (``state``: as for ``_make_persistent_decode``).
     """
 
     step = _make_spec_decode_body(
@@ -628,8 +684,10 @@ def _make_persistent_spec_decode(
         ngram=ngram,
     )
 
-    def run(params, kv, toks, positions, hist, temps, seeds, steps,
-            budgets, active, *extra):
+    def run(params, kv, state, hist, *extra):
+        toks, positions, temps, seeds, steps, budgets, active = (
+            _unpack_slot_state(state)
+        )
         fin0 = (~active) | (steps >= budgets)
         if eos_token is not None:
             fin0 = fin0 | (toks == eos_token)

@@ -43,6 +43,20 @@ scratch) for the loop's whole lifetime.  The K-step ``chunked`` path
 stays the pinned-bit-identical reference (streams are identical by
 construction: one shared body, one sampler key schedule).
 
+**A dispatch's small arguments** are HOST arrays, and the program call
+makes the transfers (inside the spans ``serve/decode`` and
+``serve/prefill``): the step path converts nothing beforehand.  A decode
+dispatch's seven per-slot inputs are one packed ``(7, num_slots)`` int32
+array (``generation.pack_slot_state``, unpacked on the device bit for
+bit), written once in ``_decode_args`` for all four decode programs; the
+span ``serve/decode_args`` holds that packing, the copies of the
+speculative history and the page tables, and the cost-card lookup — no
+transfer, except the persistent loop's, whose state goes to the device
+first so that deferred first tokens can be spliced into it there.  A
+prefill's are the padded prompt and NumPy scalars with their dtypes
+written out.  Every array handed over is fresh or a copy: the host
+mirrors are written again in ``serve/harvest``.
+
 Admitting or retiring a request changes only tiny dynamic inputs
 (positions, temperatures, budgets, a slot index), never a compiled
 shape — the jit cache stays at two programs (plus one per extra bucket
@@ -110,6 +124,7 @@ from ..generation import (
     _make_persistent_decode,
     _make_persistent_spec_decode,
     _make_slot_sampler,
+    pack_slot_state,
 )
 from ..nn.module import functional_call
 from ..nn.moe import moe_count_tape, tape_totals
@@ -2239,6 +2254,20 @@ class ServeEngine:
         self.metrics.count("tokens_generated")
         self.metrics.ttft_s.record(req.first_token_at - req.submitted_at)
 
+    @staticmethod
+    def _sampling_args(req: Request) -> tuple:
+        """A prefill program's last two arguments: the request's
+        temperature and seed as one-element HOST arrays.  Like every
+        small argument of a dispatch they cross to the device inside the
+        program call (its own argument path), not through a
+        ``jnp.asarray`` each beforehand.  The dtypes are written out: a
+        Python number would be weakly typed and compile a second
+        program."""
+        return (
+            np.asarray([req.temperature], np.float32),
+            np.asarray([req.seed], np.int32),
+        )
+
     def _dispatch_prefill_slab(self, req: Request, slot: int) -> int:
         if (
             self.chunked_prefill is not None
@@ -2254,11 +2283,10 @@ class ServeEngine:
         args = (
             self.params,
             self.cache.kv,
-            jnp.asarray(padded),
-            jnp.int32(req.prompt.size),
-            jnp.int32(slot),
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.seed], jnp.int32),
+            padded,
+            np.int32(req.prompt.size),
+            np.int32(slot),
+            *self._sampling_args(req),
         )
         self._ensure_card(name, program, args)
         with self._phase("prefill"), self._watch(name):
@@ -2319,11 +2347,10 @@ class ServeEngine:
                 args = (
                     self.params,
                     self.cache.kv,
-                    jnp.asarray(padded),
-                    jnp.int32(ln),
-                    jnp.int32(slot),
-                    jnp.asarray([req.temperature], jnp.float32),
-                    jnp.asarray([req.seed], jnp.int32),
+                    padded,
+                    np.int32(ln),
+                    np.int32(slot),
+                    *self._sampling_args(req),
                 )
             else:
                 program = self._prefill_warm_program(bucket)
@@ -2331,12 +2358,11 @@ class ServeEngine:
                 args = (
                     self.params,
                     self.cache.kv,
-                    jnp.asarray(padded),
-                    jnp.int32(start),
-                    jnp.int32(ln),
-                    jnp.int32(slot),
-                    jnp.asarray([req.temperature], jnp.float32),
-                    jnp.asarray([req.seed], jnp.int32),
+                    padded,
+                    np.int32(start),
+                    np.int32(ln),
+                    np.int32(slot),
+                    *self._sampling_args(req),
                 )
             self._ensure_card(name, program, args)
             with self._phase("prefill"), self._watch(name):
@@ -2375,16 +2401,12 @@ class ServeEngine:
         args = [
             self.params,
             self.cache.kv,
-            jnp.asarray(self.cache.page_tables[slot]),
-            jnp.asarray(padded),
+            self.cache.page_tables[slot].copy(),
+            padded,
         ]
         if pfx > 0:
-            args.append(jnp.int32(pfx))
-        args += [
-            jnp.int32(suffix.size),
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.seed], jnp.int32),
-        ]
+            args.append(np.int32(pfx))
+        args += [np.int32(suffix.size), *self._sampling_args(req)]
         name = "serve/prefill/{}/b{}".format(
             "warm" if pfx > 0 else "cold", bucket
         )
@@ -2458,16 +2480,12 @@ class ServeEngine:
             args = [
                 self.params,
                 self.cache.kv,
-                jnp.asarray(self.cache.page_tables[slot]),
-                jnp.asarray(padded),
+                self.cache.page_tables[slot].copy(),
+                padded,
             ]
             if warm:
-                args.append(jnp.int32(start))
-            args += [
-                jnp.int32(ln),
-                jnp.asarray([req.temperature], jnp.float32),
-                jnp.asarray([req.seed], jnp.int32),
-            ]
+                args.append(np.int32(start))
+            args += [np.int32(ln), *self._sampling_args(req)]
             name = "serve/prefill/{}/b{}".format(
                 "warm" if warm else "cold", bucket
             )
@@ -2485,6 +2503,65 @@ class ServeEngine:
             self._record_tp_collectives(bucket)
         self._adopt_prefix(req)
         return tok
+
+    def _decode_args(self) -> tuple:
+        """The argument list of a decode dispatch, written once for the
+        four decode programs (fused / persistent, each plain or
+        speculative): ``(params, kv, state[, hist][, page_tables])``.
+
+        ``state`` is the per-slot state packed into ONE host array
+        (``generation.pack_slot_state``), and it crosses to the device
+        INSIDE the program call, through the dispatch's own argument
+        path: one transfer, where seven ``jnp.asarray`` were seven
+        Python-level ``device_put``s of 0.28 ms each on the chip's host,
+        every step, with the device idle (PERF.md, PR 31).  Every array
+        handed over is one nothing writes afterwards — the packed state
+        is fresh, and ``_hist`` and the page tables, live mirrors written
+        again in ``serve/harvest`` and at admission, go as copies — so no
+        ordering of transfer and bookkeeping is relied on."""
+        cache = self.cache
+        if self._persistent:
+            # the ACTIVE mask carries the cache-full rule: positions() is
+            # clamped to max_len - 1, so the room check must come from
+            # the UNCLAMPED host positions or it could never fire
+            # (_make_persistent_decode docstring)
+            mask = cache.active & (cache.pos < self.max_len)
+        else:
+            mask = ~cache.active  # retired slots: finished
+        state = pack_slot_state(
+            self._last_tok,
+            cache.positions(),
+            self._temps,
+            self._seeds,
+            self._ntok,
+            self._budget,
+            mask,
+        )
+        if self._persistent:
+            # freshly prefilled slots: their first token exists only on
+            # device; splice it into the state's last-token row without a
+            # fetch (a tiny host-staged update, no sync).  The state is a
+            # device array on EVERY persistent dispatch, tokens pending or
+            # none: one argument type, one entry in the jit's dispatch
+            # cache.  The index is ARRAY-typed on purpose: a python-int
+            # index is a static value baked into the scatter executable,
+            # so each distinct slot would compile its own op — a per-slot
+            # recompile the recompile watcher flags in the bench's
+            # measured window
+            state = jnp.asarray(state)
+            for slot, dev_tok in self._pending_first.items():
+                state = state.at[0, jnp.asarray(slot, jnp.int32)].set(dev_tok)
+        args = [self.params, cache.kv, state]
+        if self.speculate:
+            args.append(self._hist.copy())
+        if self.paged:
+            # tiny int32 dynamic input; rewritten host-side at every
+            # admit/retire, and only there: pages are freed or reallocated
+            # at chunk and drain boundaries, so it is invariant within a
+            # dispatch and no frozen in-loop write can land on a page this
+            # table doesn't own
+            args.append(cache.page_tables.copy())
+        return tuple(args)
 
     def _decode_step(self, skip: Optional[Request] = None) -> None:
         """One fused decode dispatch: ``K = decode_chunk`` on-device
@@ -2506,23 +2583,9 @@ class ServeEngine:
             running = self.scheduler.running
             k_steps = self.decode_chunk
             program = self._decode_program()
-            args = [
-                self.params,
-                self.cache.kv,
-                jnp.asarray(self._last_tok),
-                jnp.asarray(self.cache.positions()),
-                jnp.asarray(self._temps),
-                jnp.asarray(self._seeds),
-                jnp.asarray(self._ntok),
-                jnp.asarray(self._budget),
-                jnp.asarray(~self.cache.active),  # retired slots: finished
-            ]
-            if self.paged:
-                # tiny int32 dynamic input; rewritten host-side at every
-                # admit/retire, scan-invariant within the chunk
-                args.append(jnp.asarray(self.cache.page_tables))
+            args = self._decode_args()
             name = f"serve/decode/k{k_steps}"
-            self._ensure_card(name, program, tuple(args))
+            self._ensure_card(name, program, args)
         with self._phase("decode"), self._watch(name):
             out = program(*args)
             kv, block = out[0], out[1]
@@ -2535,7 +2598,7 @@ class ServeEngine:
         with self._phase("harvest"):
             # drop this dispatch's device handles here, inside the phase:
             # left to the frame's teardown they are freed after it, in
-            # nobody's span (seven small buffers and the token block)
+            # nobody's span (the outputs; the arguments are host arrays)
             del args, out
             self.metrics.count("host_syncs")
             self._harvest_numerics()
@@ -2593,39 +2656,10 @@ class ServeEngine:
         with self._phase("decode_args"):
             running = self.scheduler.running
             program = self._persistent_program()
-            toks = jnp.asarray(self._last_tok)
-            for slot, dev_tok in self._pending_first.items():
-                # freshly prefilled slots: their first token exists only on
-                # device; splice it into the loop's last-token row without a
-                # fetch (a tiny host-staged update, no sync).  The index is
-                # ARRAY-typed on purpose: a python-int index is a static
-                # value baked into the scatter executable, so each distinct
-                # slot would compile its own op — a per-slot recompile the
-                # recompile watcher flags in the bench's measured window
-                toks = toks.at[jnp.asarray(slot, jnp.int32)].set(dev_tok)
-            args = [
-                self.params,
-                self.cache.kv,
-                toks,
-                jnp.asarray(self.cache.positions()),
-                jnp.asarray(self._temps),
-                jnp.asarray(self._seeds),
-                jnp.asarray(self._ntok),
-                jnp.asarray(self._budget),
-                # the active mask carries the cache-full rule: positions()
-                # is clamped to max_len - 1, so the room check must come
-                # from the UNCLAMPED host positions or it could never fire
-                # (_make_persistent_decode docstring)
-                jnp.asarray(self.cache.active & (self.cache.pos < self.max_len)),
-            ]
-            if self.paged:
-                # scan-invariant within the loop: pages are only ever freed
-                # or reallocated host-side at drain boundaries, so no frozen
-                # in-loop write can land on a page this table doesn't own
-                args.append(jnp.asarray(self.cache.page_tables))
+            args = self._decode_args()
             self._stream_events.clear()
             name = f"serve/decode/persistent/r{self.ring_capacity}"
-            self._ensure_card(name, program, tuple(args))
+            self._ensure_card(name, program, args)
         with self._phase("decode"), self._watch(name):
             out = program(*args)
             kv, ring, valid, iters = out[0], out[1], out[2], out[3]
@@ -2639,7 +2673,7 @@ class ServeEngine:
             )
         with self._phase("harvest"):
             # (device handles: see _decode_step)
-            del args, toks, out, ring, valid, iters
+            del args, out, ring, valid, iters
             n_it = int(n_it)
             self._pending_first.clear()
             self.metrics.count("host_syncs")  # the drain IS the sync
@@ -2758,22 +2792,9 @@ class ServeEngine:
             running = self.scheduler.running
             k_steps = self.decode_chunk
             program = self._spec_decode_program()
-            args = [
-                self.params,
-                self.cache.kv,
-                jnp.asarray(self._last_tok),
-                jnp.asarray(self.cache.positions()),
-                jnp.asarray(self._hist),
-                jnp.asarray(self._temps),
-                jnp.asarray(self._seeds),
-                jnp.asarray(self._ntok),
-                jnp.asarray(self._budget),
-                jnp.asarray(~self.cache.active),  # retired slots: finished
-            ]
-            if self.paged:
-                args.append(jnp.asarray(self.cache.page_tables))
+            args = self._decode_args()
             name = f"serve/decode/spec{self.speculate}/k{k_steps}"
-            self._ensure_card(name, program, tuple(args))
+            self._ensure_card(name, program, args)
         with self._phase("decode"), self._watch(name):
             out = program(*args)
             kv, ys, cs = out[0], out[1], out[2]
@@ -2837,34 +2858,12 @@ class ServeEngine:
         with self._phase("decode_args"):
             running = self.scheduler.running
             program = self._spec_persistent_program()
-            toks = jnp.asarray(self._last_tok)
-            for slot, dev_tok in self._pending_first.items():
-                # freshly prefilled slots: splice the on-device first token
-                # into the loop's last-token row without a fetch (ARRAY-
-                # typed index: a python int would bake a per-slot scatter
-                # executable — see _persistent_step)
-                toks = toks.at[jnp.asarray(slot, jnp.int32)].set(dev_tok)
-            args = [
-                self.params,
-                self.cache.kv,
-                toks,
-                jnp.asarray(self.cache.positions()),
-                jnp.asarray(self._hist),
-                jnp.asarray(self._temps),
-                jnp.asarray(self._seeds),
-                jnp.asarray(self._ntok),
-                jnp.asarray(self._budget),
-                # room check from the UNCLAMPED host positions, exactly as
-                # in _persistent_step
-                jnp.asarray(self.cache.active & (self.cache.pos < self.max_len)),
-            ]
-            if self.paged:
-                args.append(jnp.asarray(self.cache.page_tables))
+            args = self._decode_args()
             name = (
                 f"serve/decode/persistent/spec{self.speculate}"
                 f"/r{self.ring_capacity}"
             )
-            self._ensure_card(name, program, tuple(args))
+            self._ensure_card(name, program, args)
         with self._phase("decode"), self._watch(name):
             out = program(*args)
             kv, ring, cnts, iters = out[0], out[1], out[2], out[3]
@@ -2878,7 +2877,7 @@ class ServeEngine:
             )
         with self._phase("harvest"):
             # (device handles: see _decode_step)
-            del args, toks, out, ring, cnts, iters
+            del args, out, ring, cnts, iters
             n_it = int(n_it)
             self._pending_first.clear()
             self.metrics.count("host_syncs")  # the drain IS the sync
