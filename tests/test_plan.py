@@ -398,42 +398,6 @@ class TestServeEnginePlan:
             eng.step()
         assert len(h.result().tokens) == 4
 
-    def test_tp_rule_is_a_deprecation_shim(self):
-        import warnings
-
-        from torchdistx_tpu.models import LlamaConfig
-        from torchdistx_tpu.parallel.tp import llama_tp_rule
-        from torchdistx_tpu.serve.engine import ServeEngine
-
-        from jax.sharding import Mesh
-
-        mesh = Mesh(np.array(jax.devices()[:2]).reshape(2), ("tp",))
-        cfg = LlamaConfig(
-            vocab_size=64, dim=32, n_layers=2, n_heads=4, n_kv_heads=2,
-            max_seq_len=64,
-        )
-        tdx.manual_seed(0)
-        model = Llama(cfg)
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            eng = ServeEngine(
-                model, num_slots=2, max_len=32, mesh=mesh,
-                tp_rule=llama_tp_rule(mesh),
-            )
-        assert any(
-            issubclass(x.category, DeprecationWarning) for x in w
-        )
-        assert eng.plan is None  # a bare rule cannot be lifted to a plan
-        with pytest.raises(ValueError, match="not both"):
-            ServeEngine(
-                model, num_slots=2, max_len=32, mesh=mesh,
-                plan=llama_tp_plan(mesh), tp_rule=llama_tp_rule(mesh),
-            )
-        with pytest.raises(ValueError, match="plan requires mesh"):
-            ServeEngine(
-                model, num_slots=2, max_len=32, plan=llama_tp_plan(mesh)
-            )
-
 
 class TestReshardToPlan:
     def test_transition_prices_then_books_identically(self, mesh8):

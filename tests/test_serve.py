@@ -1282,12 +1282,12 @@ class TestTPServing:
         bad = Mesh(np.asarray(jax.devices()[:2]), ("data",))
         with pytest.raises(ValueError, match="tp_axis"):
             ServeEngine(_llama_tp(), num_slots=1, max_len=32, mesh=bad)
-        from torchdistx_tpu.parallel.tp import llama_tp_rule
+        from torchdistx_tpu.parallel.tp import llama_tp_plan
 
         with pytest.raises(ValueError, match="requires mesh"):
             ServeEngine(
                 _llama_tp(), num_slots=1, max_len=32,
-                tp_rule=llama_tp_rule(_tp_mesh(2)),
+                plan=llama_tp_plan(_tp_mesh(2)),
             )
 
 

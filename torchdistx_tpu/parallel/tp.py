@@ -119,7 +119,7 @@ def shard_params(
     This is the post-hoc sibling of being *born* sharded via
     ``materialize_module(sharding_rule=...)`` — the serving path uses it
     because inference engines usually receive finished weights rather
-    than materialize them (``ServeEngine(mesh=, tp_rule=)``).
+    than materialize them (``ServeEngine(mesh=, plan=)``).
     """
     out = {}
     for path, leaf in params.items():

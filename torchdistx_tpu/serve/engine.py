@@ -57,6 +57,16 @@ prefill's are the padded prompt and NumPy scalars with their dtypes
 written out.  Every array handed over is fresh or a copy: the host
 mirrors are written again in ``serve/harvest``.
 
+**The step path is written once**, whatever the options: one
+``_decode_step`` (``serve/decode_args`` → ``serve/decode`` →
+``serve/harvest``), for which the four decode programs differ by a row
+of ``_DECODE_VARIANTS`` (the builder, the outputs fetched, how the fetch
+reads as tokens and counts per iteration and slot), and one
+``_dispatch_prefill`` (``serve/prefill`` per chunk), for which whole
+against chunked is the length of a chunk list and slab against paged,
+cold against warm, is ``_prefill_call``'s choice of program and
+arguments.  docs/serving.md has the table.
+
 Admitting or retiring a request changes only tiny dynamic inputs
 (positions, temperatures, budgets, a slot index), never a compiled
 shape — the jit cache stays at two programs (plus one per extra bucket
@@ -94,6 +104,7 @@ contiguous (``page_size=None``) engine (tests/test_serve.py).
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from collections import deque
 from typing import Any, Iterable, List, Optional, Sequence, Union
@@ -248,6 +259,47 @@ def _default_buckets(max_len: int) -> tuple:
     return tuple(buckets)
 
 
+# What differs between the four decode programs once ``_decode_args`` has
+# built their arguments, as data ``ServeEngine._decode_step`` reads: the
+# builder's name, how many outputs after the KV carry the host fetches,
+# and how that fetch reads as ``(tokens[iteration, slot, lane],
+# count[iteration, slot], iterations)`` — the one shape the walk knows.
+# A fused program ran every row of its block (``decode_chunk`` of them); a
+# persistent loop reports how many it ran, the drained ring's cursor.  A
+# step makes views only, nothing sized ``slots x lanes``: this runs after
+# every dispatch with the host's caches cold from the wait, where even one
+# ``np.ones`` of 16 cost 19 us against 1.5 us warm (PERF.md, PR 32).
+
+
+@functools.lru_cache(maxsize=None)
+def _ones(shape):
+    return np.ones(shape, np.int32)
+
+
+def _read_block(block):  # one token every iteration: count 1
+    return block[:, :, None], _ones(block.shape), len(block)
+
+
+def _read_ring(ring, valid, cursor):  # count: the valid bit
+    return ring[:, :, None], valid, int(cursor)
+
+
+def _read_spec_blocks(blocks, counts):
+    return blocks, counts, len(blocks)
+
+
+def _read_spec_ring(ring, counts, cursor):
+    return ring, counts, int(cursor)
+
+
+_DECODE_VARIANTS = {  # by (persistent, speculative)
+    (False, False): ("_decode_program", 1, _read_block),
+    (True, False): ("_persistent_program", 3, _read_ring),
+    (False, True): ("_spec_decode_program", 2, _read_spec_blocks),
+    (True, True): ("_spec_persistent_program", 3, _read_spec_ring),
+}
+
+
 class ServeEngine:
     """Continuous-batching serving engine over a slot-based KV cache.
 
@@ -373,10 +425,6 @@ class ServeEngine:
         ``Trainer`` / ``reshard_to_plan`` / fleet ``handoff_to`` would
         hold).  Default when ``mesh`` is given:
         ``parallel.tp.llama_tp_plan(mesh, tp_axis)``.
-      tp_rule: DEPRECATED — a bare parameter sharding rule ``(path,
-        leaf) -> NamedSharding``.  Kept as a shim (emits
-        ``DeprecationWarning``); pass ``plan=`` instead, which also
-        covers the KV pool, validation, and pricing.
       tp_axis: the mesh axis name to tensor-shard over (default
         ``"tp"``); other axes of the mesh are left replicated.
       chunked_prefill: prefill-chunk threshold in tokens.  A prompt (or
@@ -438,7 +486,6 @@ class ServeEngine:
         stall_timeout_s: Optional[float] = None,
         mesh: Optional[Any] = None,
         plan: Optional[Any] = None,
-        tp_rule: Optional[Any] = None,
         tp_axis: str = "tp",
         chunked_prefill: Optional[int] = None,
         speculate: int = 0,
@@ -500,43 +547,21 @@ class ServeEngine:
             self.tp = int(mesh.shape[self.tp_axis])
             from ..parallel.tp import llama_tp_plan, shard_params
 
-            if plan is not None and tp_rule is not None:
-                raise ValueError("pass plan= or tp_rule=, not both")
-            if tp_rule is not None:
-                # deprecation shim: a bare rule callable places params
-                # but cannot validate, price, or derive carry shardings
-                import warnings
-
-                warnings.warn(
-                    "ServeEngine(tp_rule=) is deprecated: pass the "
-                    "declarative plan instead — ServeEngine(plan="
-                    "llama_tp_plan(mesh, tp_axis)) or any ShardingPlan "
-                    "(parallel/plan.py)",
-                    DeprecationWarning,
-                    stacklevel=2,
+            if plan is None:
+                plan = llama_tp_plan(mesh, self.tp_axis)
+            if plan.mesh is not mesh and tuple(
+                plan.mesh.devices.flat
+            ) != tuple(mesh.devices.flat):
+                raise ValueError(
+                    "plan.mesh does not cover the engine mesh — build "
+                    "the plan on the serving mesh (plan.with_mesh)"
                 )
-                rule = tp_rule
-            else:
-                if plan is None:
-                    plan = llama_tp_plan(mesh, self.tp_axis)
-                if plan.mesh is not mesh and tuple(
-                    plan.mesh.devices.flat
-                ) != tuple(mesh.devices.flat):
-                    raise ValueError(
-                        "plan.mesh does not cover the engine mesh — build "
-                        "the plan on the serving mesh (plan.with_mesh)"
-                    )
-                rule = plan.as_rule()
-            self.params = shard_params(self.params, rule)
+            self.params = shard_params(self.params, plan.as_rule())
         else:
-            if tp_rule is not None:
-                raise ValueError("tp_rule requires mesh=")
             if plan is not None:
                 raise ValueError("plan requires mesh=")
             self.tp = 1
-            rule = None
         self.plan = plan
-        self._tp_rule = rule
         # closed-form comm accounting needs the block geometry; a model
         # whose config doesn't expose it serves fine, just unaudited
         _layers = getattr(cfg, "n_layers", None) or getattr(
@@ -2200,10 +2225,7 @@ class ServeEngine:
         return True
 
     def _prefill_request(self, req: Request, slot: int) -> None:
-        if self.paged:
-            tok = self._dispatch_prefill_paged(req, slot)
-        else:
-            tok = self._dispatch_prefill_slab(req, slot)
+        tok = self._dispatch_prefill(req, slot)
         self.cache.admit(slot, req.prompt.size)
         self._temps[slot] = req.temperature
         self._seeds[slot] = req.seed
@@ -2268,71 +2290,53 @@ class ServeEngine:
             np.asarray([req.seed], np.int32),
         )
 
-    def _dispatch_prefill_slab(self, req: Request, slot: int) -> int:
-        if (
-            self.chunked_prefill is not None
-            and req.prompt.size > self.chunked_prefill
-        ):
-            return self._dispatch_prefill_slab_chunked(req, slot)
-        bucket = self._bucket_for(req.prompt.size)
-        req.record_event("prefill", bucket=bucket, cold=True)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, : req.prompt.size] = req.prompt
-        program = self._prefill_program(bucket)
-        name = f"serve/prefill/b{bucket}"
-        args = (
-            self.params,
-            self.cache.kv,
-            padded,
-            np.int32(req.prompt.size),
-            np.int32(slot),
-            *self._sampling_args(req),
-        )
-        self._ensure_card(name, program, args)
-        with self._phase("prefill"), self._watch(name):
-            out = program(*args)
-            kv, tok = out[0], out[1]
-            # rebind BEFORE the host sync: the dispatch donated the old
-            # slab, so if the sync raises the engine must
-            # already hold the live output, not a deleted buffer
-            self.cache.kv = kv
-            if self.numerics:
-                self._pending_digests.append(out[-1])
-            if self._moe_counts:
-                self.metrics.add_device_counts("prefill", out[2])
-            if not self._persistent:  # persistent defers to the drain
-                tok = int(np.asarray(tok))  # host sync: first token exists
-        self.metrics.count("tokens_prefilled", bucket)
-        self._record_tp_collectives(bucket)
-        return tok
+    def _dispatch_prefill(self, req: Request, slot: int):
+        """The prefill of one admitted request, whole or in chunks, slab
+        or paged: one dispatch per chunk, written once.  Returns the
+        first token (a device scalar in persistent mode, which defers
+        the fetch to the drain).
 
-    def _dispatch_prefill_slab_chunked(self, req: Request, slot: int) -> int:
-        """Chunked SLAB prefill: the prompt lands in
-        ``chunked_prefill``-sized chunks — the first through the cold
-        (static ``cache_pos=0``) bucket program, the rest through the
-        warm slot-row family (``_prefill_warm_program``) — with one
-        decode dispatch interleaved between consecutive chunks
+        A paged engine consumes the admission gate's page reservation:
+        it points the slot's table at the chain, prefills ONLY the
+        uncached suffix (tokens past the page-aligned prefix hit — the
+        hit is the prefill compute, and tokens, the cache saved), and
+        adopts the request's full-prompt pages into the prefix index.
+
+        A prompt (slab) or suffix (paged) over ``chunked_prefill`` takes
+        the CHUNKED way: ``_prefill_chunks``' pieces, with one decode
+        dispatch interleaved between consecutive ones
         (``_interleave_decode``, skipping this half-prefilled request).
-
-        The slot is PARKED at row ``max_len - 1`` for the duration: the
-        interleaved decode program rewrites every slot's current row,
-        inactive slots included, and the slot's stale position could
-        land that garbage inside an already-written chunk.  Row
-        ``max_len - 1`` is safe: prefill never claims it (``prompt <=
-        max_len - max_new < max_len``), a slab row is private to its
-        slot, and the slot's own decode write replaces it in the same
-        dispatch that first makes it visible (the stale-row argument of
-        kv_cache.py, applied to one designated row).  ``cache.admit``
-        restores the true position after the final chunk."""
-        chunks = self._prefill_chunks(0, req.prompt.size)
-        req.record_event(
-            "prefill",
-            bucket=self._bucket_for(chunks[0][1]),
-            cold=True,
-            chunks=len(chunks),
+        Anything else is the chunk list of length one.  The slot is
+        PARKED at row ``max_len - 1`` for the duration: the interleaved
+        decode program rewrites every slot's current row, inactive slots
+        included, and the slot's stale position could land that garbage
+        inside an already-written chunk.  Row ``max_len - 1`` is safe:
+        prefill never claims it (``prompt <= max_len - max_new <
+        max_len``), and the slot's own decode write replaces it in the
+        same dispatch that first makes it visible (the stale-row
+        argument of kv_cache.py, applied to one designated row).  On the
+        slab the row is private to its slot; paged, the parked write
+        routes through the slot's table to its LAST entry — the scratch
+        page for a short chain, else the request's own tail page, never
+        a shared prefix page (the hit is at most the prompt, which sits
+        strictly below ``max_len``).  ``cache.admit`` restores the true
+        position after the final chunk."""
+        pfx = req.prefix_len if self.paged else 0
+        total = req.prompt.size - pfx
+        chunked = (
+            self.chunked_prefill is not None and total > self.chunked_prefill
         )
-        self.cache.pos[slot] = self.max_len - 1  # park (see docstring)
-        self.metrics.count("chunked_prefills")
+        # the tail fold may leave ONE chunk: still the chunked way
+        chunks = self._prefill_chunks(pfx, total) if chunked else [(pfx, total)]
+        fields = {"bucket": self._bucket_for(chunks[0][1]), "cold": pfx == 0}
+        if self.paged:
+            fields["prefix_hit_tokens"] = pfx
+            self.cache.set_table(slot, req.pages)
+        if chunked:
+            fields["chunks"] = len(chunks)
+            self.cache.pos[slot] = self.max_len - 1  # park (see docstring)
+            self.metrics.count("chunked_prefills")
+        req.record_event("prefill", **fields)
         tok = None
         for i, (start, ln) in enumerate(chunks):
             if i > 0:
@@ -2340,96 +2344,65 @@ class ServeEngine:
             bucket = self._bucket_for(ln)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :ln] = req.prompt[start : start + ln]
-            req.record_event("prefill_chunk", start=start, bucket=bucket)
-            if start == 0:
-                program = self._prefill_program(bucket)
-                name = f"serve/prefill/b{bucket}"
-                args = (
-                    self.params,
-                    self.cache.kv,
-                    padded,
-                    np.int32(ln),
-                    np.int32(slot),
-                    *self._sampling_args(req),
-                )
-            else:
-                program = self._prefill_warm_program(bucket)
-                name = f"serve/prefill/warm/b{bucket}"
-                args = (
-                    self.params,
-                    self.cache.kv,
-                    padded,
-                    np.int32(start),
-                    np.int32(ln),
-                    np.int32(slot),
-                    *self._sampling_args(req),
-                )
+            if chunked:
+                req.record_event("prefill_chunk", start=start, bucket=bucket)
+            program, name, args = self._prefill_call(
+                req, slot, start, ln, padded
+            )
             self._ensure_card(name, program, args)
             with self._phase("prefill"), self._watch(name):
                 out = program(*args)
-                kv, tok = out[0], out[1]
-                self.cache.kv = kv  # before any sync: slab was donated
+                # rebind BEFORE the host sync: the dispatch donated the
+                # old slab (or pools), so if the sync raises the engine
+                # must already hold the live output, not a deleted buffer
+                self.cache.kv, tok = out[0], out[1]
                 if self.numerics:
                     self._pending_digests.append(out[-1])
+                if self._moe_counts:
+                    # the cold slab program's; an expert model is served
+                    # through no other (the constructor's refusals)
+                    self.metrics.add_device_counts("prefill", out[2])
                 if i == len(chunks) - 1 and not self._persistent:
-                    tok = int(np.asarray(tok))  # host sync: first token
+                    tok = int(np.asarray(tok))  # host sync: first token exists
+            # only what was computed: a prefix hit's tokens are not
             self.metrics.count("tokens_prefilled", bucket)
-            self.metrics.count("prefill_chunks")
+            if chunked:
+                self.metrics.count("prefill_chunks")
             self._record_tp_collectives(bucket)
+        if self.paged:
+            self._adopt_prefix(req)
         return tok
 
-    def _dispatch_prefill_paged(self, req: Request, slot: int) -> int:
-        """Consume the admission gate's page reservation: point the
-        slot's table at the chain, prefill ONLY the uncached suffix
-        (tokens past the page-aligned prefix hit), and adopt the
-        request's full-prompt pages into the prefix index."""
-        if (
-            self.chunked_prefill is not None
-            and req.prompt.size - req.prefix_len > self.chunked_prefill
-        ):
-            return self._dispatch_prefill_paged_chunked(req, slot)
-        ps, pfx = self.page_size, req.prefix_len
-        suffix = req.prompt[pfx:]
-        bucket = self._bucket_for(suffix.size)
-        req.record_event(
-            "prefill", bucket=bucket, cold=pfx == 0, prefix_hit_tokens=pfx
-        )
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, : suffix.size] = suffix
-        self.cache.set_table(slot, req.pages)
-        program = self._paged_prefill_program(bucket, warm=pfx > 0)
-        args = [
-            self.params,
-            self.cache.kv,
-            self.cache.page_tables[slot].copy(),
-            padded,
-        ]
-        if pfx > 0:
-            args.append(np.int32(pfx))
-        args += [np.int32(suffix.size), *self._sampling_args(req)]
-        name = "serve/prefill/{}/b{}".format(
-            "warm" if pfx > 0 else "cold", bucket
-        )
-        self._ensure_card(name, program, tuple(args))
-        with self._phase("prefill"), self._watch(name):
-            out = program(*args)
-            kv, tok = out[0], out[1]
-            self.cache.kv = kv  # before the sync: the pools were donated
-            if self.numerics:
-                self._pending_digests.append(out[-1])
-            if not self._persistent:  # persistent defers to the drain
-                tok = int(np.asarray(tok))
-        # only the suffix bucket was computed — the prefix hit is the
-        # prefill compute (and token) the cache saved
-        self.metrics.count("tokens_prefilled", bucket)
-        self._record_tp_collectives(bucket)
-        self._adopt_prefix(req)
-        return tok
+    def _prefill_call(
+        self, req: Request, slot: int, start: int, ln: int, padded
+    ) -> tuple:
+        """``(program, card name, args)`` for one prefill chunk: ``ln``
+        prompt tokens, bucket-padded in ``padded``, landing at cache
+        position ``start``.  COLD (``start == 0``, static in the program,
+        so ``cached_attention``'s flash fast path applies) or WARM (a
+        traced position: a later chunk, or a paged prefix hit, which is
+        why chunked and prefix-hit prefill share programs), slab (the
+        slot is an argument) or paged (its table row is)."""
+        bucket = padded.shape[1]
+        warm = start > 0
+        at = (np.int32(start),) if warm else ()
+        if self.paged:
+            program = self._paged_prefill_program(bucket, warm=warm)
+            name = f"serve/prefill/{'warm' if warm else 'cold'}/b{bucket}"
+            row = self.cache.page_tables[slot].copy()
+            where = (row, padded, *at, np.int32(ln))
+        else:
+            build = self._prefill_warm_program if warm else self._prefill_program
+            program = build(bucket)
+            name = f"serve/prefill/{'warm/' if warm else ''}b{bucket}"
+            where = (padded, *at, np.int32(ln), np.int32(slot))
+        args = (self.params, self.cache.kv, *where, *self._sampling_args(req))
+        return program, name, args
 
     def _adopt_prefix(self, req: Request) -> None:
-        """Post-prefill prefix bookkeeping shared by the one-shot and
-        chunked paged paths: hit-rate counters + handing the request's
-        full-prompt page-aligned pages to the radix index."""
+        """A paged prefill's prefix bookkeeping: hit-rate counters +
+        handing the request's full-prompt page-aligned pages to the
+        radix index."""
         if self.prefix_index is None:
             return
         ps = self.page_size
@@ -2439,70 +2412,6 @@ class ServeEngine:
         self.prefix_index.insert(
             req.prompt[: n_full * ps], req.pages[:n_full], self.pool
         )
-
-    def _dispatch_prefill_paged_chunked(self, req: Request, slot: int) -> int:
-        """Chunked PAGED prefill: the uncached suffix lands in chunks
-        through the EXISTING cold/warm paged program families — the warm
-        family's traced ``pfx_len`` is exactly a chunk's start position,
-        so chunked prefill and prefix-hit prefill share programs — with
-        decode dispatches interleaved like the slab path.
-
-        Parking at ``max_len - 1`` is safe here too: the parked write
-        routes through the slot's table to its LAST entry — the scratch
-        page for a short chain, else the request's own tail page, never
-        a shared prefix page (the prefix is at most the prompt, which
-        sits strictly below ``max_len``, so the hit can never reach the
-        last table entry) — and the slot's own decode write replaces the
-        row in the dispatch that first makes it visible."""
-        ps, pfx = self.page_size, req.prefix_len
-        suffix = req.prompt[pfx:]
-        chunks = self._prefill_chunks(pfx, suffix.size)
-        req.record_event(
-            "prefill",
-            bucket=self._bucket_for(chunks[0][1]),
-            cold=pfx == 0,
-            prefix_hit_tokens=pfx,
-            chunks=len(chunks),
-        )
-        self.cache.set_table(slot, req.pages)
-        self.cache.pos[slot] = self.max_len - 1  # park (slab docstring)
-        self.metrics.count("chunked_prefills")
-        tok = None
-        for i, (start, ln) in enumerate(chunks):
-            if i > 0:
-                self._interleave_decode(req)
-            bucket = self._bucket_for(ln)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :ln] = req.prompt[start : start + ln]
-            req.record_event("prefill_chunk", start=start, bucket=bucket)
-            warm = start > 0
-            program = self._paged_prefill_program(bucket, warm=warm)
-            args = [
-                self.params,
-                self.cache.kv,
-                self.cache.page_tables[slot].copy(),
-                padded,
-            ]
-            if warm:
-                args.append(np.int32(start))
-            args += [np.int32(ln), *self._sampling_args(req)]
-            name = "serve/prefill/{}/b{}".format(
-                "warm" if warm else "cold", bucket
-            )
-            self._ensure_card(name, program, tuple(args))
-            with self._phase("prefill"), self._watch(name):
-                out = program(*args)
-                kv, tok = out[0], out[1]
-                self.cache.kv = kv  # before any sync: pools were donated
-                if self.numerics:
-                    self._pending_digests.append(out[-1])
-                if i == len(chunks) - 1 and not self._persistent:
-                    tok = int(np.asarray(tok))
-            self.metrics.count("tokens_prefilled", bucket)
-            self.metrics.count("prefill_chunks")
-            self._record_tp_collectives(bucket)
-        self._adopt_prefix(req)
-        return tok
 
     def _decode_args(self) -> tuple:
         """The argument list of a decode dispatch, written once for the
@@ -2564,126 +2473,79 @@ class ServeEngine:
         return tuple(args)
 
     def _decode_step(self, skip: Optional[Request] = None) -> None:
-        """One fused decode dispatch: ``K = decode_chunk`` on-device
-        steps, ONE host sync for the whole ``(K, num_slots)`` token
-        block.  The host then walks each running request's column with
-        the same finish rules the device mask applied
-        (``_check_finished``), so the host's bookkeeping (positions,
-        token counts, finish reasons, metrics) and the device's frozen
-        carries agree step for step; tokens a request emitted after its
-        own finish never exist on the host side, and the slot-steps the
-        device masked out are accounted in ``masked_slot_steps``."""
-        if self._persistent:
-            if self.speculate:
-                return self._spec_persistent_step(skip)
-            return self._persistent_step(skip)
-        if self.speculate:
-            return self._spec_decode_step(skip)
+        """One decode dispatch, ONE host sync, one walk — whichever of
+        the four decode programs this engine runs (``_DECODE_VARIANTS``:
+        fused or persistent, each one-token or speculative).
+
+        The fused programs run ``decode_chunk`` on-device iterations;
+        the persistent ones loop on-device until every slot's finish bit
+        sets or the ring fills, and the pending prefill first-tokens ride
+        the drain's sync.  Either way the host gets tokens per
+        ``(iteration, slot, lane)`` and a count per ``(iteration,
+        slot)``: the count is 0 exactly from where the device froze the
+        slot (rows past it are rewrites), and a speculative iteration
+        emits up to ``speculate + 1`` tokens.  The walk applies the same
+        finish rules the device's mask did (``_check_finished``), so the
+        host's bookkeeping (positions, token counts, finish reasons,
+        metrics) and the device's frozen carries agree iteration for
+        iteration, token for token; tokens a request emitted after its
+        own finish never exist on the host side, and the slot-iterations
+        the device ran past a finish are accounted in
+        ``masked_slot_steps``.  A request the ring cut off (budget-bound
+        exit) simply stays running and continues from its frozen carry
+        at the next dispatch — spanning drains is the persistent analog
+        of spanning chunks.  Speculation multiplies tokens per sync, it
+        never adds one: ``host_syncs == ring_drains`` either way."""
+        persistent, spec = self._persistent, self.speculate
         with self._phase("decode_args"):
             running = self.scheduler.running
-            k_steps = self.decode_chunk
-            program = self._decode_program()
+            builder, n_out, read = _DECODE_VARIANTS[persistent, bool(spec)]
+            program = getattr(self, builder)()
             args = self._decode_args()
-            name = f"serve/decode/k{k_steps}"
+            self._stream_events.clear()  # the streamed tail's, if any
+            name = "serve/decode{}{}/{}".format(
+                "/persistent" if persistent else "",
+                f"/spec{spec}" if spec else "",
+                f"r{self.ring_capacity}" if persistent
+                else f"k{self.decode_chunk}",
+            )
             self._ensure_card(name, program, args)
         with self._phase("decode"), self._watch(name):
             out = program(*args)
-            kv, block = out[0], out[1]
-            self.cache.kv = kv  # before the sync: old slab was donated
+            self.cache.kv = out[0]  # before the sync: old slab was donated
             if self.numerics:
                 self._pending_digests.append(out[-1])
             if self._moe_counts:
-                self.metrics.add_device_counts("decode", out[2])
-            block = np.asarray(block)  # ONE host sync per K slot-steps
+                # after the fetched outputs; the fused one-token program's
+                # alone (the constructor's refusals)
+                self.metrics.add_device_counts("decode", out[1 + n_out])
+            # ONE host sync per dispatch: the program's token outputs and
+            # every pending first token together.  The first read waits
+            # for the program; the other copies are in flight behind that
+            # wait (``device_get`` does the same under a tree walk that
+            # costs the one-output programs 16 us more than this)
+            pending = self._pending_first
+            leaves = (*out[1 : 1 + n_out], *pending.values())
+            for i in range(1, len(leaves)):
+                leaves[i].copy_to_host_async()
+            host = [np.asarray(x) for x in leaves]
+            fetched, firsts = host[:n_out], dict(zip(pending, host[n_out:]))
         with self._phase("harvest"):
             # drop this dispatch's device handles here, inside the phase:
             # left to the frame's teardown they are freed after it, in
             # nobody's span (the outputs; the arguments are host arrays)
-            del args, out
+            del args, out, leaves
+            tokens, count, n_it = read(*fetched)
+            self._pending_first.clear()
             self.metrics.count("host_syncs")
             self._harvest_numerics()
             self.metrics.count("decode_dispatches")
-            self.metrics.count("decode_steps", k_steps)
-            self._record_tp_collectives(self.num_slots, k_steps)
-            now = time.monotonic()
-            emitted = 0
-            for req in running:
-                if req is skip or not self.cache.active[req.slot]:
-                    # not yet cache-admitted: the mid-chunked-prefill request
-                    # itself (parked, device-frozen) or a same-batch admit an
-                    # interleaved dispatch ran ahead of — their tokens start
-                    # at their own prefill, not here
-                    continue
-                slot = req.slot
-                took = 0
-                for j in range(k_steps):
-                    tok = int(block[j, slot])
-                    self._ntok[slot] += 1
-                    self.cache.advance_slot(slot)
-                    self._last_tok[slot] = tok
-                    req.generated.append(tok)
-                    emitted += 1
-                    took = j + 1
-                    if self._check_finished(req, tok, now):
-                        # the device froze this slot for the rest of the
-                        # chunk; those slot-steps bought nothing
-                        self.metrics.count("masked_slot_steps", k_steps - 1 - j)
-                        break
-                ev = ("decode_chunk", now, {"tokens": took})
-                if req.events and req.events[-1][0] == "finish":
-                    # _check_finished logged the finish inside the loop; keep
-                    # the lifecycle log in causal order (chunk, then finish)
-                    req.events.insert(-1, ev)
-                else:
-                    req.events.append(ev)
-            self.metrics.count("tokens_generated", emitted)
-            self.metrics.count("tokens_decoded", emitted)
-            self._record_drain()
-            self._observe_gauges()
-
-    def _persistent_step(self, skip: Optional[Request] = None) -> None:
-        """One persistent-loop dispatch: the while_loop runs on-device
-        until every slot's finish bit sets or the ring fills, then the
-        host drains the ring — ONE sync for the whole wave, the pending
-        prefill first-tokens riding along.  The drained walk applies the
-        exact ``_check_finished`` rules the device's finish mask did
-        (the valid mask bounds the walk: True exactly on the rows a live
-        slot sampled, the finishing token included), so host bookkeeping
-        and device carries agree iteration for iteration.  A request the
-        ring cut off (budget-bound exit) simply stays running and
-        continues from its frozen carry at the next dispatch — spanning
-        drains is the persistent analog of spanning chunks."""
-        with self._phase("decode_args"):
-            running = self.scheduler.running
-            program = self._persistent_program()
-            args = self._decode_args()
-            self._stream_events.clear()
-            name = f"serve/decode/persistent/r{self.ring_capacity}"
-            self._ensure_card(name, program, args)
-        with self._phase("decode"), self._watch(name):
-            out = program(*args)
-            kv, ring, valid, iters = out[0], out[1], out[2], out[3]
-            self.cache.kv = kv  # before the sync: old slab was donated
-            if self.numerics:
-                self._pending_digests.append(out[-1])
-            # ONE host sync drains the ring, the valid mask, the cursor,
-            # and every pending first token together
-            block, vmask, n_it, firsts = jax.device_get(
-                (ring, valid, iters, dict(self._pending_first))
-            )
-        with self._phase("harvest"):
-            # (device handles: see _decode_step)
-            del args, out, ring, valid, iters
-            n_it = int(n_it)
-            self._pending_first.clear()
-            self.metrics.count("host_syncs")  # the drain IS the sync
-            self._harvest_numerics()
-            self.metrics.count("ring_drains")
-            self.metrics.count("decode_dispatches")
             self.metrics.count("decode_steps", n_it)
-            self.metrics.count("loop_iterations", n_it)
-            self._record_tp_collectives(self.num_slots, n_it)
-            self.metrics.observe_ring(n_it)
+            self._record_tp_collectives(self.num_slots * (spec + 1), n_it)
+            if persistent:
+                self.metrics.count("ring_drains")
+                self.metrics.count("loop_iterations", n_it)
+                self.metrics.observe_ring(n_it)
             now = time.monotonic()
             # streamed tail (opt-in): the iteration-0 callback timestamp is
             # when the wave's first tokens actually existed host-side —
@@ -2694,8 +2556,14 @@ class ServeEngine:
             emitted = 0
             any_cut = False
             for req in running:
-                if req is skip:
-                    # mid-chunked-prefill request: parked, device-frozen
+                if req is skip or not (
+                    persistent or self.cache.active[req.slot]
+                ):
+                    # not yet cache-admitted: the mid-chunked-prefill request
+                    # itself (parked, device-frozen) or, fused, a same-batch
+                    # admit an interleaved dispatch ran ahead of — their
+                    # tokens start at their own prefill, not here (a
+                    # persistent one's ride this drain: ``firsts``)
                     continue
                 slot = req.slot
                 taken = 0
@@ -2703,234 +2571,68 @@ class ServeEngine:
                 if slot in firsts:
                     tok = int(firsts[slot])
                     self._record_first(req, tok, first_ts)
-                    if self._check_finished(req, tok, first_ts):
-                        # the device's fin0 froze this slot before iteration
-                        # 0 (EOS first token / one-token budget): it idled
-                        # the whole loop
-                        finished = True
+                    # finished here, the device's fin0 froze this slot
+                    # before iteration 0 (EOS first token / one-token
+                    # budget): it idled the whole loop
+                    finished = self._check_finished(req, tok, first_ts)
                 if not finished:
                     for j in range(n_it):
-                        if not vmask[j, slot]:
-                            break  # frozen from here on: rows are rewrites
-                        tok = int(block[j, slot])
-                        self._ntok[slot] += 1
-                        self.cache.advance_slot(slot)
-                        self._last_tok[slot] = tok
-                        req.generated.append(tok)
-                        emitted += 1
-                        taken = j + 1
-                        if self._check_finished(req, tok, now):
-                            finished = True
-                            break
-                if finished:
-                    # iterations the loop kept running past this slot's
-                    # finish — the persistent analog of mid-chunk waste
-                    self.metrics.count("masked_slot_steps", n_it - taken)
-                else:
-                    any_cut = True  # ring filled before this request's end
-                ev = ("decode_chunk", now, {"tokens": taken})
-                if req.events and req.events[-1][0] == "finish":
-                    # keep the lifecycle log causal (chunk, then finish)
-                    req.events.insert(-1, ev)
-                else:
-                    req.events.append(ev)
-            if any_cut:
-                self.metrics.count("ring_full_drains")
-            self.metrics.count("tokens_generated", emitted)
-            self.metrics.count("tokens_decoded", emitted)
-            self._record_drain()
-            self._observe_gauges()
-
-    def _consume_spec_block(
-        self, req: Request, ys_row, c: int, now: float
-    ) -> tuple:
-        """Consume ONE verified block (``c`` accepted tokens of a
-        ``(speculate + 1,)`` row) for one request: the same per-token
-        bookkeeping as the one-token walks, plus the draft-economy
-        counters and the history mirror.  The device truncation rule
-        guarantees any finish condition lands exactly on the block's
-        LAST emitted token (``generation._make_spec_decode_body``), so
-        the walk and the device's frozen carry agree token for token.
-        Returns ``(emitted, finished)``."""
-        K = self.speculate
-        # per live slot-iteration: K lanes drafted, c - 1 of them
-        # accepted, the rest of the K + 1 verify lanes spent on
-        # rejected (overwritten-before-visible) positions
-        self.metrics.count("draft_tokens_proposed", K)
-        self.metrics.count("draft_tokens_accepted", c - 1)
-        self.metrics.count("spec_rejected_lane_steps", (K + 1) - c)
-        slot = req.slot
-        emitted = 0
-        finished = False
-        for i in range(c):
-            tok = int(ys_row[i])
-            self._ntok[slot] += 1
-            self.cache.advance_slot(slot)
-            self._last_tok[slot] = tok
-            # post-advance, the slot's position IS the token's stream
-            # index — append it to the draft history at that row
-            p = int(self.cache.pos[slot])
-            if p < self.max_len:
-                self._hist[slot, p] = tok
-            req.generated.append(tok)
-            emitted += 1
-            if self._check_finished(req, tok, now):
-                finished = True
-                break
-        return emitted, finished
-
-    def _spec_decode_step(self, skip: Optional[Request] = None) -> None:
-        """The speculative sibling of ``_decode_step``: each of the
-        ``decode_chunk`` on-device iterations drafts, verifies and
-        accepts up to ``speculate + 1`` tokens per slot, still with ONE
-        host sync for the whole dispatch.  The walk consumes a VARIABLE
-        number of tokens per iteration per slot — ``cs[j, slot]`` is the
-        device's emitted count (0 exactly where the old valid/finished
-        mask was False), so host bookkeeping and device carries agree
-        iteration for iteration, token for token."""
-        with self._phase("decode_args"):
-            running = self.scheduler.running
-            k_steps = self.decode_chunk
-            program = self._spec_decode_program()
-            args = self._decode_args()
-            name = f"serve/decode/spec{self.speculate}/k{k_steps}"
-            self._ensure_card(name, program, args)
-        with self._phase("decode"), self._watch(name):
-            out = program(*args)
-            kv, ys, cs = out[0], out[1], out[2]
-            self.cache.kv = kv  # before the sync: old slab was donated
-            if self.numerics:
-                self._pending_digests.append(out[-1])
-            # ONE host sync for the blocks and the counts together
-            ys, cs = jax.device_get((ys, cs))
-        with self._phase("harvest"):
-            # (device handles: see _decode_step)
-            del args, out
-            self.metrics.count("host_syncs")
-            self._harvest_numerics()
-            self.metrics.count("decode_dispatches")
-            self.metrics.count("decode_steps", k_steps)
-            self._record_tp_collectives(
-                self.num_slots * (self.speculate + 1), k_steps
-            )
-            now = time.monotonic()
-            emitted = 0
-            for req in running:
-                if req is skip or not self.cache.active[req.slot]:
-                    # not yet cache-admitted (mid-chunked-prefill / a
-                    # same-batch admit an interleaved dispatch ran ahead of)
-                    continue
-                slot = req.slot
-                took = 0
-                for j in range(k_steps):
-                    c = int(cs[j, slot])
-                    if c == 0:
-                        break  # frozen from here on
-                    n, finished = self._consume_spec_block(
-                        req, ys[j, slot], c, now
-                    )
-                    emitted += n
-                    took = j + 1
-                    if finished:
-                        # the device froze this slot for the rest of the
-                        # chunk; those iterations bought nothing
-                        self.metrics.count("masked_slot_steps", k_steps - 1 - j)
-                        break
-                ev = ("decode_chunk", now, {"tokens": took})
-                if req.events and req.events[-1][0] == "finish":
-                    # keep the lifecycle log causal (chunk, then finish)
-                    req.events.insert(-1, ev)
-                else:
-                    req.events.append(ev)
-            self.metrics.count("tokens_generated", emitted)
-            self.metrics.count("tokens_decoded", emitted)
-            self._record_drain()
-            self._observe_gauges()
-
-    def _spec_persistent_step(self, skip: Optional[Request] = None) -> None:
-        """The speculative sibling of ``_persistent_step``: one
-        while-loop dispatch, one drain.  The ring holds one verified
-        block per ITERATION (up to ``speculate + 1`` tokens each) and
-        the count ring subsumes the old valid mask (``cnts[j, b] > 0``
-        exactly where it was True), so ``host_syncs == ring_drains``
-        exactly as before — speculation multiplies tokens per sync, it
-        never adds one."""
-        with self._phase("decode_args"):
-            running = self.scheduler.running
-            program = self._spec_persistent_program()
-            args = self._decode_args()
-            name = (
-                f"serve/decode/persistent/spec{self.speculate}"
-                f"/r{self.ring_capacity}"
-            )
-            self._ensure_card(name, program, args)
-        with self._phase("decode"), self._watch(name):
-            out = program(*args)
-            kv, ring, cnts, iters = out[0], out[1], out[2], out[3]
-            self.cache.kv = kv  # before the sync: old slab was donated
-            if self.numerics:
-                self._pending_digests.append(out[-1])
-            # ONE host sync drains the block ring, the count ring, the
-            # cursor, and every pending first token together
-            block, cmat, n_it, firsts = jax.device_get(
-                (ring, cnts, iters, dict(self._pending_first))
-            )
-        with self._phase("harvest"):
-            # (device handles: see _decode_step)
-            del args, out, ring, cnts, iters
-            n_it = int(n_it)
-            self._pending_first.clear()
-            self.metrics.count("host_syncs")  # the drain IS the sync
-            self._harvest_numerics()
-            self.metrics.count("ring_drains")
-            self.metrics.count("decode_dispatches")
-            self.metrics.count("decode_steps", n_it)
-            self.metrics.count("loop_iterations", n_it)
-            self._record_tp_collectives(
-                self.num_slots * (self.speculate + 1), n_it
-            )
-            self.metrics.observe_ring(n_it)
-            now = time.monotonic()
-            emitted = 0
-            any_cut = False
-            for req in running:
-                if req is skip:
-                    # mid-chunked-prefill request: parked, device-frozen
-                    continue
-                slot = req.slot
-                taken = 0
-                finished = False
-                if slot in firsts:
-                    tok = int(firsts[slot])
-                    self._record_first(req, tok, now)
-                    if self._check_finished(req, tok, now):
-                        # fin0 froze this slot before iteration 0
-                        finished = True
-                if not finished:
-                    for j in range(n_it):
-                        c = int(cmat[j, slot])
+                        c = count.item(j, slot)
                         if c == 0:
                             break  # frozen from here on: rows are rewrites
-                        n, finished = self._consume_spec_block(
-                            req, block[j, slot], c, now
-                        )
-                        emitted += n
                         taken = j + 1
+                        if spec:
+                            # per live slot-iteration: spec lanes drafted,
+                            # c - 1 of them accepted, the rest of the
+                            # spec + 1 verify lanes spent on rejected
+                            # (overwritten-before-visible) positions
+                            self.metrics.count("draft_tokens_proposed", spec)
+                            self.metrics.count("draft_tokens_accepted", c - 1)
+                            self.metrics.count(
+                                "spec_rejected_lane_steps", (spec + 1) - c
+                            )
+                        # the iteration's block: one lane for the one-token
+                        # programs, the c accepted lanes of a speculative
+                        # one.  The device truncation rule puts any finish
+                        # on the block's LAST emitted token
+                        # (``generation._make_spec_decode_body``), so walk
+                        # and frozen carry agree token for token.  Scalars
+                        # are read straight out of the fetched arrays: this
+                        # runs per slot and step (``serve.harvest_ms_p50``)
+                        for i in range(c):
+                            tok = tokens.item(j, slot, i)
+                            self._ntok[slot] += 1
+                            self.cache.advance_slot(slot)
+                            self._last_tok[slot] = tok
+                            if spec:
+                                # post-advance, the slot's position IS the
+                                # token's stream index: the draft history's
+                                # row for it
+                                p = int(self.cache.pos[slot])
+                                if p < self.max_len:
+                                    self._hist[slot, p] = tok
+                            req.generated.append(tok)
+                            emitted += 1
+                            if self._check_finished(req, tok, now):
+                                finished = True
+                                break
                         if finished:
                             break
                 if finished:
-                    # iterations the loop kept running past this slot's
-                    # finish — the persistent analog of mid-chunk waste
+                    # the device froze this slot for the rest of the chunk
+                    # (or the loop ran on past it): those slot-iterations
+                    # bought nothing
                     self.metrics.count("masked_slot_steps", n_it - taken)
                 else:
-                    any_cut = True  # ring filled before this request's end
+                    any_cut = True  # this dispatch ended before the request
                 ev = ("decode_chunk", now, {"tokens": taken})
                 if req.events and req.events[-1][0] == "finish":
-                    # keep the lifecycle log causal (chunk, then finish)
+                    # _check_finished logged the finish inside the loop; keep
+                    # the lifecycle log in causal order (chunk, then finish)
                     req.events.insert(-1, ev)
                 else:
                     req.events.append(ev)
-            if any_cut:
+            if persistent and any_cut:
                 self.metrics.count("ring_full_drains")
             self.metrics.count("tokens_generated", emitted)
             self.metrics.count("tokens_decoded", emitted)
