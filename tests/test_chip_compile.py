@@ -10,8 +10,8 @@ executable.  Nothing runs; what is pinned is acceptance (and that the
 program holds the Mosaic call), not results — those are the interpret
 tests' and ``chip_smoke.py``'s.
 
-Widths: head_dim 128; Hkv 32 (llama2_7b) and 8 (mistral_7b, llama3_8b);
-slab 2048-4096; page 16; vocab 32000 and GPT-2's 50257; the DeepSeek-V3
+Widths: head_dim 128; Hkv 32 (llama2_7b), 8 (mistral_7b, llama3_8b) and
+16 of 16 heads (deepseek-coder-1.3b); slab 2048-4096; page 16; vocab 32000 and GPT-2's 50257; the DeepSeek-V3
 family at kanana-2-30b-a3b's: a 576-lane latent row (stored on 640) in 32
 slots of 8192, 32 heads at qk 192 / v 128, 128 experts of 2048 x 768.
 
@@ -32,9 +32,11 @@ cache is off around these compiles: an entry written for a described
 device cannot be read back without one, and warns on every later run.
 """
 
+import base64
 import math
 import os
 import re
+import struct
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +94,19 @@ def _kernel_names(text):
     return names
 
 
+def _has_grid(text, grid):
+    """Every Mosaic kernel of the executable iterates over ``grid``.  A
+    kernel travels in the custom call's ``backend_config`` as MLIR
+    bytecode, base64; its ``iteration_bounds`` is a dense i64 array
+    attribute, which the bytecode holds as its little-endian words."""
+    bodies = [
+        base64.b64decode(b) for b in re.findall(r'"body":"([^"]+)"', text)
+    ]
+    assert bodies, "no serialized Mosaic kernel in the program"
+    want = struct.pack(f"<{len(grid)}q", *grid)
+    return all(want in body for body in bodies)
+
+
 # The kernels' names are fixed by the program (``name=`` on each
 # ``pl.pallas_call``) and the benchmark's trace readers find kernels by
 # three rules on them, stated here and not imported, and checked on the
@@ -143,7 +158,7 @@ def _decode_case(family, quantized, hq, hkv):
 
 
 DECODE_CASES = [
-    (family, quantized, 32, hkv)
+    (family, quantized, hq, hkv)
     for family, quantized in [
         ("decode_attention", False),
         ("decode_attention_block", False),
@@ -152,15 +167,15 @@ DECODE_CASES = [
         ("decode_attention", True),
         ("paged_decode_attention", True),
     ]
-    for hkv in (32, 8)
+    for hq, hkv in ((32, 32), (32, 8), (16, 16))
 ]
 
 
 @pytest.mark.parametrize(
     "family,quantized,hq,hkv", DECODE_CASES,
     ids=[
-        f"{f}-{'int8' if q else 'bf16'}-hkv{hkv}"
-        for f, q, _, hkv in DECODE_CASES
+        f"{f}-{'int8' if q else 'bf16'}-hkv{hkv}" + ("" if hq == 32 else f"of{hq}")
+        for f, q, hq, hkv in DECODE_CASES
     ],
 )
 def test_decode_attention_compiles(one_chip, family, quantized, hq, hkv):
@@ -169,6 +184,14 @@ def test_decode_attention_compiles(one_chip, family, quantized, hq, hkv):
     names = _kernel_names(text)
     assert names == [DECODE_NAMES[family]]
     assert "flash_forward" not in names[0] and "decode" in names[0]  # rule 3
+    # one grid step reads its rows for every KV head (PR 33)
+    s = 4 if family.endswith("block") else 1
+    g, block_k = da._blocking(
+        hkv, D, 1 if quantized else 2, SLOTS_L, -(-s * (hq // hkv) // 8) * 8,
+        *((PS, PS) if family.startswith("paged") else (512,)),
+    )
+    assert g == hkv
+    assert _has_grid(text, (B, 1, SLOTS_L // block_k))
 
 
 # One layer's decode write + attend at Mistral-7B widths (the serve
@@ -244,18 +267,21 @@ def _relayouts_of_the_cache(text, n):
 
 
 @pytest.mark.parametrize(
-    "paged,quantized",
-    [(False, False), (False, True), (True, False)],
+    "paged,quantized,steps",
+    [(False, False, 8), (False, True, 4), (True, False, M_L // PS)],
     ids=["slab-bf16", "slab-int8", "paged16-bf16"],
 )
 def test_decode_step_leaves_the_cache_in_place(
-    one_chip, monkeypatch, paged, quantized
+    one_chip, monkeypatch, paged, quantized, steps
 ):
     """The cache reaches the kernel as it is stored: besides parameters
     and tuple plumbing, only the in-place row write (and the fusion
     around it) has a result of the cache's size — no ``reshape``,
     ``copy``, ``transpose`` or other fusion.  On the ``(…, Hkv, D)``
-    storage this failed with two ``reshape`` instructions a layer."""
+    storage this failed with two ``reshape`` instructions a layer.
+    The kernel's grid is ``steps`` row blocks a slot for all eight KV
+    heads at once: 128 grid steps a call on the bf16 slab, where a grid
+    step a head and 512 rows a block made 512 (PERF.md §6 PR 33)."""
     (chip,) = one_chip.device_set
     # ``interpret=None`` and ``use_flash`` ask jax.devices()[0].platform
     monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
@@ -285,6 +311,7 @@ def test_decode_step_leaves_the_cache_in_place(
     assert _kernel_names(text) == [
         "tdx_paged_decode_attention" if paged else "tdx_decode_attention"
     ]
+    assert _has_grid(text, (M_B, 1, steps))
     offenders = _relayouts_of_the_cache(text, math.prod(lead) * M_HKV * D)
     assert not offenders, offenders
 
@@ -332,6 +359,7 @@ def test_serve_decode_program_compiles_from_the_packed_state(
     monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
     text = engine._decode_program().lower(params, kv, state).compile().as_text()
     assert _kernel_names(text) == ["tdx_decode_attention"] * layers
+    assert _has_grid(text, (M_B, 1, 8))  # 128 grid steps a layer
     offenders = _relayouts_of_the_cache(text, M_B * M_L * M_HKV * D)
     assert not offenders, offenders
 

@@ -20,10 +20,19 @@ import jax.numpy as jnp
 
 from torchdistx_tpu.ops.attention import slot_cached_attention
 from torchdistx_tpu.ops.decode_attention import (
+    _LANES,
+    _MIN_BLOCK_K,
+    _VMEM_BUDGET,
+    _blocking,
+    _vmem_bytes,
     decode_attention,
     paged_decode_attention,
 )
-from torchdistx_tpu.serve.kv_cache import merge_heads, split_heads
+from torchdistx_tpu.serve.kv_cache import (
+    merge_heads,
+    quantize_kv,
+    split_heads,
+)
 
 _ULP = 3e-7  # ~2 f32 ulps at unit scale
 
@@ -90,11 +99,12 @@ class TestKernelMatchesReference:
     @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
     @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
     def test_head_per_lane_block_layout_matches(self, paged, quantized):
-        """head_dim 128 — the real width — takes the OTHER block layout:
-        one KV head per 128-lane block of the stored (Hkv * D) tail,
-        picked by the grid (every smaller-D case above spans the whole
-        tail and walks heads in-kernel).  Multi-block rows, GQA, and the
-        int8 one-hot scale-column select, against the jnp path."""
+        """head_dim 128 — the real width, where a KV head is a whole
+        128-lane tile of the stored (Hkv * D) tail and the kernel's
+        static lane slices are tile-aligned (until PR 33 the grid picked
+        one head's lane block a step; TestFoldedGrid has the wider
+        cases).  Multi-block rows, GQA, and the int8 one-hot
+        scale-column select, against the jnp path."""
         from torchdistx_tpu.serve.kv_cache import dequantize_kv, quantize_kv
 
         rs = np.random.RandomState(128 + 2 * paged + quantized)
@@ -465,3 +475,155 @@ class TestKernelSweep:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=_ULP, atol=_ULP
         )
+
+
+def _grids(fn, *args):
+    """The grid of every ``pallas_call`` that tracing ``fn`` records."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+# (id, Hq, Hkv, S, int8, page size or None); head_dim 128 throughout
+_FOLDED = [
+    ("gqa-8x4", 32, 8, 1, False, None),
+    ("mha-16", 16, 16, 1, False, None),
+    ("verify-s4", 8, 2, 4, False, None),
+    ("verify-s4-mha", 4, 4, 4, False, None),
+    ("int8", 16, 8, 1, True, None),
+    ("int8-verify-s4", 16, 8, 4, True, None),
+    ("paged-shared-prefix", 8, 2, 1, False, 16),
+    ("paged-shared-prefix-int8", 8, 2, 1, True, 16),
+    ("paged-verify-s4", 8, 2, 4, False, 16),
+]
+
+
+class TestFoldedGrid:
+    """head_dim 128, the real width: ONE grid step reads a slot's row
+    block for every KV head (grid ``(B, 1, n_k)``), where the parent of
+    PR 33 took a grid step a head.  Against ``slot_cached_attention``'s
+    jnp path through the same entry point, so the write, the int8
+    quantize-on-write and the page gather are the engine's own; slots
+    at depth 0, mid-block, on a block's edge and at ``max_len - S``."""
+
+    @pytest.mark.parametrize(
+        "hq,hkv,s,quantized,ps", [c[1:] for c in _FOLDED],
+        ids=[c[0] for c in _FOLDED],
+    )
+    def test_matches_jnp_path(self, hq, hkv, s, quantized, ps):
+        rs = np.random.RandomState(hq + 7 * hkv + 31 * s + quantized)
+        b, d, max_len = 4, 128, 256
+        positions = jnp.asarray([0, 77, 128, max_len - s], jnp.int32)
+        q, k_new, v_new = (
+            jnp.asarray(rs.randn(b, s, h, d), jnp.float32)
+            for h in (hq, hkv, hkv)
+        )
+        slabs = [
+            jnp.asarray(rs.randn(b, max_len, hkv, d), jnp.float32)
+            for _ in range(2)
+        ]
+        tables = None
+        if ps is not None:
+            # slots 0/1 and 2/3 share their first two pages (a prompt's
+            # prefix, attended in place); each slot writes past them
+            pp = max_len // ps
+            tables = 1 + np.arange(b * pp, dtype=np.int32).reshape(b, pp)
+            tables[1, :2], tables[3, :2] = tables[0, :2], tables[2, :2]
+            positions = jnp.maximum(positions, 2 * ps)
+            pool = rs.permutation(b * pp + 1)  # shuffled: no identity map
+            tables = jnp.asarray(pool[tables].astype(np.int32))
+            slabs = [
+                jnp.zeros((b * pp + 1, ps, hkv, d), jnp.float32)
+                .at[tables.reshape(-1)]
+                .set(x.reshape(b * pp, ps, hkv, d))
+                for x in slabs
+            ]
+        if quantized:
+            (kq, ks), (vq, vs) = map(quantize_kv, slabs)
+            cache = tuple(merge_heads(x) for x in (kq, vq, ks, vs))
+        else:
+            cache = tuple(merge_heads(x) for x in slabs)
+
+        def attend(use_flash):
+            return slot_cached_attention(
+                q, k_new, v_new, cache, positions, use_flash=use_flash,
+                page_tables=tables,
+            )
+
+        ref, ref_cache = attend(False)
+        out, out_cache = attend(True)
+        for x, y in zip(out_cache, ref_cache):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        # the head-per-lane-block test's bar: 128-term f32 dots and an
+        # online-softmax merge over the blocks
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), rtol=2e-6, atol=2e-6
+        )
+        rows = -(-s * (hq // hkv) // 8) * 8
+        g, block_k = _blocking(
+            hkv, d, 1 if quantized else 4, max_len, rows,
+            ps or 512, ps or _MIN_BLOCK_K,
+        )
+        assert g == hkv and max_len // block_k > 1
+        assert _grids(lambda: attend(True)) == [(b, 1, max_len // block_k)]
+
+
+# (Hkv, D, item size, rows a slot, page size or None) -> (g, block_k) at
+# 8 query rows a head and the default bound of 512.  The first three are
+# the benchmark's widths (Mistral-7B, Llama-2-7B, deepseek-coder-1.3b)
+# at their 2048-row slabs: what scripts/bench_decode_attention.py
+# measured fastest on the chip (PERF.md §6 PR 33).
+_BLOCKING = [
+    ((8, 128, 2, 2048, None), (8, 256)),
+    ((32, 128, 2, 2048, None), (32, 128)),
+    ((16, 128, 2, 2048, None), (16, 256)),
+    ((8, 128, 2, 8192, None), (8, 512)),  # longer slots: fewer steps
+    ((8, 128, 1, 2048, None), (8, 512)),  # int8: half the bytes a row
+    ((8, 128, 2, 2048, 16), (8, 16)),  # paged: the block is the page
+    ((32, 128, 2, 4096, 16), (32, 16)),
+    ((128, 128, 2, 2048, None), (32, 128)),  # the tail does not fit
+    ((8, 256, 2, 8192, None), (8, 256)),
+    ((12, 64, 4, 1024, None), (12, 128)),  # GPT-2's 64: the whole tail
+    ((2, 64, 4, 96, None), (2, 96)),
+    ((2, 8, 4, 64, None), (2, 64)),  # the test models: one block
+    ((3, 64, 2, 8192, 128), (3, 128)),
+]
+
+
+class TestBlocking:
+    @pytest.mark.parametrize(
+        "shapes,want", _BLOCKING, ids=[str(c[0]) for c in _BLOCKING]
+    )
+    def test_rule(self, shapes, want):
+        hkv, d, itemsize, kv_rows, ps = shapes
+        g, block_k = _blocking(
+            hkv, d, itemsize, kv_rows, 8, ps or 512, ps or _MIN_BLOCK_K
+        )
+        assert (g, block_k) == want
+        assert hkv % g == 0 and kv_rows % block_k == 0
+        assert block_k <= 512 and (ps is None or block_k == ps)
+        assert _vmem_bytes(g, block_k, hkv, d, itemsize, 8) <= _VMEM_BUDGET
+        if d % _LANES != 0:
+            assert g == hkv
+
+    def test_the_bound_is_an_upper_bound(self):
+        """``block_k=`` caps what the rule may pick and never raises it;
+        a bound that does not divide the rows is halved until it does."""
+        assert _blocking(8, 128, 2, 2048, 8, 128) == (8, 128)
+        assert _blocking(8, 128, 2, 2048, 8, 64) == (8, 64)
+        assert _blocking(8, 128, 2, 2048, 8, 4096) == (8, 256)
+        assert _blocking(2, 8, 4, 48, 8, 32) == (2, 16)
+
+    def test_more_query_rows_never_widen_a_block(self):
+        """The verify block's taller matmul (rows = S * n_rep) takes
+        more fast memory a head, so ``g`` can only fall with it."""
+        gs = [_blocking(64, 128, 2, 2048, r, 512)[0] for r in (8, 16, 64)]
+        assert gs == sorted(gs, reverse=True)

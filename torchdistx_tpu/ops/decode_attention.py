@@ -17,8 +17,8 @@ Layout and masking:
   step.  Mosaic tiles the last two axes of a block as (sublane, lane)
   and refuses a block that squeezes the second-to-last one, so a head
   cannot be picked by squeezing an ``Hkv`` axis; with the tail merged a
-  KV head is the 128-aligned lane range ``[h * D, (h + 1) * D)`` and
-  the K/V block is the plain ``(block_k, D)`` tile at lane-block ``h``.
+  KV head is the 128-aligned lane range ``[h * D, (h + 1) * D)`` of
+  the plain ``(block_k, g * D)`` K/V block.
   The cache has to be STORED that way, because on the chip the merge is
   not a view: XLA tiles the last two axes of an array too, so a bf16
   ``(B, L, Hkv, D)`` array is laid out ``{3,2,1,0:T(8,128)(2,1)}`` with
@@ -28,13 +28,23 @@ Layout and masking:
   whole array (67 MB a slab at Mistral-7B widths, for K and for V, in
   every layer of every decode step: 29 % of the device's busy time when
   this wrapper still did it, PERF.md §6 PR 28).
-  Grid is ``(B, Hkv, n_k)``.  When ``D`` is not a multiple of the
-  128-lane width (GPT-2's 64, the test models) the block spans the
-  whole ``Hkv * D`` tail instead and the kernel walks the heads with
-  static lane slices (grid ``(B, 1, n_k)``); which of the two is a
-  function of the shapes alone.  int8 scales are stored
-  ``(B, max_len, Hkv)`` and the kernel selects its head's column with
-  an exact one-hot lane reduction.
+  Grid is ``(B, Hkv / g, n_k)``: one grid step reads ``block_k`` rows
+  of a slot for ``g`` KV heads at once and walks them with static lane
+  slices, and ``g`` is all ``Hkv`` wherever the block fits the fast
+  memory (grid ``(B, 1, n_k)``).  A grid step costs 0.2-0.35 us on a
+  v5e whether it reads anything or not, and most steps of a slab whose
+  slots are shallow are pruned ones: cut a head a step (the kernel's
+  grid until PR 33) a call at Mistral-7B's widths was 512 steps and took
+  190 us with every slot at depth 0, 203 us at the serving cell's depths
+  — the time was the grid (PERF.md §6 PR 33).  Whole rows are also one
+  contiguous piece of HBM where a head's rows are ``D`` lanes at a
+  stride of ``Hkv * D``.  ``g`` and ``block_k`` come from ONE function
+  of the shapes, :func:`_blocking`, with its fast-memory budget and
+  what it trades written down in it; ``D`` that is not a multiple of
+  the 128-lane width (GPT-2's 64, the test models) cannot be cut out of
+  the tail by a block, so there ``g`` is ``Hkv`` always.  int8 scales
+  are stored ``(B, max_len, Hkv)`` and the kernel selects a head's
+  column with an exact one-hot lane reduction.
 - GQA is folded in: the ``n_rep = Hq // Hkv`` query heads of one KV
   group ride as the ROWS of each matmul (padded up to the f32 sublane
   minimum of 8), so no repeated K/V ever materializes — the kernel
@@ -55,11 +65,11 @@ so shared-prefix pages are attended in place, never copied to a
 contiguous buffer.
 
 Exactness contract (pinned in tests/test_decode_attention.py): when the
-whole row fits one K block (``max_len <= block_k``, the common serving
-geometry) the kernel computes mask -> rowmax -> exp -> sum -> divide ->
-dot in exactly ``jax.nn.softmax``'s op order, so the interpret-mode
-PROBABILITIES are bit-identical to ``slot_cached_attention``'s jnp path;
-the one remaining divergence is the final P@V contraction, whose
+whole row fits one K block (:func:`_blocking` keeps a slab of up to
+255 rows, or one page, whole) the kernel computes mask -> rowmax -> exp
+-> sum -> divide -> dot in exactly ``jax.nn.softmax``'s op order, so the
+interpret-mode PROBABILITIES are bit-identical to
+``slot_cached_attention``'s jnp path; the one remaining divergence is the final P@V contraction, whose
 reduction XLA's CPU emitter associates differently for the batched
 einsum than for any per-(slot, kv-head) dot a blocked kernel can issue —
 measured <= 2 f32 ulps, and pinned at that tolerance (the same
@@ -93,7 +103,87 @@ __all__ = [
 
 _NEG_INF = -1e30
 _MIN_ROWS = 8  # f32 sublane minimum: GQA group rows pad up to this
-_LANES = 128  # TPU lane width: a head is its own lane block iff D % 128 == 0
+_LANES = 128  # TPU lane width: a head is whole lane tiles iff D % 128 == 0
+# What one call's blocks may take of the fast memory a kernel gets
+# without asking for more (16 MiB of scoped VMEM on a v5e; the rest is
+# the compiler's own).
+_VMEM_BUDGET = 12 * 2**20
+# A grid step costs 0.2-0.35 us on a v5e whether it reads anything or
+# not; a block costs its rows read past a slot's depth (half of it on
+# average) and the DMA of a slot's first block and the arithmetic of its
+# last, which nothing overlaps.  Taken together a step costs what about
+# this many bytes of a block cost (PERF.md §6 PR 33: the sweep of
+# ``scripts/bench_decode_attention.py`` over 4, 8 and 16 KiB of K and V
+# a row and 2048 and 8192 rows a slot puts it between 64 and 256 KiB).
+_STEP_BYTES = 128 * 2**10
+_MIN_BLOCK_K = 128  # the MXU's width: a slab block is not cut below it
+
+
+def _vmem_bytes(
+    g: int, block_k: int, hkv: int, d: int, itemsize: int, rows: int
+) -> int:
+    """Fast memory of one grid step that reads ``block_k`` cache rows of
+    ``g`` KV heads: what Pallas double-buffers, the scratch, and the
+    body's float32 temporaries."""
+    up = lambda n: -(-n // _LANES) * _LANES  # a minor axis pads to lanes
+    kv = 2 * 2 * block_k * up(g * d) * itemsize  # K and V, two buffers
+    # the int8 cache's (block_k, Hkv) f32 scale blocks, counted for every
+    # cache so that one rule serves both
+    scales = 2 * 2 * block_k * up(hkv) * 4
+    # q and o (two buffers each) and acc, as f32; m and l one lane wide
+    small = (5 * up(d) + 2 * _LANES) * g * rows * 4
+    # every head's K and V slices in f32, its logits and probabilities
+    temporaries = (2 * up(d) + 2 * rows) * g * block_k * 4
+    return kv + scales + small + temporaries
+
+
+def _blocking(
+    hkv: int, d: int, itemsize: int, kv_rows: int, rows: int,
+    block_k: int, min_block_k: int = _MIN_BLOCK_K,
+) -> tuple[int, int]:
+    """``(g, block_k)``: how a call is cut into grid steps, from its
+    shapes alone — ``Hkv`` KV heads of ``d`` lanes, a cache of
+    ``itemsize`` bytes an element and ``kv_rows`` logical rows a slot,
+    ``rows`` query rows a KV head (``S * n_rep``, padded).  A slot takes
+    ``(Hkv / g) * (kv_rows / block_k)`` grid steps, pruned ones included.
+
+    ``g``: a step reads its rows for as many heads as fit, all ``Hkv``
+    if it can — whole cache rows, one contiguous piece of HBM, an eighth
+    of the steps at Mistral's widths — and else the largest divisor of
+    ``Hkv`` whose blocks are inside ``_VMEM_BUDGET``.  A head that is not
+    whole lane tiles (``d % 128 != 0``) cannot be cut out of the tail by
+    a block, so there ``g`` is ``Hkv``.
+
+    ``block_k``: the caller's upper bound, halved until it divides
+    ``kv_rows``, then halved while that is cheaper by ``_STEP_BYTES`` —
+    half the rows past a slot's depth for twice the steps — or while the
+    whole-row block is over the budget (the same count of steps as fewer
+    heads would give, and less read past the depth), but not below
+    ``min_block_k``."""
+    block_k = _shrink_block(block_k, kv_rows)
+    row_bytes = 2 * hkv * d * itemsize  # K and V
+
+    def fits(g, block_k):
+        return _vmem_bytes(g, block_k, hkv, d, itemsize, rows) <= _VMEM_BUDGET
+
+    # a slot pays ``bk * row_bytes`` for its blocks and ``_STEP_BYTES``
+    # for each of its ``kv_rows / bk`` steps; ``bk / 2`` is cheaper iff
+    # ``bk / 2 * bk * row_bytes > kv_rows * _STEP_BYTES``
+    while (
+        block_k % 2 == 0
+        and block_k // 2 >= min_block_k
+        and (
+            (block_k // 2) * block_k * row_bytes > kv_rows * _STEP_BYTES
+            or not fits(hkv, block_k)
+        )
+    ):
+        block_k //= 2
+    if d % _LANES != 0:
+        return hkv, block_k
+    return next(
+        g for g in range(hkv, 0, -1)
+        if hkv % g == 0 and (g == 1 or fits(g, block_k))
+    ), block_k
 
 
 def _decode_kernel(
@@ -183,17 +273,25 @@ def _decode_kernel(
             preferred_element_type=jnp.float32,
         )
 
+    # The heads of a block are independent, and each is a chain of two
+    # small matmuls with two cross-lane reductions between them.  Written
+    # head after head the chip runs the chains one after the other (0.26
+    # us a head and block on a v5e, PERF.md §6 PR 33); written stage by
+    # stage over the heads, as below, they overlap.  A head's operations
+    # and their order are the same either way.
+    heads = range(g)
+
     if n_k == 1:
         # Single-block fast path in the jnp reference's exact op order
         # (mask, rowmax, exp, sum, divide, dot) — bit-identical to
         # slot_cached_attention's softmax in interpret mode.  No scratch
         # state: the whole visible row is here.
-        for j in range(g):
-            logits = tile(j)
-            m = jnp.max(logits, axis=-1, keepdims=True)
-            unnorm = jnp.exp(logits - m)
-            probs = unnorm / jnp.sum(unnorm, axis=-1, keepdims=True)
-            o_ref[j] = pv(probs, j).astype(o_ref.dtype)
+        logits = [tile(j) for j in heads]
+        m = [jnp.max(x, axis=-1, keepdims=True) for x in logits]
+        unnorm = [jnp.exp(x - mj) for x, mj in zip(logits, m)]
+        probs = [u / jnp.sum(u, axis=-1, keepdims=True) for u in unnorm]
+        for j in heads:
+            o_ref[j] = pv(probs[j], j).astype(o_ref.dtype)
         return
 
     @pl.when(kk == 0)
@@ -207,19 +305,21 @@ def _decode_kernel(
     # the index map)
     @pl.when(kk * block_k <= pos + (s - 1))
     def _compute():
-        for j in range(g):
-            logits = tile(j)
-            m_prev = m_ref[j]
-            m_new = jnp.maximum(
-                m_prev, jnp.max(logits, axis=-1, keepdims=True)
+        logits = [tile(j) for j in heads]
+        m_prev = [m_ref[j] for j in heads]
+        m_new = [
+            jnp.maximum(mp, jnp.max(x, axis=-1, keepdims=True))
+            for mp, x in zip(m_prev, logits)
+        ]
+        p = [jnp.exp(x - mn) for x, mn in zip(logits, m_new)]
+        correction = [jnp.exp(mp - mn) for mp, mn in zip(m_prev, m_new)]
+        out = [pv(p[j], j) for j in heads]
+        for j in heads:
+            l_ref[j] = l_ref[j] * correction[j] + jnp.sum(
+                p[j], axis=-1, keepdims=True
             )
-            p = jnp.exp(logits - m_new)
-            correction = jnp.exp(m_prev - m_new)
-            l_ref[j] = l_ref[j] * correction + jnp.sum(
-                p, axis=-1, keepdims=True
-            )
-            acc_ref[j] = acc_ref[j] * correction + pv(p, j)
-            m_ref[j] = m_new
+            acc_ref[j] = acc_ref[j] * correction[j] + out[j]
+            m_ref[j] = m_new[j]
 
     @pl.when(kk == n_k - 1)
     def _emit():
@@ -230,20 +330,28 @@ def _decode_kernel(
         ).astype(o_ref.dtype)
 
 
-# the extra scope keeps a transformation's wrapping (``vmap(...)``) off
-# the kernel's own name (ops/flash_attention.py has the long form)
+# One traced and lowered computation for all the layers of a program: a
+# decode program calls this once a layer with the same shapes, and traced
+# in place each call costs ~60 ms of a process's set-up on the host (the
+# body is unrolled over the heads; 24 layers, twice a program: +2.7 s of
+# ``setup_s`` on the Mistral cell, PERF.md §6 PR 33).  As a jitted
+# function it is traced on its first call and found in jit's cache after.
+# The scope inside keeps a transformation's wrapping (``vmap(...)``) off
+# the kernel's own name (ops/flash_attention.py has the long form).
+@functools.partial(jax.jit, static_argnames=("block_k", "scale", "interpret"))
 @jax.named_scope("decode_attention")
 def _launch(
-    q, ck, cv, k_scale, v_scale, prefetch, kv_index, *,
-    block_k, n_k, scale, interpret, name,
+    q, ck, cv, k_scale, v_scale, positions, page_tables, *,
+    block_k, scale, interpret,
 ):
     """Shared wrapper of the four families.  ``ck``/``cv``: the slab
-    (B, max_len, Hkv * D) or the pools (num_pages, page_size, Hkv * D),
-    handed to the kernel as they are; ``Hkv`` is ``ck.shape[-1] // D``.
-    ``kv_index(bb, kk, *prefetch_refs) -> (lead, row_block)`` names the
-    K/V block grid step ``(bb, ·, kk)`` reads; ``prefetch`` are the
-    scalar-prefetch operands, per-slot base depths first.  ``name`` is
-    the kernel's fixed name in the compiled program and in a profile."""
+    (B, max_len, Hkv * D) or, with ``page_tables`` (B, pages_per_slot),
+    the pools (num_pages, page_size, Hkv * D), handed to the kernel as
+    they are; ``Hkv`` is ``ck.shape[-1] // D``.  ``positions`` (per-slot
+    base depths) and the flattened table are the scalar-prefetch
+    operands.  :func:`_blocking` cuts a slot's logical rows into the
+    blocks of a grid step, ``block_k`` its upper bound (the paged
+    callers pass the page)."""
     b, s, hq, d = q.shape
     if ck.ndim != 3 or ck.shape[-1] % d != 0 or cv.shape != ck.shape:
         raise ValueError(
@@ -263,17 +371,37 @@ def _launch(
                 f"kv scale shapes {k_scale.shape}/{v_scale.shape} != "
                 f"cache rows + kv heads {want}"
             )
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     n_rep = hq // hkv
-    # heads per K/V block: one where a head is a whole number of lane
-    # tiles, else the whole tail (module docstring)
-    g = 1 if d % _LANES == 0 else hkv
 
     # fold (B, S, Hq, D) into (B, Hkv, rows, D): S tokens x n_rep GQA
     # heads per KV group, padded up to the f32 sublane minimum
     real = s * n_rep
     rows = -(-real // _MIN_ROWS) * _MIN_ROWS
+    # the paged block is the page: there only the heads a step reads give
+    paged = page_tables is not None
+    ps = ck.shape[1]
+    kv_rows = ps * page_tables.shape[1] if paged else ps
+    g, block_k = _blocking(
+        hkv, d, ck.dtype.itemsize, kv_rows, rows, block_k,
+        ps if paged else _MIN_BLOCK_K,
+    )
+    n_k = kv_rows // block_k
+    # the last visible block of slot ``bb``, by its deepest query row
+    if paged:
+        # the table is flattened for SMEM scalar prefetch: entry b*n_k + kk
+        prefetch = [positions, page_tables.astype(jnp.int32).reshape(-1)]
+
+        def kv_index(bb, kk, pos_ref, pt_ref):
+            last = jnp.minimum(pos_ref[bb] + (s - 1), kv_rows - 1) // ps
+            return (pt_ref[bb * n_k + jnp.minimum(kk, last)], 0)
+
+    else:
+        prefetch = [positions]
+
+        def kv_index(bb, kk, pos_ref):
+            last = jnp.minimum(pos_ref[bb] + (s - 1), kv_rows - 1) // block_k
+            return (bb, jnp.minimum(kk, last))
+
     qg = q.reshape(b, s, hkv, n_rep, d).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(b, hkv, real, d)
     if rows != real:
@@ -318,7 +446,8 @@ def _launch(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-        name=name,
+        # the kernel's fixed name in the compiled program and in a profile
+        name="tdx_paged_decode_attention" if paged else "tdx_decode_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -330,6 +459,14 @@ def _launch(
         .transpose(0, 2, 1, 3, 4)
         .reshape(b, s, hq, d)
     )
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    """Off-TPU the kernels run in interpret mode, per the repo kernel
+    convention; resolved before the jitted wrapper, whose cache it keys."""
+    if interpret is None:
+        return jax.devices()[0].platform != "tpu"
+    return interpret
 
 
 def decode_attention(
@@ -353,10 +490,15 @@ def decode_attention(
     attends).  ``positions``: (B,) int32 — slot ``b`` attends cache rows
     ``j <= positions[b]``.  Returns (B, 1, Hq, D) in ``q.dtype``.
 
-    ``block_k`` is an upper bound (halved until it divides ``max_len``);
-    when one block covers ``max_len`` the interpret-mode result is
-    bit-identical to the jnp reference (module docstring).  ``interpret``
-    defaults to True off-TPU, per the repo kernel convention.
+    How the call is cut into grid steps — the rows a step reads and
+    for how many KV heads at once — is :func:`_blocking`'s to decide
+    from the shapes; ``block_k`` is the upper bound on the rows it may
+    pick (it halves the bound until it divides ``max_len``, and further
+    where a shorter block is cheaper or the block would not fit the
+    fast memory).  When one block covers ``max_len`` the interpret-mode
+    result is bit-identical to the jnp reference (module docstring).
+    ``interpret`` defaults to True off-TPU, per the repo kernel
+    convention.
 
     **int8 cache** (``kv_dtype="int8"``): pass the f32 per-row per-head
     scales as ``k_scale``/``v_scale`` of shape (B, max_len, Hkv) —
@@ -401,21 +543,16 @@ def decode_attention_block(
     speculation.  The DMA clamp and block pruning use the block's
     deepest row ``positions[b] + S - 1`` (blocks past it re-map onto the
     last visible one: Pallas skips the DMA when the mapped block index
-    is unchanged, so pruned grid steps move no bytes).
+    is unchanged, so pruned grid steps move no bytes).  ``block_k`` is
+    the upper bound it is in :func:`decode_attention`: the rows a step
+    reads and the heads it reads them for are :func:`_blocking`'s, and
+    the taller matmul's ``rows`` are one of its arguments.
     ``k_scale``/``v_scale``: int8-cache dequant scales, exactly as in
     :func:`decode_attention`.
     """
-    s, max_len = q.shape[1], ck.shape[1]
-    block_k = _shrink_block(block_k, max_len)
-
-    def kv_index(bb, kk, pos_ref):
-        last = jnp.minimum(pos_ref[bb] + (s - 1), max_len - 1) // block_k
-        return (bb, jnp.minimum(kk, last))
-
     return _launch(
-        q, ck, cv, k_scale, v_scale, [positions.astype(jnp.int32)],
-        kv_index, block_k=block_k, n_k=max_len // block_k, scale=scale,
-        interpret=interpret, name="tdx_decode_attention",
+        q, ck, cv, k_scale, v_scale, positions.astype(jnp.int32), None,
+        block_k=block_k, scale=scale, interpret=_interpret(interpret),
     )
 
 
@@ -486,24 +623,12 @@ def paged_decode_attention_block(
     block's deepest row ``positions[b] + S - 1``).  ``k_scale``/
     ``v_scale``: int8-cache dequant scales of shape (num_pages,
     page_size, Hkv), gathered through the same table."""
-    s, ps = q.shape[1], ck.shape[1]
     if page_tables.shape[0] != q.shape[0]:
         raise ValueError(
             f"page_tables rows {page_tables.shape[0]} != batch {q.shape[0]}"
         )
-    pp = page_tables.shape[1]
-
-    def kv_index(bb, kk, pos_ref, pt_ref):
-        last = jnp.minimum(pos_ref[bb] + (s - 1), pp * ps - 1) // ps
-        return (pt_ref[bb * pp + jnp.minimum(kk, last)], 0)
-
     return _launch(
-        q, ck, cv, k_scale, v_scale,
-        # the table is flattened for SMEM scalar prefetch: entry b*pp + kk
-        [
-            positions.astype(jnp.int32),
-            page_tables.astype(jnp.int32).reshape(-1),
-        ],
-        kv_index, block_k=ps, n_k=pp, scale=scale, interpret=interpret,
-        name="tdx_paged_decode_attention",
+        q, ck, cv, k_scale, v_scale, positions.astype(jnp.int32),
+        page_tables, block_k=ck.shape[1], scale=scale,
+        interpret=_interpret(interpret),
     )
