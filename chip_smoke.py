@@ -37,6 +37,15 @@ Default run (one chip, 16 GB):
   requirement at ``LATENT_LOGIT_TOL``, and the two paths' whole-prompt
   logits within ``LATENT_FORWARD_MEAN_TOL`` of each other in the mean.  ``--latent-only`` runs this
   phase alone.
+- *serve_recurrent*: the Jamba family at AI21-Jamba2-3B's widths, one
+  whole period (13 Mamba layers + 1 attention layer, 1.6 B parameters):
+  the slab engine on the kernel path (``tdx_selective_scan`` in the
+  bucketed prefills, ``tdx_selective_state_update`` over 12 decode
+  steps, MQA 20 / 1 through ``tdx_flash_forward`` and
+  ``tdx_decode_attention``) beside the jnp forms, the same
+  teacher-forced requirement at ``RECURRENT_LOGIT_TOL`` and the two
+  paths' whole-prompt logits within ``RECURRENT_FORWARD_MEAN_TOL`` in
+  the mean.  ``--recurrent-only`` runs this phase alone.
 - *train*: llama_1b at 2 x 2048 tokens, flash attention and
   AnyPrecisionAdamW, a few ``ShardedTrainStep`` steps through ``Trainer``:
   losses finite and falling, Pallas calls in the compiled step, zero
@@ -75,6 +84,14 @@ SERVE_LOGIT_TOL = 0.25
 #: maximum than a dense model's (a wrong cache row lands ~4 std away)
 LATENT_FORWARD_MEAN_TOL = 0.03
 LATENT_LOGIT_TOL = 1.0
+#: the Jamba family at real widths and one period, kernel path against
+#: jnp forms (bf16; logits of std ~1).  Both paths keep the recurrent
+#: state in float32 and do a step's arithmetic in the same order, so
+#: they differ as the dense model's paths do; a state lost, a padding
+#: row let into it or a slot's state written to another moves every
+#: later logit by about one std
+RECURRENT_FORWARD_MEAN_TOL = 0.03
+RECURRENT_LOGIT_TOL = 0.25
 #: sharded vs single-device loss, per step (bf16 params, f32 loss; the
 #: two runs reduce in different orders)
 FOUR_CHIP_LOSS_RTOL = 2e-2
@@ -443,6 +460,23 @@ def phase_serve_latent(log: CompileLog):
                 forward_mean_tol=LATENT_FORWARD_MEAN_TOL)
 
 
+def phase_serve_recurrent(log: CompileLog):
+    """The Jamba family at real widths through the same serve phase:
+    recurrent state beside KV rows in the slab, the selective-scan
+    prefill kernel told each prompt's true length, the in-place
+    state-update decode kernel."""
+    from torchdistx_tpu.models import Jamba
+
+    model = materialize_checked(
+        log, "jamba2_3b/1-period",
+        ctor=lambda: Jamba.from_name(
+            "jamba2_3b", n_layers=14, max_seq_len=1024
+        ),
+    )
+    phase_serve(log, model, modes=("slab",), logit_tol=RECURRENT_LOGIT_TOL,
+                forward_mean_tol=RECURRENT_FORWARD_MEAN_TOL)
+
+
 def _check_kernels_compiled(engine, mode: str) -> None:
     """Every program the kernel-path engine dispatched holds Mosaic
     custom calls, by its cost card: proof the Pallas path was the
@@ -609,6 +643,8 @@ def main(argv=None) -> int:
                     help="run only the four-chip sharded path")
     ap.add_argument("--latent-only", action="store_true",
                     help="run only the latent-cache serve phase")
+    ap.add_argument("--recurrent-only", action="store_true",
+                    help="run only the recurrent-state serve phase")
     args = ap.parse_args(argv)
 
     import jax
@@ -634,6 +670,9 @@ def main(argv=None) -> int:
         elif args.latent_only:
             phase = "serve_latent"
             phase_serve_latent(log)
+        elif args.recurrent_only:
+            phase = "serve_recurrent"
+            phase_serve_recurrent(log)
         else:
             phase = "materialize"
             model = phase_materialize(log, "llama2_7b")
@@ -643,6 +682,9 @@ def main(argv=None) -> int:
             free_device_memory()
             phase = "serve_latent"
             phase_serve_latent(log)
+            free_device_memory()
+            phase = "serve_recurrent"
+            phase_serve_recurrent(log)
             free_device_memory()
             phase = "train"
             phase_train(log)

@@ -491,3 +491,96 @@ def test_fused_ce_fwd_bwd_compiles(one_chip, n, d, v):
         "tdx_fused_ce_backward_dw", "tdx_fused_ce_backward_dx",
         "tdx_fused_ce_forward",
     ]
+
+
+# -- AI21-Jamba2-3B: the selective-scan kernels and MQA at 20 / 1 ------------
+
+J_C, J_N, J_SLOTS, J_L = 5120, 16, 256, 2048  # d_inner, d_state, slots, rows
+
+
+@pytest.mark.parametrize("bucket", [256, 512, 1024])
+def test_selective_scan_compiles(one_chip, bucket):
+    """``tdx_selective_scan`` at the published widths over each bucket
+    of the Jamba cell: the (16, 1024) state block resident across the
+    time chunks, ``B`` and ``C`` as (rows, 16, 1) columns."""
+    from torchdistx_tpu.ops.selective_scan import selective_scan
+
+    def fn(x, dt, a, b, c, dskip, z, h0, true_len):
+        return selective_scan(
+            x, dt, a, b, c, dskip, z, h0, true_len[0],
+            use_kernel=True, interpret=False,
+        )
+
+    rows = ((1, bucket, J_C), jnp.bfloat16)
+    coef = ((1, bucket, J_N), jnp.float32)
+    text = _compile(
+        fn, one_chip, rows, ((1, bucket, J_C), jnp.float32),
+        ((J_N, J_C), jnp.float32), coef, coef, ((J_C,), jnp.float32), rows,
+        ((1, J_N, J_C), jnp.float32), ((1,), jnp.int32),
+    )
+    assert _kernel_names(text) == ["tdx_selective_scan"]
+    assert _has_grid(text, (1, J_C // 1024, bucket // 128))
+
+
+def test_state_update_compiles_and_leaves_the_state_in_place(one_chip):
+    """``tdx_selective_state_update`` over 256 slots: the donated state
+    is the kernel's operand AND its result (``input_output_aliases``),
+    so nothing else in the program has a result of the state's size
+    (84 MB a layer: a copy would double a decode step's second memory
+    stream)."""
+    from torchdistx_tpu.ops.selective_scan import selective_state_update
+
+    def fn(h, x, dt, a, b, c, dskip, z):
+        return selective_state_update(
+            h, x, dt, a, b, c, dskip, z, use_kernel=True, interpret=False
+        )
+
+    rows = ((J_SLOTS, J_C), jnp.bfloat16)
+    coef = ((J_SLOTS, J_N), jnp.float32)
+    text = _compile(
+        fn, one_chip, ((J_SLOTS, J_N, J_C), jnp.float32), rows,
+        ((J_SLOTS, J_C), jnp.float32), ((J_N, J_C), jnp.float32), coef, coef,
+        ((J_C,), jnp.float32), rows, donate=(0,),
+    )
+    assert _kernel_names(text) == ["tdx_selective_state_update"]
+    assert _has_grid(text, (J_SLOTS // 16, J_C // 1280))
+    others = [
+        f"{name} = {type_} {op}"
+        for _, name, op, type_, _ in _cache_sized(text, J_SLOTS * J_N * J_C)
+        if op not in PLUMBING and op != "custom-call"
+    ]
+    assert not others, others
+    assert "output_to_operand_aliasing" in text or "input_output_alias" in text
+
+
+def test_mqa_decode_step_leaves_the_cache_in_place(one_chip, monkeypatch):
+    """The Jamba cell's two attention layers: 20 query heads on ONE KV
+    head of 128 (a group of 20, a 256-byte row an array), 256 slots of
+    2048 — ``_blocking``'s rule at a shape no other cell has."""
+    (chip,) = one_chip.device_set
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+    row = ((J_SLOTS, 1, 1, D), jnp.bfloat16)
+    cache = ((J_SLOTS, J_L, D), jnp.bfloat16)
+
+    def fn(q, k_new, v_new, positions, ck, cv):
+        return slot_cached_attention(
+            q, k_new, v_new, (ck, cv), positions, use_flash=True
+        )
+
+    text = _compile(
+        fn, one_chip, ((J_SLOTS, 1, 20, D), jnp.bfloat16), row, row,
+        ((J_SLOTS,), jnp.int32), cache, cache, donate=(4, 5),
+    )
+    assert _kernel_names(text) == ["tdx_decode_attention"]
+    offenders = _relayouts_of_the_cache(text, J_SLOTS * J_L * D)
+    assert not offenders, offenders
+
+
+def test_mqa_flash_prefill_compiles(one_chip):
+    """A Jamba prefill's attention: 20 query heads on one KV head."""
+    text = _compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False),
+        one_chip, ((1, 1024, 20, D), jnp.bfloat16),
+        ((1, 1024, 1, D), jnp.bfloat16), ((1, 1024, 1, D), jnp.bfloat16),
+    )
+    assert _kernel_names(text) == [FLASH_FORWARD]

@@ -834,6 +834,96 @@ class TestKVCacheUnit:
         assert cache.positions()[0] == 3
 
 
+class TestMixedKinds:
+    """A cache whose layers hold different kinds of entry
+    (``kv_cache.entry_kind``): a Jamba stack's recurrent state beside
+    the KV rows of its attention layers.  The kind is the entry's TYPE,
+    layer by layer; nothing is read off layer 0 or an entry's length."""
+
+    @staticmethod
+    def _jamba():
+        from torchdistx_tpu.models import Jamba
+
+        tdx.manual_seed(5)
+        return Jamba.from_name("tiny")  # layers: s p s s p s
+
+    def test_kinds_and_sizes_go_layer_by_layer(self):
+        from torchdistx_tpu.serve.kv_cache import RecurrentState, cache_kinds
+
+        model = self._jamba()
+        cfg = model.cfg
+        kinds = ("state", "pair", "state", "state", "pair", "state")
+        assert cache_kinds(model) == kinds
+        cache = SlotKVCache(model, num_slots=3, max_len=16)
+        assert cache.kinds == kinds and not cache.latent
+        assert cache.kv_heads == 1  # of the layers that have heads
+        assert isinstance(cache.kv[0], RecurrentState)
+        assert type(cache.kv[1]) is tuple and len(cache.kv[1]) == 2
+        # a pair is stored with its head tail merged, a state as the model makes it
+        assert cache.kv[1][0].shape == (3, 16, cfg.n_kv_heads * cfg.head_dim)
+        assert cache.kv[0].conv.shape == (3, 3 * cfg.d_inner)
+        assert cache.kv[0].ssm.shape == (3, cfg.d_state, cfg.d_inner)
+        assert cache.kv[0].ssm.dtype == jnp.float32
+        row = 2 * cfg.n_kv_heads * cfg.head_dim * 4  # K and V, float32
+        state = 4 * (cfg.d_state * cfg.d_inner * 4 + 3 * cfg.d_inner * 4)
+        assert cache.kv_row_bytes == row
+        assert cache.state_slot_bytes == state
+        assert cache.kv_data_nbytes == 2 * 3 * 16 * row  # rows only
+        assert cache.kv_scale_nbytes == 0
+        assert cache.nbytes == cache.kv_data_nbytes + 3 * state
+        assert cache.kv_dtype_name == "float32"
+        assert all(a.committed for entry in cache.kv for a in entry)
+
+    def test_a_row_dtype_casts_the_rows_and_never_the_state(self):
+        cache = SlotKVCache(
+            self._jamba(), num_slots=2, max_len=16, kv_dtype="bfloat16"
+        )
+        assert cache.kv[1][0].dtype == jnp.bfloat16
+        assert cache.kv_dtype_name == "bfloat16"
+        assert cache.kv[0].ssm.dtype == jnp.float32
+        assert cache.kv[0].conv.dtype == jnp.float32  # the model's
+
+    def test_int8_is_refused_over_what_has_no_heads(self):
+        with pytest.raises(ValueError, match="recurrent state"):
+            SlotKVCache(self._jamba(), num_slots=2, max_len=16, kv_dtype="int8")
+
+    def test_write_slot_replaces_a_state_whole_and_a_pairs_rows(self):
+        from torchdistx_tpu.serve.kv_cache import RecurrentState, write_slot
+
+        model = self._jamba()
+        cache = SlotKVCache(model, num_slots=3, max_len=16)
+        before = jax.tree_util.tree_map(lambda a: a + 1.0, cache.kv)
+        slab = jax.tree_util.tree_map(
+            lambda a: jnp.full(a.shape, 7.0, a.dtype), model.init_cache(1, 8)
+        )
+        after = jax.jit(write_slot)(before, slab, jnp.int32(1))
+        assert [type(e) for e in after] == [type(e) for e in before]
+        for old, new in zip(before, after):
+            if isinstance(new, RecurrentState):
+                for o, n in zip(old, new):  # slot 1 whole, the others as they were
+                    assert np.all(np.asarray(n[1]) == 7.0)
+                    np.testing.assert_array_equal(n[0], o[0])
+                    np.testing.assert_array_equal(n[2], o[2])
+            else:
+                for o, n in zip(old, new):  # the slab's 8 rows of slot 1
+                    assert np.all(np.asarray(n[1, :8]) == 7.0)
+                    np.testing.assert_array_equal(n[1, 8:], o[1, 8:])
+                    np.testing.assert_array_equal(n[0], o[0])
+
+    def test_a_plain_pair_cache_is_what_it_was(self):
+        """The models' ``(k, v)`` contract needs no type: plain tuples
+        in, plain tuples stored, every layer a pair."""
+        cache = SlotKVCache(_llama(), num_slots=2, max_len=16)
+        assert set(cache.kinds) == {"pair"} and not cache.latent
+        assert all(type(e) is tuple and len(e) == 2 for e in cache.kv)
+        assert cache.state_slot_bytes == 0
+        assert cache.kv_data_nbytes == cache.nbytes
+        quant = SlotKVCache(_llama(), num_slots=2, max_len=16, kv_dtype="int8")
+        assert all(type(e) is tuple and len(e) == 4 for e in quant.kv)
+        assert quant.kv_scale_nbytes > 0 and quant.kv_dtype_name == "int8"
+        assert quant.kv_data_nbytes * 4 == cache.kv_data_nbytes
+
+
 def _eqns(jaxpr):
     """Every equation of a jaxpr, nested jaxprs (jit, scan, while, the
     vmapped write's loop) included."""

@@ -1,5 +1,6 @@
 from .deepseek_v3 import DeepseekV3, DeepseekV3Config, deepseek_v3_configs
 from .gpt2 import GPT2, GPT2Config, gpt2_configs
+from .jamba import Jamba, JambaConfig, jamba_configs
 from .llama import Llama, LlamaConfig, llama_configs
 from .mixtral import Mixtral, MixtralConfig, mixtral_configs
 from .resnet import ResNet, resnet18, resnet50, resnet101
@@ -10,6 +11,9 @@ __all__ = [
     "DeepseekV3",
     "DeepseekV3Config",
     "deepseek_v3_configs",
+    "Jamba",
+    "JambaConfig",
+    "jamba_configs",
     "Llama",
     "LlamaConfig",
     "llama_configs",

@@ -27,7 +27,8 @@ Kanana-2-30B-A3B, ...), per layer, ``x`` the RMS-normed input:
 - the cache row is ``[c (after its norm) ; k_r (after rope)]``, ``kv_lora_rank
   + qk_rope_head_dim`` wide (576), ONE per token and layer, **stored
   zero-padded to a whole number of 128-lane tiles** (640): ``init_cache``
-  returns per layer the 1-tuple ``(latent (B, S, cache_width),)``, which
+  returns per layer the typed entry ``LatentEntry(latent (B, S,
+  cache_width))`` (``serve/kv_cache.py``), which
   is also how ``serve/kv_cache.py`` stores it.  The padding costs no
   memory the chip would not spend anyway (a bf16 array tiled (8, 128)
   with 576 lanes minor is laid out on 640) and it is what keeps the
@@ -52,10 +53,11 @@ name.
 
 The model exposes what ``generation.generate`` and ``ServeEngine`` ask
 of one (``init_cache``, ``forward_cached``, ``forward_decode``), with
-two hints the engine reads: ``latent_cache`` (its cache entry is one
-latent array; paging, int8, speculation, persistent decode, chunked
-prefill and a TP mesh are refused over it) and ``forward_cached``'s
-``logits_at`` (the head applied to the one position that is sampled).
+a typed cache entry (``LatentEntry``: the engine refuses paging, int8,
+speculation, persistent decode, chunked prefill and a TP mesh over it)
+and one hint the engine reads, ``prefill_logits_at``
+(``forward_cached``'s ``logits_at``: the head applied to the one
+position that is sampled).
 """
 
 from __future__ import annotations
@@ -72,6 +74,7 @@ from .. import nn
 from ..nn.moe import MoE
 from ..ops.attention import latent_slot_cached_attention, multihead_attention
 from ..ops.flash_attention import resolve_use_flash
+from ..serve.kv_cache import LatentEntry
 from .llama import LlamaMLP, _hf_normal, _rope_freqs
 
 __all__ = ["DeepseekV3Config", "DeepseekV3", "deepseek_v3_configs"]
@@ -260,7 +263,7 @@ class MLAttention(nn.Module):
         )
         if isinstance(cache_pos, int) and cache_pos == 0:
             k, v = self._expand(rows)
-            return self._out(self._causal(q, k, v)), (latent,)
+            return self._out(self._causal(q, k, v)), LatentEntry(latent)
         k, v = self._expand(latent.astype(x.dtype))
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
         visible = (
@@ -269,7 +272,9 @@ class MLAttention(nn.Module):
         )
         logits = jnp.where(visible[None, None], logits * self.scale, -jnp.inf)
         probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        return self._out(jnp.einsum("bhqk,bkhd->bqhd", probs, v)), (latent,)
+        return self._out(
+            jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        ), LatentEntry(latent)
 
     def forward_decode(self, x, rope, cache, positions):
         """One token a serving slot, each at its own depth, in the
@@ -348,9 +353,8 @@ class DeepseekV3Block(nn.Module):
 
 
 class DeepseekV3(nn.Module):
-    #: the serve engine reads these: the cache entry is one latent array,
-    #: and ``forward_cached`` can apply the head to one position only
-    latent_cache = True
+    #: the serve engine reads this: ``forward_cached`` can apply the head
+    #: to one position only
     prefill_logits_at = True
     #: the expert layers record rows and groups under
     #: ``nn.moe.moe_count_tape`` (the grouped path does)
@@ -395,11 +399,13 @@ class DeepseekV3(nn.Module):
         return self._head(x)
 
     def init_cache(self, batch_size: int, max_seq: Optional[int] = None):
-        """Per layer the 1-tuple ``(latent,)``: zeros (B, max_seq,
+        """Per layer a ``LatentEntry(latent)``: zeros (B, max_seq,
         cache_width)."""
         cfg = self.cfg
         shape = (batch_size, max_seq or cfg.max_seq_len, cfg.cache_width)
-        return [(jnp.zeros(shape, cfg.dtype),) for _ in range(cfg.n_layers)]
+        return [
+            LatentEntry(jnp.zeros(shape, cfg.dtype)) for _ in range(cfg.n_layers)
+        ]
 
     def forward_cached(self, tokens, cache, cache_pos, logits_at=None):
         """``tokens`` (prefill chunk or one decode token) against the

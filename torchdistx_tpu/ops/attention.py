@@ -414,7 +414,7 @@ def latent_slot_cached_attention(
     ``q``: (B, H, W) absorbed queries ``[q_nope W_uk ; q_rope]``, ``W =
     latent + rope width``.  ``row_new``: (B, 1, W), each slot's new cache
     row ``[c ; k_r]`` (norm and rope applied).  ``cache``: the engine's
-    latent entry, a 1-tuple ``(latent,)`` of shape (slots, max_len, W) —
+    latent entry, a ``LatentEntry(latent)`` of shape (slots, max_len, W) —
     the kernel's operand as it is stored (``serve/kv_cache.py``).  The
     row is written at ``positions[b]`` by the slab's one scatter
     (``scatter_slot_tokens``), then slot ``b`` attends rows ``j <=
@@ -423,8 +423,8 @@ def latent_slot_cached_attention(
     the same math (``ops.latent_decode_attention.latent_attend``).  Every
     visible row is read once, for the score and for the value (its first
     ``value_width`` lanes).  Returns ``(o~ (B, H, value_width),
-    (latent,))``; ``o~`` still goes through ``W_uv``."""
-    from ..serve.kv_cache import scatter_slot_tokens
+    LatentEntry(latent))``; ``o~`` still goes through ``W_uv``."""
+    from ..serve.kv_cache import LatentEntry, scatter_slot_tokens
     from . import latent_decode_attention as lda
     from .flash_attention import resolve_use_flash
 
@@ -436,7 +436,7 @@ def latent_slot_cached_attention(
         else lda.latent_attend
     )
     out = attend(q, latent, positions, value_width=value_width, scale=scale)
-    return out, (latent,)
+    return out, LatentEntry(latent)
 
 
 def multihead_attention(

@@ -79,6 +79,18 @@ greedy slot's token stream is bit-identical to
 ``generation.generate`` on that prompt alone, for every ``decode_chunk``
 (pinned in tests/test_serve.py).
 
+**What a slot holds is the model's to say**, layer by layer
+(``kv_cache.entry_kind``): KV rows as a ``(k, v)`` pair, a latent row
+(``LatentEntry``, multi-head latent attention), or a recurrent state
+with no rows at all (``RecurrentState``, a state-space layer: written
+whole by the prefill, rewritten whole for every slot by every decode
+step).  The step path is the same for all of them — the programs carry
+the cache as a pytree and ``write_slot`` / the models' own
+``forward_decode`` know the kinds; what is NOT the same is the set of
+options built over each kind: ``_SLAB_ONLY`` names, for a latent cache
+and for recurrent state, what the constructor (and ``migrate_to`` /
+``handoff_to``) refuse, and why.
+
 Sampling (``generation._make_slot_sampler``) reuses ``generate``'s
 top-k/top-p filters; the two jitted programs live in the model's
 ``generation._cached_jit`` store so executables are collected with the
@@ -142,8 +154,11 @@ from ..nn.moe import moe_count_tape, tape_totals
 from ..utils.compat import jit_cache_size
 from ..utils.profiling import timed_annotation
 from .kv_cache import (
+    LATENT,
+    STATE,
     PagedKVCache,
     SlotKVCache,
+    cache_kinds,
     canonicalize_kv_dtype,
     heads_view,
     paged_scatter_rows,
@@ -246,6 +261,19 @@ def _cache_sharding(
         if isinstance(sh, NamedSharding):
             return NamedSharding(sh.mesh, PartitionSpec())
     return None
+
+
+#: cache kinds that only the slab cache and the default programs serve,
+#: and how a refusal names each.  Why, by mechanism -- a latent row has
+#: no head axis for int8 scales or a TP split, and its warm / paged /
+#: speculative programs are not written; a recurrent state has no rows
+#: at all: a page or a shared prefix would need a state SNAPSHOT at the
+#: page's edge, a rejected draft a ROLLBACK of the state, a warm chunk a
+#: program that carries the state in, a TP mesh a split of ``d_inner``;
+#: and a move between engines (``migrate_to`` / ``handoff_to``) or a
+#: replayed session would copy a state that nothing here tests, so over
+#: recurrent state those are refused too.
+_SLAB_ONLY = {LATENT: "a latent cache", STATE: "recurrent state"}
 
 
 def _default_buckets(max_len: int) -> tuple:
@@ -509,12 +537,17 @@ class ServeEngine:
                 f"length {limit}"
             )
         self.model = model
-        # a latent-cache model (multi-head latent attention: one latent
-        # row a token and layer, models/deepseek_v3.py) is served from
-        # the slab with the default programs only; everything else is
-        # refused here, by name, not half-built
-        self.latent = bool(getattr(model, "latent_cache", False))
-        if self.latent:
+        # what the model's cache entries hold, layer by layer
+        # (kv_cache.entry_kind).  A latent entry (multi-head latent
+        # attention, models/deepseek_v3.py) and a recurrent state (a
+        # state-space layer, models/jamba.py) are served from the slab
+        # with the default programs only; everything else is refused
+        # here, by name, not half-built (_SLAB_ONLY has the reasons)
+        kinds = cache_kinds(model)
+        self.latent = LATENT in kinds
+        self.recurrent = STATE in kinds
+        rec = resolve_record(record)
+        for kind in (k for k in _SLAB_ONLY if k in kinds):
             refused = {
                 "page_size (a paged cache, and with it the prefix cache)":
                     page_size is not None or num_pages is not None,
@@ -525,10 +558,14 @@ class ServeEngine:
                 "chunked_prefill": chunked_prefill is not None,
                 "mesh (tensor parallelism)": mesh is not None,
             }
+            if kind == STATE:
+                refused["record (the session recorder)"] = bool(
+                    getattr(rec, "enabled", False)
+                )
             bad = [name for name, asked in refused.items() if asked]
             if bad:
                 raise ValueError(
-                    f"{', '.join(bad)}: not supported over a latent cache "
+                    f"{', '.join(bad)}: not supported over {_SLAB_ONLY[kind]} "
                     f"({type(model).__name__}): it is served from the "
                     "slab cache with the chunked decode program only"
                 )
@@ -734,25 +771,11 @@ class ServeEngine:
         self._kv_quant_alarmed = False
         # the dtype actually stored (model default resolved), for the
         # attributable refusal/plan naming satellite
-        self.kv_dtype_name = str(self.cache.kv[0][0].dtype)
+        self.kv_dtype_name = self.cache.kv_dtype_name
         self.scheduler = Scheduler(self.num_slots, max_tokens_in_flight)
-        # per-token KV footprint across all layers, scales included —
-        # the quantization win the gauges make visible
-        _kv_rows = (
-            self.num_pages * self.page_size
-            if self.paged
-            else self.num_slots * self.max_len
-        )
-        self.metrics = ServeMetrics(
-            self.num_slots,
-            num_pages=self.num_pages,
-            ring_capacity=self.ring_capacity,
-            speculate=self.speculate or None,
-            kv_cache_bytes=self.cache.nbytes,
-            kv_bytes_per_token=self.cache.nbytes // _kv_rows,
-            kv_quant_err_max=0.0 if self.kv_quantized else None,
-            kv_quant_err_rms=0.0 if self.kv_quantized else None,
-            kv_row_bytes=self.cache.kv_row_bytes,
+        self.metrics = self._new_metrics(
+            0.0 if self.kv_quantized else None,
+            0.0 if self.kv_quantized else None,
         )
         self._sampler = _make_slot_sampler(jnp.int32, top_k, top_p)
         # persistent mode: prefill defers its first-token fetch — the
@@ -817,7 +840,6 @@ class ServeEngine:
         self._bb_source = "engine"
         self._bb_in_drain = False
         self._bb_finished_pending: list = []
-        rec = resolve_record(record)
         if rec is not None:
             self.attach_recorder(rec)
 
@@ -1172,6 +1194,7 @@ class ServeEngine:
         """
         if target is self:
             raise ValueError("cannot migrate an engine into itself")
+        self._refuse_state_move("migrate_to", target)
         if target._draining:
             raise RuntimeError(
                 "migration target is itself draining — migrate to a "
@@ -1336,6 +1359,7 @@ class ServeEngine:
         """
         if target is self:
             raise ValueError("cannot hand a request off to its own engine")
+        self._refuse_state_move("handoff_to", target)
         if target._draining:
             raise RuntimeError(
                 "handoff target is draining — hand off to a live engine"
@@ -1397,6 +1421,14 @@ class ServeEngine:
             "collectives": int(n_coll),
             "pages_moved": int(pages_moved),
         }
+
+    def _refuse_state_move(self, what: str, target: "ServeEngine") -> None:
+        if self.recurrent or target.recurrent:
+            raise ValueError(
+                f"{what}: not supported over {_SLAB_ONLY[STATE]} "
+                f"({type(self.model).__name__}): a slot's state would "
+                "have to move with its rows, which nothing here tests"
+            )
 
     @staticmethod
     def _kv_unit_sharding(dst, *, lead_none: bool):
@@ -1564,27 +1596,40 @@ class ServeEngine:
             total += size
         return total
 
+    def _new_metrics(self, quant_err_max, quant_err_rms) -> ServeMetrics:
+        """A fresh :class:`ServeMetrics` with this engine's geometry and
+        footprint gauges: the per-token KV footprint across all layers
+        that hold rows, scales included (the quantization win the gauges
+        make visible), and beside it what a slot holds of recurrent
+        state."""
+        cache = self.cache
+        rows = (
+            self.num_pages * self.page_size
+            if self.paged
+            else self.num_slots * self.max_len
+        )
+        state = cache.state_slot_bytes
+        return ServeMetrics(
+            self.num_slots,
+            num_pages=self.num_pages,
+            ring_capacity=self.ring_capacity,
+            speculate=self.speculate or None,
+            kv_cache_bytes=cache.nbytes,
+            kv_bytes_per_token=(cache.nbytes - state * self.num_slots) // rows,
+            kv_quant_err_max=quant_err_max,
+            kv_quant_err_rms=quant_err_rms,
+            kv_row_bytes=cache.kv_row_bytes,
+            state_slot_bytes=state or None,
+        )
+
     def reset_metrics(self) -> ServeMetrics:
         """Rebind ``self.metrics`` to a fresh :class:`ServeMetrics` with
         THIS engine's geometry (slots, pages, ring, speculate) — the one
         correct way to reset between bench passes; hand-constructing the
         object would silently drop the paged/persistent/speculative
         gauge families."""
-        _kv_rows = (
-            self.num_pages * self.page_size
-            if self.paged
-            else self.num_slots * self.max_len
-        )
-        self.metrics = ServeMetrics(
-            self.num_slots,
-            num_pages=self.num_pages,
-            ring_capacity=self.ring_capacity,
-            speculate=self.speculate or None,
-            kv_cache_bytes=self.cache.nbytes,
-            kv_bytes_per_token=self.cache.nbytes // _kv_rows,
-            kv_quant_err_max=self.metrics.kv_quant_err_max,
-            kv_quant_err_rms=self.metrics.kv_quant_err_rms,
-            kv_row_bytes=self.cache.kv_row_bytes,
+        self.metrics = self._new_metrics(
+            self.metrics.kv_quant_err_max, self.metrics.kv_quant_err_rms
         )
         return self.metrics
 

@@ -28,18 +28,43 @@ flattens the NEW ROWS, never the cache; a reader that needs heads (the
 jnp attends, :func:`paged_view`, chunked prefill's warm program) takes
 a 4-D view of what it read (:func:`split_heads`).
 
-**A latent entry** (multi-head latent attention, ``models/deepseek_v3``):
-a layer's entry is ONE array ``(latent,)`` of shape ``(num_slots,
-max_len, W)`` — a token's compressed key/value and its one shared rope
-key side by side (``kv_lora_rank + qk_rope_head_dim`` = 576 lanes,
-which the model zero-pads to whole 128-lane tiles, ``W`` = 640), where
-the pair layout would hold ``heads x (qk + v)`` (10240 for the same
-model).  It has no head axis to merge: ``model.init_cache`` already
-returns it in the form ``tdx_latent_decode_attention`` reads, prefill
-writes its slab with the same ``dynamic_update_slice`` and a decode step
-its row with the same scatter as a pair's arrays.  Only the slab layout
-holds one (the engine refuses paging, int8 and the warm / chunked
-programs over it, by name).
+**A layer's entry says what kind it is** (:func:`entry_kind`), and a
+cache may hold layers of different kinds:
+
+- a **pair** ``(k, v)``, a plain tuple, the models' documented cache
+  contract -- and, under ``kv_dtype="int8"``, the cache's own quantized
+  representation of it, ``(k, v, k_scale, v_scale)``;
+- a **latent** entry, :class:`LatentEntry` (multi-head latent attention,
+  ``models/deepseek_v3``): ONE array ``(num_slots, max_len, W)`` -- a
+  token's compressed key/value and its one shared rope key side by side
+  (``kv_lora_rank + qk_rope_head_dim`` = 576 lanes, which the model
+  zero-pads to whole 128-lane tiles, ``W`` = 640), where the pair
+  layout would hold ``heads x (qk + v)`` (10240 for the same model).  It
+  has no head axis to merge: ``model.init_cache`` already returns it in
+  the form ``tdx_latent_decode_attention`` reads, prefill writes its
+  slab with the same ``dynamic_update_slice`` and a decode step its row
+  with the same scatter as a pair's arrays;
+- a **recurrent state**, :class:`RecurrentState` (a state-space layer,
+  ``models/jamba``): ``conv (num_slots, (K - 1) * d_inner)``, the last
+  ``K - 1`` inputs of the layer's causal convolution, and ``ssm
+  (num_slots, d_state, d_inner)`` float32, the recurrence's state --
+  what a slot holds of the whole context, constant in its length.  It
+  has NO row axis and no position: a prefill writes the slot's state
+  whole (``write_slot``: the state after the prompt's last REAL token;
+  the model sees to that, ``models/jamba.py``) and every decode step
+  rewrites the state of EVERY slot whole (in place:
+  ``tdx_selective_state_update``), that of a retired or frozen slot
+  too.  That is safe by the argument this module makes for rows,
+  overwrite-before-visible: nothing reads a slot's state but that
+  slot's own next step, and a slot is read again only after an
+  admission, whose prefill has overwritten the state whole.  The
+  model's layout and dtypes are kept as they are (no head tail to
+  merge; ``kv_dtype`` casts rows, never the float32 state).
+
+Only the slab layout holds a latent entry or a recurrent state (the
+engine refuses paging, int8 and the warm / chunked programs over them,
+by name); the kinds are told by the entry's TYPE, layer by layer, never
+by its length or by layer 0.
 
 In both, admitting/retiring a request changes only tiny dynamic inputs
 (positions, a table row, a host bit) — never a device shape — so the
@@ -93,7 +118,7 @@ exactly like the frozen single-token writes.
 
 from __future__ import annotations
 
-from typing import Any, List, Optional
+from typing import Any, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -111,7 +136,10 @@ __all__ = [
     "split_heads",
     "heads_view",
     "stored_rows",
-    "is_latent",
+    "LatentEntry",
+    "RecurrentState",
+    "entry_kind",
+    "cache_kinds",
     "write_slot",
     "paged_view",
     "paged_scatter_rows",
@@ -278,10 +306,49 @@ def stored_rows(entry: Any, k: jax.Array, v: jax.Array) -> tuple:
     return tuple(merge_heads(x).astype(c.dtype) for x, c in zip(rows, entry))
 
 
-def is_latent(entry: Any) -> bool:
-    """A layer's entry that is one latent array ``(latent,)`` (module
-    docstring), not a ``(k, v)`` pair or its quantized 4-tuple."""
-    return len(entry) == 1
+class LatentEntry(NamedTuple):
+    """A layer's latent cache (module docstring): one array ``(lead,
+    rows, W)``."""
+
+    latent: jax.Array
+
+
+class RecurrentState(NamedTuple):
+    """A state-space layer's per-slot state (module docstring): ``conv
+    (lead, (K - 1) * d_inner)`` and ``ssm (lead, d_state, d_inner)``
+    float32."""
+
+    conv: jax.Array
+    ssm: jax.Array
+
+
+PAIR, LATENT, STATE = "pair", "latent", "state"
+
+
+def entry_kind(entry: Any) -> str:
+    """What a layer's cache entry holds: ``"state"``
+    (:class:`RecurrentState`), ``"latent"`` (:class:`LatentEntry`) or
+    ``"pair"`` -- the plain tuple of the models' ``(k, v)`` contract,
+    which the cache itself may hold quantized (``(k, v, k_scale,
+    v_scale)``).  Told by the entry's type, never by its length."""
+    if isinstance(entry, RecurrentState):
+        return STATE
+    if isinstance(entry, LatentEntry):
+        return LATENT
+    return PAIR
+
+
+def cache_kinds(model: Any) -> tuple:
+    """The kind of every layer's entry of ``model.init_cache``, from
+    shapes alone (nothing is allocated)."""
+    return tuple(
+        entry_kind(e) for e in jax.eval_shape(lambda: model.init_cache(1, 2))
+    )
+
+
+def _like(entry: Any, arrays) -> Any:
+    """``arrays`` as an entry of ``entry``'s kind."""
+    return tuple(arrays) if entry_kind(entry) == PAIR else type(entry)(*arrays)
 
 
 def write_slot(kv: Any, slab: Any, slot) -> Any:
@@ -298,14 +365,21 @@ def write_slot(kv: Any, slab: Any, slot) -> Any:
     jitted prefill program); the write is a pure
     ``dynamic_update_slice`` per layer — no recompile across slots.
     A latent entry's slab ``(latent (1, bucket, W),)`` is already in the
-    stored form.
+    stored form, and so is a recurrent state's ``(conv (1, .), ssm (1,
+    ., .))``, which replaces the slot's state whole.
     """
     return [
-        tuple(
-            lax.dynamic_update_slice(c, x.astype(c.dtype), (slot, 0, 0))
-            for c, x in zip(
-                entry, s if is_latent(entry) else stored_rows(entry, *s)
-            )
+        _like(
+            entry,
+            (
+                lax.dynamic_update_slice(
+                    c, x.astype(c.dtype), (slot,) + (0,) * (c.ndim - 1)
+                )
+                for c, x in zip(
+                    entry,
+                    stored_rows(entry, *s) if entry_kind(entry) == PAIR else s,
+                )
+            ),
         )
         for entry, s in zip(kv, slab)
     ]
@@ -501,31 +575,66 @@ class _HostBookkeeping:
             for a in pair
         )
 
+    def _row_entries(self) -> list:
+        """The entries that hold rows (a pair, a latent entry): a
+        recurrent state has none."""
+        return [e for e, k in zip(self.kv, self.kinds) if k != STATE]
+
+    @property
+    def latent(self) -> bool:
+        """Some layer's entry is a latent array."""
+        return LATENT in self.kinds
+
     @property
     def kv_data_nbytes(self) -> int:
-        """Bytes of the K/V data arrays alone (scales excluded) — the
-        quantity that halves exactly under ``kv_dtype="int8"``."""
+        """Bytes of the K/V data arrays alone (scales and recurrent
+        state excluded) — the quantity that halves exactly under
+        ``kv_dtype="int8"``."""
         return sum(
             int(np.prod(a.shape)) * a.dtype.itemsize
-            for entry in self.kv
+            for entry in self._row_entries()
             for a in entry[:2]
         )
 
     @property
     def kv_row_bytes(self) -> int:
-        """Bytes one token takes in one layer's data arrays: ``2 x Hkv x
-        D x itemsize`` for a pair, ``W x itemsize`` for a latent entry
-        (1280 for DeepSeek-V3's 576 bf16 lanes stored on 640)."""
-        return sum(a.shape[-1] * a.dtype.itemsize for a in self.kv[0][:2])
+        """Bytes one token takes in the data arrays of ONE layer that
+        holds rows: ``2 x Hkv x D x itemsize`` for a pair, ``W x
+        itemsize`` for a latent entry (1280 for DeepSeek-V3's 576 bf16
+        lanes stored on 640).  The first such layer is read (a model's
+        row layers are alike); 0 where no layer holds rows."""
+        rows = self._row_entries()
+        if not rows:
+            return 0
+        return sum(a.shape[-1] * a.dtype.itemsize for a in rows[0][:2])
 
     @property
     def kv_scale_nbytes(self) -> int:
         """Bytes of the f32 scale arrays (0 for unquantized caches)."""
         return sum(
             int(np.prod(a.shape)) * a.dtype.itemsize
-            for entry in self.kv
+            for entry in self._row_entries()
             for a in entry[2:]
         )
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Bytes ONE slot holds of recurrent state, all layers together
+        (0 without such a layer): constant in the context length, and
+        what a decode step reads and writes once a slot."""
+        return sum(
+            int(np.prod(a.shape[1:])) * a.dtype.itemsize
+            for entry, kind in zip(self.kv, self.kinds)
+            if kind == STATE
+            for a in entry
+        )
+
+    @property
+    def kv_dtype_name(self) -> str:
+        """The dtype the rows are stored in (the first layer that holds
+        rows; a cache of recurrent state alone names its first array)."""
+        rows = self._row_entries() or self.kv
+        return str(rows[0][0].dtype)
 
     def _init_device(
         self, model: Any, lead: int, rows: int, kv_dtype: Any, placement: Any
@@ -533,8 +642,10 @@ class _HostBookkeeping:
         """Build ``self.kv``: ``model.init_cache(lead, rows)`` — the
         model's layout and dtype, ``(lead, rows, Hkv, D)`` pairs —
         brought into the stored representation (the cache's dtype, every
-        array's head tail merged: module docstring) and COMMITTED to
-        ``placement``.  Also records ``kv_dtype`` / ``quantized`` /
+        array's head tail merged: module docstring; a latent entry and a
+        recurrent state are stored as the model makes them) and
+        COMMITTED to ``placement``.  Also records ``kinds`` (every
+        layer's :func:`entry_kind`), ``kv_dtype`` / ``quantized`` /
         ``kv_heads`` (the readers that need the head axis back ask for
         the last).
 
@@ -569,27 +680,40 @@ class _HostBookkeeping:
         self.kv_dtype = kv_dtype = canonicalize_kv_dtype(kv_dtype)
         self.quantized = quantized = kv_dtype == "int8"
         shapes = jax.eval_shape(lambda: model.init_cache(lead, rows))
-        self.latent = latent = is_latent(shapes[0])
-        if latent and quantized:
+        self.kinds = kinds = tuple(entry_kind(e) for e in shapes)
+        if quantized and any(k != PAIR for k in kinds):
             raise ValueError(
-                "kv_dtype='int8' is not supported over a latent cache: "
-                "the per-head scales have no head to belong to"
+                "kv_dtype='int8' is not supported over a latent cache or "
+                "recurrent state: the per-head scales have no head to "
+                "belong to"
             )
+        dt = None if kv_dtype is None else _KV_DTYPES[kv_dtype]
 
         def stored():  # closes over the model only, never over ``self``
-            base = model.init_cache(lead, rows)
-            if latent:  # already the stored form: no head tail to merge
-                dt = base[0][0].dtype if kv_dtype is None else _KV_DTYPES[kv_dtype]
-                return [(c.astype(dt),) for (c,) in base]
-            if quantized:
-                base = quantize_cache(base)
-            elif kv_dtype is not None:
-                dt = _KV_DTYPES[kv_dtype]
-                base = [(k.astype(dt), v.astype(dt)) for k, v in base]
-            return [tuple(merge_heads(a) for a in entry) for entry in base]
+            out = []
+            for kind, entry in zip(kinds, model.init_cache(lead, rows)):
+                if kind == STATE:  # the model's layout and dtypes, as is
+                    out.append(entry)
+                elif kind == LATENT:  # no head tail to merge
+                    (c,) = entry
+                    out.append(LatentEntry(c if dt is None else c.astype(dt)))
+                else:
+                    if quantized:
+                        (entry,) = quantize_cache([entry])
+                    elif dt is not None:
+                        entry = tuple(a.astype(dt) for a in entry)
+                    out.append(tuple(merge_heads(a) for a in entry))
+            return out
 
-        # a latent row is shared by every head: it has no head axis
-        self.kv_heads = None if latent else int(shapes[0][0].shape[2])
+        # the head count of the layers that have heads (a latent row is
+        # shared by every head; a recurrent state has none): what the
+        # readers that need the head axis back ask for
+        heads = {int(e[0].shape[2]) for e, k in zip(shapes, kinds) if k == PAIR}
+        if len(heads) > 1:
+            raise ValueError(
+                f"the model's pair layers disagree on their KV heads: {heads}"
+            )
+        self.kv_heads = heads.pop() if heads else None
         if placement is None:
             placement = jax.devices()[0]
         sharding = (

@@ -167,7 +167,11 @@ class ServeMetrics:
     know their KV pool footprint add ``kv_cache_bytes`` (total resident
     KV bytes, quantization scales included) and ``kv_bytes_per_token``
     (pool bytes per cache token-row — int8 caches publish roughly half
-    the bf16 figure); quantized (int8) engines additionally publish
+    the bf16 figure; the rows alone where the cache also holds recurrent
+    state) and ``kv_row_bytes`` (one token in one layer that holds
+    rows); engines over a state-space model add ``state_slot_bytes``
+    (what ONE slot holds of recurrent state, all layers together:
+    constant in the context length); quantized (int8) engines additionally publish
     ``kv_quant_err_max`` / ``kv_quant_err_rms`` (observed KV dequant
     error from the numerics-observatory digests; the max is pinned
     ``<= s/2`` by the power-of-two quantizer's round-to-nearest bound).
@@ -218,10 +222,17 @@ class ServeMetrics:
         kv_quant_err_max: Optional[float] = None,
         kv_quant_err_rms: Optional[float] = None,
         kv_row_bytes: Optional[int] = None,
+        state_slot_bytes: Optional[int] = None,
     ):
         global _LATEST
         _LATEST = self
         self.num_slots = int(num_slots)
+        # bytes one slot holds of recurrent state, all layers together
+        # (a state-space model; None without such a layer)
+        self.state_slot_bytes = (
+            state_slot_bytes if state_slot_bytes is None
+            else int(state_slot_bytes)
+        )
         # bytes one token takes in one layer's cache data: 2 x Hkv x D x
         # itemsize for a (k, v) pair, W x itemsize for a latent row
         self.kv_row_bytes = (
@@ -417,6 +428,8 @@ class ServeMetrics:
             gauges["kv_bytes_per_token"] = self.kv_bytes_per_token
         if self.kv_row_bytes is not None:
             gauges["kv_row_bytes"] = self.kv_row_bytes
+        if self.state_slot_bytes is not None:
+            gauges["state_slot_bytes"] = self.state_slot_bytes
         if self.kv_quant_err_max is not None:
             gauges["kv_quant_err_max"] = self.kv_quant_err_max
         if self.kv_quant_err_rms is not None:
