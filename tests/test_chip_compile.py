@@ -321,13 +321,16 @@ def test_serve_decode_program_compiles_from_the_packed_state(
 ):
     """The engine's whole decode program at Mistral-7B's widths (two
     layers of the cell's 24; 16 slots of 2048), lowered from the
-    signature the engine dispatches since PR 31 — ``(params, kv, state)``
-    with the per-slot state ONE ``(7, num_slots)`` int32 argument
-    (``generation.pack_slot_state``) — and compiled for the described
-    chip: the unpacking costs the program no kernel (one decode-attention
-    call a layer, as before) and nothing of the cache's size is copied.
+    signature the engine dispatches — ``(params, kv, carry, firsts,
+    state)``: the per-slot state ONE host argument
+    (``generation.pack_slot_state``, PR 31) with the row that says which
+    slots start from it, the rest from the last dispatch's carry, which
+    like the prefills' first tokens stays on the device (PR 35) — and
+    compiled for the described chip: the unpacking and the choice cost
+    the program no kernel (one decode-attention call a layer, as before)
+    and nothing of the cache's size is copied.
     ``benchmarks/proof/describe_compile.py`` spells the seven-argument
-    list of before; this is the described compile of the new one."""
+    list of before PR 31; this is the described compile of the new one."""
     import torchdistx_tpu as tdx
     from torchdistx_tpu.generation import SLOT_STATE_ROWS
     from torchdistx_tpu.models import Llama
@@ -352,12 +355,17 @@ def test_serve_decode_program_compiles_from_the_packed_state(
 
     params = {n: shape(p) for n, p in model.named_parameters()}
     kv = jax.tree_util.tree_map(shape, engine.cache.kv)
-    state = jax.ShapeDtypeStruct(
-        (SLOT_STATE_ROWS, M_B), jnp.int32, sharding=one_chip
+    def ints(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=one_chip)
+
+    small = (
+        ints(SLOT_STATE_ROWS, M_B), ints(M_B), ints(SLOT_STATE_ROWS + 1, M_B)
     )
     # ``interpret=None`` and ``use_flash`` ask jax.devices()[0].platform
     monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
-    text = engine._decode_program().lower(params, kv, state).compile().as_text()
+    text = (
+        engine._decode_program().lower(params, kv, *small).compile().as_text()
+    )
     assert _kernel_names(text) == ["tdx_decode_attention"] * layers
     assert _has_grid(text, (M_B, 1, 8))  # 128 grid steps a layer
     offenders = _relayouts_of_the_cache(text, M_B * M_L * M_HKV * D)

@@ -274,7 +274,7 @@ class TestServeEngine:
             model, num_slots=2, max_len=64, prefill_buckets=(bucket,)
         )
         jaxpr = jax.make_jaxpr(engine._prefill_program(bucket))(
-            engine.params, engine.cache.kv,
+            engine.params, engine.cache.kv, engine._firsts,
             jnp.zeros((1, bucket), jnp.int32), jnp.int32(7), jnp.int32(0),
             jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
         )

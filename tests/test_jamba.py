@@ -343,9 +343,9 @@ class TestServeEngine:
         )
         vocab, bucket = model.cfg.vocab_size, 32
         jaxpr = jax.make_jaxpr(engine._prefill_program(bucket))(
-            engine.params, engine.cache.kv, jnp.zeros((1, bucket), jnp.int32),
-            jnp.int32(5), jnp.int32(0), jnp.zeros((1,), jnp.float32),
-            jnp.zeros((1,), jnp.int32),
+            engine.params, engine.cache.kv, engine._firsts,
+            jnp.zeros((1, bucket), jnp.int32), jnp.int32(5), jnp.int32(0),
+            jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
         )
 
         def eqns(j):

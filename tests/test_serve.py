@@ -527,8 +527,12 @@ class TestFinishMasking:
         r = results[0]
         assert r.finish_reason == "stop"
         np.testing.assert_array_equal(r.tokens, expect)  # nothing after j
-        # EOS emitted at in-chunk step j = 2 -> K - 1 - j wasted
-        assert engine.metrics.counters["masked_slot_steps"] == k_chunk - 3
+        # EOS emitted at in-chunk step j = 2 -> K - 1 - j wasted, and the
+        # whole of the next chunk: the host cannot foresee an EOS, so the
+        # successor was in flight, the slot frozen in it, when it saw this
+        counters = engine.metrics.counters
+        assert counters["lagged_slot_steps"] == k_chunk
+        assert counters["masked_slot_steps"] == k_chunk - 3 + k_chunk
         # the slot's write position froze where the host stopped: 3
         # decode steps consumed (the prefill token rode the prefill
         # dispatch; the EOS token was sampled at step j=2), not K

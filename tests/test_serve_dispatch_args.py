@@ -2,9 +2,13 @@
 
 The step path hands its programs HOST arrays and the dispatch call makes
 the transfers: the per-slot state of a decode dispatch is one packed
-``(7, num_slots)`` int32 array (``generation.pack_slot_state``), a
-prefill's scalars are NumPy scalars and one-element arrays with their
-dtypes written out.  Pinned here:
+``(7, num_slots)`` int32 array (``generation.pack_slot_state``; on the
+fused one-token program ``(8, num_slots)``: the row that says which slots
+start from it, PR 35), a prefill's scalars are NumPy scalars and
+one-element arrays with their dtypes written out.  What else a program
+takes is already on the device and travels from one program to the next:
+the first tokens' vector (every prefill, the fused one-token decode) and
+that decode's carry.  Pinned here:
 
 - no ``jnp.asarray`` / ``jax.device_put`` between the start of
   ``serve/decode_args`` (or of ``serve/schedule``) and the dispatch — the
@@ -144,12 +148,13 @@ class _Spy:
         self.calls = {"decode_args": 0, "schedule": 0}
         self.open = []  # the phases open now, innermost last
         self.small_args = []  # (program getter, [type of each small leaf])
+        self.resident = []  # (program getter, device-resident arguments)
         self.pending_at_dispatch = []
         real_phase = engine._phase
 
         @contextlib.contextmanager
-        def phase(name):
-            with real_phase(name):
+        def phase(name, *sink):
+            with real_phase(name, *sink):
                 self.open.append(name)
                 try:
                     yield
@@ -175,7 +180,17 @@ class _Spy:
 
     def _watch(self, engine):
         def around(getter, program, args):
-            self.small_args.append((getter, [type(x) for x in args[2:]]))
+            # what stays on the device between programs is no transfer:
+            # told apart by identity, not by type
+            resident = [
+                x for x in args[2:]
+                if x is engine._carry or x is engine._firsts
+            ]
+            self.resident.append((getter, len(resident)))
+            self.small_args.append((getter, [
+                type(x) for x in args[2:]
+                if not any(x is r for r in resident)
+            ]))
             if "persistent" in getter:
                 self.pending_at_dispatch.append(len(engine._pending_first))
             return program(*args)
@@ -219,9 +234,17 @@ def test_no_host_conversion_before_a_dispatch_and_streams_exact(
         assert spy.calls["decode_args"] == 0
         for types in decodes:
             assert all(issubclass(t, np.ndarray) for t in types)
-    # one packed state + the history (speculative) + the tables (paged)
+    # one packed state + the history (speculative) + the tables (paged):
+    # with the row of sources the state is still ONE host array
     assert {len(t) for t in decodes} == {
         1 + bool(engine.speculate) + bool(engine.paged)
+    }
+    # and beside them only what lives on the device: the first tokens'
+    # vector (a prefill), with the carry (the fused one-token decode)
+    carries = not (engine._persistent or engine.speculate)
+    assert {n for g, n in spy.resident if "prefill" in g} == {1}
+    assert {n for g, n in spy.resident if "prefill" not in g} == {
+        2 if carries else 0
     }
     for request, tokens in zip(requests, served):
         np.testing.assert_array_equal(tokens, _reference(model, request))

@@ -19,6 +19,32 @@ regenerated to make a failure go away: a differing counter or event is a
 copy merged wrongly (a skipped ``active`` check, a lost
 ``ring_full_drains``, a ``prefill_chunk`` event on an unchunked prefill).
 
+**The one sanctioned regeneration (PR 35)**: ``slab-whole`` and
+``paged-whole``, the two cases whose engine now reads its tokens one
+dispatch late (``ServeEngine._lags``: the fused one-token program with
+whole prefills), because there the order of a step itself changed.  Six
+counters changed and no event of any request (a request's own order
+``first_token`` / ``decode_chunk`` x n / ``finish`` is what it was; only
+the step in which each lands is one later).  From the parent's numbers:
+
+- ``decode_dispatches`` / ``decode_steps`` 21 -> 22.  The run ends with
+  request 3 (budget 19), which takes slot 1 after request 1 (budget 4).
+  The parent saw request 1 finish in the walk of ``D3`` and admitted
+  request 3 in step 4: tokens 2..19 in ``D4..D21``.  Here the walk of
+  ``D3`` is in step 4, the admission in step 5: ``D5..D22``.  The host
+  foresees that last budget, so no ``D23`` is issued.
+- ``host_syncs`` 27 -> 28: a fetch a decode dispatch and one a prefill, as
+  before, 22 + 6.
+- ``lagged_dispatches`` 21: every dispatch but ``D1`` was issued with its
+  predecessor unread.
+- ``lagged_slot_steps`` 5, ``masked_slot_steps`` 0 -> 5: requests 0, 1, 2,
+  4 and 5 end by their budget inside a dispatch whose successor is already
+  in flight with the slot frozen in it (one slot-step each,
+  ``decode_chunk`` 1); request 3 ends the run with nothing in flight.
+
+The chunked cases of those kinds (``chunked_prefill`` settles at once),
+the persistent and the speculative kinds are the parent's to the letter.
+
 No combination is refused by the constructor: all ten run.  The scenario
 has prompts on both sides of the chunk threshold, one prompt that shares
 its first page with an earlier one (the paged engines' warm prefill and
@@ -150,7 +176,8 @@ def test_a_chunked_prefill_folded_to_one_chunk_is_still_chunked(kind):
 GOLDEN = {
     "slab-whole": {
         "counters": {
-            "decode_dispatches": 21, "decode_steps": 21, "host_syncs": 27,
+            "decode_dispatches": 22, "decode_steps": 22, "host_syncs": 28,
+            "lagged_dispatches": 21, "lagged_slot_steps": 5, "masked_slot_steps": 5,
             "prefill_calls": 6, "requests_admitted": 6, "requests_completed": 6,
             "requests_submitted": 6, "tokens_decoded": 53, "tokens_generated": 59,
             "tokens_prefilled": 72,
@@ -225,7 +252,8 @@ GOLDEN = {
     },
     "paged-whole": {
         "counters": {
-            "decode_dispatches": 21, "decode_steps": 21, "host_syncs": 27,
+            "decode_dispatches": 22, "decode_steps": 22, "host_syncs": 28,
+            "lagged_dispatches": 21, "lagged_slot_steps": 5, "masked_slot_steps": 5,
             "prefill_calls": 6, "prefix_hit_tokens": 8, "prefix_lookup_tokens": 51,
             "requests_admitted": 6, "requests_completed": 6, "requests_submitted": 6,
             "tokens_decoded": 53, "tokens_generated": 59, "tokens_prefilled": 64,

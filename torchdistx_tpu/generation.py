@@ -132,15 +132,25 @@ def _make_slot_sampler(
 #: (``pack_slot_state`` / ``_unpack_slot_state``)
 SLOT_STATE_ROWS = 7
 
+#: the fused one-token program's host array has one row more: per slot,
+#: where the dispatch takes that slot's state from.  The program returns
+#: its final carry packed the same way, and the next dispatch starts from
+#: it wherever the host has nothing newer to say (``_make_fused_decode``)
+KEEP_CARRY = 0  # the previous dispatch's final carry, still on the device
+FROM_HOST = 1  # this array's column: the host changed the slot since
+FIRST_ON_DEVICE = 2  # the column, but the token from the prefills' vector
+
 
 def pack_slot_state(
-    toks, positions, temps, seeds, steps, budgets, mask
+    toks, positions, temps, seeds, steps, budgets, mask, source=None
 ) -> np.ndarray:
     """The serve decode programs' per-slot state as ONE host array:
     ``(SLOT_STATE_ROWS, num_slots)`` int32 — last tokens, write
     positions, temperatures (their float32 BITS), sampler seeds, tokens
     sampled so far, budgets, and the program's mask (finished for the
-    fused scan, active for the persistent loop) as 0/1.
+    fused scan, active for the persistent loop) as 0/1; with ``source``
+    (``KEEP_CARRY`` / ``FROM_HOST`` / ``FIRST_ON_DEVICE`` per slot) one
+    row more, still one array.
 
     One array is one host-to-device transfer inside the dispatch call.
     Seven arrays were seven: 0.11 ms each on the chip's host through the
@@ -150,7 +160,10 @@ def pack_slot_state(
     ``_unpack_slot_state`` gives the seven back bit for bit on the
     device."""
     toks = np.asarray(toks)
-    state = np.empty((SLOT_STATE_ROWS, toks.shape[0]), np.int32)
+    rows = SLOT_STATE_ROWS + (source is not None)
+    state = np.empty((rows, toks.shape[0]), np.int32)
+    if source is not None:
+        state[SLOT_STATE_ROWS] = source
     state[0] = toks
     state[1] = positions
     state[2] = np.asarray(temps, np.float32).view(np.int32)
@@ -171,6 +184,18 @@ def _unpack_slot_state(state):
     )
     temps = jax.lax.bitcast_convert_type(temps, jnp.float32)
     return toks, positions, temps, seeds, steps, budgets, mask != 0
+
+
+def _pack_carry(carry, temps, seeds, budgets):
+    """A fused dispatch's final carry ``(kv, tok, pos, stp, fin)`` and
+    the three per-slot inputs it does not change, as the packed state
+    the next dispatch starts from: ``pack_slot_state``'s rows, on the
+    device."""
+    _, tok, pos, stp, fin = carry
+    return jnp.stack([
+        tok, pos, jax.lax.bitcast_convert_type(temps, jnp.int32), seeds,
+        stp, budgets, fin.astype(jnp.int32),
+    ])
 
 
 def _make_decode_body(
@@ -254,9 +279,23 @@ def _make_fused_decode(
     separate one-step dispatches do to a retired slot's row, which is
     what makes fused-vs-sequential cache states comparable.
 
-    Returns ``run(params, kv, state, *extra) -> (kv, (K, B) token
-    block)``.  ``state`` is ``pack_slot_state(toks, positions, temps,
-    seeds, steps, budgets, finished)``: one argument, one transfer.
+    Returns ``run(params, kv, carry, firsts, state, *extra) -> (kv, (K,
+    B) token block, carry)``.  ``state`` is ``pack_slot_state(toks,
+    positions, temps, seeds, steps, budgets, finished, source)``: one
+    argument, one transfer.  ``carry`` is what the previous dispatch
+    returned, never fetched: the K-th step's ``(tok, pos, stp, fin)``
+    with the temperatures, seeds and budgets beside them, packed as
+    ``state`` is.  A slot starts from the carry where ``source`` says
+    ``KEEP_CARRY`` and from ``state``'s column elsewhere (admitted,
+    expired, moved: what the host changed since), its token from
+    ``firsts`` -- the ``(B,)`` vector the prefill programs write their
+    sampled token into -- where ``source`` says ``FIRST_ON_DEVICE``.  So
+    the next dispatch needs nothing the host learns from fetching this
+    one's block, and the engine reads the block one dispatch late
+    (``ServeEngine._decode_step``).  A token the host has not seen may
+    already end its request (EOS as the first token, a budget of one):
+    the initial mask adds those rules on the device, as the persistent
+    loop's does.
     ``extra`` is empty for the contiguous slot cache; the PAGED engine
     passes its page tables there — scan-invariant (a request's full
     page-aligned footprint is allocated at admission, so no chunk ever
@@ -266,8 +305,8 @@ def _make_fused_decode(
     With ``numerics=True`` (the engine's numerics observatory) each scan
     step runs under a declared-site tape and the merged
     ``{site: digest}`` dict rides the carry, returned as one extra
-    trailing output — same dispatch, same sync, one more (tiny) fetched
-    leaf.  ``numerics=False`` traces the exact pre-observatory program.
+    trailing output — same dispatch, one more (tiny) fetched leaf.
+    ``numerics=False`` traces the program without it.
 
     With ``moe_counts=True`` (a model whose expert layers record under
     ``nn.moe.moe_count_tape``; without ``numerics``) the K steps' rows
@@ -280,11 +319,25 @@ def _make_fused_decode(
         model, sampler, eos_token=eos_token, max_len=max_len
     )
 
-    def run(params, kv, state, *extra):
+    def run(params, kv, carry, firsts, state, *extra):
+        source = state[SLOT_STATE_ROWS]
         toks, positions, temps, seeds, steps, budgets, finished = (
-            _unpack_slot_state(state)
+            _unpack_slot_state(
+                jnp.where(source != KEEP_CARRY, state[:SLOT_STATE_ROWS], carry)
+            )
         )
+        toks = jnp.where(source == FIRST_ON_DEVICE, firsts, toks)
+        finished = finished | (steps >= budgets)
+        if eos_token is not None:
+            finished = finished | (toks == eos_token)
         init = (kv, toks, positions, steps, finished)
+
+        def out(last, toks_block, *more):
+            return (
+                last[0], toks_block,
+                _pack_carry(last, temps, seeds, budgets), *more,
+            )
+
         if moe_counts and not numerics:
             from .nn.moe import moe_count_tape, tape_totals
 
@@ -293,19 +346,19 @@ def _make_fused_decode(
                     carry = step(params, temps, seeds, budgets, extra, carry)
                 return carry, (carry[1], tape_totals(tape))
 
-            (kv, _, _, _, _), (toks_block, counts) = jax.lax.scan(
+            last, (toks_block, counts) = jax.lax.scan(
                 body, init, None, length=decode_chunk
             )
-            return kv, toks_block, jnp.sum(counts, axis=0)
+            return out(last, toks_block, jnp.sum(counts, axis=0))
         if not numerics:
             def body(carry, _):
                 carry = step(params, temps, seeds, budgets, extra, carry)
                 return carry, carry[1]  # emit new_tok
 
-            (kv, _, _, _, _), toks_block = jax.lax.scan(
+            last, toks_block = jax.lax.scan(
                 body, init, None, length=decode_chunk
             )
-            return kv, toks_block
+            return out(last, toks_block)
 
         def body(carry, _):
             inner, digs = carry
@@ -314,10 +367,10 @@ def _make_fused_decode(
             digs = merge_digest_trees(digs, tape.digests())
             return (inner, digs), inner[1]  # emit new_tok
 
-        (inner, digs), toks_block = jax.lax.scan(
+        (last, digs), toks_block = jax.lax.scan(
             body, (init, _zero_site_digests()), None, length=decode_chunk
         )
-        return inner[0], toks_block, digs
+        return out(last, toks_block, digs)
 
     return run
 

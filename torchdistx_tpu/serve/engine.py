@@ -55,13 +55,30 @@ transfer, except the persistent loop's, whose state goes to the device
 first so that deferred first tokens can be spliced into it there.  A
 prefill's are the padded prompt and NumPy scalars with their dtypes
 written out.  Every array handed over is fresh or a copy: the host
-mirrors are written again in ``serve/harvest``.
+mirrors are written again in ``serve/harvest``.  Two arguments are no
+transfer, because they only ever travel from one program to the next on
+the device: the fused one-token program's final carry, which the next
+dispatch starts from, and the vector every prefill program writes its
+sampled token into.
+
+**Tokens are read one dispatch late** where that carry exists and
+prefills are whole (``ServeEngine._lags``: what every default gives):
+``step()`` k issues decode ``D(k)`` behind ``D(k-1)``, which is still
+running, and only then fetches and walks ``D(k-1)``'s block, over the
+requests and slots ``D(k-1)`` was dispatched with.  The host overrides
+the carry only for the slots it changed since (one more row of the
+packed state).  ``step``'s docstring says what a caller sees;
+``_settle()`` (fetch and walk whatever is in flight) is what ``drain``,
+``migrate_to`` / ``handoff_to``, ``step_prefill`` and the end of ``run``
+call before they read the mirrors or the cache.  Every other engine
+settles right after each dispatch: the same step.
 
 **The step path is written once**, whatever the options: one
 ``_decode_step`` (``serve/decode_args`` → ``serve/decode`` →
 ``serve/harvest``), for which the four decode programs differ by a row
 of ``_DECODE_VARIANTS`` (the builder, the outputs fetched, how the fetch
-reads as tokens and counts per iteration and slot), and one
+reads as tokens and counts per iteration and slot, whether a carry comes
+back), and one
 ``_dispatch_prefill`` (``serve/prefill`` per chunk), for which whole
 against chunked is the length of a chunk list and slab against paged,
 cold against warm, is ``_prefill_call``'s choice of program and
@@ -119,7 +136,7 @@ import contextlib
 import functools
 import time
 from collections import deque
-from typing import Any, Iterable, List, Optional, Sequence, Union
+from typing import Any, Iterable, List, NamedTuple, Optional, Sequence, Union
 
 import numpy as np
 
@@ -139,6 +156,9 @@ from ..obs.numerics import (
 from ..obs.trace import get_tracer, request_trace_events
 
 from ..generation import (
+    FIRST_ON_DEVICE,
+    FROM_HOST,
+    SLOT_STATE_ROWS,
     _NUMERICS_SITES,
     _cached_jit,
     _check_sampling_args,
@@ -276,6 +296,15 @@ def _cache_sharding(
 _SLAB_ONLY = {LATENT: "a latent cache", STATE: "recurrent state"}
 
 
+def _first(firsts, slot, tok) -> tuple:
+    """The tail of every prefill program's outputs: the sampled token,
+    and ``firsts`` -- the ``(num_slots,)`` vector of the slots' first
+    tokens, which lives on the device -- with it written in.  A decode
+    dispatch issued before the host has fetched the token takes it from
+    there (``generation.FIRST_ON_DEVICE``)."""
+    return tok[0], firsts.at[slot].set(tok[0])
+
+
 def _default_buckets(max_len: int) -> tuple:
     """Powers of two from 16 up to (and covering) ``max_len``."""
     buckets = []
@@ -290,8 +319,11 @@ def _default_buckets(max_len: int) -> tuple:
 # What differs between the four decode programs once ``_decode_args`` has
 # built their arguments, as data ``ServeEngine._decode_step`` reads: the
 # builder's name, how many outputs after the KV carry the host fetches,
-# and how that fetch reads as ``(tokens[iteration, slot, lane],
-# count[iteration, slot], iterations)`` — the one shape the walk knows.
+# how that fetch reads as ``(tokens[iteration, slot, lane],
+# count[iteration, slot], iterations)`` — the one shape the walk knows —
+# and whether the program returns its final per-slot carry for the next
+# dispatch to start from, so that the host may read a dispatch's tokens
+# one dispatch late (``ServeEngine._lags`` has the rest of that rule).
 # A fused program ran every row of its block (``decode_chunk`` of them); a
 # persistent loop reports how many it ran, the drained ring's cursor.  A
 # step makes views only, nothing sized ``slots x lanes``: this runs after
@@ -321,11 +353,24 @@ def _read_spec_ring(ring, counts, cursor):
 
 
 _DECODE_VARIANTS = {  # by (persistent, speculative)
-    (False, False): ("_decode_program", 1, _read_block),
-    (True, False): ("_persistent_program", 3, _read_ring),
-    (False, True): ("_spec_decode_program", 2, _read_spec_blocks),
-    (True, True): ("_spec_persistent_program", 3, _read_spec_ring),
+    (False, False): ("_decode_program", 1, _read_block, True),
+    # loops on the device until its slots finish: its drain is the sync
+    # it exists to make rare, and there is no next dispatch to hide it
+    (True, False): ("_persistent_program", 3, _read_ring, False),
+    # drafts from ``_hist``, a host mirror copied into every dispatch:
+    # the next dispatch needs what the walk of this one writes there
+    (False, True): ("_spec_decode_program", 2, _read_spec_blocks, False),
+    (True, True): ("_spec_persistent_program", 3, _read_spec_ring, False),
 }
+
+
+class _Flight(NamedTuple):
+    """A decode dispatch whose outputs the host has not read."""
+
+    outputs: tuple  # the device arrays the row's reader takes
+    riders: list  # ``(request, slot)`` as they were when it was dispatched
+    iterations: Optional[int]  # a fused scan's; a loop reports its own
+    digests: Any  # the numerics observatory's output, or None
 
 
 class ServeEngine:
@@ -778,10 +823,41 @@ class ServeEngine:
             0.0 if self.kv_quantized else None,
         )
         self._sampler = _make_slot_sampler(jnp.int32, top_k, top_p)
-        # persistent mode: prefill defers its first-token fetch — the
-        # device scalar parks here (slot -> 0-d array) and materializes
-        # with the next ring drain's single sync
+        # a prefill's first token parks here (slot -> 0-d device array,
+        # in admission order) where its fetch is deferred: to the next
+        # ring drain's single sync in persistent mode, to the end of the
+        # step that admitted on a row that lags (``_first_tokens``), which
+        # also keeps the dispatch's host seconds until then
         self._pending_first: dict = {}
+        self._prefill_host_s: dict = {}
+        # THE LAG.  Whether this engine reads a decode dispatch's tokens
+        # one dispatch late: the row of ``_DECODE_VARIANTS`` has to return
+        # its carry, and prefills must be whole -- a chunked prefill runs
+        # decode dispatches INSIDE an admission (``_interleave_decode``)
+        # around a slot parked by the host, each of which would have to
+        # be read before the next chunk's bookkeeping; those engines
+        # settle at once.  Nothing else decides it: no option, no variable
+        self._lags = (
+            _DECODE_VARIANTS[self._persistent, bool(self.speculate)][3]
+            and self.chunked_prefill is None
+        )
+        self._in_flight: Optional[_Flight] = None
+        # what the fused one-token program starts a slot from
+        # (generation.KEEP_CARRY / FROM_HOST / FIRST_ON_DEVICE): the host
+        # marks the slots it changed since the last dispatch
+        self._source = np.zeros(self.num_slots, np.int32)
+        # the last dispatch's final carry and the vector the prefill
+        # programs write their sampled tokens into: device arrays that
+        # only ever travel from one program to the next
+        where = self._repl_sharding
+        if where is None:
+            where = jax.tree_util.tree_leaves(self.cache.kv)[0].sharding
+        self._carry = jax.device_put(
+            np.zeros((SLOT_STATE_ROWS, self.num_slots), np.int32), where
+        )
+        self._firsts = jax.device_put(
+            np.zeros(self.num_slots, np.int32), where
+        )
         # streamed-tail host sink: (monotonic_ts, tokens, live, cursor)
         # per loop iteration, consumed (and cleared) at each drain
         self._stream_events: list = []
@@ -936,24 +1012,68 @@ class ServeEngine:
         free slots (one prefill dispatch each), then run ONE fused decode
         dispatch — ``decode_chunk`` on-device steps — over every slot.
         Admission therefore lands exactly at chunk boundaries, and
-        running-request deadlines are checked once per chunk (a deadline
-        can overshoot by at most one chunk's wall time).  Returns the
-        number of unfinished requests (queued + running)."""
+        running-request deadlines are checked once per chunk.  Returns
+        the number of unfinished requests (queued + running).
+
+        **When a step's tokens become visible.**  On the fused one-token
+        program with whole prefills (``_lags``: the default engine, slab
+        or paged) a decode dispatch's tokens are read one dispatch late:
+        this step issues its dispatch ``D(k)`` behind the previous
+        step's ``D(k-1)``, which is still running, and only then fetches
+        and walks ``D(k-1)``'s block, so the device always has the next
+        dispatch queued while the host works.  The tokens of the dispatch
+        a ``step()`` issues are in ``Request.generated`` at the end of
+        the FOLLOWING ``step()``; a request's first token at the end of
+        the step that admitted it; a finish one step after the device
+        froze the slot (the slot rides that one dispatch frozen,
+        ``lagged_slot_steps``), so a running request's deadline can
+        overshoot by two chunks' wall time, not one.  Whoever needs the
+        host mirrors and the cache to agree with the device — ``drain``,
+        ``migrate_to``, ``handoff_to``, the end of ``run`` — calls
+        ``_settle()`` first; ``finished_requests()`` and the metrics
+        report what the host has seen.  Every other engine (persistent,
+        speculative, ``chunked_prefill``) reads each dispatch at once:
+        the same step, settled right after its dispatch."""
         with self._phase("schedule"):
             self._schedule("step")
-            if not self.scheduler.running:
+            pause = not self.scheduler.running or self._in_flight_ends_all()
+            if pause and self._in_flight is None:
                 self._observe_gauges()  # nothing to decode: the tick ends
-                return self.scheduler.queue_depth
-        # ends in ``serve/harvest``, which observes the gauges
-        self._decode_step()
+        if pause:
+            # nothing to issue: whatever is in flight is read now, so that
+            # an idle engine has nothing queued when the next request comes
+            self._settle()
+        else:
+            # ends in ``serve/harvest``, which observes the gauges
+            self._decode_step()
         return self.scheduler.queue_depth + len(self.scheduler.running)
 
-    def _phase(self, name: str):
+    def _in_flight_ends_all(self) -> bool:
+        """Whether the host can prove, from its own counts, that the
+        dispatch in flight is the last one every running request needs:
+        each rode it and exhausts its budget inside it (an EOS cannot be
+        foreseen; a request admitted since has not ridden it).  Another
+        dispatch would only carry frozen slots."""
+        flight = self._in_flight
+        if flight is None:
+            return False
+        running = self.scheduler.running
+        if any(
+            self._ntok[req.slot] + flight.iterations < self._budget[req.slot]
+            for req in running
+        ):
+            return False  # in steady state the first request says so
+        riders = {id(req) for req, _ in flight.riders}
+        return all(id(req) in riders for req in running)
+
+    def _phase(self, name: str, sink=None):
         """One phase of a tick: the span ``serve/<name>`` (in any
         profile, and on the host tracer when enabled) with its host
-        seconds recorded into the ``<name>_s`` histogram."""
+        seconds recorded into the ``<name>_s`` histogram, or handed to
+        ``sink`` where the record is made later."""
         return timed_annotation(
-            f"serve/{name}", getattr(self.metrics, f"{name}_s").record
+            f"serve/{name}",
+            sink or getattr(self.metrics, f"{name}_s").record,
         )
 
     def _schedule(self, tick_kind: str) -> None:
@@ -1005,6 +1125,7 @@ class ServeEngine:
         with self._phase("schedule"):
             self._schedule("step_prefill")
             self._observe_gauges()
+        self._settle()  # the first tokens, where their fetch was deferred
         return self.scheduler.queue_depth + len(self.scheduler.running)
 
     def run(
@@ -1022,6 +1143,7 @@ class ServeEngine:
                 handles.append(self.submit(r, max_new_tokens=max_new_tokens))
         while self.step():
             pass
+        self._settle()  # an EOS finish leaves its successor in flight
         return [h.result() for h in handles]
 
     # -- session black box (obs/blackbox.py) -----------------------------
@@ -1147,6 +1269,7 @@ class ServeEngine:
 
     def _drain_impl(self, *, complete: bool) -> int:
         self._draining = True
+        self._settle()  # the suspended state is what the device holds
         now = time.monotonic()
         # the queued head learns WHY it stopped moving right away — not
         # at some later step(), and regardless of whether a slot is free
@@ -1195,6 +1318,7 @@ class ServeEngine:
         if target is self:
             raise ValueError("cannot migrate an engine into itself")
         self._refuse_state_move("migrate_to", target)
+        self._settle()  # a drained engine may have been stepped since
         if target._draining:
             raise RuntimeError(
                 "migration target is itself draining — migrate to a "
@@ -1306,7 +1430,13 @@ class ServeEngine:
         :meth:`handoff_to` (per-request prefill->decode disaggregation);
         the caller has validated capacity.  Returns
         ``(src_slot, dst_slot, wire_bytes, collectives, pages_moved)``.
+
+        The source is settled first (its mirrors and cache are then the
+        device's); the target need not be: its dispatch in flight did not
+        carry the request, and its next one starts both slots from the
+        host's columns (``_source``).
         """
+        self._settle()
         s_a = req.slot
         pos_a = int(self.cache.pos[s_a])
         pages_a = list(req.pages) if (self.paged and req.pages) else None
@@ -1338,6 +1468,7 @@ class ServeEngine:
             (self._hist, target._hist),
         ):
             arr_b[s_b] = arr_a[s_a]
+        self._source[s_a] = target._source[s_b] = FROM_HOST
         return s_a, s_b, w, c, len(pages_a) if pages_a is not None else 0
 
     def handoff_to(self, target: "ServeEngine", req: Request) -> dict:
@@ -1360,6 +1491,7 @@ class ServeEngine:
         if target is self:
             raise ValueError("cannot hand a request off to its own engine")
         self._refuse_state_move("handoff_to", target)
+        self._settle()  # the request may have finished on the device
         if target._draining:
             raise RuntimeError(
                 "handoff target is draining — hand off to a live engine"
@@ -1753,7 +1885,7 @@ class ServeEngine:
         # the sampled one only: no (bucket, vocab) array in the program
         one_row = bool(getattr(model, "prefill_logits_at", False))
 
-        def build(params, kv, tokens, true_len, slot, temp, seed):
+        def build(params, kv, firsts, tokens, true_len, slot, temp, seed):
             def body():
                 slab = model.init_cache(1, bucket)
                 logits, slab = functional_call(
@@ -1767,7 +1899,7 @@ class ServeEngine:
                     )
                 last = tap("logits", logits[:, 0, :])
                 tok = sampler(last, temp, seed, jnp.zeros((1,), jnp.int32))
-                return write_slot(kv, slab, slot), tok[0]
+                return write_slot(kv, slab, slot), *_first(firsts, slot, tok)
 
             if moe_counts:
                 with moe_count_tape() as tape:
@@ -1792,7 +1924,7 @@ class ServeEngine:
             ("serve_prefill", bucket) + self._static_key(),
             build,
             donate_argnums=(1,),
-            out_shardings=self._out_shardings(1),
+            out_shardings=self._out_shardings(2),
         )
 
     def _prefill_warm_program(self, bucket: int):
@@ -1808,7 +1940,8 @@ class ServeEngine:
         model, sampler, max_len = self.model, self._sampler, self.max_len
         num_on, kv_heads = self.numerics, self.cache.kv_heads
 
-        def build(params, kv, tokens, cache_pos, true_len, slot, temp, seed):
+        def build(params, kv, firsts, tokens, cache_pos, true_len, slot,
+                  temp, seed):
             def body():
                 def row(c):
                     return jax.lax.dynamic_slice(
@@ -1832,7 +1965,7 @@ class ServeEngine:
                     logits, true_len - 1, 1, axis=1
                 )[:, 0, :])
                 tok = sampler(last, temp, seed, jnp.zeros((1,), jnp.int32))
-                return write_slot(kv, view, slot), tok[0]
+                return write_slot(kv, view, slot), *_first(firsts, slot, tok)
 
             return _taped(num_on, body)
 
@@ -1842,7 +1975,7 @@ class ServeEngine:
             ("serve_prefill_warm", bucket) + self._static_key(),
             build,
             donate_argnums=(1,),
-            out_shardings=self._out_shardings(1),
+            out_shardings=self._out_shardings(2),
         )
 
     def _paged_prefill_program(self, bucket: int, warm: bool):
@@ -1863,8 +1996,8 @@ class ServeEngine:
         model, sampler, ps = self.model, self._sampler, self.page_size
         num_on, kv_heads = self.numerics, self.cache.kv_heads
 
-        def build_warm(params, kv, pt_row, tokens, pfx_len, true_len,
-                       temp, seed):
+        def build_warm(params, kv, firsts, pt_row, tokens, pfx_len, true_len,
+                       slot, temp, seed):
             def body():
                 view = paged_view(kv, pt_row, kv_heads)
                 logits, view = functional_call(
@@ -1878,11 +2011,12 @@ class ServeEngine:
                 out = paged_scatter_rows(
                     kv, view, pt_row, ps, pfx_len, bucket
                 )
-                return out, tok[0]
+                return out, *_first(firsts, slot, tok)
 
             return _taped(num_on, body)
 
-        def build_cold(params, kv, pt_row, tokens, true_len, temp, seed):
+        def build_cold(params, kv, firsts, pt_row, tokens, true_len, slot,
+                       temp, seed):
             def body():
                 view = paged_view(kv, pt_row, kv_heads)
                 logits, view = functional_call(
@@ -1896,7 +2030,7 @@ class ServeEngine:
                 out = paged_scatter_rows(
                     kv, view, pt_row, ps, jnp.int32(0), bucket
                 )
-                return out, tok[0]
+                return out, *_first(firsts, slot, tok)
 
             return _taped(num_on, body)
 
@@ -1907,7 +2041,7 @@ class ServeEngine:
             ("serve_prefill_paged", bucket, warm) + self._static_key(),
             build_warm if warm else build_cold,
             donate_argnums=(1,),
-            out_shardings=self._out_shardings(1),
+            out_shardings=self._out_shardings(2),
         )
 
     def _decode_program(self):
@@ -1935,7 +2069,7 @@ class ServeEngine:
             + self._static_key(),
             build,
             donate_argnums=(1,),  # kv slab: same aliasing as prefill
-            out_shardings=self._out_shardings(1),
+            out_shardings=self._out_shardings(2),  # the block, the carry
         )
 
     def _persistent_program(self):
@@ -2287,11 +2421,13 @@ class ServeEngine:
         self.metrics.queue_wait_s.record(
             (req.admitted_at or now) - req.submitted_at
         )
-        if self._persistent:
+        self._source[slot] = FIRST_ON_DEVICE
+        if self._persistent or self._lags:
             # NO host sync here: the device scalar parks until the next
-            # ring drain (the loop program recomputes the finish bit
-            # on-device, so an EOS/instantly-over-budget first token
-            # still freezes its slot before iteration 0)
+            # ring drain, or the end of this step on a row that lags (the
+            # program recomputes the finish bit on-device, so an EOS or
+            # instantly-over-budget first token still freezes its slot
+            # before iteration 0)
             self._pending_first[slot] = tok
             return
         self.metrics.count("host_syncs")  # the dispatch's token fetch
@@ -2338,8 +2474,9 @@ class ServeEngine:
     def _dispatch_prefill(self, req: Request, slot: int):
         """The prefill of one admitted request, whole or in chunks, slab
         or paged: one dispatch per chunk, written once.  Returns the
-        first token (a device scalar in persistent mode, which defers
-        the fetch to the drain).
+        first token (a device scalar where its fetch is deferred: to the
+        drain in persistent mode, to ``_first_tokens`` on a row that
+        lags, whose ``prefill_s`` record waits with it).
 
         A paged engine consumes the admission gate's page reservation:
         it points the slot's table at the chain, prefills ONLY the
@@ -2395,19 +2532,27 @@ class ServeEngine:
                 req, slot, start, ln, padded
             )
             self._ensure_card(name, program, args)
-            with self._phase("prefill"), self._watch(name):
+            # a row that lags keeps the seconds: the prefill's one record
+            # is made when its token arrives (``_first_tokens``)
+            sink = (
+                functools.partial(self._prefill_host_s.__setitem__, slot)
+                if self._lags else None
+            )
+            with self._phase("prefill", sink), self._watch(name):
                 out = program(*args)
                 # rebind BEFORE the host sync: the dispatch donated the
                 # old slab (or pools), so if the sync raises the engine
                 # must already hold the live output, not a deleted buffer
-                self.cache.kv, tok = out[0], out[1]
+                self.cache.kv, tok, self._firsts = out[:3]
                 if self.numerics:
                     self._pending_digests.append(out[-1])
                 if self._moe_counts:
                     # the cold slab program's; an expert model is served
                     # through no other (the constructor's refusals)
-                    self.metrics.add_device_counts("prefill", out[2])
-                if i == len(chunks) - 1 and not self._persistent:
+                    self.metrics.add_device_counts("prefill", out[3])
+                if i == len(chunks) - 1 and not (
+                    self._persistent or self._lags
+                ):
                     tok = int(np.asarray(tok))  # host sync: first token exists
             # only what was computed: a prefix hit's tokens are not
             self.metrics.count("tokens_prefilled", bucket)
@@ -2435,13 +2580,16 @@ class ServeEngine:
             program = self._paged_prefill_program(bucket, warm=warm)
             name = f"serve/prefill/{'warm' if warm else 'cold'}/b{bucket}"
             row = self.cache.page_tables[slot].copy()
-            where = (row, padded, *at, np.int32(ln))
+            where = (row, padded, *at, np.int32(ln), np.int32(slot))
         else:
             build = self._prefill_warm_program if warm else self._prefill_program
             program = build(bucket)
             name = f"serve/prefill/{'warm/' if warm else ''}b{bucket}"
             where = (padded, *at, np.int32(ln), np.int32(slot))
-        args = (self.params, self.cache.kv, *where, *self._sampling_args(req))
+        args = (
+            self.params, self.cache.kv, self._firsts, *where,
+            *self._sampling_args(req),
+        )
         return program, name, args
 
     def _adopt_prefix(self, req: Request) -> None:
@@ -2461,7 +2609,8 @@ class ServeEngine:
     def _decode_args(self) -> tuple:
         """The argument list of a decode dispatch, written once for the
         four decode programs (fused / persistent, each plain or
-        speculative): ``(params, kv, state[, hist][, page_tables])``.
+        speculative): ``(params, kv, [carry, firsts,] state[, hist][,
+        page_tables])``.
 
         ``state`` is the per-slot state packed into ONE host array
         (``generation.pack_slot_state``), and it crosses to the device
@@ -2472,8 +2621,22 @@ class ServeEngine:
         handed over is one nothing writes afterwards — the packed state
         is fresh, and ``_hist`` and the page tables, live mirrors written
         again in ``serve/harvest`` and at admission, go as copies — so no
-        ordering of transfer and bookkeeping is relied on."""
+        ordering of transfer and bookkeeping is relied on.
+
+        The fused one-token program also takes the last dispatch's final
+        carry and the prefills' first tokens, device arrays both, and
+        ``state`` gains the row that says which slots start from the
+        host's column (``_source``: admitted, expired, moved since the
+        last dispatch).  With nothing in flight the mirrors ARE the
+        device's state and every slot starts from them: the same program,
+        the same argument types."""
         cache = self.cache
+        carries = _DECODE_VARIANTS[self._persistent, bool(self.speculate)][3]
+        source = None
+        if carries:
+            source, self._source = self._source, np.zeros_like(self._source)
+            if self._in_flight is None:
+                source = np.maximum(source, FROM_HOST)
         if self._persistent:
             # the ACTIVE mask carries the cache-full rule: positions() is
             # clamped to max_len - 1, so the room check must come from
@@ -2490,6 +2653,7 @@ class ServeEngine:
             self._ntok,
             self._budget,
             mask,
+            source,
         )
         if self._persistent:
             # freshly prefilled slots: their first token exists only on
@@ -2505,7 +2669,10 @@ class ServeEngine:
             state = jnp.asarray(state)
             for slot, dev_tok in self._pending_first.items():
                 state = state.at[0, jnp.asarray(slot, jnp.int32)].set(dev_tok)
-        args = [self.params, cache.kv, state]
+        args = [self.params, cache.kv]
+        if carries:
+            args += [self._carry, self._firsts]
+        args.append(state)
         if self.speculate:
             args.append(self._hist.copy())
         if self.paged:
@@ -2513,7 +2680,9 @@ class ServeEngine:
             # admit/retire, and only there: pages are freed or reallocated
             # at chunk and drain boundaries, so it is invariant within a
             # dispatch and no frozen in-loop write can land on a page this
-            # table doesn't own
+            # table doesn't own (a page freed while the dispatch that still
+            # names it is in flight is written again only by programs
+            # queued behind that dispatch)
             args.append(cache.page_tables.copy())
         return tuple(args)
 
@@ -2521,6 +2690,21 @@ class ServeEngine:
         """One decode dispatch, ONE host sync, one walk — whichever of
         the four decode programs this engine runs (``_DECODE_VARIANTS``:
         fused or persistent, each one-token or speculative).
+
+        **The order.**  ``serve/decode_args``: the arguments, and the
+        requests and slots the dispatch carries, as they are now (its
+        ``riders``).  ``serve/decode``: the dispatch ``D(k)``, then the
+        fetch of a token block — ``D(k)``'s own, or on an engine that
+        lags (``_lags``) that of ``D(k-1)``, which has been running since
+        the previous step while the host walked, returned, was observed,
+        scheduled and packed.  Then the step's first tokens
+        (``_first_tokens``) and ``serve/harvest``: the walk of the block
+        just fetched, over ITS riders — never over ``scheduler.running``
+        as it is now: a slot freed by the last walk and admitted again
+        since holds, in the block of the dispatch that was in flight, its
+        previous tenant's frozen token.  Reading at once is this same
+        order with the dispatch settled right after it is issued;
+        ``_settle()`` is the second half alone.
 
         The fused programs run ``decode_chunk`` on-device iterations;
         the persistent ones loop on-device until every slot's finish bit
@@ -2543,8 +2727,20 @@ class ServeEngine:
         never adds one: ``host_syncs == ring_drains`` either way."""
         persistent, spec = self._persistent, self.speculate
         with self._phase("decode_args"):
-            running = self.scheduler.running
-            builder, n_out, read = _DECODE_VARIANTS[persistent, bool(spec)]
+            builder, n_out, _, carries = _DECODE_VARIANTS[
+                persistent, bool(spec)
+            ]
+            # not yet cache-admitted: the mid-chunked-prefill request
+            # itself (parked, device-frozen) or, fused, a same-batch
+            # admit an interleaved dispatch ran ahead of — their tokens
+            # start at their own prefill, not here (a persistent one's
+            # ride this drain: its first token)
+            active = self.cache.active
+            riders = [
+                (req, req.slot)
+                for req in self.scheduler.running
+                if req is not skip and (persistent or active[req.slot])
+            ]
             program = getattr(self, builder)()
             args = self._decode_args()
             self._stream_events.clear()  # the streamed tail's, if any
@@ -2558,131 +2754,215 @@ class ServeEngine:
         with self._phase("decode"), self._watch(name):
             out = program(*args)
             self.cache.kv = out[0]  # before the sync: old slab was donated
-            if self.numerics:
-                self._pending_digests.append(out[-1])
+            rest = 1 + n_out  # what follows the fetched outputs
+            if carries:
+                self._carry = out[rest]
+                rest += 1
             if self._moe_counts:
-                # after the fetched outputs; the fused one-token program's
-                # alone (the constructor's refusals)
-                self.metrics.add_device_counts("decode", out[1 + n_out])
-            # ONE host sync per dispatch: the program's token outputs and
-            # every pending first token together.  The first read waits
-            # for the program; the other copies are in flight behind that
-            # wait (``device_get`` does the same under a tree walk that
-            # costs the one-output programs 16 us more than this)
-            pending = self._pending_first
-            leaves = (*out[1 : 1 + n_out], *pending.values())
-            for i in range(1, len(leaves)):
-                leaves[i].copy_to_host_async()
-            host = [np.asarray(x) for x in leaves]
-            fetched, firsts = host[:n_out], dict(zip(pending, host[n_out:]))
-        with self._phase("harvest"):
-            # drop this dispatch's device handles here, inside the phase:
-            # left to the frame's teardown they are freed after it, in
-            # nobody's span (the outputs; the arguments are host arrays)
-            del args, out, leaves
-            tokens, count, n_it = read(*fetched)
-            self._pending_first.clear()
-            self.metrics.count("host_syncs")
-            self._harvest_numerics()
-            self.metrics.count("decode_dispatches")
-            self.metrics.count("decode_steps", n_it)
-            self._record_tp_collectives(self.num_slots * (spec + 1), n_it)
-            if persistent:
-                self.metrics.count("ring_drains")
-                self.metrics.count("loop_iterations", n_it)
-                self.metrics.observe_ring(n_it)
-            now = time.monotonic()
-            # streamed tail (opt-in): the iteration-0 callback timestamp is
-            # when the wave's first tokens actually existed host-side —
-            # tighter than the drain time for first-token latency
-            first_ts = now
-            if self._stream_events:
-                first_ts = min(now, self._stream_events[0][0])
-            emitted = 0
-            any_cut = False
-            for req in running:
-                if req is skip or not (
-                    persistent or self.cache.active[req.slot]
+                # the fused one-token program's alone (the constructor's
+                # refusals)
+                self.metrics.add_device_counts("decode", out[rest])
+            due = _Flight(
+                out[1 : 1 + n_out],
+                riders,
+                None if persistent else self.decode_chunk,
+                out[-1] if self.numerics else None,
+            )
+            if self._lags:  # read the predecessor's block, not this one's
+                due, self._in_flight = self._in_flight, due
+                if due is not None:
+                    self.metrics.count("lagged_dispatches")
+            # drop the dispatch's handles: the arguments are host arrays
+            # but for the old carry, the outputs live on in the flight
+            del args, out
+            fetched = self._fetch(due)
+        self._land(due, fetched)
+
+    def _settle(self) -> None:
+        """Fetch and walk whatever is in flight — a decode dispatch's
+        block, first tokens whose fetch was deferred — so that the host
+        mirrors, the requests and the cache say what the device holds.
+        ``drain``, ``migrate_to`` / ``handoff_to`` (``_move_running``),
+        ``step_prefill`` and the end of ``run`` call it before they read
+        any of those; ``step`` calls it when it has nothing to issue.
+        ``finished_requests()`` and the metrics do not: they report what
+        the host has seen.  With nothing in flight it does nothing."""
+        due, self._in_flight = self._in_flight, None
+        if due is None and not (self._lags and self._pending_first):
+            return
+        fetched = None
+        if due is not None:
+            with self._phase("decode"):
+                fetched = self._fetch(due)
+        self._land(due, fetched)
+
+    def _fetch(self, flight: Optional[_Flight]):
+        """THE host sync of a decode dispatch: its token outputs and, in
+        persistent mode, every pending first token together, as ``(host
+        arrays, {slot: first token})``.  The first read waits for the
+        program; the other copies are in flight behind that wait
+        (``device_get`` does the same under a tree walk that costs the
+        one-output programs 16 us more than this).  On an engine that
+        lags the program has usually ended by now."""
+        if flight is None:
+            return None  # the first dispatch after a settle: nothing due
+        pending = self._pending_first if self._persistent else {}
+        leaves = (*flight.outputs, *pending.values())
+        for i in range(1, len(leaves)):
+            leaves[i].copy_to_host_async()
+        host = [np.asarray(x) for x in leaves]
+        self.metrics.count("host_syncs")
+        if flight.digests is not None:
+            self._pending_digests.append(flight.digests)
+        n_out = len(flight.outputs)
+        return host[:n_out], dict(zip(pending, host[n_out:]))
+
+    def _first_tokens(self) -> None:
+        """On an engine that lags, the first tokens of the requests this
+        step admitted, fetched in admission order once the step's decode
+        dispatch is queued behind their prefills.  A step that admitted
+        returns only when they are on the host: time to first token stays
+        a decode step plus a prefill.
+
+        Each wait sits in a ``serve/schedule`` > ``serve/prefill`` span
+        pair and completes the prefill's ONE ``prefill_s`` record: its
+        dispatch's host seconds plus this wait, which runs from the
+        arrival of the previous result to the arrival of its token (its
+        device time).  ``schedule_s.total - prefill_s.total`` stays the
+        host's own scheduling time."""
+        if not (self._lags and self._pending_first):
+            return
+        with self._phase("schedule"):
+            by_slot = {req.slot: req for req in self.scheduler.running}
+            while self._pending_first:
+                slot = next(iter(self._pending_first))
+                dev_tok = self._pending_first.pop(slot)
+                held = self._prefill_host_s.pop(slot)
+                with self._phase(
+                    "prefill",
+                    lambda s, held=held: self.metrics.prefill_s.record(
+                        held + s
+                    ),
                 ):
-                    # not yet cache-admitted: the mid-chunked-prefill request
-                    # itself (parked, device-frozen) or, fused, a same-batch
-                    # admit an interleaved dispatch ran ahead of — their
-                    # tokens start at their own prefill, not here (a
-                    # persistent one's ride this drain: ``firsts``)
-                    continue
-                slot = req.slot
-                taken = 0
-                finished = False
-                if slot in firsts:
-                    tok = int(firsts[slot])
-                    self._record_first(req, tok, first_ts)
-                    # finished here, the device's fin0 froze this slot
-                    # before iteration 0 (EOS first token / one-token
-                    # budget): it idled the whole loop
-                    finished = self._check_finished(req, tok, first_ts)
-                if not finished:
-                    for j in range(n_it):
-                        c = count.item(j, slot)
-                        if c == 0:
-                            break  # frozen from here on: rows are rewrites
-                        taken = j + 1
-                        if spec:
-                            # per live slot-iteration: spec lanes drafted,
-                            # c - 1 of them accepted, the rest of the
-                            # spec + 1 verify lanes spent on rejected
-                            # (overwritten-before-visible) positions
-                            self.metrics.count("draft_tokens_proposed", spec)
-                            self.metrics.count("draft_tokens_accepted", c - 1)
-                            self.metrics.count(
-                                "spec_rejected_lane_steps", (spec + 1) - c
-                            )
-                        # the iteration's block: one lane for the one-token
-                        # programs, the c accepted lanes of a speculative
-                        # one.  The device truncation rule puts any finish
-                        # on the block's LAST emitted token
-                        # (``generation._make_spec_decode_body``), so walk
-                        # and frozen carry agree token for token.  Scalars
-                        # are read straight out of the fetched arrays: this
-                        # runs per slot and step (``serve.harvest_ms_p50``)
-                        for i in range(c):
-                            tok = tokens.item(j, slot, i)
-                            self._ntok[slot] += 1
-                            self.cache.advance_slot(slot)
-                            self._last_tok[slot] = tok
-                            if spec:
-                                # post-advance, the slot's position IS the
-                                # token's stream index: the draft history's
-                                # row for it
-                                p = int(self.cache.pos[slot])
-                                if p < self.max_len:
-                                    self._hist[slot, p] = tok
-                            req.generated.append(tok)
-                            emitted += 1
-                            if self._check_finished(req, tok, now):
-                                finished = True
-                                break
-                        if finished:
-                            break
-                if finished:
-                    # the device froze this slot for the rest of the chunk
-                    # (or the loop ran on past it): those slot-iterations
-                    # bought nothing
-                    self.metrics.count("masked_slot_steps", n_it - taken)
-                else:
-                    any_cut = True  # this dispatch ended before the request
-                ev = ("decode_chunk", now, {"tokens": taken})
-                if req.events and req.events[-1][0] == "finish":
-                    # _check_finished logged the finish inside the loop; keep
-                    # the lifecycle log in causal order (chunk, then finish)
-                    req.events.insert(-1, ev)
-                else:
-                    req.events.append(ev)
-            if persistent and any_cut:
-                self.metrics.count("ring_full_drains")
-            self.metrics.count("tokens_generated", emitted)
-            self.metrics.count("tokens_decoded", emitted)
+                    tok = int(np.asarray(dev_tok))  # host sync
+                self.metrics.count("host_syncs")
+                now = time.monotonic()
+                req = by_slot[slot]
+                self._record_first(req, tok, now)
+                self._check_finished(req, tok, now)
             self._record_drain()
+
+    def _land(self, flight: Optional[_Flight], fetched) -> None:
+        """What follows a fetch: the step's first tokens, then
+        ``serve/harvest`` — the walk of the fetched block over the
+        dispatch's own riders, finishes, counters, the gauges."""
+        self._first_tokens()
+        with self._phase("harvest"):
+            if flight is not None:
+                self._walk(flight, *fetched)
+            self._harvest_numerics()
             self._observe_gauges()
+
+    def _walk(self, flight: _Flight, fetched, firsts) -> None:
+        persistent, spec = self._persistent, self.speculate
+        read = _DECODE_VARIANTS[persistent, bool(spec)][2]
+        tokens, count, n_it = read(*fetched)
+        self._pending_first.clear()  # the persistent loop's: ``firsts``
+        self.metrics.count("decode_dispatches")
+        self.metrics.count("decode_steps", n_it)
+        self._record_tp_collectives(self.num_slots * (spec + 1), n_it)
+        if persistent:
+            self.metrics.count("ring_drains")
+            self.metrics.count("loop_iterations", n_it)
+            self.metrics.observe_ring(n_it)
+        now = time.monotonic()
+        # streamed tail (opt-in): the iteration-0 callback timestamp is
+        # when the wave's first tokens actually existed host-side —
+        # tighter than the drain time for first-token latency
+        first_ts = now
+        if self._stream_events:
+            first_ts = min(now, self._stream_events[0][0])
+        emitted = 0
+        any_cut = False
+        for req, slot in flight.riders:
+            if req.finish_reason is not None:
+                # finished since the dispatch: by a deadline (the token
+                # is dropped with the rest), or by the walk before this
+                # one, a dispatch late — the device had frozen the slot
+                # and this block holds its last token again, which is
+                # NOT the next tenant's (``lagged_slot_steps``)
+                continue
+            taken = 0
+            finished = False
+            if slot in firsts:
+                tok = int(firsts[slot])
+                self._record_first(req, tok, first_ts)
+                # finished here, the device's fin0 froze this slot
+                # before iteration 0 (EOS first token / one-token
+                # budget): it idled the whole loop
+                finished = self._check_finished(req, tok, first_ts)
+            if not finished:
+                for j in range(n_it):
+                    c = count.item(j, slot)
+                    if c == 0:
+                        break  # frozen from here on: rows are rewrites
+                    taken = j + 1
+                    if spec:
+                        # per live slot-iteration: spec lanes drafted,
+                        # c - 1 of them accepted, the rest of the
+                        # spec + 1 verify lanes spent on rejected
+                        # (overwritten-before-visible) positions
+                        self.metrics.count("draft_tokens_proposed", spec)
+                        self.metrics.count("draft_tokens_accepted", c - 1)
+                        self.metrics.count(
+                            "spec_rejected_lane_steps", (spec + 1) - c
+                        )
+                    # the iteration's block: one lane for the one-token
+                    # programs, the c accepted lanes of a speculative
+                    # one.  The device truncation rule puts any finish
+                    # on the block's LAST emitted token
+                    # (``generation._make_spec_decode_body``), so walk
+                    # and frozen carry agree token for token.  Scalars
+                    # are read straight out of the fetched arrays: this
+                    # runs per slot and step (``serve.harvest_ms_p50``)
+                    for i in range(c):
+                        tok = tokens.item(j, slot, i)
+                        self._ntok[slot] += 1
+                        self.cache.advance_slot(slot)
+                        self._last_tok[slot] = tok
+                        if spec:
+                            # post-advance, the slot's position IS the
+                            # token's stream index: the draft history's
+                            # row for it
+                            p = int(self.cache.pos[slot])
+                            if p < self.max_len:
+                                self._hist[slot, p] = tok
+                        req.generated.append(tok)
+                        emitted += 1
+                        if self._check_finished(req, tok, now):
+                            finished = True
+                            break
+                    if finished:
+                        break
+            if finished:
+                # the device froze this slot for the rest of the chunk
+                # (or the loop ran on past it): those slot-iterations
+                # bought nothing
+                self.metrics.count("masked_slot_steps", n_it - taken)
+            else:
+                any_cut = True  # this dispatch ended before the request
+            ev = ("decode_chunk", now, {"tokens": taken})
+            if req.events and req.events[-1][0] == "finish":
+                # _check_finished logged the finish inside the loop; keep
+                # the lifecycle log in causal order (chunk, then finish)
+                req.events.insert(-1, ev)
+            else:
+                req.events.append(ev)
+        if persistent and any_cut:
+            self.metrics.count("ring_full_drains")
+        self.metrics.count("tokens_generated", emitted)
+        self.metrics.count("tokens_decoded", emitted)
+        self._record_drain()
 
     def _check_finished(self, req: Request, tok: int, now: float) -> bool:
         if self.eos_token is not None and tok == self.eos_token:
@@ -2709,6 +2989,17 @@ class ServeEngine:
             self.metrics.count("host_syncs")
             self._harvest_numerics()
             self._record_first(req, tok, now)
+            held = self._prefill_host_s.pop(slot, None)
+            if held is not None:
+                self.metrics.prefill_s.record(held)
+        flight = self._in_flight
+        if flight is not None and reason != "deadline":
+            # one of the device's own rules, seen a dispatch late: the
+            # dispatch in flight carries the slot frozen (a deadline is
+            # the host's: that slot's token is computed and dropped)
+            self.metrics.count("lagged_slot_steps", flight.iterations)
+            self.metrics.count("masked_slot_steps", flight.iterations)
+        self._source[slot] = FROM_HOST  # the next dispatch: finished
         self.scheduler.retire(req)
         self.cache.retire(slot)  # paged: also rewires the table to scratch
         if self.paged and req.pages is not None:

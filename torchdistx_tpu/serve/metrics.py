@@ -113,7 +113,14 @@ class ServeMetrics:
     token, THE number the fused decode loop exists to shrink),
     ``masked_slot_steps`` (slot-steps the on-device finish mask threw
     away because a request finished mid-chunk: the wasted-work side of
-    the host-sync tradeoff), the speculative-decoding set —
+    the host-sync tradeoff, or rode one more dispatch frozen because the
+    host reads its tokens a dispatch late), ``lagged_dispatches`` (decode
+    dispatches issued with their predecessor's tokens unread: every one
+    in steady state on an engine that lags, 0 on one that reads at once)
+    and ``lagged_slot_steps`` (slot-steps a dispatch spent on a slot
+    whose finish the host had not yet seen: one per request, the cost of
+    the lag; also in ``masked_slot_steps``), the speculative-decoding
+    set —
     ``draft_tokens_proposed`` (n-gram draft tokens offered to the
     verifier: ``speculate`` per live slot-iteration),
     ``draft_tokens_accepted`` (drafts that matched the verified greedy
@@ -295,6 +302,8 @@ class ServeMetrics:
             "decode_dispatches": 0,
             "host_syncs": 0,
             "masked_slot_steps": 0,
+            "lagged_dispatches": 0,
+            "lagged_slot_steps": 0,
             "draft_tokens_proposed": 0,
             "draft_tokens_accepted": 0,
             "spec_rejected_lane_steps": 0,
