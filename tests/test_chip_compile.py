@@ -592,3 +592,193 @@ def test_mqa_flash_prefill_compiles(one_chip):
         ((1, 1024, 1, D), jnp.bfloat16), ((1, 1024, 1, D), jnp.bfloat16),
     )
     assert _kernel_names(text) == [FLASH_FORWARD]
+
+
+# The Qwen3-Next family at Qwen3-Next-80B-A3B's widths (the serve cell's:
+# 128 slots of 4096; Gated DeltaNet 16 key / 32 value heads of 128, a
+# (128, 128) float32 state a head; attention 16 query on 2 KV heads of
+# 256; 128 of 512 experts of 2048 x 512 held, top 10).
+Q_SLOTS, Q_L, Q_HK, Q_HV, Q_D = 128, 4096, 16, 32, 128
+Q_HQ, Q_HKV, Q_HEAD = 16, 2, 256
+Q_HELD, Q_WIDTH, Q_F, Q_TOP = 128, 512, 512, 10
+
+
+@pytest.mark.parametrize("bucket", [512, 3072])
+def test_gated_delta_chunk_compiles(one_chip, bucket):
+    """``tdx_gated_delta_chunk`` at the published widths over the cell's
+    smallest and largest bucket: a head's (128, 128) state resident
+    across its chunks of 128 rows, the products in float32."""
+    from torchdistx_tpu.ops.gated_delta import gated_delta_chunk
+
+    def fn(q, k, v, g, beta, s0, true_len):
+        return gated_delta_chunk(
+            q, k, v, g, beta, s0, true_len[0], use_kernel=True, interpret=False
+        )
+
+    keys = ((1, bucket, Q_HK, Q_D), jnp.float32)
+    gates = ((1, bucket, Q_HV), jnp.float32)
+    text = _compile(
+        fn, one_chip, keys, keys, ((1, bucket, Q_HV, Q_D), jnp.bfloat16),
+        gates, gates, ((1, Q_HV, Q_D, Q_D), jnp.float32), ((1,), jnp.int32),
+    )
+    assert _kernel_names(text) == ["tdx_gated_delta_chunk"]
+    assert _has_grid(text, (1, Q_HV, bucket // 128))
+
+
+def test_gated_delta_update_compiles_and_leaves_the_state_in_place(one_chip):
+    """``tdx_gated_delta_update`` over 128 slots: the donated state is
+    the kernel's operand AND its result (``input_output_aliases``), so
+    nothing else in the program has a result of the state's size (268 MB
+    a layer: a copy would double a decode step's second memory stream)."""
+    from torchdistx_tpu.ops.gated_delta import gated_delta_update
+
+    def fn(s, q, k, v, g, beta):
+        return gated_delta_update(
+            s, q, k, v, g, beta, use_kernel=True, interpret=False
+        )
+
+    keys = ((Q_SLOTS, Q_HK, Q_D), jnp.float32)
+    gates = ((Q_SLOTS, Q_HV), jnp.float32)
+    text = _compile(
+        fn, one_chip, ((Q_SLOTS, Q_HV, Q_D, Q_D), jnp.float32), keys, keys,
+        ((Q_SLOTS, Q_HV, Q_D), jnp.bfloat16), gates, gates, donate=(0,),
+    )
+    assert _kernel_names(text) == ["tdx_gated_delta_update"]
+    assert _has_grid(text, (Q_SLOTS, Q_HV // 16))
+    others = [
+        f"{name} = {type_} {op}"
+        for _, name, op, type_, _ in _cache_sized(
+            text, Q_SLOTS * Q_HV * Q_D * Q_D)
+        if op not in PLUMBING and op != "custom-call"
+    ]
+    assert not others, others
+    assert "output_to_operand_aliasing" in text or "input_output_alias" in text
+
+
+def test_head_256_decode_step_leaves_the_cache_in_place(one_chip, monkeypatch):
+    """The Qwen3-Next cell's two attention layers: 16 query heads on 2 KV
+    heads of 256 (a group of 8, a 1024-byte row an array), 128 slots of
+    4096 — ``_blocking``'s rule at a head no other cell has."""
+    (chip,) = one_chip.device_set
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+    row = ((Q_SLOTS, 1, Q_HKV, Q_HEAD), jnp.bfloat16)
+    cache = ((Q_SLOTS, Q_L, Q_HKV * Q_HEAD), jnp.bfloat16)
+
+    def fn(q, k_new, v_new, positions, ck, cv):
+        return slot_cached_attention(
+            q, k_new, v_new, (ck, cv), positions, use_flash=True
+        )
+
+    text = _compile(
+        fn, one_chip, ((Q_SLOTS, 1, Q_HQ, Q_HEAD), jnp.bfloat16), row, row,
+        ((Q_SLOTS,), jnp.int32), cache, cache, donate=(4, 5),
+    )
+    assert _kernel_names(text) == ["tdx_decode_attention"]
+    offenders = _relayouts_of_the_cache(text, Q_SLOTS * Q_L * Q_HKV * Q_HEAD)
+    assert not offenders, offenders
+
+
+def test_head_256_flash_prefill_compiles(one_chip):
+    """A Qwen3-Next prefill's attention: 16 query heads on 2 KV heads of
+    256 over the largest bucket."""
+    text = _compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False),
+        one_chip, ((1, 3072, Q_HQ, Q_HEAD), jnp.bfloat16),
+        ((1, 3072, Q_HKV, Q_HEAD), jnp.bfloat16),
+        ((1, 3072, Q_HKV, Q_HEAD), jnp.bfloat16),
+    )
+    assert _kernel_names(text) == [FLASH_FORWARD]
+
+
+@pytest.mark.parametrize("tokens", [128, 3072], ids=["decode128", "prefill3072"])
+def test_grouped_matmul_over_a_share_compiles(one_chip, tokens):
+    """The held experts' SwiGLU at width 512 (one column block of 512
+    for gate and up) over a decode step's 1,280 choices (16-row tiles)
+    and a prefill's 30,720 (256-row tiles), the rows held elsewhere
+    sorted into dead tiles (``plan_groups(absent=True)``)."""
+    from torchdistx_tpu.ops.grouped_matmul import (
+        grouped_matmul, plan_groups, row_tile,
+    )
+
+    def experts(x, ids, w_gate, w_up, w_down):
+        here = ids < Q_HELD
+        plan = plan_groups(
+            jnp.where(here, ids, Q_HELD), Q_HELD,
+            row_tile(ids.shape[0], x.dtype), absent=True,
+        )
+        kw = dict(use_kernel=True, interpret=False)
+        h = grouped_matmul(
+            x[plan.src // Q_TOP], w_gate, plan, rhs_up=w_up, block_n=512, **kw
+        )
+        return grouped_matmul(h, w_down, plan, block_n=512, **kw)[plan.dest]
+
+    text = _compile(
+        experts, one_chip, ((tokens, 2048), jnp.bfloat16),
+        ((tokens * Q_TOP,), jnp.int32), ((Q_HELD, 2048, Q_F), jnp.bfloat16),
+        ((Q_HELD, 2048, Q_F), jnp.bfloat16), ((Q_HELD, Q_F, 2048), jnp.bfloat16),
+    )
+    assert _kernel_names(text) == ["tdx_grouped_matmul"] * 2
+
+
+def test_qwen3_next_serve_programs_compile(one_chip, monkeypatch):
+    """The engine's decode program and its largest prefill program for
+    one period of the Qwen3-Next cell (3 Gated-DeltaNet layers + 1
+    attention layer at the published widths, 128 of 512 experts held; 16
+    slots of 4096 so that the CPU-side cache stays small), lowered from
+    the signatures the engine dispatches and compiled for the described
+    chip: the described compile that ``benchmarks/proof/describe_compile.py``
+    (a file of older signatures) cannot make.  The kernels by name, one
+    of each a layer and program; the 4-D state written in place."""
+    import torchdistx_tpu as tdx
+    from torchdistx_tpu.generation import SLOT_STATE_ROWS
+    from torchdistx_tpu.models import Qwen3Next
+    from torchdistx_tpu.serve import ServeEngine
+
+    (chip,) = one_chip.device_set
+    slots, bucket = 16, 3072
+    model = tdx.deferred_init(
+        lambda: Qwen3Next.from_name(
+            "qwen3_next_80b_a3b", n_layers=4, vocab_size=37984,
+            max_seq_len=Q_L, experts_held=(0, Q_HELD),
+        )
+    )
+    engine = ServeEngine(  # its (small) cache lives on the CPU
+        model, num_slots=slots, max_len=Q_L, prefill_buckets=(bucket,),
+        cost_cards=False,
+    )
+    assert engine.cache.state_slot_bytes == 3 * (2097152 + 49152)
+    assert engine.cache.kv_row_bytes == 2048
+
+    def shape(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def ints(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=one_chip)
+
+    params = {n: shape(p) for n, p in model.named_parameters()}
+    kv = jax.tree_util.tree_map(shape, engine.cache.kv)
+    # ``interpret=None`` and ``use_flash`` ask jax.devices()[0].platform
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+    decode = engine._decode_program().lower(
+        params, kv, ints(SLOT_STATE_ROWS, slots), ints(slots),
+        ints(SLOT_STATE_ROWS + 1, slots),
+    ).compile().as_text()
+    names = _kernel_names(decode)
+    assert sorted(set(names)) == [
+        "tdx_decode_attention", "tdx_gated_delta_update", "tdx_grouped_matmul"]
+    assert (names.count("tdx_gated_delta_update"), names.count(
+        "tdx_decode_attention"), names.count("tdx_grouped_matmul")) == (3, 1, 8)
+    state = slots * Q_HV * Q_D * Q_D
+    copies = [
+        f"{name} = {type_} {op}"
+        for _, name, op, type_, _ in _cache_sized(decode, state)
+        if op in ("copy", "transpose")
+    ]
+    assert not copies, copies
+    prefill = engine._prefill_program(bucket).lower(
+        params, kv, ints(slots), ints(1, bucket), ints(), ints(),
+        jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one_chip), ints(1),
+    ).compile().as_text()
+    names = _kernel_names(prefill)
+    assert (names.count("tdx_gated_delta_chunk"), names.count(
+        FLASH_FORWARD), names.count("tdx_grouped_matmul")) == (3, 1, 8)

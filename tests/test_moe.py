@@ -396,3 +396,130 @@ class TestRouter:
     def test_bad_scoring_rejected(self):
         with pytest.raises(ValueError, match="scoring"):
             MoE(8, 16, 4, 2, scoring="tanh")
+
+
+class TestHeldShare:
+    """``MoE(held=(lo, hi))``: a layer told which experts it holds (one
+    chip's share of an expert-parallel group, without the exchange)."""
+
+    E, K, D, F = 16, 4, 32, 24
+
+    def _whole(self, **kw):
+        tdx.manual_seed(0)
+        return MoE(
+            self.D, self.F, self.E, top_k=self.K, dispatch_mode="grouped",
+            shared_ffn_dim=self.F, shared_gate=True, **kw,
+        )
+
+    def _share(self, whole, lo, hi, **kw):
+        part = self._whole(held=(lo, hi), **kw)
+        params = dict(whole.named_parameters())
+        for name in ("w_gate", "w_up", "w_down"):
+            params[name] = params[name][lo:hi]
+        assert {n: p.shape for n, p in part.named_parameters()} == {
+            n: p.shape for n, p in params.items()
+        }
+        return part, params
+
+    def _shared_term(self, whole, x):
+        gate = jax.nn.sigmoid(whole.shared_gate(x))
+        return gate * whole.shared(x)
+
+    @pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "kernel"])
+    def test_four_shares_add_up_to_the_uncut_layer(self, use_kernel):
+        """The shared expert counted once: ``sum_shares(y - shared) +
+        shared`` is the layer that holds every expert, the router's
+        weights renormalised over all ``top_k`` choices in each."""
+        from torchdistx_tpu.nn.moe import moe_count_tape, tape_totals
+
+        whole = self._whole()
+        x = jax.random.normal(jax.random.PRNGKey(1), (3, 7, self.D))
+        want = whole(x)
+        shared = self._shared_term(whole, x)
+        total, rows, elsewhere = shared, 0, 0
+        for lo in range(0, self.E, 4):
+            part, params = self._share(whole, lo, lo + 4, use_kernel=use_kernel)
+            assert part.router.weight.shape == (self.E, self.D)  # all scored
+            assert part.w_gate.shape == (4, self.D, self.F)
+            with moe_count_tape() as tape:
+                y = functional_call(part, params, (x,))
+            counts = np.asarray(tape_totals(tape))
+            assert counts.shape == (3,) and counts[0] + counts[2] == 21 * self.K
+            assert 0 < counts[1] <= 4  # held experts touched
+            rows, elsewhere = rows + counts[0], elsewhere + counts[2]
+            total = total + y - shared
+        assert rows == 21 * self.K and elsewhere == 3 * rows
+        assert float(jnp.abs(want).max()) > 0.01
+        np.testing.assert_allclose(
+            np.asarray(total), np.asarray(want), rtol=0, atol=2e-6
+        )
+
+    def test_the_default_is_bit_for_bit_todays_layer(self):
+        """``held=None`` and ``held=(0, n_experts)``: the same leaves, the
+        same jaxpr (no op and no shape differs), the same bits, and a
+        two-number tape."""
+        from torchdistx_tpu.nn.moe import moe_count_tape, tape_totals
+
+        whole, spelled = self._whole(), self._whole(held=(0, self.E))
+        assert spelled.held is None
+        x = jax.random.normal(jax.random.PRNGKey(2), (2, 5, self.D))
+        params = dict(whole.named_parameters())
+        texts = [
+            str(jax.make_jaxpr(lambda p, v, m=m: functional_call(m, p, (v,)))(
+                params, x))
+            for m in (whole, spelled)
+        ]
+        assert texts[0] == texts[1]
+        np.testing.assert_array_equal(
+            np.asarray(whole(x)), np.asarray(functional_call(spelled, params, (x,)))
+        )
+        with moe_count_tape() as tape:
+            whole(x)
+        assert np.asarray(tape_totals(tape)).shape == (2,)
+        assert tape[0][0] == 10 * self.K  # static: every row is here
+
+    def test_a_share_no_token_chose_adds_nothing(self):
+        """No held row at all (top-1 of 16, the share one expert nobody
+        picked): the layer computes one dead tile and returns zeros."""
+        tdx.manual_seed(3)
+        x = jax.random.normal(jax.random.PRNGKey(4), (1, 6, self.D))
+        m = MoE(self.D, self.F, self.E, top_k=1, dispatch_mode="grouped")
+        picked = set(np.asarray(jnp.argmax(m.router(x), -1)).ravel().tolist())
+        lo = next(e for e in range(self.E) if e not in picked)
+        tdx.manual_seed(3)
+        part = MoE(self.D, self.F, self.E, top_k=1, dispatch_mode="grouped",
+                   held=(lo, lo + 1))
+        np.testing.assert_array_equal(np.asarray(part(x)), 0.0)
+
+    @pytest.mark.parametrize(
+        "kwargs,match",
+        [
+            (dict(held=(4, 2)), "not a range"),
+            (dict(held=(0, 99)), "not a range"),
+            (dict(held=(0, 4), dispatch_mode="einsum"), "needs dispatch_mode='grouped'"),
+        ],
+    )
+    def test_refusals(self, kwargs, match):
+        kw = dict(dispatch_mode="grouped")
+        kw.update(kwargs)
+        with pytest.raises(ValueError, match=match):
+            MoE(self.D, self.F, self.E, top_k=2, **kw)
+        with pytest.raises(ValueError, match="shared_gate=True without"):
+            MoE(self.D, self.F, self.E, top_k=2, shared_gate=True)
+
+    def test_plan_sorts_absent_rows_into_dead_tiles(self):
+        from torchdistx_tpu.ops.grouped_matmul import plan_groups
+
+        ids = jnp.asarray([3, 1, 3, 0, 3, 1, 3, 3], jnp.int32)  # 3 = absent
+        plan = plan_groups(ids, 3, 2, absent=True)
+        assert int(plan.groups) == 2 and int(plan.n_tiles[0]) == 2
+        assert np.asarray(plan.tile_group)[:2].tolist() == [0, 1]
+        dest = np.asarray(plan.dest)
+        assert dest[[0, 2, 4, 6, 7]].tolist() == [0] * 5  # nothing of their own
+        assert sorted(dest[[3, 1, 5]].tolist()) == [0, 2, 3]
+        src = np.asarray(plan.src)
+        assert src[0] == 3 and sorted(src[2:4].tolist()) == [1, 5]
+        # every row absent: one tile stays, of group 0
+        none = plan_groups(jnp.full((4,), 3, jnp.int32), 3, 2, absent=True)
+        assert int(none.groups) == 0 and int(none.n_tiles[0]) == 1
+        assert int(none.tile_group[0]) <= 2

@@ -51,10 +51,25 @@ The router is configurable for the DeepSeek-V3 family: ``scoring``
 (``"softmax"`` | ``"sigmoid"``, the latter in float32), a learned
 ``selection_bias`` that enters the choice of experts and not their
 weights, ``routed_scale`` on the renormalised weights, and a shared
-expert (``shared_ffn_dim``) that every token takes.
+expert (``shared_ffn_dim``) that every token takes, with
+``shared_gate=True`` under a gate of its own, ``sigmoid(w_sg . x)`` (the
+Qwen3-Next family).
+
+**A layer told which experts it holds** (``held=(lo, hi)``, the grouped
+path only): what one chip of an expert-parallel group has of the layer.
+The router stays ``n_experts`` wide and the choice and the
+renormalisation run over ALL experts, as on every other chip; the three
+stacks hold experts ``lo .. hi - 1`` only, and the layer computes the
+rows whose expert it holds: a choice outside the share joins no group,
+reads no weight and adds nothing.  The result is this chip's PARTIAL
+sum (plus the shared expert, which every chip computes alike): the
+shares of a layer add up to the whole layer once the shared term is
+counted once (``tests/test_moe.py``).  Nothing here stands in for the
+other chips or for the exchange between them.
 
 Counters: under :func:`moe_count_tape` every grouped call records the
-rows it computed and the groups it touched (a traced scalar); the serve
+rows it computed and the groups it touched (a traced scalar), and a
+layer that holds a share also the rows it left to the others; the serve
 programs sum them on the device (``serve/engine.py``).
 """
 
@@ -75,6 +90,13 @@ from .layers import Linear
 __all__ = ["MoE", "moe_shard_rule", "moe_count_tape", "tape_totals"]
 
 
+#: the column block of the grouped path's fused gate-and-up matmul, by
+#: the experts' width: 384 is kanana-2-30b's sweep at 768 (two blocks a
+#: stack); at 512 a block of 384 would fall to 256 (the widest dividing
+#: tile)
+_UP_BLOCK_N = {512: 512}
+
+
 class _Tape(threading.local):
     current = None
 
@@ -86,7 +108,10 @@ _tape = _Tape()
 def moe_count_tape():
     """While open (at trace time), every grouped expert call appends
     ``(rows, groups)``: the (token, expert) rows it computed (static) and
-    the experts that had at least one (a traced int32 scalar)."""
+    the experts that had at least one (a traced int32 scalar).  A layer
+    that holds a share of its experts appends ``(rows, groups,
+    elsewhere)``, all traced: the rows of the experts it holds, the held
+    experts touched, and the rows whose expert is held elsewhere."""
     tape: list = []
     prev, _tape.current = _tape.current, tape
     try:
@@ -96,10 +121,14 @@ def moe_count_tape():
 
 
 def tape_totals(tape) -> jax.Array:
-    """int32 ``[rows, groups]`` summed over the tape's calls."""
-    rows = sum(r for r, _ in tape)
-    groups = sum((g for _, g in tape), jnp.zeros((), jnp.int32))
-    return jnp.stack([jnp.asarray(rows, jnp.int32), groups])
+    """int32 ``[rows, groups]`` summed over the tape's calls; ``[rows,
+    groups, elsewhere]`` where a call recorded rows held elsewhere."""
+    zero = jnp.zeros((), jnp.int32)
+    rows = sum(t[0] for t in tape)
+    totals = [jnp.asarray(rows, jnp.int32), sum((t[1] for t in tape), zero)]
+    if any(len(t) > 2 for t in tape):
+        totals.append(sum((t[2] for t in tape if len(t) > 2), zero))
+    return jnp.stack(totals)
 
 
 class _SharedFFN(Module):
@@ -139,12 +168,16 @@ class MoE(Module):
         shared_ffn_dim: Optional[int] = None,
         weight_init: Optional[Callable] = None,
         use_kernel: Optional[bool] = None,
+        held: Optional[tuple] = None,
+        shared_gate: bool = False,
     ) -> None:
         """``weight_init``: optional ``fn(shape, dtype)`` for every leaf
-        (router, selection bias, expert stacks, shared expert), in
-        construction order; default: the uniform fan-in bounds.
+        (router, selection bias, expert stacks, shared expert and its
+        gate), in construction order; default: the uniform fan-in bounds.
         ``use_kernel``: the grouped path's Pallas kernel (None = on a
-        TPU), else its jnp form."""
+        TPU), else its jnp form.  ``held=(lo, hi)``: the share of the
+        experts this layer holds (module docstring; None = all).
+        ``shared_gate``: the shared expert's own sigmoid gate."""
         super().__init__()
         if not 1 <= top_k <= n_experts:
             raise ValueError(f"top_k={top_k} out of range for {n_experts} experts")
@@ -168,6 +201,24 @@ class MoE(Module):
             raise ValueError(
                 f"scoring {scoring!r} (expected 'softmax' or 'sigmoid')"
             )
+        if held is not None:
+            lo, hi = held = (int(held[0]), int(held[1]))
+            if not 0 <= lo < hi <= n_experts:
+                raise ValueError(
+                    f"held={held} is not a range of the {n_experts} experts"
+                )
+            if dispatch_mode != "grouped":
+                raise ValueError(
+                    "held= (a share of the experts) needs "
+                    "dispatch_mode='grouped': the dense and capacity paths "
+                    "compute every expert"
+                )
+            if held == (0, n_experts):
+                held = None  # the whole layer: today's program
+        if shared_gate and not shared_ffn_dim:
+            raise ValueError("shared_gate=True without a shared expert")
+        self.held = held
+        n_held = n_experts if held is None else held[1] - held[0]
         self.dim = dim
         self.ffn_dim = ffn_dim
         self.n_experts = n_experts
@@ -177,6 +228,7 @@ class MoE(Module):
         self.scoring = scoring
         self.routed_scale = float(routed_scale)
         self.use_kernel = use_kernel
+        self.up_block_n = _UP_BLOCK_N.get(ffn_dim, 384)
         self.router = Linear(
             dim, n_experts, bias=False, dtype=dtype, weight_init=weight_init
         )
@@ -197,12 +249,17 @@ class MoE(Module):
             )
         else:
             self.register_parameter("e_score_correction_bias", None)
-        self.w_gate = Parameter(up_init((n_experts, dim, ffn_dim), dtype))
-        self.w_up = Parameter(up_init((n_experts, dim, ffn_dim), dtype))
-        self.w_down = Parameter(down_init((n_experts, ffn_dim, dim), dtype))
+        self.w_gate = Parameter(up_init((n_held, dim, ffn_dim), dtype))
+        self.w_up = Parameter(up_init((n_held, dim, ffn_dim), dtype))
+        self.w_down = Parameter(down_init((n_held, ffn_dim, dim), dtype))
         self.shared = (
             _SharedFFN(dim, shared_ffn_dim, dtype, weight_init)
             if shared_ffn_dim
+            else None
+        )
+        self.shared_gate = (
+            Linear(dim, 1, bias=False, dtype=dtype, weight_init=weight_init)
+            if shared_gate
             else None
         )
 
@@ -251,7 +308,11 @@ class MoE(Module):
             y = self._dense_forward(x, probs)
         if self.shared is not None:
             with jax.named_scope("moe/shared"):
-                y = y + self.shared(x)
+                shared = self.shared(x)
+                if self.shared_gate is not None:
+                    gate = self.shared_gate(x).astype(jnp.float32)
+                    shared = (jax.nn.sigmoid(gate) * shared).astype(x.dtype)
+                y = y + shared
         if return_aux:
             return y, self._balance_loss(probs)
         return y
@@ -260,7 +321,11 @@ class MoE(Module):
         """No token dropped, work proportional to ``tokens x top_k``: the
         (token, expert) rows sorted by expert, the SwiGLU as grouped
         matmuls over the experts that have rows, each token's ``top_k``
-        results gathered back and summed under their weights."""
+        results gathered back and summed under their weights.  With a
+        share of the experts (``held``) the rows of the others sort past
+        the held groups into dead tiles (``plan_groups(absent=True)``):
+        the layout keeps its static ``tokens x top_k`` rows, of which
+        the kernel works the held ones."""
         from ..ops.grouped_matmul import grouped_matmul, plan_groups, row_tile
 
         k = self.top_k
@@ -269,21 +334,39 @@ class MoE(Module):
         n = xf.shape[0]
         with jax.named_scope("moe/route"):
             top_p, top_i = self._choose(probs.reshape(n, self.n_experts))
-            plan = plan_groups(
-                top_i.reshape(-1).astype(jnp.int32), self.n_experts,
-                row_tile(n * k, x.dtype),
-            )
+            ids = top_i.reshape(-1).astype(jnp.int32)
+            if self.held is None:
+                here = None
+                plan = plan_groups(
+                    ids, self.n_experts, row_tile(n * k, x.dtype)
+                )
+            else:
+                lo, hi = self.held
+                here = (ids >= lo) & (ids < hi)
+                # the tile by the rows EXPECTED here (an even router sends
+                # the share its part of the choices), the layout by all
+                expected = max(1, n * k * (hi - lo) // self.n_experts)
+                plan = plan_groups(
+                    jnp.where(here, ids - lo, hi - lo), hi - lo,
+                    row_tile(expected, x.dtype), absent=True,
+                )
         if _tape.current is not None:
-            _tape.current.append((n * k, plan.groups))
+            if here is None:
+                _tape.current.append((n * k, plan.groups))
+            else:
+                rows = jnp.sum(here, dtype=jnp.int32)
+                _tape.current.append((rows, plan.groups, n * k - rows))
         with jax.named_scope("moe/experts"):
             h = grouped_matmul(
                 xf[plan.src // k], self.w_gate, plan, rhs_up=self.w_up,
-                block_n=384, use_kernel=self.use_kernel,
+                block_n=self.up_block_n, use_kernel=self.use_kernel,
             )
             y = grouped_matmul(
                 h, self.w_down, plan, block_n=512, use_kernel=self.use_kernel
             )
             y = y[plan.dest].reshape(n, k, d).astype(jnp.float32)
+            if here is not None:  # a row held elsewhere names a row not its own
+                y = jnp.where(here.reshape(n, k, 1), y, 0.0)
             y = jnp.einsum("nk,nkd->nd", top_p, y).astype(x.dtype)
         return y.reshape(*lead, d)
 

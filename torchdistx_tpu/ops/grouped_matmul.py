@@ -87,23 +87,41 @@ def _col_tile(n: int, cap: int) -> int:
     return n
 
 
-def plan_groups(group_ids: jax.Array, n_groups: int, tm: int) -> GroupPlan:
-    """``group_ids`` (rows,) int32 in ``[0, n_groups)``, any order."""
+def plan_groups(
+    group_ids: jax.Array, n_groups: int, tm: int, absent: bool = False
+) -> GroupPlan:
+    """``group_ids`` (rows,) int32 in ``[0, n_groups)``, any order.
+
+    ``absent=True`` (an expert layer that holds a share of its experts,
+    ``nn/moe.py``): an id of ``n_groups`` marks a row whose group is not
+    here.  Such a row sorts last, joins no tile and reads no weight; its
+    ``dest`` is row 0, whose value the caller must not use.  The tiles
+    it would have filled are dead ones.  With no row here at all one
+    tile of garbage is still computed (the kernel's index maps need a
+    last real tile)."""
     rows = group_ids.shape[0]
     tiles = -(-rows // tm) + min(n_groups, rows)
     order = jnp.argsort(group_ids, stable=True).astype(jnp.int32)
-    sizes = jnp.zeros((n_groups,), jnp.int32).at[group_ids].add(1)
+    if absent:
+        sizes = jnp.zeros((n_groups + 1,), jnp.int32).at[group_ids].add(1)
+        sizes = sizes[:n_groups]
+    else:
+        sizes = jnp.zeros((n_groups,), jnp.int32).at[group_ids].add(1)
     padded = (sizes + tm - 1) // tm * tm
     pad_end = jnp.cumsum(padded)
     pad_start = pad_end - padded
     start = jnp.cumsum(sizes) - sizes
     n_tiles = pad_end[-1] // tm
+    if absent:
+        n_tiles = jnp.maximum(n_tiles, 1)
     # tile -> group: the group whose padded range holds the tile's first
     # row; dead tiles take the last real tile's group
     first_row = jnp.minimum(jnp.arange(tiles), n_tiles - 1) * tm
     tile_group = jnp.searchsorted(pad_end, first_row, side="right").astype(
         jnp.int32
     )
+    if absent:
+        tile_group = jnp.minimum(tile_group, n_groups - 1)
     # padded row -> sorted position (dead rows: position 0)
     r = jnp.arange(tiles * tm)
     g = jnp.repeat(tile_group, tm)
@@ -113,6 +131,8 @@ def plan_groups(group_ids: jax.Array, n_groups: int, tm: int) -> GroupPlan:
     # flat pair -> padded row
     sorted_g = group_ids[order]
     dest_sorted = pad_start[sorted_g] + (jnp.arange(rows) - start[sorted_g])
+    if absent:
+        dest_sorted = jnp.where(sorted_g < n_groups, dest_sorted, 0)
     dest = jnp.zeros((rows,), jnp.int32).at[order].set(
         dest_sorted.astype(jnp.int32)
     )

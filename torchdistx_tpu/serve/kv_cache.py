@@ -45,15 +45,19 @@ cache may hold layers of different kinds:
   slab with the same ``dynamic_update_slice`` and a decode step its row
   with the same scatter as a pair's arrays;
 - a **recurrent state**, :class:`RecurrentState` (a state-space layer,
-  ``models/jamba``): ``conv (num_slots, (K - 1) * d_inner)``, the last
-  ``K - 1`` inputs of the layer's causal convolution, and ``ssm
-  (num_slots, d_state, d_inner)`` float32, the recurrence's state --
-  what a slot holds of the whole context, constant in its length.  It
+  ``models/jamba``; a Gated-DeltaNet layer, ``models/qwen3_next``):
+  ``conv (num_slots, (K - 1) * width)``, the last ``K - 1`` inputs of
+  the layer's causal convolution, and ``ssm`` float32, the recurrence's
+  state in the model's layout (``(num_slots, d_state, d_inner)`` for
+  Mamba-1, ``(num_slots, heads, Dk, Dv)`` for the delta rule's matrix a
+  head) -- what a slot holds of the whole context, constant in its
+  length.  It
   has NO row axis and no position: a prefill writes the slot's state
   whole (``write_slot``: the state after the prompt's last REAL token;
   the model sees to that, ``models/jamba.py``) and every decode step
   rewrites the state of EVERY slot whole (in place:
-  ``tdx_selective_state_update``), that of a retired or frozen slot
+  ``tdx_selective_state_update``, ``tdx_gated_delta_update``), that of a
+  retired or frozen slot
   too.  That is safe by the argument this module makes for rows,
   overwrite-before-visible: nothing reads a slot's state but that
   slot's own next step, and a slot is read again only after an
@@ -314,9 +318,9 @@ class LatentEntry(NamedTuple):
 
 
 class RecurrentState(NamedTuple):
-    """A state-space layer's per-slot state (module docstring): ``conv
-    (lead, (K - 1) * d_inner)`` and ``ssm (lead, d_state, d_inner)``
-    float32."""
+    """A recurrent layer's per-slot state (module docstring): ``conv
+    (lead, (K - 1) * width)`` and ``ssm`` float32 in the model's layout,
+    ``(lead, d_state, d_inner)`` or ``(lead, heads, Dk, Dv)``."""
 
     conv: jax.Array
     ssm: jax.Array

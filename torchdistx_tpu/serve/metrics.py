@@ -367,9 +367,11 @@ class ServeMetrics:
         pending, self._device_counters = self._device_counters, {}
         self._device_adds = 0
         for key, value in pending.items():
-            rows, groups = (int(v) for v in np.asarray(value))
             phase = key[len("moe_"):]
-            for name, n in (("moe_routed_rows", rows), ("moe_groups", groups)):
+            # a third count where the expert layers hold a share of
+            # their experts: the rows whose expert is held elsewhere
+            names = ("moe_routed_rows", "moe_groups", "moe_rows_elsewhere")
+            for name, n in zip(names, (int(v) for v in np.asarray(value))):
                 for full in (name, f"{name}_{phase}"):
                     self.counters[full] = self.counters.get(full, 0) + n
 
