@@ -412,13 +412,13 @@ def test_latent_decode_step_leaves_the_cache_in_place(one_chip, monkeypatch):
 def test_grouped_matmul_compiles(one_chip, tokens):
     """The experts' SwiGLU as two grouped matmuls (gate and up fused,
     then down) over a decode step's 192 rows (16-row tiles) and a
-    prefill's 24576 (256-row tiles)."""
+    prefill's 24576 (128-row tiles), the layout built by counting."""
     from torchdistx_tpu.ops.grouped_matmul import (
         grouped_matmul, plan_groups, row_tile,
     )
 
     def experts(x, ids, w_gate, w_up, w_down):
-        plan = plan_groups(ids, K_E, row_tile(ids.shape[0], x.dtype))
+        plan = plan_groups(ids, K_E, row_tile(ids.shape[0], K_E, x.dtype))
         kw = dict(use_kernel=True, interpret=False)
         h = grouped_matmul(
             x[plan.src // K_TOP], w_gate, plan, rhs_up=w_up, block_n=384, **kw
@@ -693,24 +693,27 @@ def test_head_256_flash_prefill_compiles(one_chip):
 @pytest.mark.parametrize("tokens", [128, 3072], ids=["decode128", "prefill3072"])
 def test_grouped_matmul_over_a_share_compiles(one_chip, tokens):
     """The held experts' SwiGLU at width 512 (one column block of 512
-    for gate and up) over a decode step's 1,280 choices (16-row tiles)
-    and a prefill's 30,720 (256-row tiles), the rows held elsewhere
-    sorted into dead tiles (``plan_groups(absent=True)``)."""
+    for gate and up) over the layout of a share: sized by the rows an
+    even router sends here, twice over (a decode step's 1,280 choices:
+    640 rows in 16-row tiles; a prefill's 30,720: 15,360 in 64-row
+    tiles), the rows held elsewhere in no tile."""
     from torchdistx_tpu.ops.grouped_matmul import (
         grouped_matmul, plan_groups, row_tile,
     )
 
+    expected = tokens * Q_TOP * Q_HELD // Q_WIDTH
+    tm = row_tile(expected, Q_HELD, jnp.bfloat16)
+    tiles = -(-2 * expected // tm) + Q_HELD
+
     def experts(x, ids, w_gate, w_up, w_down):
-        here = ids < Q_HELD
-        plan = plan_groups(
-            jnp.where(here, ids, Q_HELD), Q_HELD,
-            row_tile(ids.shape[0], x.dtype), absent=True,
-        )
+        plan = plan_groups(ids, Q_HELD, tm, tiles)  # an id >= 128: elsewhere
         kw = dict(use_kernel=True, interpret=False)
         h = grouped_matmul(
             x[plan.src // Q_TOP], w_gate, plan, rhs_up=w_up, block_n=512, **kw
         )
-        return grouped_matmul(h, w_down, plan, block_n=512, **kw)[plan.dest]
+        y = grouped_matmul(h, w_down, plan, block_n=512, **kw)
+        # zeros for a row held elsewhere
+        return y.at[plan.dest].get(mode="fill", fill_value=0)
 
     text = _compile(
         experts, one_chip, ((tokens, 2048), jnp.bfloat16),
@@ -718,6 +721,41 @@ def test_grouped_matmul_over_a_share_compiles(one_chip, tokens):
         ((Q_HELD, 2048, Q_F), jnp.bfloat16), ((Q_HELD, Q_F, 2048), jnp.bfloat16),
     )
     assert _kernel_names(text) == ["tdx_grouped_matmul"] * 2
+
+
+def test_share_layer_compiles_with_its_fallback(one_chip, monkeypatch):
+    """One expert layer of the Qwen3-Next cell (128 of 512 experts held,
+    no shared expert) at a prefill's 2,048 tokens: the layout sized by
+    the rows held and the full-size one it falls back to are the two
+    branches of a ``conditional``, each with its two kernels, and both
+    lower for the chip."""
+    import torchdistx_tpu as tdx
+    from torchdistx_tpu.nn import functional_call
+    from torchdistx_tpu.nn.moe import MoE, moe_count_tape, tape_totals
+
+    (chip,) = one_chip.device_set
+    layer = tdx.deferred_init(
+        lambda: MoE(2048, Q_F, Q_WIDTH, top_k=Q_TOP, dtype=jnp.bfloat16,
+                    dispatch_mode="grouped", held=(0, Q_HELD), use_kernel=True)
+    )
+    params = {
+        n: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip)
+        for n, p in layer.named_parameters()
+    }
+
+    def fn(params, x):
+        with moe_count_tape() as tape:
+            y = functional_call(layer, params, (x,))
+        return y, tape_totals(tape)
+
+    # ``interpret=None`` asks jax.devices()[0].platform
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+    text = jax.jit(fn).lower(
+        params,
+        jax.ShapeDtypeStruct((1, 2048, 2048), jnp.bfloat16, sharding=one_chip),
+    ).compile().as_text()
+    assert _kernel_names(text) == ["tdx_grouped_matmul"] * 4
+    assert " conditional(" in text
 
 
 def test_qwen3_next_serve_programs_compile(one_chip, monkeypatch):
@@ -728,7 +766,9 @@ def test_qwen3_next_serve_programs_compile(one_chip, monkeypatch):
     the signatures the engine dispatches and compiled for the described
     chip: the described compile that ``benchmarks/proof/describe_compile.py``
     (a file of older signatures) cannot make.  The kernels by name, one
-    of each a layer and program; the 4-D state written in place."""
+    of each a layer and program (the grouped matmul two a layer in each
+    of an expert layer's two layouts: the one sized by the rows held and
+    its full-size fallback); the 4-D state written in place."""
     import torchdistx_tpu as tdx
     from torchdistx_tpu.generation import SLOT_STATE_ROWS
     from torchdistx_tpu.models import Qwen3Next
@@ -767,7 +807,7 @@ def test_qwen3_next_serve_programs_compile(one_chip, monkeypatch):
     assert sorted(set(names)) == [
         "tdx_decode_attention", "tdx_gated_delta_update", "tdx_grouped_matmul"]
     assert (names.count("tdx_gated_delta_update"), names.count(
-        "tdx_decode_attention"), names.count("tdx_grouped_matmul")) == (3, 1, 8)
+        "tdx_decode_attention"), names.count("tdx_grouped_matmul")) == (3, 1, 16)
     state = slots * Q_HV * Q_D * Q_D
     copies = [
         f"{name} = {type_} {op}"
@@ -781,4 +821,4 @@ def test_qwen3_next_serve_programs_compile(one_chip, monkeypatch):
     ).compile().as_text()
     names = _kernel_names(prefill)
     assert (names.count("tdx_gated_delta_chunk"), names.count(
-        FLASH_FORWARD), names.count("tdx_grouped_matmul")) == (3, 1, 8)
+        FLASH_FORWARD), names.count("tdx_grouped_matmul")) == (3, 1, 16)

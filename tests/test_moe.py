@@ -286,10 +286,10 @@ class TestGroupedDispatch:
 class TestGroupedMatmul:
     """``ops/grouped_matmul.py``: the layout and the kernel."""
 
-    def _plan(self, ids, n_groups=6, tm=8):
+    def _plan(self, ids, n_groups=6, tm=8, tiles=None):
         from torchdistx_tpu.ops.grouped_matmul import plan_groups
 
-        return plan_groups(jnp.asarray(ids, jnp.int32), n_groups, tm)
+        return plan_groups(jnp.asarray(ids, jnp.int32), n_groups, tm, tiles)
 
     def test_plan_layout(self):
         ids = [3, 0, 3, 3, 5, 0, 3, 3, 3, 3, 3, 3]  # group 3: 9 rows, 2 tiles
@@ -298,13 +298,20 @@ class TestGroupedMatmul:
         assert plan.tile_group.shape == (2 + 6,)  # ceil(12 / 8) + groups
         np.testing.assert_array_equal(plan.tile_group[:4], [0, 3, 3, 5])
         np.testing.assert_array_equal(plan.tile_group[4:], [5] * 4)  # dead
-        # every pair sits in a row of a tile of its own group
+        # every pair sits in a row of a tile of its own group, in the
+        # order the pairs came (a row's rank in its group: no sort)
         dest = np.asarray(plan.dest)
+        np.testing.assert_array_equal(
+            dest, [8, 0, 9, 10, 24, 1, 11, 12, 13, 14, 15, 16]
+        )
         np.testing.assert_array_equal(
             np.asarray(plan.tile_group)[dest // 8], ids
         )
-        assert len(set(dest)) == len(ids)
         np.testing.assert_array_equal(np.asarray(plan.src)[dest], range(12))
+        # a static count of tiles of the caller's own: the same rows
+        few = self._plan(ids, tiles=5)
+        assert few.tile_group.shape == (5,) and few.src.shape == (40,)
+        np.testing.assert_array_equal(few.dest, dest)
 
     @pytest.mark.parametrize("swiglu", [False, True])
     def test_kernel_matches_jnp_path_and_rows(self, swiglu):
@@ -444,8 +451,9 @@ class TestHeldShare:
             with moe_count_tape() as tape:
                 y = functional_call(part, params, (x,))
             counts = np.asarray(tape_totals(tape))
-            assert counts.shape == (3,) and counts[0] + counts[2] == 21 * self.K
+            assert counts.shape == (4,) and counts[0] + counts[2] == 21 * self.K
             assert 0 < counts[1] <= 4  # held experts touched
+            assert counts[3] == 0  # an even routing fits the capped layout
             rows, elsewhere = rows + counts[0], elsewhere + counts[2]
             total = total + y - shared
         assert rows == 21 * self.K and elsewhere == 3 * rows
@@ -507,19 +515,220 @@ class TestHeldShare:
         with pytest.raises(ValueError, match="shared_gate=True without"):
             MoE(self.D, self.F, self.E, top_k=2, shared_gate=True)
 
-    def test_plan_sorts_absent_rows_into_dead_tiles(self):
+    def test_plan_leaves_absent_rows_out_of_the_layout(self):
         from torchdistx_tpu.ops.grouped_matmul import plan_groups
 
         ids = jnp.asarray([3, 1, 3, 0, 3, 1, 3, 3], jnp.int32)  # 3 = absent
-        plan = plan_groups(ids, 3, 2, absent=True)
+        plan = plan_groups(ids, 3, 2)
         assert int(plan.groups) == 2 and int(plan.n_tiles[0]) == 2
         assert np.asarray(plan.tile_group)[:2].tolist() == [0, 1]
+        padded_rows = plan.src.shape[0]
+        assert padded_rows == (4 + 3) * 2
         dest = np.asarray(plan.dest)
-        assert dest[[0, 2, 4, 6, 7]].tolist() == [0] * 5  # nothing of their own
-        assert sorted(dest[[3, 1, 5]].tolist()) == [0, 2, 3]
+        # one past the layout: a scatter there drops, a gather there fills
+        assert dest[[0, 2, 4, 6, 7]].tolist() == [padded_rows] * 5
+        assert dest[[3, 1, 5]].tolist() == [0, 2, 3]
         src = np.asarray(plan.src)
-        assert src[0] == 3 and sorted(src[2:4].tolist()) == [1, 5]
-        # every row absent: one tile stays, of group 0
-        none = plan_groups(jnp.full((4,), 3, jnp.int32), 3, 2, absent=True)
+        assert src[:4].tolist() == [3, 0, 1, 5] and not src[4:].any()
+        # every row absent: one tile stays, of a group that exists
+        none = plan_groups(jnp.full((4,), 3, jnp.int32), 3, 2)
         assert int(none.groups) == 0 and int(none.n_tiles[0]) == 1
         assert int(none.tile_group[0]) <= 2
+        assert np.asarray(none.dest).tolist() == [none.src.shape[0]] * 4
+
+
+#: (name, layer, tokens, top_k, boosted experts): the routing of every
+#: case is the router's own but for the experts whose logit is lifted by
+#: 50 (every token then chooses them first).  ``share`` holds experts
+#: 0-3 of 16 (and the test walks the other three shares too)
+LAYOUT_CASES = [
+    ("whole-decode", "whole", 8, 4, ()),
+    ("whole-prefill", "whole", 96, 4, ()),
+    ("whole-one_group_holds_every_row", "whole", 24, 1, (5,)),
+    ("share-decode", "share", 8, 4, ()),
+    ("share-prefill", "share", 96, 4, ()),
+    ("share-every_row_absent", "share", 24, 4, (4, 9, 10, 15)),
+    ("share-one_group_holds_every_row", "share", 24, 4, (2, 9, 10, 15)),
+    ("share-held_rows_over_cap", "share", 24, 4, (0, 1, 2, 3)),
+]
+
+
+class TestGroupedLayout:
+    """The layout of an expert layer's rows (``plan_groups``, built by
+    counting; sized by the rows held where the layer holds a share, with
+    the full-size layout as the exact fallback), case by case."""
+
+    E, D, F = 16, 32, 24
+
+    def _routing(self, tokens, top_k, boosted, seed=0):
+        """A dense float32 layer whose router lifts ``boosted``, its
+        input, and the choices it makes."""
+        tdx.manual_seed(seed)
+        dense = MoE(self.D, self.F, self.E, top_k=top_k)
+        x = np.random.RandomState(seed).randn(tokens, self.D).astype(np.float32)
+        x[:, 0] = 1.0
+        lift = np.zeros((self.E,), np.float32)
+        lift[list(boosted)] = 50.0
+        dense.router.weight = tdx.nn.Parameter(
+            dense.router.weight.at[:, 0].set(jnp.asarray(lift))
+        )
+        x = jnp.asarray(x)
+        _, top_i = dense._choose(dense._route(x))
+        return dense, x, np.asarray(top_i).reshape(-1)
+
+    def _layout_of(self, layer, tokens, top_k, held):
+        """``(n_groups, tm, cap)`` as ``MoE._grouped_forward`` sizes it
+        (``cap`` None for the whole layer)."""
+        from torchdistx_tpu.ops.grouped_matmul import row_tile
+
+        if layer == "whole":
+            return self.E, row_tile(tokens * top_k, self.E, jnp.float32), None
+        n_held = held[1] - held[0]
+        expected = tokens * top_k * n_held // self.E
+        tm = row_tile(expected, n_held, jnp.float32)
+        return n_held, tm, -(-2 * expected // tm) * tm
+
+    @pytest.mark.parametrize(
+        "layer,tokens,top_k,boosted",
+        [c[1:] for c in LAYOUT_CASES], ids=[c[0] for c in LAYOUT_CASES],
+    )
+    def test_plan_holds_each_held_pair_once(self, layer, tokens, top_k, boosted):
+        """Each held pair sits in exactly one padded row, a tile belongs
+        to one group, an absent pair sits in none, and ``n_tiles`` /
+        ``groups`` are NumPy's counts: in the layout the layer would
+        use (the capped one where the held rows fit it)."""
+        from torchdistx_tpu.ops.grouped_matmul import plan_groups
+
+        _, _, chosen = self._routing(tokens, top_k, boosted)
+        held = (0, self.E) if layer == "whole" else (0, 4)
+        n_groups, tm, cap = self._layout_of(layer, tokens, top_k, held)
+        here = (chosen >= held[0]) & (chosen < held[1])
+        ids = np.where(here, chosen - held[0], n_groups).astype(np.int32)
+        fits = cap is not None and here.sum() <= cap
+        assert fits == (layer == "share" and boosted != (0, 1, 2, 3))
+        tiles = cap // tm + n_groups if fits else None
+        plan = plan_groups(jnp.asarray(ids), n_groups, tm, tiles)
+        n_layout = plan.tile_group.shape[0]
+        if tiles is None:
+            assert n_layout == -(-len(ids) // tm) + min(n_groups, len(ids))
+        else:
+            assert n_layout == tiles < -(-len(ids) // tm) + n_groups
+        padded_rows = n_layout * tm
+        assert plan.src.shape == (padded_rows,) and plan.tm == tm
+        sizes = np.bincount(ids[here], minlength=n_groups)[:n_groups]
+        n_tiles = max(1, int(sum(-(-s // tm) for s in sizes)))
+        assert int(plan.n_tiles[0]) == n_tiles <= n_layout
+        assert int(plan.groups) == int((sizes > 0).sum())
+        dest, src = np.asarray(plan.dest), np.asarray(plan.src)
+        tile_group = np.asarray(plan.tile_group)
+        assert (dest[~here] == padded_rows).all()  # an absent pair: no row
+        mine = dest[here]
+        assert len(set(mine.tolist())) == len(mine)  # one row a held pair
+        assert (mine < n_tiles * tm).all()
+        np.testing.assert_array_equal(src[mine], np.flatnonzero(here))
+        np.testing.assert_array_equal(tile_group[mine // tm], ids[here])
+        dead = np.ones((padded_rows,), bool)
+        dead[mine] = False
+        assert not src[dead].any()  # and nobody else's pair in any row
+        assert (tile_group[n_tiles:] == tile_group[n_tiles - 1]).all()
+        assert ((0 <= tile_group) & (tile_group < n_groups)).all()
+
+    @pytest.mark.parametrize(
+        "layer,tokens,top_k,boosted",
+        [c[1:] for c in LAYOUT_CASES], ids=[c[0] for c in LAYOUT_CASES],
+    )
+    def test_layer_equals_the_dense_reference(self, layer, tokens, top_k, boosted):
+        """The grouped layer (every one of the four shares, where the
+        case is a share's) against the dense float32 layer over the
+        experts it holds; the shares add up to the whole layer; the
+        call whose held rows pass its layout's cap says so and is still
+        exact."""
+        from torchdistx_tpu.nn.moe import moe_count_tape, tape_totals
+
+        dense, x, chosen = self._routing(tokens, top_k, boosted)
+        params = dict(dense.named_parameters())
+        want = np.asarray(dense(x))
+        assert np.abs(want).max() > 1e-3
+        kw = dict(top_k=top_k, dispatch_mode="grouped")
+        if layer == "whole":
+            grouped = MoE(self.D, self.F, self.E, **kw)
+            with moe_count_tape() as tape:
+                got = functional_call(grouped, params, (x,))
+            counts = np.asarray(tape_totals(tape))
+            assert counts.tolist() == [
+                tokens * top_k, len(np.unique(chosen))]
+            np.testing.assert_allclose(
+                np.asarray(got), want, rtol=1e-5, atol=1e-5)
+            return
+        total = np.zeros_like(want)
+        for lo in range(0, self.E, 4):
+            hi = lo + 4
+            part = MoE(self.D, self.F, self.E, held=(lo, hi), **kw)
+            mine = dict(params)
+            for name in ("w_gate", "w_up", "w_down"):
+                mine[name] = params[name][lo:hi]
+
+            def run(p, v, m=part):  # the tape read where it was written
+                with moe_count_tape() as tape:
+                    y = functional_call(m, p, (v,))
+                return y, tape_totals(tape)
+
+            got, counts = (np.asarray(a) for a in jax.jit(run)(mine, x))
+            here = (chosen >= lo) & (chosen < hi)
+            _, tm, cap = self._layout_of("share", tokens, top_k, (lo, hi))
+            assert counts.tolist() == [
+                here.sum(), len(np.unique(chosen[here])), (~here).sum(),
+                int(here.sum() > cap),
+            ]
+            # the dense layer with every other expert's output zeroed
+            outside = jnp.ones((self.E, 1, 1)).at[lo:hi].set(0.0) > 0
+            alone = dict(params, w_down=jnp.where(outside, 0.0, params["w_down"]))
+            np.testing.assert_allclose(
+                got, np.asarray(functional_call(dense, alone, (x,))),
+                rtol=1e-5, atol=1e-5,
+            )
+            total += got
+        np.testing.assert_allclose(total, want, rtol=1e-5, atol=2e-5)
+        over = boosted == (0, 1, 2, 3)
+        assert (int((chosen < 4).sum()) > self._layout_of(
+            "share", tokens, top_k, (0, 4))[2]) == over
+
+    @pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "pallas"])
+    def test_fallback_is_exact_under_the_kernel_and_counted_by_the_engine_names(
+        self, use_kernel
+    ):
+        """Held rows over ``cap``: the full-size layout runs (both
+        branches of the ``cond`` under ``jit`` and under ``grad``), and
+        ``ServeMetrics`` files the call under ``moe_layout_overflows``."""
+        from torchdistx_tpu.nn.moe import moe_count_tape, tape_totals
+        from torchdistx_tpu.serve.metrics import ServeMetrics
+
+        dense, x, chosen = self._routing(24, 4, (0, 1, 2, 3))
+        params = dict(dense.named_parameters())
+        part = MoE(self.D, self.F, self.E, top_k=4, dispatch_mode="grouped",
+                   held=(0, 4), use_kernel=use_kernel)
+        mine = dict(params)
+        for name in ("w_gate", "w_up", "w_down"):
+            mine[name] = params[name][:4]
+
+        def run(p, v):
+            with moe_count_tape() as tape:
+                y = functional_call(part, p, (v,))
+            return y, tape_totals(tape)
+
+        got, counts = jax.jit(run)(mine, x)
+        assert np.asarray(counts).tolist() == [96, 4, 0, 1]
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(dense(x)), rtol=1e-5, atol=1e-5)
+        metrics = ServeMetrics(num_slots=1)
+        metrics.add_device_counts("prefill", counts)
+        metrics.add_device_counts("decode", jnp.asarray([8, 2, 24, 0]))
+        metrics.sync_device_counters()
+        c = metrics.counters
+        assert (c["moe_layout_overflows"], c["moe_layout_overflows_prefill"],
+                c["moe_layout_overflows_decode"]) == (1, 1, 0)
+        assert c["moe_routed_rows"] == 104 and c["moe_rows_elsewhere"] == 24
+        if not use_kernel:  # the kernel has no derivative rule
+            g = jax.grad(
+                lambda p: jnp.mean(functional_call(part, p, (x,)) ** 2))(mine)
+            assert float(jnp.abs(g["w_gate"]).sum()) > 0.0

@@ -1,24 +1,33 @@
 """Pallas grouped matmul for routed experts: ``tdx_grouped_matmul``.
 
 An expert layer that drops no token has as many rows as the router made
-choices (``tokens x top_k``), unevenly spread over the experts.  Sorted
-by expert they form *groups* of consecutive rows, each multiplied by its
-own expert's matrix: ``out[r] = lhs[r] @ rhs[group(r)]``.  Work is
-proportional to the rows, not to ``tokens x experts`` as the dense
-compute of ``nn/moe.py`` is, and an expert no token chose is never
-touched.
+choices of the experts it holds (``tokens x top_k`` where it holds them
+all), unevenly spread over the experts.  Laid out by expert they form
+*groups* of consecutive rows, each multiplied by its own expert's
+matrix: ``out[r] = lhs[r] @ rhs[group(r)]``.  Work is proportional to
+the rows, not to ``tokens x experts`` as the dense compute of
+``nn/moe.py`` is, and an expert no token chose is never touched.
 
 Layout (``plan_groups``): every group is padded up to a whole number of
 row tiles of ``tm`` rows, so a tile belongs to ONE group and the kernel
 is a plain tiled matmul whose right-hand block is picked by a
-scalar-prefetched ``tile_group[i]``.  The number of tiles is static
-(``ceil(rows / tm) + min(groups, rows)``: every non-empty group can end
-in one partial tile); tiles past the last real one are *dead*: their
-index maps fold onto the last real tile (an unchanged block index moves
-no bytes) and their compute is skipped.  The grid runs the row tiles
-innermost, so consecutive tiles of one group reuse the resident weight
-block: each weight block of a group with rows is read once per call,
-and the weights of an empty group never.
+scalar-prefetched ``tile_group[i]``.  The number of tiles is static:
+by default ``ceil(rows / tm) + min(groups, rows)`` (every non-empty
+group can end in one partial tile), enough for any ids; a caller whose
+rows are mostly held elsewhere passes ``tiles = cap / tm + groups`` for
+the ``cap`` rows it expects at most here and checks the count of rows
+here against ``cap`` before it uses the plan (``nn/moe.py`` falls back
+to the default under ``lax.cond``).  A row's place is its group's start
+plus its rank in the group, both counted over the ``(rows, groups)``
+comparison of ids and groups (no sort, no gather of single elements: the
+chip runs those an element at a time); the one indexed operation is the
+scatter of the row ids that inverts ``dest`` into ``src``.  Tiles past
+the last real one are *dead*: their index maps fold onto the last real
+tile (an unchanged block index moves no bytes) and their compute is
+skipped.  The grid runs the row tiles innermost, so consecutive tiles of
+one group reuse the resident weight block: each weight block of a group
+with rows is read once per call, and the weights of an empty group
+never.
 
 ``swiglu=True`` takes two right-hand stacks (gate, up) and writes
 ``silu(lhs @ gate) * (lhs @ up)``: the SwiGLU's two matmuls share the
@@ -55,7 +64,8 @@ class GroupPlan(NamedTuple):
     ``src`` (padded_rows,): for every padded row the flat (token *
     top_k + choice) pair it holds — dead rows name pair 0, their results
     are never read.  ``dest`` (rows,): the padded row of every flat
-    pair.  ``tile_group`` (tiles,): the group of every row tile (dead
+    pair; ``padded_rows``, one past the layout, for a pair whose group
+    is not here.  ``tile_group`` (tiles,): the group of every row tile (dead
     tiles repeat the last real tile's).  ``n_tiles`` (1,): the real
     tiles.  ``groups``: how many groups have at least one row."""
 
@@ -67,15 +77,28 @@ class GroupPlan(NamedTuple):
     tm: int
 
 
-def row_tile(rows: int, dtype) -> int:
-    """Rows a tile: large enough to feed the MXU where the groups are
-    long (a prefill), the sublane packing of the dtype where they are a
-    row or two (a decode step, where the weights' bytes are the cost)."""
-    least = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-    for tm in (256, 128, 64, 32):
-        if rows >= 32 * tm:
+#: (rows a tile, least rows an even router sends ONE group): one expert
+#: layer alone on the chip (``scripts/bench_expert_layer.py --row-tile``,
+#: PR 37), us a layer at 32 / 64 / 128 / 256 rows a tile.  128 experts
+#: of 512 held of 512 (a layout of twice the expected rows): 10 rows a
+#: group 1386 / 1448 / 1576 / -, 20: 1601 / 1624 / 1862 / -, 40: 2332 /
+#: 1947 / 2157 / -, 60: 2918 / 2573 / 3260 / -.  128 experts of 768, all
+#: held: 48 rows a group - / 2188 / 2295 / 2975, 96: - / 3106 / 3082 /
+#: 3530, 192: - / 5105 / 4930 / 4998, 288: - / 6968 / 6706 / 6933.  A
+#: larger tile feeds the MXU more rows a weight block and pads every
+#: group's end with more rows that are gathered and computed for nothing
+_ROW_TILES = ((128, 96), (64, 32), (32, 8))
+
+
+def row_tile(rows: int, n_groups: int, dtype) -> int:
+    """Rows a tile, by the rows an even router sends ONE group (``rows /
+    n_groups``): large enough to feed the MXU where the groups are long
+    (a prefill), the sublane packing of the dtype where they are a row
+    or two (a decode step, where the weights' bytes are the cost)."""
+    for tm, least in _ROW_TILES:
+        if rows >= least * n_groups:
             return tm
-    return least
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
 def _col_tile(n: int, cap: int) -> int:
@@ -87,54 +110,74 @@ def _col_tile(n: int, cap: int) -> int:
     return n
 
 
-def plan_groups(
-    group_ids: jax.Array, n_groups: int, tm: int, absent: bool = False
-) -> GroupPlan:
-    """``group_ids`` (rows,) int32 in ``[0, n_groups)``, any order.
+#: rows a block of ``_running_count``: 128 read 60 us at 20,480 x 128,
+#: 256 and 512 read 76 and 74, ``jnp.cumsum`` (a reduce-window) 186
+#: (chip runs, PR 37)
+_COUNT_BLOCK = 128
 
-    ``absent=True`` (an expert layer that holds a share of its experts,
-    ``nn/moe.py``): an id of ``n_groups`` marks a row whose group is not
-    here.  Such a row sorts last, joins no tile and reads no weight; its
-    ``dest`` is row 0, whose value the caller must not use.  The tiles
-    it would have filled are dead ones.  With no row here at all one
-    tile of garbage is still computed (the kernel's index maps need a
-    last real tile)."""
+
+def _running_count(member: jax.Array) -> jax.Array:
+    """Inclusive count down the rows of a ``(rows, groups)`` boolean
+    matrix, int32: within blocks of ``_COUNT_BLOCK`` rows a lower
+    triangle of ones times the block on the MXU (0 / 1 in bfloat16,
+    sums of at most a block's rows in float32: exact), plus the running
+    total of the blocks before."""
+    rows, g = member.shape
+    b = _COUNT_BLOCK
+    blocks = jnp.pad(member, ((0, -rows % b), (0, 0))).reshape(-1, b, g)
+    tri = jnp.tril(jnp.ones((b, b), jnp.bfloat16))
+    inner = jnp.einsum(
+        "ij,bjg->big", tri, blocks.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+    before = jnp.cumsum(inner[:, -1], axis=0) - inner[:, -1]
+    return (inner + before[:, None]).reshape(-1, g)[:rows]
+
+
+def plan_groups(
+    group_ids: jax.Array, n_groups: int, tm: int, tiles: Optional[int] = None
+) -> GroupPlan:
+    """``group_ids`` (rows,) int32, any order.  An id outside ``[0,
+    n_groups)`` marks a row whose group is not here (an expert layer
+    that holds a share of its experts, ``nn/moe.py``): it joins no tile
+    and reads no weight, and its ``dest`` is ``padded_rows``, one past
+    the layout (a scatter there drops, a gather there fills).
+
+    ``tiles`` (static): the tiles of the layout, by default enough for
+    any ``group_ids``.  A caller that passes fewer must know that the
+    rows here fit (``rows_here / tm + n_groups`` tiles always do) and
+    not use the plan otherwise.  With no row here at all one tile of
+    garbage is still computed (the kernel's index maps need a last real
+    tile).
+
+    Built by counting over the ``(rows, n_groups)`` comparison of ids
+    and groups, which the chip does a vector at a time; the one
+    operation an element at a time is the scatter that inverts ``dest``
+    into ``src``."""
     rows = group_ids.shape[0]
-    tiles = -(-rows // tm) + min(n_groups, rows)
-    order = jnp.argsort(group_ids, stable=True).astype(jnp.int32)
-    if absent:
-        sizes = jnp.zeros((n_groups + 1,), jnp.int32).at[group_ids].add(1)
-        sizes = sizes[:n_groups]
-    else:
-        sizes = jnp.zeros((n_groups,), jnp.int32).at[group_ids].add(1)
+    if tiles is None:
+        tiles = -(-rows // tm) + min(n_groups, rows)
+    padded_rows = tiles * tm
+    member = group_ids[:, None] == jnp.arange(n_groups, dtype=jnp.int32)
+    count = _running_count(member)  # a row's rank in its group, plus one
+    sizes = count[-1]
     padded = (sizes + tm - 1) // tm * tm
     pad_end = jnp.cumsum(padded)
     pad_start = pad_end - padded
-    start = jnp.cumsum(sizes) - sizes
-    n_tiles = pad_end[-1] // tm
-    if absent:
-        n_tiles = jnp.maximum(n_tiles, 1)
+    n_tiles = jnp.maximum(pad_end[-1] // tm, 1)
     # tile -> group: the group whose padded range holds the tile's first
-    # row; dead tiles take the last real tile's group
-    first_row = jnp.minimum(jnp.arange(tiles), n_tiles - 1) * tm
-    tile_group = jnp.searchsorted(pad_end, first_row, side="right").astype(
-        jnp.int32
-    )
-    if absent:
-        tile_group = jnp.minimum(tile_group, n_groups - 1)
-    # padded row -> sorted position (dead rows: position 0)
-    r = jnp.arange(tiles * tm)
-    g = jnp.repeat(tile_group, tm)
-    off = r - pad_start[g]
-    live = (off < sizes[g]) & (r < pad_end[-1])
-    src = order[jnp.where(live, start[g] + off, 0)]
-    # flat pair -> padded row
-    sorted_g = group_ids[order]
-    dest_sorted = pad_start[sorted_g] + (jnp.arange(rows) - start[sorted_g])
-    if absent:
-        dest_sorted = jnp.where(sorted_g < n_groups, dest_sorted, 0)
-    dest = jnp.zeros((rows,), jnp.int32).at[order].set(
-        dest_sorted.astype(jnp.int32)
+    # row, as a count of the ranges that end at or before it; dead tiles
+    # take the last real tile's group
+    first_row = jnp.minimum(jnp.arange(tiles, dtype=jnp.int32), n_tiles - 1) * tm
+    tile_group = jnp.sum(pad_end[None, :] <= first_row[:, None], axis=1)
+    tile_group = jnp.minimum(tile_group, n_groups - 1).astype(jnp.int32)
+    # flat pair -> padded row: its group's start plus its rank there
+    dest = jnp.sum(jnp.where(member, pad_start[None, :] + count - 1, 0), axis=1)
+    here = (group_ids >= 0) & (group_ids < n_groups)
+    dest = jnp.where(here, dest, padded_rows).astype(jnp.int32)
+    # padded row -> flat pair (dead rows: pair 0)
+    src = jnp.zeros((padded_rows,), jnp.int32).at[dest].set(
+        jnp.arange(rows, dtype=jnp.int32), mode="drop"
     )
     return GroupPlan(
         src, dest, tile_group, n_tiles.reshape(1).astype(jnp.int32),

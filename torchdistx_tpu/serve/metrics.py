@@ -347,7 +347,8 @@ class ServeMetrics:
 
     def add_device_counts(self, phase: str, counts: Any) -> None:
         """Accumulate one dispatch's expert-layer counts, a device int32
-        ``[rows, groups]`` (``nn.moe.tape_totals``), under ``phase``
+        ``[rows, groups]`` or ``[rows, groups, elsewhere, overflows]``
+        (``nn.moe.tape_totals``), under ``phase``
         (``"prefill"`` | ``"decode"``).  An add on the device: no
         transfer, no sync (but for one fold every 4096 adds)."""
         key = f"moe_{phase}"
@@ -368,9 +369,12 @@ class ServeMetrics:
         self._device_adds = 0
         for key, value in pending.items():
             phase = key[len("moe_"):]
-            # a third count where the expert layers hold a share of
-            # their experts: the rows whose expert is held elsewhere
-            names = ("moe_routed_rows", "moe_groups", "moe_rows_elsewhere")
+            # two more counts where the expert layers hold a share of
+            # their experts: the rows whose expert is held elsewhere, and
+            # the calls that held more rows than their layout is sized
+            # for and ran the full-size one
+            names = ("moe_routed_rows", "moe_groups", "moe_rows_elsewhere",
+                     "moe_layout_overflows")
             for name, n in zip(names, (int(v) for v in np.asarray(value))):
                 for full in (name, f"{name}_{phase}"):
                     self.counters[full] = self.counters.get(full, 0) + n
