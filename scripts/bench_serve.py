@@ -162,8 +162,8 @@ def _parse_args():
         metavar="T",
         help="append a chunked-prefill A/B phase: a long-prompt admission "
         "mid-decode, unchunked vs chunked at threshold T (must be a "
-        "prefill bucket) — the headline is the active requests' max "
-        "inter-token gap, chunked strictly below unchunked",
+        "prefill bucket) — the headline is that the active requests "
+        "receive tokens between the long prompt's chunks, by count",
     )
     ap.add_argument(
         "--migrate-tp-to",
@@ -384,11 +384,9 @@ def _phase_summary(rec: dict) -> dict:
             tokens_prefilled_warm=rec.get("tokens_prefilled_warm"),
             pages_in_use_hwm=rec.get("pages_in_use_hwm"),
         )
-    if "max_gap_s_chunked" in rec:  # the chunked-prefill A/B phase
+    if "shorts_rode_interleaved" in rec:  # the chunked-prefill A/B phase
         out.update(
-            max_gap_s_unchunked=rec.get("max_gap_s_unchunked"),
-            max_gap_s_chunked=rec.get("max_gap_s_chunked"),
-            gap_reduction=rec.get("gap_reduction"),
+            shorts_rode_interleaved=rec.get("shorts_rode_interleaved"),
             interleaved_dispatches=rec.get("interleaved_dispatches"),
         )
     if "prefix_hit_rate_affinity" in rec:  # the fleet routing A/B
@@ -1505,11 +1503,11 @@ def _child_chunked_prefill(args) -> None:
     long-prompt admission mid-flight — unchunked (the long prefill is a
     single dispatch that stalls every active slot) vs chunked at
     threshold T (the engine interleaves a decode dispatch between
-    chunks).  The headline is the short requests' max inter-token gap
-    across the admission window, computed from the ``decode_chunk``
-    lifecycle events (one host timestamp per dispatch walk); the phase
-    flags ``error`` when chunking does not strictly shrink the gap, so
-    the STRICT nightly catches a broken interleave.  Token streams must
+    chunks).  The headline is a count: every short request receives
+    tokens in the decode dispatches that run between the long prompt's
+    chunks (the requests' ``first_decode_cycle`` / ``last_decode_cycle``);
+    the phase flags ``error`` when none does, so the STRICT nightly
+    catches a broken interleave.  Token streams must
     be bit-identical between the two engines (chunking may never change
     what a request decodes, only when the host sees it)."""
     t_chunk = int(args.chunked_prefill)
@@ -1574,7 +1572,6 @@ def _child_chunked_prefill(args) -> None:
             ]
             engine.step()
             engine.step()
-            t_submit = time.monotonic()
             hl = engine.submit(
                 long_prompt,
                 max_new_tokens=long_new,
@@ -1583,30 +1580,26 @@ def _child_chunked_prefill(args) -> None:
             )
             while engine.step():
                 pass
-            return [h.result() for h in hs], hl.result(), t_submit
+            return [h.result() for h in hs], hl.result()
 
-        def max_gap(short_results, long_result, t_submit):
-            """Largest inter-token wall gap of any short request whose
-            gap interval overlaps the long request's admission window
-            (submit .. first token) — the stall being measured."""
-            t_first = next(
-                (ts for nm, ts, _ in long_result.events
-                 if nm == "first_token"),
-                None,
+        def rode_interleaved(short_results, long_result, interleaved):
+            """Whether every short request received tokens BETWEEN the
+            long prompt's chunks, by count: the long request's first
+            decode block is the first dispatch after its last chunk, the
+            ``interleaved`` dispatches before it ran between its chunks,
+            and a short request rode them if its own blocks reach from
+            before the first of them to the last (the requests' decode
+            cycle numbers; the per-tick ``decode_chunk`` events whose
+            timestamps gave ``max_gap_s_*`` went in PR 38)."""
+            first = long_result.first_decode_cycle
+            if first is None or interleaved < 1:
+                return False
+            return all(
+                r.first_decode_cycle is not None
+                and r.first_decode_cycle < first - interleaved
+                and r.last_decode_cycle >= first - 1
+                for r in short_results
             )
-            if t_first is None:
-                raise RuntimeError("long request never emitted a token")
-            worst = 0.0
-            for r in short_results:
-                times = [
-                    ts
-                    for nm, ts, _ in r.events
-                    if nm in ("first_token", "decode_chunk")
-                ]
-                for a, b in zip(times, times[1:]):
-                    if b >= t_submit and a <= t_first:
-                        worst = max(worst, b - a)
-            return worst
 
         def run_side(chunked: bool):
             engine = ServeEngine(
@@ -1624,38 +1617,28 @@ def _child_chunked_prefill(args) -> None:
             # second-call recompile: the full scenario, twice
             scenario(engine)
             scenario(engine)
-            # min over repeats: the structural stall (the long prefill
-            # blocking the decode walk) is a FLOOR on the max gap —
-            # host noise (GC, scheduler) only ever adds, so the min is
-            # the robust estimator and keeps the strict A/B from
-            # flaking on tiny CPU-smoke intervals.  Metrics and the
-            # comm profile are reset per repeat so the embedded
-            # (deterministic, gated) counters cover exactly ONE
-            # scenario.
-            gap = None
-            for _ in range(3):
-                engine.reset_metrics()
-                watcher.reset()
-                with comm_audit() as comm_prof:
-                    s, l, t_submit = scenario(engine)
-                g = max_gap(s, l, t_submit)
-                gap = g if gap is None else min(gap, g)
-            return engine, gap, s, l, comm_prof
+            # metrics and the comm profile are reset so the embedded
+            # (deterministic, gated) counters cover exactly ONE scenario
+            engine.reset_metrics()
+            watcher.reset()
+            with comm_audit() as comm_prof:
+                s, l = scenario(engine)
+            return engine, s, l, comm_prof
 
         from torchdistx_tpu.obs.comm import comm_audit
 
-        eng_a, gap_a, shorts_a, long_a, _ = run_side(chunked=False)
-        eng_b, gap_b, shorts_b, long_b, comm_b = run_side(chunked=True)
+        eng_a, shorts_a, long_a, _ = run_side(chunked=False)
+        eng_b, shorts_b, long_b, comm_b = run_side(chunked=True)
         record["recompile_measure"] = watcher.snapshot()
         # the chunked side's analytic collective profile (mesh runs)
         record["comm"] = comm_b.to_json()
 
-        record["max_gap_s_unchunked"] = round(gap_a, 6)
-        record["max_gap_s_chunked"] = round(gap_b, 6)
-        record["gap_reduction"] = round(gap_a / gap_b, 3) if gap_b else None
         mb = eng_b.metrics.to_json()
         record["interleaved_dispatches"] = mb["counters"].get(
             "prefill_interleaved_dispatches", 0
+        )
+        record["shorts_rode_interleaved"] = rode_interleaved(
+            shorts_b, long_b, record["interleaved_dispatches"]
         )
         record["prefill_chunks"] = mb["counters"].get("prefill_chunks", 0)
         streams_equal = all(
@@ -1678,11 +1661,10 @@ def _child_chunked_prefill(args) -> None:
                 "chunked prefill never interleaved a decode dispatch "
                 f"(long prompt {long_len} tokens, threshold {t_chunk})"
             )
-        elif not gap_b < gap_a:
+        elif not record["shorts_rode_interleaved"]:
             record["error"] = (
-                "chunked prefill did not shrink the admission stall "
-                f"(max inter-token gap {gap_b:.4f}s chunked vs "
-                f"{gap_a:.4f}s unchunked)"
+                "the short requests received no tokens between the long "
+                "prompt's chunks"
             )
         _dump_obs(record, eng_b, "chunked_prefill")
     except Exception as e:  # degraded-but-parseable, bench.py contract

@@ -622,12 +622,16 @@ class TestReplaySpans:
 SERVE_LEAVES = [
     "serve/schedule", "serve/decode_args", "serve/decode", "serve/harvest",
 ]
+#: spans inside those: a prefill in ``serve/schedule``; the call and the
+#: blocked read in ``serve/decode``
+SERVE_CHILDREN = ["serve/prefill", "serve/dispatch", "serve/wait"]
 
 
-def _profiled_host_spans(tmp_path, body):
+def _profiled_host_spans(tmp_path, body, stats=False):
     """Run ``body()`` under a real ``jax.profiler`` trace and read the
     host plane back: ``[(name, start_ns, end_ns)]`` of every span under
-    the program's prefixes, by start."""
+    the program's prefixes, by start (outer before inner); with
+    ``stats`` each followed by its annotation's stats as a dict."""
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
@@ -640,6 +644,7 @@ def _profiled_host_spans(tmp_path, body):
     data = jax.profiler.ProfileData.from_file(str(found[-1]))
     spans = [
         (e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+        + ((dict(e.stats),) if stats else ())
         for plane in data.planes
         if plane.name.startswith("/host:")
         for line in plane.lines
@@ -733,12 +738,13 @@ class TestOneSpanPrimitive:
         spans = _profiled_host_spans(tmp_path, three_steps)
         names = [n for n, _, _ in spans]
         # exactly these names: no ``#k=v#`` tail, no per-step label
-        assert set(names) == set(SERVE_LEAVES) | {"serve/prefill"}
+        # (``cycle=n`` rides as a stat: tests/test_serve_cycle_account.py)
+        assert set(names) == set(SERVE_LEAVES) | set(SERVE_CHILDREN)
         ends = [i for i, n in enumerate(names) if n == "serve/harvest"]
         assert len(ends) == 3
         for k, i in enumerate(ends):
             step = spans[ends[k - 1] + 1 if k else 0 : i + 1]
-            leaves = [s for s in step if s[0] != "serve/prefill"]
+            leaves = [s for s in step if s[0] not in SERVE_CHILDREN]
             # flat leaves in order, harvest last: nothing of the step
             # is left after it (nothing timed).  The step that admitted
             # has a second ``serve/schedule`` after its decode dispatch:

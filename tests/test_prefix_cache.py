@@ -276,7 +276,9 @@ class TestPagedEngineIntegration:
         )
         engine.run([{"prompt": _prompts(9, (6,))[0], "max_new_tokens": 3}])
         j = json.loads(json.dumps(engine.metrics.to_json()))
-        assert set(j) == {"counters", "gauges", "histograms", "derived"}
+        assert set(j) == {
+            "counters", "gauges", "histograms", "derived", "cycles",
+        }
         assert j["counters"]["requests_completed"] == 1
         assert j["gauges"]["num_pages"] == engine.num_pages
         assert j["gauges"]["pages_in_use_hwm"] >= 1

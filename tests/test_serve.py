@@ -1460,17 +1460,22 @@ class TestChunkedPrefill:
         assert c["prefill_interleaved_dispatches"] == 2
         assert plain.metrics.counters["chunked_prefills"] == 0
         # the latency claim: short slots received tokens BETWEEN the
-        # long prompt's chunks — decode_chunk events timestamped inside
-        # the admission window (prefill start .. long first token)
-        t0 = next(ts for n, ts, d in long_b.events if n == "prefill")
-        t1 = next(ts for n, ts, d in long_b.events if n == "first_token")
-        interleaved = [
-            ts
-            for r in shorts_b
-            for n, ts, _ in r.events
-            if n == "decode_chunk" and t0 < ts < t1
-        ]
-        assert interleaved, "no decode dispatch landed between chunks"
+        # long prompt's chunks.  The long request's first decode block is
+        # the first dispatch after its last chunk; the interleaved
+        # dispatches are the ones just before it, and every short request
+        # was riding them (its blocks reach from before to after)
+        first = long_b.first_decode_cycle
+        between = range(
+            first - c["prefill_interleaved_dispatches"], first
+        )
+        for r in shorts_b:
+            assert r.first_decode_cycle < between[0]
+            assert r.last_decode_cycle >= between[-1], (
+                "no decode dispatch landed between chunks"
+            )
+        # unchunked, the long prompt stalled them: no dispatch lies
+        # between its admission and its own first block
+        assert plain.metrics.counters["prefill_interleaved_dispatches"] == 0
         # and chunking changed WHEN, never WHAT: all streams identical
         for ra, rb in zip(shorts_a + [long_a], shorts_b + [long_b]):
             np.testing.assert_array_equal(ra.tokens, rb.tokens)

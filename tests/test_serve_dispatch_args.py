@@ -153,8 +153,8 @@ class _Spy:
         real_phase = engine._phase
 
         @contextlib.contextmanager
-        def phase(name, *sink):
-            with real_phase(name, *sink):
+        def phase(name, *sink, **stats):
+            with real_phase(name, *sink, **stats):
                 self.open.append(name)
                 try:
                     yield

@@ -45,6 +45,16 @@ the step in which each lands is one later).  From the parent's numbers:
 The chunked cases of those kinds (``chunked_prefill`` settles at once),
 the persistent and the speculative kinds are the parent's to the letter.
 
+**What PR 38 changed in how ``GOLDEN`` is read, not in ``GOLDEN``.**  The
+engine no longer appends a ``decode_chunk`` event a request a tick: a
+request keeps the running numbers of its first and last decode dispatch
+(``first_decode_cycle`` / ``last_decode_cycle``, also in its ``finish``
+event).  ``GOLDEN`` still holds the parent's ``decode_chunk{tokens=n}``
+entries; the comparison takes them out of the expected lifecycle and
+holds the two integers to them instead: the blocks that gave the request
+a token (``tokens`` > 0) are consecutive dispatches, so there are ``last -
+first + 1`` of them.
+
 No combination is refused by the constructor: all ten run.  The scenario
 has prompts on both sides of the chunk threshold, one prompt that shares
 its first page with an earlier one (the paged engines' warm prefill and
@@ -86,12 +96,17 @@ def _requests():
     return out
 
 
+#: a ``finish`` event's fields that say WHICH dispatches, compared apart
+_CYCLE_FIELDS = ("first_cycle", "last_cycle")
+
+
 def _events(result):
     """A request's lifecycle as strings, ``name{field=value,...}``, runs
     of one string folded to ``string*n``; timestamps left out."""
     flat = []
     for name, _, data in result.events:
-        data = {k: v for k, v in (data or {}).items() if k != "ts"}
+        data = {k: v for k, v in (data or {}).items()
+                if k != "ts" and k not in _CYCLE_FIELDS}
         fields = ",".join(f"{k}={data[k]}" for k in sorted(data))
         flat.append(name + (f"{{{fields}}}" if fields else ""))
     out = []
@@ -115,7 +130,32 @@ def _serve(engine, requests, first=2):
         pass
     results = [h.result() for h in handles]
     counters = {k: int(v) for k, v in engine.metrics.counters.items() if v}
-    return [r.tokens for r in results], counters, [_events(r) for r in results]
+    return results, counters, [_events(r) for r in results]
+
+
+def _golden_lifecycle(events):
+    """A request's ``GOLDEN`` events as the engine logs them since PR 38
+    (no ``decode_chunk`` entry), and how many of those entries held a
+    token: the decode dispatches from its first to its last."""
+    kept, blocks = [], 0
+    for entry in events:
+        if not entry.startswith("decode_chunk"):
+            kept.append(entry)
+            continue
+        fields, _, runs = entry.partition("*")
+        if fields != "decode_chunk{tokens=0}":
+            blocks += int(runs or 1)
+    return kept, blocks
+
+
+def _assert_lifecycles(results, events, golden):
+    for result, logged, expected in zip(results, events, golden):
+        kept, blocks = _golden_lifecycle(expected)
+        assert logged == kept
+        first, last = result.first_decode_cycle, result.last_decode_cycle
+        assert (0 if first is None else last - first + 1) == blocks
+        assert result.events[-1][2]["first_cycle"] == first
+        assert result.events[-1][2]["last_cycle"] == last
 
 
 def _serve_kind(model, kind, chunked):
@@ -154,12 +194,12 @@ def _case(kind, chunked):
 @pytest.mark.parametrize("kind", list(ENGINES))
 def test_step_path_counters_events_and_streams_are_the_parents(kind, chunked):
     model = _llama()
-    tokens, counters, events = _serve_kind(model, kind, chunked)
-    for request, served in zip(_requests(), tokens):
-        np.testing.assert_array_equal(served, _reference(model, request))
+    results, counters, events = _serve_kind(model, kind, chunked)
+    for request, served in zip(_requests(), results):
+        np.testing.assert_array_equal(served.tokens, _reference(model, request))
     golden = GOLDEN[_case(kind, chunked)]
     assert counters == golden["counters"]
-    assert events == golden["events"]
+    _assert_lifecycles(results, events, golden["events"])
 
 
 @pytest.mark.parametrize("kind", ["slab", "paged"])
@@ -168,9 +208,9 @@ def test_a_chunked_prefill_folded_to_one_chunk_is_still_chunked(kind):
     and ``prefill_chunks`` counted, ``chunks=1`` and a ``prefill_chunk``
     event logged.  The prompt of 8 beside it is at the threshold, not
     over it: the whole way, none of those."""
-    _, counters, events = _serve_folded(_llama(), kind)
+    results, counters, events = _serve_folded(_llama(), kind)
     assert counters == FOLDED[kind]["counters"]
-    assert events == FOLDED[kind]["events"]
+    _assert_lifecycles(results, events, FOLDED[kind]["events"])
 
 
 GOLDEN = {

@@ -28,9 +28,8 @@ timing inference into an asserted counter.
 
 from __future__ import annotations
 
-import contextlib
 import threading
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, List, Optional
 
 from jax import monitoring
 
@@ -62,16 +61,23 @@ def current_scope() -> Optional[str]:
     return st[-1] if st else None
 
 
-@contextlib.contextmanager
-def recompile_scope(label: str) -> Iterator[None]:
+class recompile_scope:
     """Attribute any XLA compile inside the body to ``label`` (innermost
-    scope wins).  Safe to nest; near-free when no watcher is active."""
-    st = _scope_stack()
-    st.append(label)
-    try:
-        yield
-    finally:
-        st.pop()
+    scope wins).  Safe to nest; near-free when no watcher is active (a
+    class, not a generator: every ``serve/*`` span of a decode step
+    enters one)."""
+
+    __slots__ = ("_label", "_stack")
+
+    def __init__(self, label: str):
+        self._label = label
+
+    def __enter__(self) -> None:
+        self._stack = _scope_stack()
+        self._stack.append(self._label)
+
+    def __exit__(self, *exc) -> None:
+        self._stack.pop()
 
 
 def _on_event(key: str, dur: float, **_metadata) -> None:

@@ -364,6 +364,27 @@ _DECODE_VARIANTS = {  # by (persistent, speculative)
 }
 
 
+# The phases of a tick, ``ServeEngine._phase``'s names: the span, the
+# histogram its seconds go to, and the field of the cycle's record
+# (``serve.metrics.CycleAccount.KEYS``) its self time is charged to.
+# ``serve/decode`` is charged nowhere: its two children cover it, and a
+# block's arrival, where one cycle ends and the next begins, lies between
+# them.  A prefill's span is scheduling where the host works and a
+# first-token wait where it blocks; its call site says which.
+_PHASES = {
+    name: (f"serve/{name}", f"{name}_s", key)
+    for name, key in (
+        ("schedule", "schedule"),
+        ("prefill", "schedule"),
+        ("decode_args", "decode_args"),
+        ("decode", None),
+        ("dispatch", "dispatch"),
+        ("wait", "wait"),
+        ("harvest", "harvest"),
+    )
+}
+
+
 class _Flight(NamedTuple):
     """A decode dispatch whose outputs the host has not read."""
 
@@ -371,6 +392,7 @@ class _Flight(NamedTuple):
     riders: list  # ``(request, slot)`` as they were when it was dispatched
     iterations: Optional[int]  # a fused scan's; a loop reports its own
     digests: Any  # the numerics observatory's output, or None
+    cycle: int  # the dispatch's running number: its spans' ``cycle`` stat
 
 
 class ServeEngine:
@@ -446,8 +468,8 @@ class ServeEngine:
       finished_history: how many finished requests to retain for
         per-request trace export (``dump_trace`` /
         ``finished_requests``).  Each retained request holds its prompt
-        array, generated tokens, and lifecycle event list (one
-        ``decode_chunk`` event per dispatch), so a long-running
+        array, generated tokens, and lifecycle event list (a handful
+        of entries a request, none a tick), so a long-running
         production engine with big prompts may want this small — 0
         disables retention entirely (lifecycle events still accumulate
         on in-flight requests and ride out on ``RequestResult.events``).
@@ -842,6 +864,17 @@ class ServeEngine:
             and self.chunked_prefill is None
         )
         self._in_flight: Optional[_Flight] = None
+        # the decode dispatches issued, a running number over the engine's
+        # life (``reset_metrics`` does not restart it): the n-th execution
+        # of the decode program on the device is ``cycle`` n of the spans
+        # and of the metrics' cycle records
+        self._cycle = 0
+        # a small output of the last program queued (its tokens): ready
+        # means the device's queue is empty.  ``_nothing_queued`` while
+        # the host knows that without asking: before the first dispatch
+        # and after a ``_settle()``, where there was nothing to overlap
+        self._last_out: Any = None
+        self._nothing_queued = True
         # what the fused one-token program starts a slot from
         # (generation.KEEP_CARRY / FROM_HOST / FIRST_ON_DEVICE): the host
         # marks the slots it changed since the last dispatch
@@ -1066,15 +1099,35 @@ class ServeEngine:
         riders = {id(req) for req, _ in flight.riders}
         return all(id(req) in riders for req in running)
 
-    def _phase(self, name: str, sink=None):
+    def _phase(self, name: str, sink=None, key=None, **stats):
         """One phase of a tick: the span ``serve/<name>`` (in any
-        profile, and on the host tracer when enabled) with its host
-        seconds recorded into the ``<name>_s`` histogram, or handed to
-        ``sink`` where the record is made later."""
-        return timed_annotation(
-            f"serve/{name}",
-            sink or getattr(self.metrics, f"{name}_s").record,
-        )
+        profile, and on the host tracer when enabled; ``stats`` as the
+        annotation's stats, ``cycle=n``) with its host seconds recorded
+        into the ``<name>_s`` histogram, or handed to ``sink`` where the
+        record is made later, and its self time charged to the running
+        cycle's ``key`` (``_PHASES`` has each phase's own)."""
+        span, hist, own_key = _PHASES[name]
+        metrics = self.metrics
+        sink = sink or getattr(metrics, hist).record
+        key = key or own_key
+        if key is not None:
+            sink = metrics.cycles.entered(key, sink)
+        return timed_annotation(span, sink, **stats)
+
+    def _device_idle(self) -> bool:
+        """Whether the last program queued has ended: every program
+        donates the cache and returns it, so with that one's outputs
+        ready the device's queue is empty."""
+        return self._last_out.is_ready()
+
+    def _count_if_starved(self, kind: str) -> None:
+        """Immediately before a ``prefill`` or ``decode`` program is
+        called: a dispatch that finds the chip idle is counted, but for
+        the first after a ``_settle()`` or on a fresh engine."""
+        if self._nothing_queued:
+            self._nothing_queued = False
+        elif self._device_idle():
+            self.metrics.cycles.starved[kind] += 1
 
     def _schedule(self, tick_kind: str) -> None:
         """The ``serve/schedule`` phase of a tick: expire deadlines, then
@@ -2538,21 +2591,27 @@ class ServeEngine:
                 functools.partial(self._prefill_host_s.__setitem__, slot)
                 if self._lags else None
             )
-            with self._phase("prefill", sink), self._watch(name):
+            # the span ends in the first token's sync where that is not
+            # deferred: a wait of the cycle's account, not scheduling
+            syncs = i == len(chunks) - 1 and not (
+                self._persistent or self._lags
+            )
+            key = "first_wait" if syncs else None
+            with self._phase("prefill", sink, key), self._watch(name):
+                self._count_if_starved("prefill")
                 out = program(*args)
                 # rebind BEFORE the host sync: the dispatch donated the
                 # old slab (or pools), so if the sync raises the engine
                 # must already hold the live output, not a deleted buffer
                 self.cache.kv, tok, self._firsts = out[:3]
+                self._last_out = tok
                 if self.numerics:
                     self._pending_digests.append(out[-1])
                 if self._moe_counts:
                     # the cold slab program's; an expert model is served
                     # through no other (the constructor's refusals)
                     self.metrics.add_device_counts("prefill", out[3])
-                if i == len(chunks) - 1 and not (
-                    self._persistent or self._lags
-                ):
+                if syncs:
                     tok = int(np.asarray(tok))  # host sync: first token exists
             # only what was computed: a prefix hit's tokens are not
             self.metrics.count("tokens_prefilled", bucket)
@@ -2726,7 +2785,8 @@ class ServeEngine:
         of spanning chunks.  Speculation multiplies tokens per sync, it
         never adds one: ``host_syncs == ring_drains`` either way."""
         persistent, spec = self._persistent, self.speculate
-        with self._phase("decode_args"):
+        cycle = self._cycle = self._cycle + 1
+        with self._phase("decode_args", cycle=cycle):
             builder, n_out, _, carries = _DECODE_VARIANTS[
                 persistent, bool(spec)
             ]
@@ -2752,30 +2812,36 @@ class ServeEngine:
             )
             self._ensure_card(name, program, args)
         with self._phase("decode"), self._watch(name):
-            out = program(*args)
-            self.cache.kv = out[0]  # before the sync: old slab was donated
-            rest = 1 + n_out  # what follows the fetched outputs
-            if carries:
-                self._carry = out[rest]
-                rest += 1
-            if self._moe_counts:
-                # the fused one-token program's alone (the constructor's
-                # refusals)
-                self.metrics.add_device_counts("decode", out[rest])
-            due = _Flight(
-                out[1 : 1 + n_out],
-                riders,
-                None if persistent else self.decode_chunk,
-                out[-1] if self.numerics else None,
-            )
-            if self._lags:  # read the predecessor's block, not this one's
-                due, self._in_flight = self._in_flight, due
-                if due is not None:
-                    self.metrics.count("lagged_dispatches")
-            # drop the dispatch's handles: the arguments are host arrays
-            # but for the old carry, the outputs live on in the flight
-            del args, out
-            fetched = self._fetch(due)
+            # the host busy: the call and the rebinding of its outputs
+            with self._phase("dispatch", cycle=cycle):
+                self._count_if_starved("decode")
+                out = program(*args)
+                self.cache.kv = out[0]  # before the sync: old slab was donated
+                self._last_out = out[1]
+                rest = 1 + n_out  # what follows the fetched outputs
+                if carries:
+                    self._carry = out[rest]
+                    rest += 1
+                if self._moe_counts:
+                    # the fused one-token program's alone (the
+                    # constructor's refusals)
+                    self.metrics.add_device_counts("decode", out[rest])
+                due = _Flight(
+                    out[1 : 1 + n_out],
+                    riders,
+                    None if persistent else self.decode_chunk,
+                    out[-1] if self.numerics else None,
+                    cycle,
+                )
+                if self._lags:  # read the predecessor's block, not this one's
+                    due, self._in_flight = self._in_flight, due
+                    if due is not None:
+                        self.metrics.count("lagged_dispatches")
+                # drop the dispatch's handles: the arguments are host arrays
+                # but for the old carry, the outputs live on in the flight
+                del args, out
+            # the host idle: ``serve/wait``
+            fetched = self._fetch(due, dispatched=cycle)
         self._land(due, fetched)
 
     def _settle(self) -> None:
@@ -2788,29 +2854,39 @@ class ServeEngine:
         ``finished_requests()`` and the metrics do not: they report what
         the host has seen.  With nothing in flight it does nothing."""
         due, self._in_flight = self._in_flight, None
-        if due is None and not (self._lags and self._pending_first):
-            return
-        fetched = None
-        if due is not None:
-            with self._phase("decode"):
-                fetched = self._fetch(due)
-        self._land(due, fetched)
+        if due is not None or (self._lags and self._pending_first):
+            fetched = None
+            if due is not None:
+                with self._phase("decode"):
+                    fetched = self._fetch(due)
+            self._land(due, fetched)
+        # nothing is queued now: the next dispatch has nothing to overlap
+        # and the next block to arrive ends no cycle
+        self._nothing_queued = True
+        self.metrics.cycles.settled()
 
-    def _fetch(self, flight: Optional[_Flight]):
+    def _fetch(self, flight: Optional[_Flight], dispatched=None):
         """THE host sync of a decode dispatch: its token outputs and, in
         persistent mode, every pending first token together, as ``(host
         arrays, {slot: first token})``.  The first read waits for the
         program; the other copies are in flight behind that wait
         (``device_get`` does the same under a tree walk that costs the
         one-output programs 16 us more than this).  On an engine that
-        lags the program has usually ended by now."""
+        lags the program has usually ended by now.  ``dispatched`` is
+        the decode dispatch issued since the last block arrived, for the
+        cycle's record."""
         if flight is None:
             return None  # the first dispatch after a settle: nothing due
         pending = self._pending_first if self._persistent else {}
         leaves = (*flight.outputs, *pending.values())
-        for i in range(1, len(leaves)):
-            leaves[i].copy_to_host_async()
-        host = [np.asarray(x) for x in leaves]
+        with self._phase("wait", cycle=flight.cycle):
+            for i in range(1, len(leaves)):
+                leaves[i].copy_to_host_async()
+            host = [np.asarray(x) for x in leaves]
+        # the arrival: where one cycle ends and the next begins
+        self.metrics.cycle_arrived(
+            flight.cycle, len(flight.riders), dispatched
+        )
         self.metrics.count("host_syncs")
         if flight.digests is not None:
             self._pending_digests.append(flight.digests)
@@ -2843,6 +2919,7 @@ class ServeEngine:
                     lambda s, held=held: self.metrics.prefill_s.record(
                         held + s
                     ),
+                    "first_wait",
                 ):
                     tok = int(np.asarray(dev_tok))  # host sync
                 self.metrics.count("host_syncs")
@@ -2857,7 +2934,8 @@ class ServeEngine:
         ``serve/harvest`` — the walk of the fetched block over the
         dispatch's own riders, finishes, counters, the gauges."""
         self._first_tokens()
-        with self._phase("harvest"):
+        stats = {} if flight is None else {"cycle": flight.cycle}
+        with self._phase("harvest", **stats):
             if flight is not None:
                 self._walk(flight, *fetched)
             self._harvest_numerics()
@@ -2906,6 +2984,10 @@ class ServeEngine:
                     c = count.item(j, slot)
                     if c == 0:
                         break  # frozen from here on: rows are rewrites
+                    if not taken:  # this block holds tokens of the request
+                        if req.first_decode_cycle is None:
+                            req.first_decode_cycle = flight.cycle
+                        req.last_decode_cycle = flight.cycle
                     taken = j + 1
                     if spec:
                         # per live slot-iteration: spec lanes drafted,
@@ -2951,13 +3033,6 @@ class ServeEngine:
                 self.metrics.count("masked_slot_steps", n_it - taken)
             else:
                 any_cut = True  # this dispatch ended before the request
-            ev = ("decode_chunk", now, {"tokens": taken})
-            if req.events and req.events[-1][0] == "finish":
-                # _check_finished logged the finish inside the loop; keep
-                # the lifecycle log in causal order (chunk, then finish)
-                req.events.insert(-1, ev)
-            else:
-                req.events.append(ev)
         if persistent and any_cut:
             self.metrics.count("ring_full_drains")
         self.metrics.count("tokens_generated", emitted)
@@ -3011,7 +3086,11 @@ class ServeEngine:
         self._temps[slot] = 0.0
         req.finish_reason = reason
         req.finished_at = now
-        req.record_event("finish", ts=now, reason=reason)
+        req.record_event(
+            "finish", ts=now, reason=reason,
+            first_cycle=req.first_decode_cycle,
+            last_cycle=req.last_decode_cycle,
+        )
         self._count_finish(req)
 
     def _count_finish(self, req: Request) -> None:

@@ -15,12 +15,15 @@ its last stdout line.
 
 from __future__ import annotations
 
+import functools
+import gc
+import heapq
 import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-__all__ = ["Histogram", "ServeMetrics", "latest_metrics"]
+__all__ = ["CycleAccount", "Histogram", "ServeMetrics", "latest_metrics"]
 
 #: the ServeMetrics this process made last.  It holds host numbers and a
 #: few device scalars, never a cache or a weight, so a reader that
@@ -32,6 +35,39 @@ _LATEST: Optional["ServeMetrics"] = None
 def latest_metrics() -> Optional["ServeMetrics"]:
     """The most recently constructed :class:`ServeMetrics`, or None."""
     return _LATEST
+
+
+class _GcClock:
+    """What the collector cost this process: the seconds it ran and its
+    passes by generation, both running totals that any number of readers
+    difference for themselves.  One instance, appended to
+    ``gc.callbacks`` once (:func:`_gc_clock`) however many
+    :class:`ServeMetrics` are made; a pass stops every thread, so a serve
+    cycle is charged the passes that ran inside it whoever set them off."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.passes = [0, 0, 0]
+        self._t0: Optional[float] = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.seconds += time.perf_counter() - self._t0
+            self.passes[info["generation"]] += 1
+            self._t0 = None
+
+
+_GC_CLOCK = _GcClock()
+
+
+def _gc_clock() -> _GcClock:
+    """The process's collector clock, hooked into ``gc.callbacks`` on
+    first use (not at import) and never twice."""
+    if _GC_CLOCK not in gc.callbacks:
+        gc.callbacks.append(_GC_CLOCK)
+    return _GC_CLOCK
 
 
 class Histogram:
@@ -95,6 +131,194 @@ class Histogram:
             "p50": self._quantile(0.50),
             "p95": self._quantile(0.95),
             "max": max(self._samples) if self._samples else None,
+        }
+
+
+class CycleAccount:
+    """The serve loop's account of itself, one record a cycle.
+
+    A *cycle* is the interval between the arrivals on the host of two
+    consecutive decode token blocks (the end of the blocking read in
+    ``ServeEngine._fetch``).  Every decode dispatch has a running number
+    and record ``n`` ends with the arrival of dispatch ``n``'s block.  On
+    an engine that reads its tokens a dispatch late the interval holds,
+    in order: the first-token waits and the walk of block ``n - 1``, the
+    caller's time between two ``step()`` calls, the next step's
+    scheduling and arguments, the dispatch of ``n + 1``
+    (``dispatched``) and the wait for block ``n``, which the device has
+    been computing all the while.
+
+    **What a record holds**, all on ``time.perf_counter`` (the clock of
+    ``utils.profiling.timed_annotation``): ``cycle_s``; the SELF seconds
+    of each phase that ended inside the cycle (:attr:`KEYS`: a span's
+    time less what its child spans cover, so the fields never overlap);
+    ``caller_s``, what no phase covers (the time between ``step()``
+    calls, and the microseconds between two phases inside one);
+    ``cpu_s``, the thread's CPU seconds OUTSIDE the two kinds of wait
+    (``time.thread_time`` read at their borders: a runtime may spin while
+    it waits); ``gc_s`` and the oldest generation collected;
+    ``descheduled_s = cycle_s - wait_s - first_wait_s - cpu_s``, wall
+    time in which the thread neither ran nor waited for the device (a
+    shared host's signature, and the collector's: its passes run on
+    whichever thread set them off); ``admitted`` (requests admitted
+    inside it), ``prefills`` (``prefill_s`` records completed inside
+    it), ``riders``.  A cycle is *plain* when it completed no prefill:
+    under the lag a prefill's device time falls in the cycle AFTER the
+    step that admitted and dispatched it, so a wave reads ``admitted
+    30`` in one record and ``prefills 30, first_wait_s 0.8`` in the
+    next.  ``cpu_s`` is as fine as the kernel's accounting of a thread's
+    time: where that ticks every 10 ms (the chip's host does), it and
+    ``descheduled_s`` say something of a cycle tens of milliseconds
+    long and nothing of a shorter one.
+
+    **What it costs**: a float add a phase, and at an arrival two
+    histogram records and a handful of compares.  A record becomes an
+    object only when it enters ``slowest`` or ``slowest_plain``, the
+    ``SLOWEST`` longest cycles since the metrics began and the longest
+    plain ones (the loop's slow-query log: where prefills come in waves
+    the longest cycles are all waves, and a stall is a plain one); the
+    reservoir is sorted for the running median of the plain cycles every
+    ``REFRESH`` of them, never inside each.  A plain cycle longer than
+    ``SLOW_FACTOR`` running medians is *slow*: their count and their
+    excess seconds over that median are kept as two sums.
+
+    :attr:`starved` counts, by ``prefill`` and ``decode``, the dispatches
+    that found the device's queue empty (``ServeEngine._count_if_starved``).
+    """
+
+    KEYS = ("schedule", "decode_args", "dispatch", "wait", "first_wait",
+            "harvest")
+    _WAITS = ("wait", "first_wait")  # the host idle, the device not
+    SLOWEST = 16
+    SLOW_FACTOR = 2.0
+    REFRESH = 256
+
+    def __init__(self, cycle_s: Histogram, cycle_plain_s: Histogram):
+        self.cycle_s, self.cycle_plain_s = cycle_s, cycle_plain_s
+        self._gc = _gc_clock()
+        self._gc_s = self._gc.seconds
+        self._gc_passes = list(self._gc.passes)
+        self._t0 = time.perf_counter()
+        self._arrival: Optional[float] = None
+        self._sums = dict.fromkeys(self.KEYS, 0.0)
+        # seconds the phases ended so far have covered, children counted
+        # once: what a span entered at one reading of it and left at
+        # another must take off its own seconds
+        self._covered = 0.0
+        self._cpu = 0.0
+        self._cpu_mark = time.thread_time()
+        self._prefills = self._admitted = 0
+        self.plain_wait_s = 0.0
+        self.plain_p50_s: Optional[float] = None
+        self._refresh_at = 16
+        self.slow_count = 0
+        self.slow_excess_s = 0.0
+        self._slowest: list = []  # heaps of (cycle_s, cycle, record)
+        self._slowest_plain: list = []
+        self.starved = {"prefill": 0, "decode": 0}
+
+    def entered(self, key: str, sink):
+        """Called as a phase is entered: the sink its span calls with its
+        seconds when it ends, ``sink`` and this account's share."""
+        if key in self._WAITS:
+            self._cpu += time.thread_time() - self._cpu_mark
+        return functools.partial(self._left, key, sink, self._covered)
+
+    def _left(self, key, sink, covered, seconds) -> None:
+        sink(seconds)
+        own = seconds - (self._covered - covered)
+        self._covered += own
+        self._sums[key] += own
+        if key in self._WAITS:
+            self._cpu_mark = time.thread_time()
+
+    def settled(self) -> None:
+        """The engine has read everything it had queued and may now sit
+        idle: the next block to arrive ends no cycle."""
+        self._arrival = None
+
+    def arrived(self, cycle: int, riders: int, dispatched: Optional[int],
+                prefills: int, admitted: int) -> None:
+        """Dispatch ``cycle``'s token block is on the host.  ``prefills``
+        and ``admitted`` are the running counts of completed
+        ``prefill_s`` records and of admissions."""
+        now = time.perf_counter()
+        last, self._arrival = self._arrival, now
+        sums, self._sums = self._sums, dict.fromkeys(self.KEYS, 0.0)
+        cpu, self._cpu = self._cpu, 0.0
+        n_prefills = prefills - self._prefills
+        plain = not n_prefills
+        n_admitted = admitted - self._admitted
+        self._prefills, self._admitted = prefills, admitted
+        gc_s, generation = self._gc.seconds - self._gc_s, None
+        if gc_s:
+            passes = self._gc.passes
+            generation = max(
+                (g for g in range(3) if passes[g] != self._gc_passes[g]),
+                default=None,
+            )
+            self._gc_s, self._gc_passes = self._gc.seconds, list(passes)
+        if last is None:
+            return  # the first block since the metrics began, or a settle
+        cycle_s = now - last
+        self.cycle_s.record(cycle_s)
+        if plain:
+            self.cycle_plain_s.record(cycle_s)
+            self.plain_wait_s += sums["wait"]
+            p50 = self.plain_p50_s
+            if p50 is not None and cycle_s > self.SLOW_FACTOR * p50:
+                self.slow_count += 1
+                self.slow_excess_s += cycle_s - p50
+            if self.cycle_plain_s.count >= self._refresh_at:
+                self.plain_p50_s = self.cycle_plain_s.quantile(0.5)
+                self._refresh_at += min(self._refresh_at, self.REFRESH)
+        # the longest of all, and the longest of the plain ones apart:
+        # where prefills come in waves every one of the former is a wave
+        heaps = [
+            heap
+            for heap in ((self._slowest, self._slowest_plain) if plain
+                         else (self._slowest,))
+            if len(heap) < self.SLOWEST or cycle_s > heap[0][0]
+        ]
+        if not heaps:
+            return
+        record = {"cycle": cycle, "at_s": now - self._t0, "cycle_s": cycle_s,
+                  "plain": plain}
+        record.update((f"{key}_s", sums[key]) for key in self.KEYS)
+        record.update(
+            caller_s=cycle_s - sum(sums.values()),
+            cpu_s=cpu,
+            gc_s=gc_s,
+            gc_generation=generation,
+            descheduled_s=cycle_s - sums["wait"] - sums["first_wait"] - cpu,
+            admitted=n_admitted,
+            prefills=n_prefills,
+            riders=riders,
+            dispatched=dispatched,
+        )
+        for heap in heaps:
+            push = (heapq.heappush if len(heap) < self.SLOWEST
+                    else heapq.heappushpop)
+            push(heap, (cycle_s, cycle, record))
+
+    def to_json(self) -> dict:
+        return {
+            "count": self.cycle_s.count,
+            "total_s": self.cycle_s.total,
+            "plain_count": self.cycle_plain_s.count,
+            "plain_total_s": self.cycle_plain_s.total,
+            "plain_wait_s": self.plain_wait_s,
+            "plain_p50_s": self.plain_p50_s,
+            "slow": {
+                "factor": self.SLOW_FACTOR,
+                "count": self.slow_count,
+                "excess_s": self.slow_excess_s,
+            },
+            "starved_dispatches": dict(self.starved),
+            "slowest": [r for _, _, r in sorted(self._slowest, reverse=True)],
+            "slowest_plain": [
+                r for _, _, r in sorted(self._slowest_plain, reverse=True)
+            ],
         }
 
 
@@ -190,13 +414,25 @@ class ServeMetrics:
     time-per-output-token figure, derived from the request's OWN
     lifecycle timestamps so the aggregate and ``RequestResult.tpot_s``
     provably agree), ``slot_occupancy`` (active / total slots, sampled
-    per decode dispatch), ``prefill_s`` / ``decode_s`` (per-dispatch
-    wall times, fetch included), and the host phases of a tick around
-    those dispatches — ``schedule_s`` (expiry + admissions, prefills
-    included), ``decode_args_s`` (host arrays and their transfers before
-    the decode dispatch) and ``harvest_s`` (the token walk and the
-    gauges after its sync): the spans ``serve/schedule``, ``serve/decode_args`` and
-    ``serve/harvest`` of a profile, for an operator without one.
+    per decode dispatch), ``prefill_s`` (one record a prefill: its
+    dispatch's host seconds plus the wait for its first token) and
+    ``decode_s`` (the span ``serve/decode``: the call of a decode
+    dispatch plus the blocked read of a token block, its predecessor's
+    on an engine that lags), the two children that split it —
+    ``dispatch_s`` (``serve/dispatch``: the host busy in the call and
+    the rebinding of its outputs) and ``wait_s`` (``serve/wait``: the
+    host blocked on the block, its slack) — and the host phases of a
+    tick around them — ``schedule_s`` (expiry + admissions, prefills
+    included), ``decode_args_s`` (the decode dispatch's argument list)
+    and ``harvest_s`` (the token walk and the gauges after its sync):
+    the spans of a profile, for an operator without one.  ``cycle_s``
+    is arrival to arrival of two consecutive decode token blocks and
+    ``cycle_plain_s`` the cycles in which no prefill was completed;
+    :class:`CycleAccount` (``to_json()["cycles"]``) splits each cycle
+    into those phases and keeps the slowest whole, and counts the
+    ``starved_dispatches``: a count that depends on timing, so it is
+    kept there and not among ``counters``, every integer of which the
+    session recorder folds into its replay digest.
 
     Prometheus: :meth:`collector` re-registers this whole set through an
     ``obs.metrics.MetricsRegistry`` (counters -> ``*_total``, gauges
@@ -216,6 +452,10 @@ class ServeMetrics:
         "schedule_s",
         "decode_args_s",
         "harvest_s",
+        "dispatch_s",
+        "wait_s",
+        "cycle_s",
+        "cycle_plain_s",
     )
 
     def __init__(
@@ -341,6 +581,11 @@ class ServeMetrics:
         self.schedule_s = Histogram()
         self.decode_args_s = Histogram()
         self.harvest_s = Histogram()
+        self.dispatch_s = Histogram()
+        self.wait_s = Histogram()
+        self.cycle_s = Histogram()
+        self.cycle_plain_s = Histogram()
+        self.cycles = CycleAccount(self.cycle_s, self.cycle_plain_s)
 
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] += n
@@ -378,6 +623,18 @@ class ServeMetrics:
             for name, n in zip(names, (int(v) for v in np.asarray(value))):
                 for full in (name, f"{name}_{phase}"):
                     self.counters[full] = self.counters.get(full, 0) + n
+
+    def cycle_arrived(
+        self, cycle: int, riders: int, dispatched: Optional[int]
+    ) -> None:
+        """Dispatch ``cycle``'s token block has arrived on the host, with
+        ``riders`` requests in it; ``dispatched`` is the decode dispatch
+        issued since the block before it arrived, if one was
+        (:class:`CycleAccount`)."""
+        self.cycles.arrived(
+            cycle, riders, dispatched,
+            self.prefill_s.count, self.counters["requests_admitted"],
+        )
 
     def observe_gauges(self, queue_depth: int, active_slots: int) -> None:
         self.queue_depth = queue_depth
@@ -506,6 +763,7 @@ class ServeMetrics:
                 for name in self._HISTOGRAMS
             },
             "derived": derived,
+            "cycles": self.cycles.to_json(),
         }
 
     def snapshot(self) -> dict:
@@ -568,6 +826,12 @@ class ServeMetrics:
                         f"{prefix}_{base}_window_count", "gauge"
                     ).add(s["window_count"])
                 )
+            starved = MetricFamily(
+                f"{prefix}_starved_dispatches_total", "counter"
+            )
+            for kind, n in j["cycles"]["starved_dispatches"].items():
+                starved.add(n, kind=kind)
+            fams.append(starved)
             return fams
 
         return collect

@@ -64,6 +64,11 @@ class RequestResult:
     queue_wait_s: Optional[float] = None
     tpot_s: Optional[float] = None
     events: List[tuple] = dataclasses.field(default_factory=list)
+    # the running numbers (``ServeEngine``'s ``cycle``) of the first and
+    # the last decode dispatch whose block held a token of this request;
+    # None where it finished on its prefill's token
+    first_decode_cycle: Optional[int] = None
+    last_decode_cycle: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -88,13 +93,19 @@ class Request:
     slot: Optional[int] = None
     generated: List[int] = dataclasses.field(default_factory=list)
     finish_reason: Optional[str] = None
+    # the decode dispatches (``ServeEngine``'s running ``cycle`` number)
+    # whose blocks held this request's first and latest decoded token:
+    # two integers, so that nothing is kept per request and tick
+    first_decode_cycle: Optional[int] = None
+    last_decode_cycle: Optional[int] = None
     # -- paged-KV reservation (engine's admission gate stashes these) ----
     pages: Optional[List[int]] = None  # page chain, prefix order
     prefix_len: int = 0  # page-aligned tokens served from the prefix cache
     # -- lifecycle event log (observability) -----------------------------
     # (name, monotonic_ts, data-dict-or-None) appended by the scheduler
     # and engine at every state change: submit -> admitted/gated/expire ->
-    # prefill -> first_token -> decode_chunk* -> finish.  JSON-able;
+    # prefill -> first_token -> finish: O(1) entries a request, none a
+    # tick.  JSON-able;
     # exported as per-request Perfetto tracks by obs.trace.
     events: List[tuple] = dataclasses.field(default_factory=list)
 
@@ -149,6 +160,8 @@ class Request:
             ),
             tpot_s=tpot,
             events=list(self.events),
+            first_decode_cycle=self.first_decode_cycle,
+            last_decode_cycle=self.last_decode_cycle,
         )
 
 

@@ -41,28 +41,48 @@ def trace(log_dir: str) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def timed_annotation(name: str, sink: Optional[Any] = None) -> Iterator[dict]:
+class timed_annotation:
     """The span primitive (``obs.trace.Tracer.span``) plus wall-clock
-    timing.  Yields a dict that gains ``{"seconds": ...}`` on exit;
-    ``sink(seconds)`` is called if given (e.g. a
+    timing.  Entering yields a dict that gains ``{"seconds": ...}`` on
+    exit; ``sink(seconds)`` is called if given (e.g. a
     ``serve.metrics.Histogram.record``).  The serving engine wraps every
     phase of a step with this, so a profiler trace and the metrics
     snapshot describe the same regions.
 
     The span enters the profiler annotation (once) and, with the tracer
-    enabled, records the host event.  The region is also a
+    enabled, records the host event; ``stats`` ride along as the
+    annotation's stats (``cycle=7``), never in its name, and are
+    formatted only while a profile is being taken.  The region is also a
     recompile-attribution scope (``obs.recompile``): an XLA compile
     fired inside it is counted under ``name`` by any installed
-    ``RecompileWatcher``.
+    ``RecompileWatcher``.  A body that raises leaves no ``seconds`` and
+    calls no sink.  (A class, not a generator: a decode step enters nine
+    of these with the host's caches cold.)
     """
-    out: dict = {}
-    t0 = time.perf_counter()
-    with get_tracer().span(name, cat="dispatch"), recompile_scope(name):
-        yield out
-    out["seconds"] = time.perf_counter() - t0
-    if sink is not None:
-        sink(out["seconds"])
+
+    __slots__ = ("_sink", "_span", "_scope", "_out", "_t0")
+
+    def __init__(self, name: str, sink: Optional[Any] = None, **stats: Any):
+        self._sink = sink
+        self._span = get_tracer().span(name, cat="dispatch", **stats)
+        self._scope = recompile_scope(name)
+
+    def __enter__(self) -> dict:
+        self._out = {}
+        self._t0 = time.perf_counter()
+        self._span.__enter__()
+        self._scope.__enter__()
+        return self._out
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        try:
+            self._scope.__exit__(exc_type, exc, tb)
+        finally:
+            self._span.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            self._out["seconds"] = seconds = time.perf_counter() - self._t0
+            if self._sink is not None:
+                self._sink(seconds)
 
 
 def cost_summary(fn: Any, *args: Any, peak_flops: Optional[float] = None, **kwargs: Any) -> dict:
