@@ -448,17 +448,23 @@ def test_flash_forward_at_unequal_widths_compiles(one_chip):
 
 
 @pytest.mark.parametrize(
-    "b,s,hq,hkv,window,remat",
+    "b,s,hq,hkv,d,dtype,window,remat",
     [
-        (2, 2048, 32, 32, None, False),  # llama2_7b MHA
-        (1, 4096, 32, 8, None, False),  # mistral_7b / llama3_8b GQA
-        (1, 4096, 32, 8, 1024, False),  # sliding window
-        (4, 2048, 16, 16, None, True),  # dscoder-1.3b under full remat
+        (2, 2048, 32, 32, D, jnp.bfloat16, None, False),  # llama2_7b MHA
+        (1, 4096, 32, 8, D, jnp.bfloat16, None, False),  # mistral_7b GQA
+        (1, 4096, 32, 8, D, jnp.bfloat16, 1024, False),  # sliding window
+        # dscoder-1.3b under full remat: the train cell's three kernels
+        (4, 2048, 16, 16, D, jnp.bfloat16, None, True),
+        # the kernels' products take the rows' dtype (bf16 above, where
+        # P^T dO and dS^T Q contract bf16 tiles over their FIRST
+        # dimension); float32 rows keep float32 products
+        (4, 2048, 16, 16, D, jnp.float32, None, False),
+        (1, 3072, 16, 2, 256, jnp.bfloat16, None, False),  # Qwen3-Next's
     ],
-    ids=["mha", "gqa", "gqa-window", "mha-remat"],
+    ids=["mha", "gqa", "gqa-window", "mha-remat", "mha-f32", "gqa-head256"],
 )
 def test_flash_attention_fwd_bwd_compiles(
-    one_chip, b, s, hq, hkv, window, remat
+    one_chip, b, s, hq, hkv, d, dtype, window, remat
 ):
     def attend(q, k, v):
         return flash_attention(
@@ -469,8 +475,8 @@ def test_flash_attention_fwd_bwd_compiles(
         out = (jax.checkpoint(attend) if remat else attend)(q, k, v)
         return jnp.sum(out.astype(jnp.float32))
 
-    q = ((b, s, hq, D), jnp.bfloat16)
-    kv = ((b, s, hkv, D), jnp.bfloat16)
+    q = ((b, s, hq, d), dtype)
+    kv = ((b, s, hkv, d), dtype)
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
     # the same three names whatever wraps the call: under remat the
     # backward pair used to take the name "checkpoint", and the forward
@@ -480,6 +486,29 @@ def test_flash_attention_fwd_bwd_compiles(
     forward = [n for n in names if "flash_forward" in n]
     assert forward == [FLASH_FORWARD]  # rule 1
     assert not [n for n in names if n not in forward and "decode" in n]  # 2
+
+
+def test_flash_biased_backward_compiles(one_chip):
+    """T5's materialised bias (12 heads of 64, bf16 rows): the recompute
+    that every backward kernel shares takes bf16 products there too.
+    (The bucket-TABLE mode is not here: Mosaic refuses its (1, buckets)
+    block of an (H, buckets) table, at the parent commit as well.)"""
+    b, s, h, d = 2, 1024, 12, 64
+
+    def loss(q, k, v, bias):
+        out = flash_attention(
+            q, k, v, bias=bias, causal=False, interpret=False
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = ((b, s, h, d), jnp.bfloat16)
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip, qkv, qkv, qkv,
+        ((h, s, s), jnp.bfloat16),
+    )
+    assert sorted(_kernel_names(text)) == sorted(
+        [FLASH_FORWARD] + FLASH_BACKWARD + ["tdx_flash_backward_dbias"]
+    )
 
 
 @pytest.mark.parametrize(

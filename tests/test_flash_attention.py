@@ -949,3 +949,274 @@ class TestSlidingWindow:
                 np.asarray(out_w), np.asarray(ref), rtol=2e-5, atol=2e-5,
                 err_msg=f"pos={pos}",
             )
+
+
+class TestStoredDtypeOperands:
+    """Every matmul of the flash kernels takes its operands in the dtype
+    the rows are stored in and accumulates in float32; the tiles between
+    the matmuls (``p``, ``ds``) are rounded to that dtype where they meet
+    stored rows, and nowhere else.  bf16 rows are held to a float32
+    ``jax.numpy`` reference of the same (already rounded) inputs.
+
+    The tolerances are what one bf16 rounding of ``p`` / ``ds`` and of
+    the result allows.  An element rounds by at most 2**-9 of itself, the
+    sum over keys of zero-mean values keeps that share (error and output
+    shrink together), and the result rounds once more: over three seeds
+    of the cases below the worst element read 0.30 % of its array's
+    largest forward and 0.74 % backward.  The limits sit three times
+    above that, and under what the NEXT precision down does: the
+    reference with ``p`` rounded to float8_e4m3 (2**-4) read 2.1-3.8 %
+    forward, and the forward's limit has to refuse it in every case."""
+
+    FWD_TOL = 1e-2  # max |out - ref| over max |ref|
+    GRAD_TOL = 2e-2
+
+    #: name -> shapes, flash_attention's keywords, what is differentiated
+    CASES = {
+        # the train cell's shape class: equal widths, head 128, causal
+        "mha128": dict(hq=2, hkv=2, d=128),
+        "gqa4": dict(hq=8, hkv=2, d=64),  # n_rep 4: float32 dK/dV partials
+        "one-kv-head": dict(hq=5, hkv=1, d=128),  # Jamba's 20 on 1
+        "head256": dict(hq=4, hkv=1, d=256),  # Qwen3-Next's width
+        "qk192-v128": dict(hq=2, hkv=2, d=192, dv=128, grads=False),  # MLA
+        "window": dict(hq=4, hkv=2, d=64, kw=dict(window=40)),
+        "bias": dict(hq=2, hkv=2, d=64, extra="bias"),  # dbias
+        "bucket-table": dict(hq=2, hkv=2, d=64, extra="table"),  # dtable
+    }
+    B, S, BLOCK = 2, 128, dict(block_q=64, block_k=32)
+    BUCKETS, MAX_DIST = 32, 128
+
+    @classmethod
+    def _inputs(cls, hq, hkv, d, dv=None, extra=None, seed=0, s=None, **_):
+        rs = np.random.RandomState(seed)
+        bf16 = lambda *s: jnp.asarray(rs.randn(*s), jnp.bfloat16)  # noqa: E731
+        s = s or cls.S
+        q = bf16(cls.B, s, hq, d)
+        k = bf16(cls.B, s, hkv, d)
+        v = bf16(cls.B, s, hkv, dv or d)
+        g = bf16(cls.B, s, hq, dv or d)  # the cotangent
+        if extra == "bias":
+            return (q, k, v, bf16(hq, s, s)), g
+        if extra == "table":
+            return (q, k, v, bf16(hq, cls.BUCKETS) * 0.5), g
+        return (q, k, v), g
+
+    @classmethod
+    def _bias_of(cls, table):
+        from torchdistx_tpu.ops.flash_attention import rel_pos_bucket
+
+        pos = jnp.arange(cls.S)
+        bucket = rel_pos_bucket(
+            pos[None, :] - pos[:, None], bidirectional=False,
+            buckets=cls.BUCKETS, max_dist=cls.MAX_DIST,
+        )
+        return jnp.transpose(table.T[bucket], (2, 0, 1))
+
+    @classmethod
+    def _flash(cls, extra, kw):
+        if extra == "table":
+            kw = dict(kw, rel_bias_buckets=cls.BUCKETS,
+                      rel_bias_max_dist=cls.MAX_DIST)
+            return lambda q, k, v, t: flash_attention(
+                q, k, v, rel_bias_table=t, **cls.BLOCK, **kw)
+        if extra == "bias":
+            return lambda q, k, v, b: flash_attention(
+                q, k, v, bias=b, **cls.BLOCK, **kw)
+        return lambda q, k, v: flash_attention(q, k, v, **cls.BLOCK, **kw)
+
+    @classmethod
+    def _reference(cls, extra, kw, p_dtype=None):
+        """Float32 throughout, ``HIGHEST`` products; ``p_dtype`` plants
+        the fault: the probabilities rounded to it before P.V."""
+        window = kw.get("window")
+
+        def ref(q, k, v, extra_arg=None):
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+            n_rep = q.shape[2] // k.shape[2]
+            k, v = (jnp.repeat(x, n_rep, axis=2) for x in (k, v))
+            logits = jnp.einsum(
+                "bqhd,bkhd->bhqk", q, k, precision="highest"
+            ) / np.sqrt(q.shape[-1])
+            if extra == "bias":
+                logits = logits + extra_arg.astype(jnp.float32)[None]
+            if extra == "table":
+                logits = logits + cls._bias_of(
+                    extra_arg.astype(jnp.float32))[None]
+            i = jnp.arange(q.shape[1])[:, None]
+            j = jnp.arange(q.shape[1])[None, :]
+            mask = j <= i
+            if window is not None:
+                mask = mask & (j > i - window)
+            p = jax.nn.softmax(jnp.where(mask, logits, -jnp.inf), axis=-1)
+            if p_dtype is not None:
+                p = p.astype(p_dtype).astype(jnp.float32)
+            return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest")
+
+        return ref
+
+    @staticmethod
+    def _gap(a, b):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        return float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+
+    @pytest.mark.parametrize("name", list(CASES))
+    def test_bf16_rows_match_a_float32_reference(self, name):
+        case = dict(self.CASES[name])
+        kw, extra = case.pop("kw", {}), case.get("extra")
+        args, g = self._inputs(**case)
+        flash = self._flash(extra, kw)
+        ref = self._reference(extra, kw)
+        fp8 = self._reference(extra, kw, p_dtype=jnp.float8_e4m3fn)
+
+        out = flash(*args)
+        assert out.dtype == jnp.bfloat16
+        gaps = {"out": self._gap(out, ref(*args))}
+        if case.get("grads", True):
+            def grads(fn):
+                return jax.grad(
+                    lambda *a: jnp.sum(
+                        fn(*a).astype(jnp.float32) * g.astype(jnp.float32)),
+                    argnums=tuple(range(len(args))),
+                )(*args)
+
+            names = ["dq", "dk", "dv"] + (["d" + extra] if extra else [])
+            for n, a, b in zip(names, grads(flash), grads(ref)):
+                assert a.dtype == jnp.bfloat16, n
+                gaps[n] = self._gap(a, b)
+        limits = {n: self.FWD_TOL if n == "out" else self.GRAD_TOL
+                  for n in gaps}
+        assert all(gaps[n] <= limits[n] for n in gaps), gaps
+        # the same limit refuses the next precision down
+        assert self._gap(out, fp8(*args)) > self.FWD_TOL
+
+    def test_residuals_sum_the_float32_probabilities(self):
+        """``return_residuals`` (ring attention's block): the raw float32
+        accumulator, and ``l`` summed from the float32 ``p``, not from the
+        tile rounded for P.V: ``l`` agrees with the reference to float32
+        noise, and a sum of the rounded tile would not."""
+        from torchdistx_tpu.ops.flash_attention import _flash_forward
+
+        (q, k, v), _ = self._inputs(hq=4, hkv=2, d=64, seed=1)
+        raw, m, l = _flash_forward(
+            q, k, v, causal=True, return_residuals=True, interpret=True,
+            **self.BLOCK,
+        )
+        assert raw.dtype == m.dtype == l.dtype == jnp.float32
+        q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+        k32, v32 = (jnp.repeat(x, 2, axis=2) for x in (k32, v32))
+        logits = jnp.einsum(
+            "bqhd,bkhd->bhqk", q32, k32, precision="highest") / 8.0
+        mask = jnp.tril(jnp.ones((self.S, self.S), bool))
+        logits = jnp.where(mask, logits, -jnp.inf)
+        m_ref = jnp.max(logits, axis=-1)
+        p = jnp.exp(logits - m_ref[..., None])
+        np.testing.assert_allclose(m, m_ref, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(l, p.sum(-1), rtol=2e-5)
+        rounded = p.astype(jnp.bfloat16).astype(jnp.float32).sum(-1)
+        assert float(jnp.max(jnp.abs(rounded / l - 1.0))) > 2e-5
+        ref = jnp.einsum("bhqk,bkhd->bqhd", p, v32, precision="highest")
+        assert self._gap(raw, ref) <= self.FWD_TOL
+
+    @staticmethod
+    def _kernels(fn, *args):
+        """{kernel name: (grid, [(lhs dtype, rhs dtype, out dtype) of
+        every ``dot_general`` inside])} over the ``pallas_call``s of
+        ``fn``'s jaxpr."""
+        found = {}
+
+        def walk(jaxpr, kernel):
+            for eqn in jaxpr.eqns:
+                inside = kernel
+                if eqn.primitive.name == "pallas_call":
+                    inside = eqn.params["name"]
+                    found[inside] = (
+                        tuple(eqn.params["grid_mapping"].grid), [])
+                if eqn.primitive.name == "dot_general" and kernel:
+                    found[kernel][1].append(tuple(
+                        str(x.aval.dtype) for x in (*eqn.invars, *eqn.outvars)
+                    ))
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, inside)
+
+        walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+        return found
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    def test_every_kernel_product_takes_the_stored_dtype(self, dtype):
+        """The reading that says the mechanism engaged: forward and both
+        backward kernels (and the dbias kernel's recompute) hold only
+        products of the INPUT dtype with a float32 result."""
+        q, k, v, bias = (
+            jnp.zeros(s, dtype) for s in
+            [(1, 128, 4, 64), (1, 128, 2, 64), (1, 128, 2, 64), (4, 128, 128)]
+        )
+
+        def loss(q, k, v, b):
+            return jnp.sum(flash_attention(
+                q, k, v, bias=b, causal=True, interpret=True, **self.BLOCK
+            ).astype(jnp.float32))
+
+        kernels = self._kernels(jax.grad(loss, (0, 1, 2, 3)), q, k, v, bias)
+        assert {n: len(dots) for n, (_, dots) in kernels.items()} == {
+            "tdx_flash_forward": 2, "tdx_flash_backward_dkv": 4,
+            "tdx_flash_backward_dq": 3, "tdx_flash_backward_dbias": 2,
+        }
+        for name, (_, dots) in kernels.items():
+            assert set(dots) == {(dtype, dtype, "float32")}, name
+
+    def test_rows_of_two_dtypes_meet_in_the_wider_one(self):
+        # bf16 queries on a float32 cache: float32 products, as before
+        (q, k, v), _ = self._inputs(hq=2, hkv=2, d=64, seed=2)
+        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        flash = self._flash(None, {})
+        _, dots = self._kernels(flash, q, k, v)["tdx_flash_forward"]
+        assert set(dots) == {("float32",) * 3}
+        gap = self._gap(flash(q, k, v), self._reference(None, {})(q, k, v))
+        assert gap <= self.FWD_TOL
+
+    def test_tile_bounds_follow_the_rows_bytes_and_the_bias(self):
+        """Where the caller names no bounds, rows of two bytes take
+        1024 x 1024 tiles in all three kernels; float32 rows and either
+        bias mode keep 256 x 512 (their tiles hold more bytes: a float32
+        dK/dV tile of 1024 x 1024 does not fit the kernel's VMEM)."""
+        def grids_of(dtype, bias=False):
+            q = jnp.zeros((1, 2048, 2, 64), dtype)
+            extra = (jnp.zeros((2, 2048, 2048), dtype),) if bias else ()
+
+            def loss(q, k, v, *b):
+                kw = {"bias": b[0]} if b else {}
+                return jnp.sum(flash_attention(
+                    q, k, v, causal=True, interpret=True, **kw
+                ).astype(jnp.float32))
+
+            kernels = self._kernels(
+                jax.grad(loss, (0, 1, 2)), q, q, q, *extra)
+            return {n.removeprefix("tdx_flash_"): grid
+                    for n, (grid, _) in kernels.items()}
+
+        large = {"forward": (2, 2, 2), "backward_dkv": (2, 2, 2),
+                 "backward_dq": (2, 2, 2)}
+        small = {"forward": (2, 8, 4), "backward_dkv": (2, 4, 8),
+                 "backward_dq": (2, 8, 4)}
+        assert grids_of(jnp.bfloat16) == large
+        assert grids_of(jnp.float32) == small
+        assert grids_of(jnp.bfloat16, bias=True) == {
+            **small, "backward_dbias": (2, 8, 4, 1)}
+
+    def test_the_large_tiles_match_the_reference(self):
+        # 2048 rows in 1024 x 1024 tiles: a diagonal tile, a whole one
+        # and a pruned one, held to the same limits as the small tiles
+        args, g = self._inputs(hq=2, hkv=1, d=64, s=2048, seed=3)
+        ref = self._reference(None, {})
+
+        def grads(fn):
+            return jax.grad(
+                lambda *a: jnp.sum(
+                    fn(*a).astype(jnp.float32) * g.astype(jnp.float32)),
+                argnums=(0, 1, 2),
+            )(*args)
+
+        assert self._gap(flash_attention(*args), ref(*args)) <= self.FWD_TOL
+        for n, a, b in zip(("dq", "dk", "dv"), grads(flash_attention),
+                           grads(ref)):
+            assert self._gap(a, b) <= self.GRAD_TOL, n

@@ -13,6 +13,13 @@ repeated K/V in HBM.
 The causal mask is end-aligned like ``multihead_attention`` (query i may
 see keys up to ``skv - sq + i``), so the two agree for every (Sq, Skv)
 combination, including cached decode where Sq < Skv.
+
+Precision: every matmul takes its operands in the dtype the rows are
+stored in and accumulates in float32; everything between the matmuls
+(logits, mask, max, sum, exponentials, lse, delta, the accumulators) is
+float32.  (On a v5e, Mosaic gives float32 operands at the default
+precision the same single bf16 pass: bf16 rows cast up first gave the
+same bits in the same time, PR 40.  The tiles' size is what costs.)
 """
 
 from __future__ import annotations
@@ -30,6 +37,23 @@ __all__ = ["flash_attention", "rel_pos_bucket"]
 
 _NEG_INF = -1e30
 _RES_LANES = 128  # TPU lane width: residual (m, l) rows broadcast over it
+
+# Upper bounds of a tile's (query rows, key rows) where the caller names
+# none.  The kernels are bound by their MXU passes and what each grid
+# step costs around them, not by the masked work a larger causal tile
+# adds: on a v5e (scripts/bench_flash_attention.py --cells, bf16 rows,
+# microseconds a call, PR 40) 4 x 2048 tokens at 16 heads of 128 took
+#   forward 1824 / dK dV 1854 / dQ 1664   at 256 x 512,
+#           1416 /       1483 /    1231   at 512 x 512,
+#           1056 /       1350 /    1173   at 512 x 1024,
+#            922 /       1345 /    1055   at 1024 x 1024,
+# and a 6144-token prefill at 32 heads of 192 / 128 8759 -> 4212.  A
+# dK/dV tile of 1024 x 2048 does not fit the kernel's 16 MiB of VMEM,
+# and with float32 rows 1024 x 1024 passes it by 0.75 MB; with a bias
+# the (query, key) tile of it and of dbias rides along at 2-4 bytes an
+# element.  Those keep the bound they have always been compiled with.
+_BLOCKS = (1024, 1024)  # rows of two bytes or fewer, no bias
+_BLOCKS_SMALL = (256, 512)
 
 
 def rel_pos_bucket(rel_pos, *, bidirectional: bool, buckets: int, max_dist: int):
@@ -131,6 +155,18 @@ def _shrink_block(block: int, s: int) -> int:
     return block
 
 
+def _dot(a, b, contract):
+    """``a`` x ``b`` over ``contract`` on the MXU, float32 out.  The
+    operands go in as they are stored; a float32 tile that meets stored
+    rows is rounded to their dtype by the caller, at the call.  Rows of
+    two dtypes meet in the wider one."""
+    dtype = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot_general(
+        a.astype(dtype), b.astype(dtype), (contract, ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _kernel(
     q_ref,
     k_ref,
@@ -173,18 +209,10 @@ def _kernel(
 
     @pl.when(any_visible)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)  # (block_q, d)
-        k = k_ref[0].astype(jnp.float32)  # (block_k, d)
-        v = v_ref[0].astype(jnp.float32)
-        logits = (
-            jax.lax.dot_general(
-                q,
-                k,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # (block_q, block_k)
+        q = q_ref[0]  # (block_q, d)
+        k = k_ref[0]  # (block_k, d)
+        v = v_ref[0]
+        logits = _dot(q, k, ((1,), (1,))) * scale  # (block_q, block_k)
         if has_bias:
             if bucket_cfg is not None:
                 logits = logits + _bucket_bias_tile(
@@ -206,8 +234,9 @@ def _kernel(
         p = jnp.exp(logits - m_new)
         correction = jnp.exp(m_prev - m_new)
         l_ref[:] = l_ref[:] * correction + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * correction + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        # l sums the float32 p; P.V takes p in the values' dtype
+        acc_ref[:] = acc_ref[:] * correction + _dot(
+            p.astype(v.dtype), v, ((1,), (0,))
         )
         m_ref[:] = m_new
 
@@ -253,20 +282,17 @@ def _bwd_recompute(
     for all three backward kernels so a masking/p-reconstruction fix can
     never desynchronize them; ``qi``/``kk`` are the tile's Q/K block
     indices in whatever grid order the caller uses."""
-    q = q_ref[0].astype(jnp.float32)  # (block_q, d)
-    k = k_ref[0].astype(jnp.float32)  # (block_k, d)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)  # (block_q, d)
-    o = o_ref[0].astype(jnp.float32)
+    q = q_ref[0]  # (block_q, d)
+    k = k_ref[0]  # (block_k, d)
+    v = v_ref[0]
+    do = do_ref[0]  # (block_q, d)
+    o = o_ref[0]
     lse = lse_ref[...][:, :1]  # (block_q, 1)
-    delta = jnp.sum(do * o, axis=-1, keepdims=True)
-    logits = (
-        jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        * scale
-    )  # (block_q, block_k)
+    delta = jnp.sum(
+        do.astype(jnp.float32) * o.astype(jnp.float32),
+        axis=-1, keepdims=True,
+    )
+    logits = _dot(q, k, ((1,), (1,))) * scale  # (block_q, block_k)
     if bias_ref is not None:
         if bucket_cfg is not None:
             logits = logits + _bucket_bias_tile(
@@ -282,10 +308,7 @@ def _bwd_recompute(
             diag_offset=diag_offset, causal=causal, window=window,
         )
         p = jnp.where(mask, p, 0.0)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    dp = _dot(do, v, ((1,), (1,)))
     return p, dp, delta
 
 
@@ -346,15 +369,13 @@ def _bwd_dkv_kernel(
             window=window,
         )
         # dV += P^T dO
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        dv_acc[:] = dv_acc[:] + _dot(
+            p.astype(do_ref.dtype), do_ref[0], ((0,), (0,))
         )
         # dS = P * (dO V^T - delta) * scale;  dK += dS^T Q
         ds = p * (dp - delta) * scale
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        dk_acc[:] = dk_acc[:] + _dot(
+            ds.astype(q_ref.dtype), q_ref[0], ((0,), (0,))
         )
 
     @pl.when(qi == n_q - 1)
@@ -408,9 +429,8 @@ def _bwd_dq_kernel(
             window=window,
         )
         ds = p * (dp - delta) * scale
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds, k_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        dq_acc[:] = dq_acc[:] + _dot(
+            ds.astype(k_ref.dtype), k_ref[0], ((1,), (0,))
         )
 
     @pl.when(kk == n_k - 1)
@@ -1068,8 +1088,8 @@ def flash_attention(
     bias: Optional[jax.Array] = None,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 256,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     rel_bias_table: Optional[jax.Array] = None,
     rel_bias_buckets: int = 32,
@@ -1094,6 +1114,11 @@ def flash_attention(
     materializes (T5 long context keeps flash's O(S) memory).
     Differentiable: the backward emits dtable via a fourth kernel.
     Requires Sq == Skv; mutually exclusive with ``bias``.
+
+    ``block_q`` / ``block_k``: upper bounds of a tile, each halved until
+    it divides its sequence length; ``None`` takes the module's
+    (``_BLOCKS``; ``_BLOCKS_SMALL`` for float32 rows and in either bias
+    mode, whose tiles hold more bytes).
 
     ``window``: sliding-window attention (Mistral/Mixtral) — query ``i``
     attends keys ``(i - window, i]``.  Requires ``causal=True``; blocks
@@ -1124,6 +1149,10 @@ def flash_attention(
         bucket_cfg = None
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
+    wide = max(jnp.dtype(x.dtype).itemsize for x in (q, k, v)) > 2
+    bound_q, bound_k = _BLOCKS_SMALL if wide or bias is not None else _BLOCKS
+    block_q = bound_q if block_q is None else block_q
+    block_k = bound_k if block_k is None else block_k
     return _flash_attention_vjp(
         q, k, v, bias, causal, scale, block_q, block_k, interpret,
         bucket_cfg, window,
@@ -1145,8 +1174,8 @@ def _flash_forward(
     bias: Optional[jax.Array] = None,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 256,
-    block_k: int = 512,
+    block_q: int = _BLOCKS_SMALL[0],
+    block_k: int = _BLOCKS_SMALL[1],
     interpret: Optional[bool] = None,
     return_residuals: bool = False,
     return_lse: bool = False,
