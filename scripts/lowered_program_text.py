@@ -1,10 +1,10 @@
 #!/usr/bin/env python
-"""Digests of the lowered text of the programs that hold no expert layer:
-Mistral-7B's serve decode and prefill programs and Jamba2-3B's (two and
-fourteen layers at the published widths, 16 slots of 2048, lowered for a
-described ``v5e:2x2`` chip from the signatures the engine dispatches),
-and a small Llama train step (``ShardedTrainStep`` on the CPU's one
-device).  A change that says it leaves those programs alone shows it by
+"""Digests of the lowered text of every serve cell's programs: the serve
+decode and prefill programs of Mistral-7B, Jamba2-3B, kanana-2-30b-a3b
+and Qwen3-Next-80B-A3B (two, fourteen, two and four layers at the
+published widths, 16 slots, lowered for a described ``v5e:2x2`` chip
+from the signatures the engine dispatches), and a small Llama train step
+(``ShardedTrainStep`` on the CPU's one device).  A change that says it leaves those programs alone shows it by
 the same digests on the parent and on itself; source paths are part of
 the text, so both trees run FROM THE SAME PATH, one after the other:
 
@@ -33,7 +33,9 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 import torchdistx_tpu as tdx  # noqa: E402
 from torchdistx_tpu.generation import SLOT_STATE_ROWS  # noqa: E402
-from torchdistx_tpu.models import Jamba, Llama  # noqa: E402
+from torchdistx_tpu.models import (  # noqa: E402
+    DeepseekV3, Jamba, Llama, Qwen3Next,
+)
 from torchdistx_tpu.serve import ServeEngine  # noqa: E402
 
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -70,6 +72,11 @@ serve_texts("mistral", tdx.deferred_init(lambda: Llama.from_name(
     rope_theta=1e6, sliding_window=None, dtype=jnp.bfloat16)), 16, 2048, (128, 1024))
 serve_texts("jamba", tdx.deferred_init(lambda: Jamba.from_name(
     "jamba2_3b", n_layers=14, max_seq_len=2048)), 16, 2048, (256, 1024))
+serve_texts("kanana", tdx.deferred_init(lambda: DeepseekV3.from_name(
+    "kanana_2_30b_a3b", n_layers=2, max_seq_len=8192)), 16, 8192, (1024, 6144))
+serve_texts("qwen3_next", tdx.deferred_init(lambda: Qwen3Next.from_name(
+    "qwen3_next_80b_a3b", n_layers=4, vocab_size=37984, max_seq_len=4096,
+    experts_held=(0, 128))), 16, 4096, (512, 3072))
 
 # the train step: a small Llama, two layers, on the CPU's one device
 from torchdistx_tpu.nn import functional  # noqa: E402

@@ -851,3 +851,83 @@ def test_qwen3_next_serve_programs_compile(one_chip, monkeypatch):
     names = _kernel_names(prefill)
     assert (names.count("tdx_gated_delta_chunk"), names.count(
         FLASH_FORWARD), names.count("tdx_grouped_matmul")) == (3, 1, 16)
+
+
+# deepseek-coder-1.3b's train step at its published widths (the train
+# cell's): hidden 2048, 16 heads of 128, FFN 5504, vocab 32256, a step of
+# 4 x 2048 tokens under AnyPrecisionAdamW
+DS_DIM, DS_FFN, DS_VOCAB, DS_ROWS, DS_SEQ = 2048, 5504, 32256, 4, 2048
+
+
+def _dscoder_step(one_chip, monkeypatch, n_layers, auto):
+    """The train step of ``n_layers`` blocks whose checkpoints resolve
+    "auto" to ``auto``, compiled for the described chip."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    import torchdistx_tpu as tdx
+    from torchdistx_tpu.models import Llama
+    from torchdistx_tpu.models.llama import LlamaConfig
+    from torchdistx_tpu.nn import functional, functional_call
+    from torchdistx_tpu.optimizers import anyprecision_adamw
+    from torchdistx_tpu.parallel import ShardedTrainStep
+    from torchdistx_tpu.parallel.fsdp import optimizer_state_shardings
+
+    (chip,) = one_chip.device_set
+    mesh = Mesh(np.array([chip]), ("fsdp",))
+    cfg = LlamaConfig(
+        vocab_size=DS_VOCAB, dim=DS_DIM, n_layers=n_layers, n_heads=16,
+        ffn_dim=DS_FFN, max_seq_len=DS_SEQ, rope_theta=1e5, norm_eps=1e-6,
+        dtype=jnp.bfloat16, remat=True,
+    )
+    model = tdx.deferred_init(lambda: Llama(cfg))
+    opt = anyprecision_adamw(1e-4)
+    step = ShardedTrainStep(
+        lambda p, b: functional.cross_entropy(
+            functional_call(model, p, (b[0],)), b[1]),
+        opt, mesh, shard_axis="fsdp",
+    )
+    params = {
+        n: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip)
+        for n, p in model.named_parameters()
+    }
+    state = jax.eval_shape(opt.init, params)
+    state = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        state, optimizer_state_shardings(state, params, mesh),
+    )
+    rows = jax.ShapeDtypeStruct((DS_ROWS, DS_SEQ), jnp.int32, sharding=one_chip)
+    # ``use_flash=None`` and ``interpret=None`` ask jax.devices()[0].platform
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+    step._build(params, state)
+    return step._jit_under(auto).lower(
+        params, state, (rows, rows),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+
+
+def test_dscoder_step_keeps_its_blocks_outputs_by_count(one_chip, monkeypatch):
+    """Under the saving choice each block keeps, for the backward, its
+    input and six matmul outputs (q, k, v, o; gate, up: 21,248 values a
+    token) in bf16, and flash's named residuals (``out``, bf16, and the
+    per-row ``lse``, float32): the compiled step's temporaries grow by
+    that count a layer, within 10 %.  Read between 4 and 6 layers: below
+    4 the step's peak is the loss's and the update's, not the kept
+    rows'.  Flash's forward runs once a layer (full recompute runs it
+    twice)."""
+    from torchdistx_tpu.utils import compat
+
+    tokens = DS_ROWS * DS_SEQ
+    counted = tokens * (
+        2 * (DS_DIM + 4 * DS_DIM + 2 * DS_FFN)  # input, q k v o, gate up
+        + 2 * DS_DIM + 4 * 16  # flash's out, and lse for 16 heads
+    )
+    temps = {}
+    for n_layers in (4, 6):
+        compiled = _dscoder_step(one_chip, monkeypatch, n_layers, "dots")
+        temps[n_layers] = compat.compiled_memory_analysis(compiled)["temp_bytes"]
+        names = _kernel_names(compiled.as_text())
+        assert names.count(FLASH_FORWARD) == n_layers
+        assert sorted(set(names)) == sorted([FLASH_FORWARD, *FLASH_BACKWARD])
+    per_layer = (temps[6] - temps[4]) / 2
+    assert abs(per_layer / counted - 1) < 0.10, (per_layer, counted)

@@ -395,19 +395,26 @@ def test_llama3_8b_preset():
 
 
 class TestRematPolicy:
-    def test_grads_identical_across_policies(self):
+    @pytest.mark.parametrize("policy", ["full", "dots", "auto"])
+    def test_grads_identical_across_policies(self, policy):
         # remat changes WHAT is saved, never the math: loss and grads must
-        # match bitwise-closely across off/full/dots
+        # match bitwise-closely between no remat and each policy ("auto"
+        # outside any scope, and under each choice a step can make)
         import torchdistx_tpu as tdx
         from torchdistx_tpu.models import Llama
         from torchdistx_tpu.nn import functional, functional_call
+        from torchdistx_tpu.nn.remat import remat_scope
 
+        runs = [("off", None, False, None), (policy, policy, True, None)]
+        if policy == "auto":
+            runs += [("auto/full", "auto", True, "full"),
+                     ("auto/dots", "auto", True, "dots")]
         results = {}
-        for policy, remat in [(None, False), ("full", True), ("dots", True)]:
+        for key, name, remat, scope in runs:
             tdx.manual_seed(0)
             kw = dict(max_seq_len=32, remat=remat, use_flash=False)
-            if policy:
-                kw["remat_policy"] = policy
+            if name:
+                kw["remat_policy"] = name
             m = tdx.deferred_init(Llama.from_name, "tiny", **kw)
             tdx.materialize_module(m)
             p = dict(m.named_parameters())
@@ -420,11 +427,16 @@ class TestRematPolicy:
                     functional_call(m, p, (toks,)), toks
                 )
 
-            l, g = jax.value_and_grad(loss)(p)
-            results[policy or "off"] = (float(l), g)
+            if scope is None:
+                l, g = jax.value_and_grad(loss)(p)
+            else:
+                with remat_scope(scope) as sc:
+                    l, g = jax.value_and_grad(loss)(p)
+                assert sc.named == {"auto"}
+            results[key] = (float(l), g)
 
         l0, g0 = results["off"]
-        for k in ("full", "dots"):
+        for k in results:
             l1, g1 = results[k]
             np.testing.assert_allclose(l1, l0, rtol=1e-6)
             for a, b in zip(
@@ -441,18 +453,23 @@ class TestRematPolicy:
         with pytest.raises(ValueError, match="remat_policy"):
             Llama.from_name("tiny", remat_policy="typo")
 
-    def test_mixtral_honors_policy(self):
+    @pytest.mark.parametrize("policy", ["dots", "auto"])
+    def test_mixtral_honors_policy(self, policy):
         # the MoE training path threads the same policy (and the same
-        # grads-invariance) as the inherited Llama paths
+        # grads-invariance) as the inherited Llama paths; "auto" is
+        # traced where a step would choose the saving program
+        import contextlib
+
         import torchdistx_tpu as tdx
         from torchdistx_tpu.models import Mixtral
         from torchdistx_tpu.nn import functional, functional_call
+        from torchdistx_tpu.nn.remat import remat_scope
 
         results = {}
-        for policy in ("full", "dots"):
+        for name in ("full", policy):
             tdx.manual_seed(0)
             m = tdx.deferred_init(
-                Mixtral.from_name, "tiny", remat=True, remat_policy=policy,
+                Mixtral.from_name, "tiny", remat=True, remat_policy=name,
                 use_flash=False,
             )
             tdx.materialize_module(m)
@@ -467,13 +484,20 @@ class TestRematPolicy:
                 )
                 return functional.cross_entropy(logits, toks) + 0.01 * aux
 
-            l, g = jax.value_and_grad(loss)(p)
-            results[policy] = (float(l), g)
+            scope = (
+                remat_scope("dots") if name == "auto"
+                else contextlib.nullcontext()
+            )
+            with scope as sc:
+                l, g = jax.value_and_grad(loss)(p)
+            if sc is not None:
+                assert sc.named == {"auto"}
+            results[name] = (float(l), g)
         np.testing.assert_allclose(
-            results["dots"][0], results["full"][0], rtol=1e-6
+            results[policy][0], results["full"][0], rtol=1e-6
         )
         for a, b in zip(
-            jax.tree_util.tree_leaves(results["dots"][1]),
+            jax.tree_util.tree_leaves(results[policy][1]),
             jax.tree_util.tree_leaves(results["full"][1]),
         ):
             np.testing.assert_allclose(

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn import functional as F
 from ..nn import init as nn_init
+from ..nn.remat import POLICIES as REMAT_POLICIES, checkpoint_policy
 from ..ops.attention import (
     cached_attention,
     multihead_attention,
@@ -46,7 +47,8 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: object = jnp.bfloat16
     remat: bool = False  # jax.checkpoint each block
-    # Rematerialization policy when remat=True (the memory/FLOPs dial):
+    # Rematerialization policy when remat=True (the memory/FLOPs dial,
+    # nn/remat.py):
     #   "full"  — recompute everything (jax.checkpoint default); smallest
     #             footprint, costs ~23% of the bench step (BASELINE.md)
     #   "dots"  — jax.checkpoint_policies.dots_with_no_batch_dims_saveable:
@@ -54,7 +56,10 @@ class LlamaConfig:
     #             recomputes — recovers most of full-remat's overhead
     #             (recompute becomes VPU work overlapped with the MXU)
     #             while still dropping the attention-probs working set
-    remat_policy: str = "full"
+    #   "auto"  — "dots" where the step that compiles the blocks finds
+    #             the saving program fits the device (ShardedTrainStep),
+    #             else "full"
+    remat_policy: str = "auto"
     sp_axis: Optional[str] = None  # sequence parallelism over this mesh axis
     # "ring" (K/V rotate, works for any head count, O(S)-bias support) or
     # "ulysses" (two all-to-alls around local attention; needs head counts
@@ -76,11 +81,11 @@ class LlamaConfig:
             raise ValueError(
                 f"sp_mode must be 'ring' or 'ulysses', got {self.sp_mode!r}"
             )
-        if self.remat_policy not in ("full", "dots"):
+        if self.remat_policy not in REMAT_POLICIES:
             # validated at construction like sp_mode (not lazily at the
             # first rematted forward, far from the typo)
             raise ValueError(
-                f"remat_policy must be 'full' or 'dots', "
+                f"remat_policy must be one of {REMAT_POLICIES}, "
                 f"got {self.remat_policy!r}"
             )
         if self.sliding_window is not None and self.sliding_window < 1:
@@ -105,14 +110,9 @@ class LlamaConfig:
 
 def _remat_policy(name: str):
     """Resolve ``LlamaConfig.remat_policy`` to a jax.checkpoint policy
-    (None = recompute everything, the jax.checkpoint default)."""
-    if name == "full":
-        return None
-    if name == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    raise ValueError(
-        f"remat_policy must be 'full' or 'dots', got {name!r}"
-    )
+    (None = recompute everything, the jax.checkpoint default); ``"auto"``
+    reads the enclosing ``nn.remat.remat_scope``."""
+    return checkpoint_policy(name)
 
 
 def _hf_normal(shape, dtype):

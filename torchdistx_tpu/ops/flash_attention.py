@@ -965,7 +965,7 @@ def _flash_fwd_rule(
         bucket_cfg=bucket_cfg,
         window=window,
     )
-    return out, (q, k, v, bias, out, lse)
+    return _named_residuals(q, k, v, bias, out, lse)
 
 
 def _attention_chunk(qc, k, v, bias_rows, row_offset, causal, scale):
@@ -1347,3 +1347,19 @@ def _flash_forward(
         m[..., 0].reshape(b, hq, sq),
         l[..., 0].reshape(b, hq, sq),
     )
+
+
+# The forward rule names its residuals: a block checkpoint that keeps its
+# matmul outputs keeps these too (nn/remat.py), so the backward does not
+# run the forward kernel again to get them.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
+def _named_residuals(q, k, v, bias, out, lse):
+    """``_flash_fwd_rule``'s result: the output and the residuals, with
+    ``out`` and ``lse`` under ``RESIDUAL_NAMES``."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
+    return out, (q, k, v, bias, out, lse)

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from ..nn.remat import remat_scope
 from ..obs.comm import record_collective as _record_comm, tree_bytes as _leaf_bytes
 from jax import shard_map
 
@@ -299,6 +300,14 @@ class ShardedTrainStep:
                 "divergent_replicas requires exactly one replica axis"
             )
         self._jitted = None
+        # what "auto" block checkpoints save (nn/remat.py), chosen by
+        # _resolve_remat at the first concrete call; the policies the
+        # traced blocks named; the checked program's projected peak and
+        # the device budget it was held to
+        self._remat_auto: Optional[str] = None
+        self.remat_named: Optional[frozenset] = None
+        self.step_projected_peak_bytes: Optional[int] = None
+        self.hbm_budget_bytes: Optional[int] = None
 
     # -- sharding helpers --------------------------------------------------
 
@@ -552,14 +561,88 @@ class ShardedTrainStep:
         # placements (TDX101; the optimizer-state lesson applied to the
         # step itself)
         p_sh, o_sh = donated_carry_shardings(params, opt_state)
-        out_sh = (p_sh, o_sh, None, None) if num_on else (p_sh, o_sh, None)
-        self._jitted = jax.jit(
-            step, donate_argnums=(0, 1), out_shardings=out_sh
+        self._out_sh = (
+            (p_sh, o_sh, None, None) if num_on else (p_sh, o_sh, None)
         )
+        self._step_fn = step
+        # "auto" blocks recompute until _resolve_remat has looked
+        self._set_jitted(self._jit_under("full"))
+        del spec_tree
+
+    def _jit_under(self, auto: str):
+        """The step jitted with ``"auto"`` blocks resolved to ``auto``:
+        the scope is set inside the traced function, one jitted callable
+        per choice (jit does not key its cache on the scope)."""
+        inner = self._step_fn
+
+        def step(params, opt_state, batch, hook_step):
+            with remat_scope(auto) as scope:
+                out = inner(params, opt_state, batch, hook_step)
+            self.remat_named = frozenset(scope.named)
+            return out
+
+        return jax.jit(step, donate_argnums=(0, 1), out_shardings=self._out_sh)
+
+    def _set_jitted(self, jitted) -> None:
         from ..obs.recompile import track_jit_cache
 
-        track_jit_cache("sharded_train_step", self._jitted)
-        del spec_tree
+        self._jitted = jitted
+        track_jit_cache("sharded_train_step", jitted)
+
+    def _resolve_remat(self, args: tuple) -> None:
+        """Choose what "auto" blocks save, once, from concrete arguments.
+
+        Where the backend reports an HBM budget, the step is lowered and
+        compiled with the blocks keeping their matmul outputs, and that
+        executable is kept (the first call finds it in jit's cache: one
+        compile) when arguments + outputs - aliased + temps fit the
+        budget.  Otherwise, and where there is no budget (the CPU mesh),
+        the blocks recompute their forward in the backward."""
+        from ..obs.memory import device_hbm_budget
+        from ..utils import compat
+
+        budget = device_hbm_budget()
+        self.hbm_budget_bytes = budget
+        self._remat_auto = "full"
+        if budget is None:
+            return
+        saving = self._jit_under("dots")
+        try:
+            compiled = saving.lower(*args).compile()
+        except jax.errors.JaxRuntimeError as e:
+            # a program that cannot fit is refused by the chip's compiler
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            return
+        ma = compat.compiled_memory_analysis(compiled)
+        if ma is not None:
+            self.step_projected_peak_bytes = (
+                ma.get("arg_bytes", 0) + ma.get("out_bytes", 0)
+                - ma.get("alias_bytes", 0) + ma.get("temp_bytes", 0)
+            )
+        peak = self.step_projected_peak_bytes
+        # a step with no "auto" block is the same program either way:
+        # keep the one already compiled
+        if "auto" not in self.remat_named or (
+            peak is not None and peak <= budget
+        ):
+            self._remat_auto = "dots"
+            self._set_jitted(saving)
+
+    @property
+    def remat_resolved(self) -> Optional[str]:
+        """What the step's block checkpoints save: ``"dots"`` / ``"full"``
+        where they asked for "auto", ``"explicit:<policy>"`` where they
+        named one, ``"none"`` for a step with no block checkpoint; None
+        before the step has been traced."""
+        named = self.remat_named
+        if named is None or self._remat_auto is None:
+            return None
+        if "auto" in named:
+            return self._remat_auto
+        if named:
+            return "explicit:" + ",".join(sorted(named))
+        return "none"
 
     def __call__(self, params: Any, opt_state: Any, batch: Any):
         """Run one step.  Returns (params, opt_state, loss).
@@ -573,7 +656,15 @@ class ShardedTrainStep:
         hook_step = self.hook_state.step_args()
         if hook_step is None:
             hook_step = jnp.int32(0)
-        out = self._jitted(params, opt_state, batch, hook_step)
+        args = (params, opt_state, batch, hook_step)
+        # never resolved on tracers (a step traced inside an outer jit
+        # runs the policy already resolved, or "full" before that)
+        if self._remat_auto is None and not any(
+            isinstance(x, jax.core.Tracer)
+            for x in jax.tree_util.tree_leaves(args)
+        ):
+            self._resolve_remat(args)
+        out = self._jitted(*args)
         self.hook_state.advance()
         if len(out) == 4:
             params, opt_state, loss, self.last_digests = out

@@ -500,6 +500,25 @@ class Trainer:
         except Exception:
             self.cost_card = None
 
+    def _log_remat(self) -> None:
+        """Log once what the step's block checkpoints save, where the
+        step chose it against the device's memory (ShardedTrainStep's
+        ``remat_resolved``)."""
+        budget = getattr(self.step, "hbm_budget_bytes", None)
+        resolved = getattr(self.step, "remat_resolved", None)
+        if budget is None or resolved is None:
+            return
+        self.log_fn(
+            {
+                "step": self.global_step,
+                "remat": resolved,
+                "step_projected_peak_bytes": getattr(
+                    self.step, "step_projected_peak_bytes", None
+                ),
+                "hbm_budget_bytes": budget,
+            }
+        )
+
     def _harvest_numerics(self) -> None:
         """Fold the step's fused digests (if any) into the numerics book.
 
@@ -618,6 +637,7 @@ class Trainer:
                 # the cost observatory's card (one extra compile) rides
                 # the same warmup boundary, booked as compile overhead
                 self._capture_cost_card(batch)
+                self._log_remat()
                 self._t_compile += time.time() - t_warm0
                 self.flight.record(
                     "warmup",
@@ -834,6 +854,24 @@ class Trainer:
                             m[name]
                         )
                     )
+            resolved = getattr(self.step, "remat_resolved", None)
+            if resolved is not None:
+                # what the step's block checkpoints keep, and the checked
+                # program's projected peak against the device's budget
+                fams.append(
+                    MetricFamily(
+                        f"{prefix}_remat_saves_dots", "gauge",
+                        "1 where the step's blocks keep their matmul "
+                        "outputs for the backward",
+                    ).add(float(resolved in ("dots", "explicit:dots")),
+                          resolved=resolved)
+                )
+                for name in ("step_projected_peak_bytes", "hbm_budget_bytes"):
+                    v = getattr(self.step, name, None)
+                    if v is not None:
+                        fams.append(
+                            MetricFamily(f"{prefix}_{name}", "gauge").add(v)
+                        )
             book = self.numerics_book
             if book is not None and book.harvests:
                 fams.extend(book.collector(prefix=f"{prefix}_numerics")())
