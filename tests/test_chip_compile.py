@@ -376,6 +376,7 @@ def test_serve_decode_program_compiles_from_the_packed_state(
 # 32 slots of 8192 rows; 32 heads; a latent row of 512 + 64 lanes stored
 # on 640; 128 experts of 2048 x 768, top 6).
 K_B, K_L, K_H, K_W, K_R = 32, 8192, 32, 640, 512
+_DYNAMIC = -(2**63)  # a grid bound known only at run time
 K_E, K_D, K_F, K_TOP = 128, 2048, 768, 6
 
 
@@ -383,8 +384,9 @@ def test_latent_decode_step_leaves_the_cache_in_place(one_chip, monkeypatch):
     """One layer's latent decode write + attend, the cache donated, as
     the engine's decode program runs it: ``tdx_latent_decode_attention``
     is handed the slab as it is stored, so each visible row is the
-    operand of that one call, and nothing of the cache's size is copied
-    or relayouted.  (Stored 576 lanes wide the slab failed this: 576 is
+    operand of that one call, its grid is the traced count of visible
+    row blocks, and nothing of the cache's size is copied or
+    relayouted.  (Stored 576 lanes wide the slab failed this: 576 is
     no multiple of 128, the compiler stored it rows-minor and relayouted
     1.3 GB a layer and step.)"""
     from torchdistx_tpu.ops.attention import latent_slot_cached_attention
@@ -404,6 +406,11 @@ def test_latent_decode_step_leaves_the_cache_in_place(one_chip, monkeypatch):
         ((K_B, K_L, K_W), jnp.bfloat16), donate=(3,),
     )
     assert _kernel_names(text) == ["tdx_latent_decode_attention"]
+    # one grid axis whose bound is traced (MLIR's dynamic size): a step
+    # per visible (slot, row block), where a fixed (32, 16) grid of
+    # 512-row blocks ran two thirds of its steps on rows no slot sees
+    assert _has_grid(text, (_DYNAMIC,))
+    assert not _has_grid(text, (K_B, K_L // 512))
     offenders = _relayouts_of_the_cache(text, K_B * K_L * K_W)
     assert not offenders, offenders
 

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from torchdistx_tpu.ops.attention import latent_slot_cached_attention
 from torchdistx_tpu.ops.latent_decode_attention import (
+    _work_list,
     latent_attend,
     latent_decode_attention,
 )
@@ -112,6 +113,99 @@ class TestKernelMatchesJnpPath:
             _dense(q[..., :W], cache[..., :W], pos, R, 0.2),
             atol=3e-2,
         )
+
+
+class TestVisibleGrid:
+    """One grid step per visible (slot, row block) pair: a slot walks its
+    blocks ``0 .. min(pos, rows - 1) // block_k`` in order and no
+    further, slots one after the other."""
+
+    ROWS, BLOCK_K = 64, 16
+
+    @pytest.mark.parametrize(
+        "positions",
+        [
+            [0, 15, 16, 63, 70],  # 0, block_k - 1, block_k, rows - 1, past
+            [0, 63],  # a slot at depth 0 beside one at full depth
+            [0, 3, 9, 15],  # every slot in one block
+            [63, 0, 40, 0],  # the deep slot first
+        ],
+        ids=["depths", "empty-beside-full", "one-block-each", "deep-first"],
+    )
+    def test_matches_jnp_path(self, positions):
+        q, cache, pos = _case(
+            np.random.RandomState(7), len(positions), 8, self.ROWS, positions
+        )
+        kw = dict(value_width=R, scale=0.2)
+        want = latent_attend(q, cache, pos, **kw)
+        got = latent_decode_attention(
+            q, cache, pos, block_k=self.BLOCK_K, **kw
+        )
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+
+    @pytest.mark.parametrize(
+        "positions", [[0, 15, 16, 63, 70], [0, 63], [5, 5, 5], [-1, 17]]
+    )
+    def test_work_list(self, positions):
+        steps, slot_of, block_of, last = (
+            np.asarray(a) for a in _work_list(
+                jnp.asarray(positions, jnp.int32), self.ROWS, self.BLOCK_K
+            )
+        )
+        n_k = self.ROWS // self.BLOCK_K
+        want_last = [min(max(p, 0), self.ROWS - 1) // self.BLOCK_K
+                     for p in positions]
+        np.testing.assert_array_equal(last, want_last)
+        assert int(steps) == sum(n + 1 for n in want_last)
+        assert slot_of.shape == block_of.shape == (len(positions) * n_k,)
+        walked = list(zip(slot_of[:steps].tolist(), block_of[:steps].tolist()))
+        # slots in order, each slot's blocks 0 .. last ascending, once
+        assert walked == [
+            (b, k) for b, n in enumerate(want_last) for k in range(n + 1)
+        ]
+        # the kernel starts a slot where block_of is 0 and emits where it
+        # is the slot's last: one of each a slot
+        firsts = [b for b, k in walked if k == 0]
+        lasts = [b for b, k in walked if k == last[b]]
+        assert firsts == lasts == list(range(len(positions)))
+        # entries past the grid repeat the last pair: in bounds, no DMA
+        assert all(
+            (b, k) == walked[-1]
+            for b, k in zip(slot_of[steps:], block_of[steps:])
+        )
+
+    def test_slot_with_no_visible_row_is_written(self):
+        """A negative position still takes the slot's first step, whose
+        arithmetic is skipped: its output is zeros, as under the fixed
+        grid, and the other slots are untouched."""
+        q, cache, _ = _case(np.random.RandomState(8), 2, 4, self.ROWS, [0, 0])
+        kw = dict(value_width=R, scale=0.2)
+        got = latent_decode_attention(
+            q, cache, jnp.asarray([-1, 40], jnp.int32),
+            block_k=self.BLOCK_K, **kw,
+        )
+        np.testing.assert_array_equal(got[0], 0.0)
+        np.testing.assert_allclose(
+            got[1],
+            latent_attend(q, cache, jnp.asarray([0, 40], jnp.int32), **kw)[1],
+            rtol=2e-6, atol=2e-6,
+        )
+
+    def test_grid_bound_is_traced(self):
+        """The step count is data: one grid axis whose bound comes from
+        the positions, so no step of a call is a pruned one."""
+        q, cache, pos = _case(np.random.RandomState(9), 2, 4, 32, [4, 30])
+        jaxpr = jax.make_jaxpr(
+            lambda q, c, p: latent_decode_attention(
+                q, c, p, value_width=R, scale=0.2, block_k=8
+            )
+        )(q, cache, pos)
+        (eqn,) = [
+            e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"
+        ]
+        mapping = eqn.params["grid_mapping"]
+        assert len(mapping.grid) == 1
+        assert mapping.num_dynamic_grid_bounds == 1
 
 
 class TestRouting:

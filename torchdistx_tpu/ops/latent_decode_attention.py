@@ -20,9 +20,19 @@ are the matmul's rows (32 of them: no padding up to a sublane minimum
 as in the GQA kernel).
 
 The cache is consumed as the engine stores it, ``(slots, rows, R + r)``
-(``serve/kv_cache.py``), grid ``(slot, row block)``, per-slot depths
-scalar-prefetched with the block pruning and DMA clamp of
-``ops/decode_attention.py``.  The matmuls run in the cache's dtype with
+(``serve/kv_cache.py``).  The grid is ONE axis of a step per VISIBLE
+(slot, row block) pair, its bound traced: :func:`_work_list` builds the
+pairs from the per-slot depths on the device, and they are
+scalar-prefetched for the index maps.  A slot's blocks run in ascending
+order, slots one after the other, so Pallas's pipeline has the next
+block's DMA in flight across a slot boundary too.  A fixed ``(slot, row
+block)`` grid would prune the blocks past a slot's depth, but a pruned
+step still costs 0.2-0.35 us on a v5e, and at the kanana cell's depths
+two thirds of such a grid's steps are pruned
+(``scripts/bench_latent_decode_attention.py``; PERF.md §6).  With no
+pruned steps, a block of 1,024 rows (1.3 MB) is the cell's fastest:
+fewer steps for ~512 rows a slot read past its depth on average.  The
+matmuls run in the cache's dtype with
 float32 accumulation (bf16 operands on the chip: at 60 FLOPs a byte the
 kernel would be compute-bound in float32), the softmax in float32.
 
@@ -78,10 +88,45 @@ def latent_attend(
     return out.astype(out_dtype)
 
 
-def _kernel(pos_ref, q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *,
-            scale, block_k, n_k, value_width):
-    b = pl.program_id(0)
-    kk = pl.program_id(1)
+def _work_list(positions: jax.Array, rows: int, block_k: int):
+    """The grid of one call, one step per VISIBLE (slot, row block)
+    pair: ``(steps, slot_of, block_of, last)``.
+
+    Slot ``b`` sees blocks ``0 .. last[b]``, ``last[b] = min(positions[b],
+    rows - 1) // block_k`` (a negative position still takes block 0,
+    whose arithmetic the kernel then skips, so the slot's output is
+    written all the same: zeros).  ``steps = sum(last + 1)``; step ``s <
+    steps`` reads block ``block_of[s]`` of slot ``slot_of[s]``, slots in
+    order and each slot's blocks ascending, so a slot's first step is
+    where ``block_of`` is 0 and its last where ``block_of == last[slot]``.
+    ``slot_of`` / ``block_of`` hold ``b * rows / block_k`` entries, the
+    most a call can take; those past ``steps`` repeat the last pair (the
+    grid never reaches them)."""
+    b = positions.shape[0]
+    last = jnp.clip(positions.astype(jnp.int32), 0, rows - 1) // block_k
+    n_vis = last + 1
+    # compares and sums of (steps, slots) and (slots, slots) masks only:
+    # a gather or a cumsum of these few numbers lowers to a string of
+    # small operations on the chip, ~25 us a call where this is one
+    slots = jnp.arange(b, dtype=jnp.int32)
+    ends = jnp.sum(
+        jnp.where(slots[None, :] <= slots[:, None], n_vis[None, :], 0), axis=1
+    )
+    s = jnp.arange(b * (rows // block_k), dtype=jnp.int32)[:, None]
+    done = ends[None, :] <= s  # slots wholly before step s
+    slot = jnp.sum(done, axis=1, dtype=jnp.int32)
+    block = s[:, 0] - jnp.sum(jnp.where(done, n_vis[None, :], 0), axis=1)
+    tail = slot == b
+    slot = jnp.where(tail, b - 1, slot)
+    block = jnp.where(tail, last[b - 1], block)
+    return ends[b - 1], slot, block, last
+
+
+def _kernel(pos_ref, slot_ref, block_ref, last_ref, q_ref, c_ref, o_ref,
+            acc_ref, m_ref, l_ref, *, scale, block_k, n_k, value_width):
+    step = pl.program_id(0)
+    b = slot_ref[step]
+    kk = block_ref[step]
     pos = pos_ref[b]
     r = value_width
 
@@ -118,7 +163,7 @@ def _kernel(pos_ref, q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(kk * block_k <= pos)  # blocks past the slot's depth: skipped
+    @pl.when(kk * block_k <= pos)  # false only for a negative position
     def _compute():
         s = scores()
         m_prev = m_ref[...]
@@ -129,7 +174,7 @@ def _kernel(pos_ref, q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *,
         acc_ref[...] = acc_ref[...] * correction + pv(p)
         m_ref[...] = m_new
 
-    @pl.when(kk == n_k - 1)
+    @pl.when(kk == last_ref[b])
     def _emit():
         o_ref[...] = (
             acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
@@ -144,7 +189,7 @@ def latent_decode_attention(
     *,
     value_width: int,
     scale: float,
-    block_k: int = 512,
+    block_k: int = 1024,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """``q``: (B, H, W) absorbed queries ``[q~ ; q_rope]`` (positional
@@ -167,21 +212,22 @@ def latent_decode_attention(
     rows = cache.shape[1]
     block_k = _shrink_block(block_k, rows)
     n_k = rows // block_k
+    steps, slot_of, block_of, last = _work_list(positions, rows, block_k)
 
-    def c_index(bb, kk, pos_ref):
-        last = jnp.minimum(pos_ref[bb], rows - 1) // block_k
-        return (bb, jnp.minimum(kk, last), 0)
+    def slot_index(step, pos_ref, slot_ref, block_ref, last_ref):
+        return (slot_ref[step], 0, 0)
+
+    def c_index(step, pos_ref, slot_ref, block_ref, last_ref):
+        return (slot_ref[step], block_ref[step], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, n_k),
+        num_scalar_prefetch=4,
+        grid=(steps,),
         in_specs=[
-            pl.BlockSpec((None, h, w), lambda bb, kk, _: (bb, 0, 0)),
+            pl.BlockSpec((None, h, w), slot_index),
             pl.BlockSpec((None, block_k, w), c_index),
         ],
-        out_specs=pl.BlockSpec(
-            (None, h, value_width), lambda bb, kk, _: (bb, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((None, h, value_width), slot_index),
         scratch_shapes=[
             pltpu.VMEM((h, value_width), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -197,7 +243,10 @@ def latent_decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, h, value_width), q.dtype),
         name=KERNEL_NAME,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(positions.astype(jnp.int32), q.astype(cache.dtype), cache)
+    )(
+        positions.astype(jnp.int32), slot_of, block_of, last,
+        q.astype(cache.dtype), cache,
+    )
